@@ -298,6 +298,12 @@ class TimeSeriesShard:
             self.stores[schema_name] = store
         return store
 
+    def schema_of(self, part_key: PartKey) -> Optional[str]:
+        """Schema name of the partition this shard holds for `part_key`,
+        None when it holds none (partition identity is the key alone)."""
+        pid = self.part_set.get(part_key.to_bytes())
+        return None if pid is None else self.partitions[pid].schema_name
+
     def get_or_create_partition(self, part_key: PartKey, schema_name: str,
                                 start_time_ms: int) -> PartitionInfo:
         """ref: TimeSeriesShard.getOrAddPartitionAndIngest:1249 +

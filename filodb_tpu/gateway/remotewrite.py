@@ -38,8 +38,10 @@ log = logging.getLogger("filodb.remotewrite")
 
 SCHEMA = "gauge"          # remote_write samples are untyped doubles; the
                           # gauge schema is the Prometheus-wire-compatible
-                          # landing shape (counters still rate() correctly:
-                          # correction happens at query time)
+                          # landing shape for a NEW series (counters still
+                          # rate() correctly: correction happens at query
+                          # time).  A series a local shard already holds
+                          # keeps its schema — see _build_slabs
 
 
 class RemoteWriteSink:
@@ -95,19 +97,20 @@ class RemoteWriteSink:
         last_seq = -1
         seqs = []
         if self.wal is not None:
-            for shard_num, keys, ts, vals in slabs:
+            for shard_num, schema, keys, ts, vals in slabs:
                 last_seq = self.wal.append_grid(
-                    shard_num, SCHEMA, keys, ts, {"value": vals},
-                    wait=False)
+                    shard_num, schema, keys, ts,
+                    {self.schemas[schema].value_column: vals}, wait=False)
                 seqs.append(last_seq)
         t_wal = _time.perf_counter()
         repl_s = 0.0
-        for i, (shard_num, keys, ts, vals) in enumerate(slabs):
+        for i, (shard_num, schema, keys, ts, vals) in enumerate(slabs):
+            col = self.schemas[schema].value_column
             shard = self.memstore.get_shard(self.dataset, shard_num)
             offset = seqs[i] if self.wal is not None else -1
             if shard is not None:
-                got = shard.ingest_columns(SCHEMA, keys, ts,
-                                           {"value": vals}, offset=offset)
+                got = shard.ingest_columns(schema, keys, ts,
+                                           {col: vals}, offset=offset)
                 n += got
                 dropped += ts.size - got
             elif self.replicator is None:
@@ -122,7 +125,7 @@ class RemoteWriteSink:
             if self.replicator is not None:
                 tr = _time.perf_counter()
                 res = self.replicator.replicate(
-                    shard_num, SCHEMA, keys, ts, {"value": vals},
+                    shard_num, schema, keys, ts, {col: vals},
                     seq=offset, require_primary=shard is None)
                 repl_s += _time.perf_counter() - tr
                 if shard is None:
@@ -163,18 +166,25 @@ class RemoteWriteSink:
     # -------------------------------------------------------- slab build
 
     def _build_slabs(self, series, stats=None
-                     ) -> List[Tuple[int, List[PartKey], np.ndarray,
+                     ) -> List[Tuple[int, str, List[PartKey], np.ndarray,
                                      np.ndarray]]:
-        """Group the request's series into rectangular (shard, keys,
-        ts [S, k], values [S, k]) slabs: one per (shard, sample-count)
-        pair, matching RecordBatch.from_grid's grid contract.  A scrape
-        push's natural shape — every series carrying the same k samples
-        — collapses to one slab per shard.  With `stats`, the per-tenant
+        """Group the request's series into rectangular (shard, schema,
+        keys, ts [S, k], values [S, k]) slabs: one per
+        (shard, schema, sample-count), matching RecordBatch.from_grid's
+        grid contract.  A scrape push's natural shape — every series
+        carrying the same k samples — collapses to one slab per shard.
+        A series the local shard already holds under another
+        single-value schema (a counter first ingested through a typed
+        door) lands in THAT schema's store: the partition's rows live
+        there, and a gauge-store write addressed by them would ack a
+        sample no query can read back.  With `stats`, the per-tenant
         newest sample timestamp is tracked in the same pass (the
         ingest-to-queryable freshness input — zero extra iteration)."""
         part_schema = self.schemas.part
         newest = stats.newest_ts_ms if stats is not None else None
-        by_group: Dict[Tuple[int, int], List[Tuple[PartKey, list]]] = {}
+        by_group: Dict[Tuple[int, str, int],
+                       List[Tuple[PartKey, list]]] = {}
+        local: Dict[int, object] = {}
         for ts_msg in series:
             if not ts_msg.samples:
                 continue
@@ -192,10 +202,20 @@ class RemoteWriteSink:
                     self.spread.spread_for(pk.shard_key()))
             else:
                 shard_num = 0
-            by_group.setdefault((shard_num, len(ts_msg.samples)),
+            if shard_num not in local:
+                local[shard_num] = self.memstore.get_shard(self.dataset,
+                                                           shard_num)
+            held = (local[shard_num].schema_of(pk)
+                    if local[shard_num] is not None else None)
+            schema = SCHEMA
+            if held is not None and held != SCHEMA:
+                cols = self.schemas[held].data_columns
+                if len(cols) == 1 and cols[0].col_type == "double":
+                    schema = held
+            by_group.setdefault((shard_num, schema, len(ts_msg.samples)),
                                 []).append((pk, ts_msg.samples))
         slabs = []
-        for (shard_num, k), rows in by_group.items():
+        for (shard_num, schema, k), rows in by_group.items():
             keys = [pk for pk, _ in rows]
             # one [S, k, 2] pass over the decoded tuples, then split —
             # the only per-sample cost is the protobuf decode itself
@@ -203,7 +223,7 @@ class RemoteWriteSink:
                              dtype=np.float64)          # [S, k, 2]
             vals = np.ascontiguousarray(mat[:, :, 0])
             ts = np.ascontiguousarray(mat[:, :, 1]).astype(np.int64)
-            slabs.append((shard_num, keys, ts, vals))
+            slabs.append((shard_num, schema, keys, ts, vals))
         return slabs
 
 

@@ -56,7 +56,9 @@ def make_mesh(n_shard: int, n_time: int = 1,
     unused count is logged once and the chosen shape exposed as gauges
     (`mesh_shard_axis` / `mesh_time_axis` / `mesh_unused_devices`)."""
     from filodb_tpu.utils.metrics import log_error_once, registry
-    devs = list(devices if devices is not None else jax.devices())
+    # local devices, like the mirror placer: per-device dispatch commits
+    # operands to each mesh device, which this process must address
+    devs = list(devices if devices is not None else jax.local_devices())
     need = n_shard * n_time
     if len(devs) < need:
         raise ValueError(f"need {need} devices, have {len(devs)}")
@@ -331,8 +333,8 @@ def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase,
                      kind: str = "rate_family", ragged: bool = False):
     """LEGACY A/B path: the Pallas fused kernel traced INSIDE shard_map.
 
-    Kept only for measurement tooling (tools/tpu_extra.py, the driver
-    dryrun, bench.py multichip's inversion probe): on a multi-device
+    Kept only for measurement tooling (the driver dryrun, bench.py
+    multichip's inversion probe): on a multi-device
     mesh this composition collapses ~30x vs the general path
     (MULTICHIP_r05.json) because the kernel re-traces and schedules per
     mesh program.  Production queries route through the per-device
@@ -1121,8 +1123,6 @@ class MeshExecutor:
         analogue of the leaf path's fused_leaf_agg_batch.  Returns the
         finished [G, W] arrays in panel order, or None when the shared
         gate rejects (callers then take the general path per panel)."""
-        import os
-
         from filodb_tpu.ops import pallas_fused as pf
         shared = packed.shared_ts_row is not None and packed.gsize is not None
         dense = packed.dense
@@ -1169,8 +1169,8 @@ class MeshExecutor:
                     panels=max(len(kidx), 1),
                     gather=pf.gather_default(kind_k)) is None:
                 return None
-            interpret = jax.default_backend() != "tpu"
-            if interpret and not os.environ.get("FILODB_TPU_FUSED_INTERPRET"):
+            interpret = pf.kernel_mode()
+            if interpret is None:
                 # no MXU here: the per-device unit becomes the host fused
                 # leaf (ops/hostleaf), same dispatch + partial-merge shape
                 # — the single-chip cost-based router's host path scaled

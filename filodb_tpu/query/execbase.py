@@ -161,8 +161,11 @@ def _fused_vals_budget() -> int:
 
         from filodb_tpu.core.devicecache import DEFAULT_HBM_LIMIT_BYTES
         mirror_limit = _MIRROR_LIMIT_SEEN or DEFAULT_HBM_LIMIT_BYTES
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
+        # the budget applies to every local device (sharded mirrors pin
+        # each shard's padded copy to its own chip): size it by the
+        # smallest one, not by whichever happens to be first
+        limit = min((int((d.memory_stats() or {}).get("bytes_limit", 0))
+                     for d in jax.local_devices()), default=0)
         if limit:
             budget = min(budget,
                          max(1 << 30, limit - mirror_limit - (2 << 30)))

@@ -212,10 +212,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         if fn in pf.MINMAX_FNS:
             # pure-XLA reduce_window path — any backend, no Pallas
             return self._fused_minmax(data, t0, t1, wends, eval_wends)
-        import jax
-        backend = jax.default_backend()
-        interpret = backend != "tpu"
-        if interpret and not os.environ.get("FILODB_TPU_FUSED_INTERPRET"):
+        interpret = pf.kernel_mode()
+        if interpret is None:
             return None                 # kernel is MXU-targeted
         if fn in ("rate", "increase") and not data.precorrected:
             return None

@@ -676,31 +676,25 @@ class FiloServer:
             from filodb_tpu.utils.traceexport import TraceExporter
             self.trace_exporter = TraceExporter(
                 self.config.trace_export_url).start()
-        self.warmup_thread = None
+        # compile the configured headline shapes BEFORE the node declares
+        # itself serving (first boot pays real XLA compiles; restarts
+        # deserialize from the persistent cache wired in __init__) so the
+        # first dashboard query finds its program ready — the reference's
+        # "query path is always ready" stance (ref: coordinator/../
+        # QueryActor.scala:98-117).  A shape the device's compiler refuses
+        # fails start-up, as parse_warmup_shapes does for a typo: a node
+        # deployed with a warm-up list must not serve without it.
         shapes = parse_warmup_shapes(self.config.warmup_shapes)
         if shapes:
-            # compile the configured headline shapes off the boot path
-            # (first boot pays real XLA compiles; restarts deserialize
-            # from the persistent cache wired in __init__) so the first
-            # dashboard query finds its program ready — the reference's
-            # "query path is always ready" stance (ref: coordinator/../
-            # QueryActor.scala:98-117)
-            import threading
-
-            def _warm():
-                from filodb_tpu.ops import pallas_fused as pf
-                from filodb_tpu.utils.metrics import registry
-                for (s, t, w, g) in shapes:
-                    try:
-                        secs = pf.warmup_compile(s, t, w, g)
-                        registry.gauge("warmup_compile_seconds") \
-                            .update(secs)
-                    except Exception:  # noqa: BLE001 — warmup is advisory
-                        registry.counter("warmup_compile_errors").increment()
-
-            self.warmup_thread = threading.Thread(
-                target=_warm, name="filodb-warmup", daemon=True)
-            self.warmup_thread.start()
+            from filodb_tpu.ops.pallas_fused import warmup_compile
+            from filodb_tpu.utils.metrics import registry
+            for (s, t, w, g) in shapes:
+                try:
+                    secs = warmup_compile(s, t, w, g)
+                except Exception:
+                    registry.counter("warmup_compile_errors").increment()
+                    raise
+                registry.gauge("warmup_compile_seconds").update(secs)
         if background_flush:
             from filodb_tpu.core.flush import FlushScheduler
             for dc in self.datasets:

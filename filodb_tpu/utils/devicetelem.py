@@ -72,12 +72,22 @@ DEFAULT_MAX_ENTRIES = 512
 _HIGH_WATER_MIN_STEP = 1 << 20
 
 
+_DEFAULT_KEY: Optional[str] = None
+
+
 def _dev_key(device) -> str:
     """Stable label value for a device: jax Devices stringify to e.g.
-    'TFRT_CPU_0' / 'TPU_3', None means 'the default device'."""
-    if device is None:
-        return "default"
-    return str(device)
+    'TFRT_CPU_0' / 'TPU_3'.  None means 'wherever jax places uncommitted
+    work', which is the first local device — booked under ITS label, so a
+    one-chip host's /admin/devices shows its work on the chip and not on
+    a phantom second entry beside an idle one."""
+    if device is not None:
+        return str(device)
+    global _DEFAULT_KEY
+    if _DEFAULT_KEY is None:
+        import jax
+        _DEFAULT_KEY = str(jax.local_devices()[0])
+    return _DEFAULT_KEY
 
 
 class _DeviceState:

@@ -1,12 +1,8 @@
-"""Version shims for the jax APIs the mesh/conformance code relies on.
+"""The jax entry points the mesh and conformance code share.
 
-The distributed path was written against the consolidated top-level API
-(``jax.shard_map``, ``jax.enable_x64``); older jax releases (the 0.4.x
-line this container ships) expose the same functionality only under
-``jax.experimental``.  Newer releases in turn REMOVED the experimental
-paths, so neither spelling is safe to hard-code — 127 tier-1 tests were
-failing on that exact skew (PR 3's A/B check first measured it).  All
-callers import the two names from here.
+``shard_map`` and ``enable_x64`` are jax's own (``jax.shard_map`` with
+``check_vma``, ``jax.enable_x64``); callers import them from here so the
+spelling lives in one place.
 
 Also home to ``has_ici()`` — whether cross-device collectives ride a real
 chip interconnect (the partial-merge path in parallel/mesh.py routes on
@@ -18,41 +14,14 @@ import jax
 
 __all__ = ["shard_map", "enable_x64", "has_ici"]
 
-
-_new_shard_map = getattr(jax, "shard_map", None)
-if _new_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-else:
-    _old_shard_map = None
+enable_x64 = jax.enable_x64
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across versions.
-
-    check_vma follows the NEW api's name (the varying-mesh-axes checker);
-    on old jax it maps onto the equivalent ``check_rep``.  None leaves
-    the version's default in place.
-    """
-    if _new_shard_map is not None:
-        if check_vma is None:
-            return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs)
-        try:
-            return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            # 0.5.x-era top-level export still spells it check_rep
-            return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_rep=check_vma)
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:  # 0.4.x: context-manager form lives under experimental
-    from jax.experimental import enable_x64  # noqa: F401
+    """``jax.shard_map``; check_vma=None leaves jax's default in place."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def has_ici() -> bool:
@@ -61,7 +30,4 @@ def has_ici() -> bool:
     plain host-side partial merge is both faster and deterministic, so
     parallel/mesh.py's partial-merge helper falls back to
     ops/agg.reduce_phase semantics."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001 — uninitialized backend: no ICI
-        return False
+    return jax.default_backend() == "tpu"

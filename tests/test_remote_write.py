@@ -142,6 +142,55 @@ def test_write_ingest_promql_and_remote_read_roundtrip():
         srv.shutdown()
 
 
+def test_write_to_a_series_held_as_counter_is_read_back(tmp_path):
+    """A series first ingested through a typed door (prom-counter) keeps
+    its schema when remote_write appends to it: the acknowledged sample
+    must be queryable, and survive a WAL restart, not land in a gauge
+    store addressed by another store's rows (chip_smoke.py found it)."""
+    from filodb_tpu.core.partkey import PartKey
+    srv = _server(tmp_path, wal=True)
+    series = _series(6, 1)
+    keys = [PartKey.make("http_req_total",
+                         {k: v for k, v in s.labels if k != "__name__"})
+            for s in series]
+    mapper, spread = srv.mappers["prometheus"], srv.spreads["prometheus"]
+    for i, pk in enumerate(keys):
+        sh = srv.memstore.get_shard("prometheus", mapper.ingestion_shard(
+            pk.shard_key_hash(), pk.partition_hash(),
+            spread.spread_for(pk.shard_key())))
+        sh.ingest_columns("prom-counter", [pk],
+                          np.array([[START - 10_000]]),
+                          {"count": np.array([[float(i)]])})
+
+    def newest(server):
+        status, resp = server.api.handle(
+            "GET", "/api/v1/query",
+            {"query": "http_req_total", "time": str(START // 1000)}, b"")
+        assert status == 200
+        return sorted(float(r["value"][1]) for r in resp["data"]["result"])
+    try:
+        status, _ = srv.api.handle("POST", "/api/v1/write", {},
+                                   _payload(series))
+        assert status == 204
+        want = [float(i * 100) for i in range(6)]
+        assert newest(srv) == want
+        assert all(st.num_series == 0
+                   for sh in srv.memstore.shards_for("prometheus")
+                   for name, st in sh.stores.items() if name == "gauge")
+    finally:
+        srv.shutdown()
+    srv2 = _server(tmp_path, wal=True)
+    try:
+        # only the remote-written samples were logged: they replay into
+        # the schema they were acknowledged in
+        assert newest(srv2) == want
+        assert {name for sh in srv2.memstore.shards_for("prometheus")
+                for name, st in sh.stores.items()
+                if st.num_series} == {"prom-counter"}
+    finally:
+        srv2.shutdown()
+
+
 def test_write_ragged_sample_counts_slab_grouping():
     """Series with different sample counts land via separate rectangular
     slabs — same totals, no per-sample path."""
