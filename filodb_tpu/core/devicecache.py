@@ -631,7 +631,11 @@ class DeviceMirror:
                 registry.counter(
                     "device_mirror_incremental_errors").increment()
                 log_error_once("device_mirror_incremental", e)
-        return self._refresh(store)
+        # the full upload (host prep + device_put of the whole store), on
+        # whichever thread pays it: a query's, or the background rebuild's
+        from filodb_tpu.utils.metrics import span
+        with span("mirror.full_upload"):
+            return self._refresh(store)
 
     # ------------------------------------------------- background rebuild
 
@@ -687,7 +691,7 @@ class DeviceMirror:
         try:
             with job.tick():
                 job.set_progress(f"shard {sn}")
-                with span("mirror_bg_rebuild"):
+                with span("mirror_bg_rebuild", hist=True):
                     with shard._write_locked("mirror_bg_rebuild"):
                         ok = self.ensure_fresh(store)
             if ok:

@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Dict, Optional
 
+from filodb_tpu.utils.metrics import span
+
 _log = logging.getLogger("filodb.flush")
 
 
@@ -146,46 +148,47 @@ class FlushScheduler:
             # shard, so the declared schedule the lag histogram measures
             # against is the per-group tick, not the full rotation
             self.job.interval_s = tick
-            with self.job.tick() as jt:
-                self.job.set_progress(
-                    f"group {group + 1}/{n_groups}, "
-                    f"{len(shards)} shard(s)")
-                wrote = 0
-                for shard in shards:
-                    if self._stop.is_set():
-                        return
-                    until = self._backoff_until.get(shard.shard_num)
-                    if until is not None and time.monotonic() < until:
-                        continue        # shard backing off after errors
-                    try:
-                        if group < shard._groups:
-                            # background flushes batch small partitions
-                            # (the write-buffer behavior); direct flush
-                            # calls seal all
-                            wrote += shard.flush_group(
-                                group,
-                                min_samples=shard.config.store
-                                .min_flush_samples)
-                            self.flushes += 1
-                            self._note_flush_ok(shard)
-                    except Exception as e:  # noqa: BLE001
-                        self._note_flush_error(shard, tick)
-                        self.job.note_error(e)
-                        _log.exception(
-                            "background flush failed shard=%d group=%d "
-                            "(streak=%d, backing off)",
-                            shard.shard_num, group,
-                            self._err_streak[shard.shard_num])
-                if wrote == 0:
-                    # a pass that PERSISTED nothing is NEUTRAL for the
-                    # job streak: empty groups and backed-off shards
-                    # prove nothing about the store, and counting them
-                    # as successes would reset the consecutive-error
-                    # streak while persists are still failing — the
-                    # /ready flip for a broken store could never engage
-                    # (per-shard streaks/backoff are tracked separately
-                    # above and unaffected)
-                    jt.skip()
+            with span("flush.pass"):
+                with self.job.tick() as jt:
+                    self.job.set_progress(
+                        f"group {group + 1}/{n_groups}, "
+                        f"{len(shards)} shard(s)")
+                    wrote = 0
+                    for shard in shards:
+                        if self._stop.is_set():
+                            return
+                        until = self._backoff_until.get(shard.shard_num)
+                        if until is not None and time.monotonic() < until:
+                            continue        # shard backing off after errors
+                        try:
+                            if group < shard._groups:
+                                # background flushes batch small partitions
+                                # (the write-buffer behavior); direct flush
+                                # calls seal all
+                                wrote += shard.flush_group(
+                                    group,
+                                    min_samples=shard.config.store
+                                    .min_flush_samples)
+                                self.flushes += 1
+                                self._note_flush_ok(shard)
+                        except Exception as e:  # noqa: BLE001
+                            self._note_flush_error(shard, tick)
+                            self.job.note_error(e)
+                            _log.exception(
+                                "background flush failed shard=%d group=%d "
+                                "(streak=%d, backing off)",
+                                shard.shard_num, group,
+                                self._err_streak[shard.shard_num])
+                    if wrote == 0:
+                        # a pass that PERSISTED nothing is NEUTRAL for the
+                        # job streak: empty groups and backed-off shards
+                        # prove nothing about the store, and counting them
+                        # as successes would reset the consecutive-error
+                        # streak while persists are still failing — the
+                        # /ready flip for a broken store could never engage
+                        # (per-shard streaks/backoff are tracked separately
+                        # above and unaffected)
+                        jt.skip()
             group += 1
             if group >= n_groups:
                 group = 0

@@ -656,7 +656,7 @@ class TimeSeriesShard:
         faults.fire("ingest.batch")
         # write-path trace: the memstore-visibility stage of an ingest
         # batch (one span per slab; stitches under the door's trace id)
-        with metrics_span("ingest_columns", dataset=self.dataset), \
+        with metrics_span("ingest_columns", hist=True, dataset=self.dataset), \
                 self._write_locked("ingest"):
             if ts.size == 0:
                 return 0
@@ -720,7 +720,7 @@ class TimeSeriesShard:
         # ingest and queries live.  The old whole-flush write_lock held
         # it >10 s per group at 131k series (soak-measured stall).
         with self._flush_lock:
-            with metrics_span("flush", dataset=self.dataset):
+            with metrics_span("flush", hist=True, dataset=self.dataset):
                 written = self._do_flush_group(group, ingestion_time_ms,
                                                min_samples)
         metrics_registry.counter("chunks_flushed",
@@ -1017,10 +1017,12 @@ class TimeSeriesShard:
             out = fn()
             if store.generation == g0:
                 return out
+            metrics_registry.counter("snapshot_read_torn").increment()
             if time.perf_counter() - t0 > 0.05:
                 torn_slow += 1
                 if torn_slow >= 2:
                     break
+        metrics_registry.counter("snapshot_read_lock_fallbacks").increment()
         with self._write_locked("query_snapshot_fallback"):
             return fn()
 

@@ -84,7 +84,7 @@ class RemoteWriteSink:
         so the spans stitch into one write-path trace."""
         import time as _time
         t0 = _time.perf_counter()
-        with metrics_span("rw_build_slabs", dataset=self.dataset):
+        with metrics_span("rw_build_slabs", hist=True, dataset=self.dataset):
             slabs = self._build_slabs(series, stats=stats)
         t_slabs = _time.perf_counter()
         n = dropped = 0

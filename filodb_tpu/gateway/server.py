@@ -89,7 +89,7 @@ class KafkaContainerSink:
         door = DoorTrace("gateway", self.topic,
                          body_bytes=sum(len(ln) for ln in lines))
         published = 0
-        with door, span("gateway_publish"):
+        with door, span("gateway_publish", hist=True):
             drops: Dict[str, int] = {}
             batches = influx_lines_to_batches(lines, self.schemas, now_ms,
                                               drops=drops)

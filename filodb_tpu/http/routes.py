@@ -24,6 +24,7 @@ import re
 from filodb_tpu.promql.lexer import ParseError, duration_to_ms
 from filodb_tpu.query.engine import QueryEngine, _prom_error_payload
 from filodb_tpu.query.rangevector import PlannerParams
+from filodb_tpu.utils.metrics import span
 
 
 class PromHttpApi:
@@ -205,7 +206,8 @@ class PromHttpApi:
                 return self._explain(eng, q, start, step, end)
             res = self.frontends[dataset].query_range(
                 q, start, step, end, planner_params)
-            payload = QueryEngine.to_prom_matrix(res)
+            with span("http.present"):
+                payload = QueryEngine.to_prom_matrix(res)
             if res.trace_id:
                 payload["traceID"] = res.trace_id
             if _want_stats(params):
@@ -250,7 +252,8 @@ class PromHttpApi:
             want_stats = _want_stats(params) or req.get("stats") in (
                 True, "true", "1", "all")
             for res in results:
-                p = QueryEngine.to_prom_matrix(res)
+                with span("http.present"):
+                    p = QueryEngine.to_prom_matrix(res)
                 if res.trace_id:
                     p["traceID"] = res.trace_id
                 if want_stats:
@@ -268,7 +271,8 @@ class PromHttpApi:
             # eng.query_instant call was a free pass around all four
             res = self.frontends[dataset].query_instant(
                 q, t, planner_params)
-            payload = QueryEngine.to_prom_vector(res)
+            with span("http.present"):
+                payload = QueryEngine.to_prom_vector(res)
             if res.trace_id:
                 payload["traceID"] = res.trace_id
             if _want_stats(params):
@@ -336,7 +340,7 @@ class PromHttpApi:
             "remote_write", dataset, headers, len(body),
             threshold_s=self._config.ingest.slow_batch_threshold_s)
         try:
-            with door, span("remote_write", dataset=dataset):
+            with door, span("remote_write", hist=True, dataset=dataset):
                 status, payload = self._remote_write_traced(
                     dataset, body, door.headers, door.stats)
         except _BadRequest as e:
@@ -365,7 +369,7 @@ class PromHttpApi:
                                                     count_samples)
         t0 = _time.perf_counter()
         try:
-            with span("rw_decode", dataset=dataset):
+            with span("rw_decode", hist=True, dataset=dataset):
                 series = remotepb.decode_write_request(
                     snappy.decompress(body))
         except (ValueError, IndexError, struct.error) as e:
@@ -391,7 +395,7 @@ class PromHttpApi:
         # org = one tenant for the whole request): an over-limit tenant
         # must not ride in behind another tenant's series
         t_adm = _time.perf_counter()
-        with span("rw_admission", dataset=dataset):
+        with span("rw_admission", hist=True, dataset=dataset):
             admitted, retry_after, rejected = admit_series(
                 series, org, self._qconfig.tenant_ingest_samples_limit)
         stats.admission_s = _time.perf_counter() - t_adm
@@ -1187,8 +1191,10 @@ class PromHttpApi:
         dispatch/ack replies and carry their node name).  GET
         /admin/traces lists known ids — `?limit=N` (default 50) keeps
         the newest N, `?origin=query|rule_eval|remote_write` filters to
-        one door's traces; /admin/traces/<id> returns the events sorted
-        by end time, answering 410 for an id the bounded ring has
+        one door's traces; /admin/traces/<id> returns the events in
+        start order (each with its span_id, parent_id and monotonic
+        start_ns / dur_ns: a tree) and the trace's wall-clock anchor,
+        answering 410 for an id the bounded ring has
         EVICTED (it existed; the buffer recycled it) vs 404 for one it
         never saw."""
         from filodb_tpu.utils.metrics import collector
@@ -1206,8 +1212,7 @@ class PromHttpApi:
             return 200, {"status": "success",
                          "data": collector.trace_ids(origin=origin,
                                                      limit=limit)}
-        evs = sorted(collector.trace(trace_id),
-                     key=lambda e: e.get("end_unix_s", 0))
+        evs = collector.trace(trace_id)
         if not evs:
             if collector.was_evicted(trace_id):
                 return 410, {"status": "error", "errorType": "gone",
@@ -1217,6 +1222,10 @@ class PromHttpApi:
                                       "spans via trace_export_url)"}
             return 404, _err(f"no trace {trace_id!r}")
         data = {"traceID": trace_id, "queryID": trace_id, "spans": evs}
+        anchor = collector.anchor(trace_id)
+        if anchor is not None:
+            # unix ns of an event = start_ns - monotonicNs + unixNs
+            data["anchor"] = {"unixNs": anchor[0], "monotonicNs": anchor[1]}
         # cross-links (PR 13): the final verdict (completed/killed/
         # deadline) and, when this query also left a slowlog record, its
         # ring seq — so trace <-> slowlog correlation works BOTH ways
@@ -1314,7 +1323,7 @@ class PromHttpApi:
             "influx", params.get("db") or self.default_dataset or "",
             headers, len(body),
             threshold_s=self._config.ingest.slow_batch_threshold_s)
-        with door, span("influx_write"):
+        with door, span("influx_write", hist=True):
             status, payload = self._influx_write(params, body,
                                                  door.stats)
         if isinstance(payload, dict):

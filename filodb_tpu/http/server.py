@@ -6,6 +6,7 @@ all route logic lives in routes.py.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import urllib.parse
@@ -13,6 +14,20 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from filodb_tpu.http.routes import PromHttpApi
+from filodb_tpu.utils.metrics import (mint_trace_id, parse_traceparent,
+                                      span, trace_context)
+
+# the data-plane doors: a request to one of these opens (or continues, from
+# a W3C `traceparent` header) a trace whose root span is `http.request`.
+# The operator's plane (/metrics, /admin, health probes) is not traced: a
+# scrape every few seconds would recycle the bounded trace ring and book
+# itself into the request spans' counters
+_TRACED_PREFIXES = ("/api/", "/promql/", "/influx/")
+
+
+def _no_span(name):
+    """In `span`'s place for a request that is not traced."""
+    return contextlib.nullcontext()
 
 
 class FiloHttpServer:
@@ -25,6 +40,16 @@ class FiloHttpServer:
         class _Handler(BaseHTTPRequestHandler):
             def _serve(self, method: str):
                 parsed = urllib.parse.urlsplit(self.path)
+                if not parsed.path.startswith(_TRACED_PREFIXES):
+                    return self._answer(method, parsed, _no_span)
+                # the root of the request's span tree: body read, routing,
+                # JSON encode and the socket write all lie inside it
+                tid = parse_traceparent(self.headers.get("traceparent")) \
+                    or mint_trace_id()
+                with trace_context(tid), span("http.request"):
+                    self._answer(method, parsed, span)
+
+            def _answer(self, method: str, parsed, sp):
                 multi = urllib.parse.parse_qs(parsed.query)
                 params = {k: v[-1] for k, v in multi.items()}
                 length = int(self.headers.get("Content-Length") or 0)
@@ -64,7 +89,7 @@ class FiloHttpServer:
                 # — abandoned dashboard polls stop consuming the
                 # concurrency semaphore and device time
                 from filodb_tpu.query.activequeries import bind_client_conn
-                with bind_client_conn(self.connection):
+                with bind_client_conn(self.connection), sp("http.route"):
                     status, payload = api_ref.handle(
                         method, parsed.path, params, body,
                         multi_params=multi, headers=dict(self.headers))
@@ -83,17 +108,22 @@ class FiloHttpServer:
                 else:
                     if isinstance(payload, dict) and "_headers" in payload:
                         extra_headers.update(payload.pop("_headers"))
-                    blob = b"" if status == 204 else json.dumps(payload).encode()
+                    if status == 204:
+                        blob = b""
+                    else:
+                        with sp("http.encode"):
+                            blob = json.dumps(payload).encode()
                     ctype = "application/json"
                 try:
-                    self.send_response(status)
-                    self.send_header("Content-Type", ctype)
-                    for k, v in extra_headers.items():
-                        self.send_header(k, v)
-                    self.send_header("Content-Length", str(len(blob)))
-                    self.end_headers()
-                    if blob:
-                        self.wfile.write(blob)
+                    with sp("http.write"):
+                        self.send_response(status)
+                        self.send_header("Content-Type", ctype)
+                        for k, v in extra_headers.items():
+                            self.send_header(k, v)
+                        self.send_header("Content-Length", str(len(blob)))
+                        self.end_headers()
+                        if blob:
+                            self.wfile.write(blob)
                 except (BrokenPipeError, ConnectionResetError):
                     # the client hung up mid-request — routine since the
                     # disconnect watcher aborts abandoned queries (their

@@ -216,8 +216,9 @@ class NodeQueryServer:
                                 # node's spans stitch into the same trace; ship
                                 # them back with the reply (the Kamon-context-
                                 # over-Akka analogue, ref: ExecPlan.scala:102)
-                                with trace_context(tid),                                         span("remote_exec",
-                                             plan=type(plan).__name__):
+                                with trace_context(tid), span(
+                                        "remote_exec", hist=True,
+                                        plan=type(plan).__name__):
                                     data, stats = plan.execute_internal(
                                         outer.source)
                                 spans = collector.take(tid) if tid else []
@@ -643,7 +644,7 @@ class RemoteNodeDispatcher(PlanDispatcher):
                         br.on_abort()
                     raise
             try:
-                with span("transport_reconnect", peer=where,
+                with span("transport_reconnect", hist=True, peer=where,
                           reason="stale_pool"):
                     sock, _ = self._sock(timeout_s)
             except socket.timeout as e2:

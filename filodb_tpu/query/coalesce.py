@@ -20,6 +20,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from filodb_tpu.utils.metrics import span
+
 
 class _Group:
     __slots__ = ("queries", "results", "error", "done")
@@ -63,7 +65,8 @@ class QueryCoalescer:
         dl = getattr(planner_params, "deadline_unix_s", 0.0) \
             if planner_params is not None else 0.0
         if leader:
-            time.sleep(self.window_s)
+            with span("frontend.coalesce_wait"):
+                time.sleep(self.window_s)
             with self._lock:
                 # close the window: later arrivals start a new group
                 if self._groups.get(key) is grp:
@@ -98,23 +101,24 @@ class QueryCoalescer:
                                      max(300.0, 10 * self.window_s))
             ent = peek_admission()
             tok = ent.token if ent is not None else None
-            if tok is None:
-                completed = grp.done.wait(timeout=bound)
-            else:
-                deadline = time.perf_counter() + bound
-                completed = False
-                while not completed:
-                    if tok.cancelled:
-                        from filodb_tpu.query.rangevector import \
-                            QueryResult
-                        return QueryResult(
-                            [], error=("query_canceled: query killed "
-                                       "waiting on a coalesce leader "
-                                       f"(reason={tok.reason or 'admin'})"))
-                    left = deadline - time.perf_counter()
-                    if left <= 0:
-                        break
-                    completed = grp.done.wait(timeout=min(left, 0.05))
+            with span("frontend.coalesce_wait"):
+                if tok is None:
+                    completed = grp.done.wait(timeout=bound)
+                else:
+                    deadline = time.perf_counter() + bound
+                    completed = False
+                    while not completed:
+                        if tok.cancelled:
+                            from filodb_tpu.query.rangevector import \
+                                QueryResult
+                            return QueryResult(
+                                [], error=("query_canceled: query killed "
+                                           "waiting on a coalesce leader "
+                                           f"(reason={tok.reason or 'admin'})"))
+                        left = deadline - time.perf_counter()
+                        if left <= 0:
+                            break
+                        completed = grp.done.wait(timeout=min(left, 0.05))
         if grp.error is not None or grp.results is None:
             # batch failed (or leader timed out): run alone
             res = self.engine.query_range(promql, start_s, step_s, end_s,

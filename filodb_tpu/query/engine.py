@@ -19,7 +19,7 @@ from filodb_tpu.promql.parser import (TimeStepParams,
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query.planner import SingleClusterPlanner
 from filodb_tpu.query.rangevector import (PlannerParams, QueryContext,
-                                          QueryResult)
+                                          QueryResult, QueryStats)
 
 
 class QueryEngine:
@@ -98,20 +98,19 @@ class QueryEngine:
         ent = peek_admission()
         if ent is not None:
             ent.set_phase("parsing")
-        t_parse0 = _time.perf_counter()
+        # span: the parse share of the fixed per-query floor is
+        # attributable in traces (parse itself is AST-memoized —
+        # promql.parser.parse_query_cached — so re-polled dashboard
+        # strings skip tokenization entirely); stats.parse_s is its clock
+        parsing = span("query_parse", hist=True)
         try:
-            # span: the parse share of the fixed per-query floor is
-            # attributable in traces (parse itself is AST-memoized —
-            # promql.parser.parse_query_cached — so re-polled dashboard
-            # strings skip tokenization entirely)
-            with span("query_parse"):
+            with parsing:
                 plan = query_range_to_logical_plan(
                     promql, TimeStepParams(start_s, step_s, end_s))
         except Exception as e:  # noqa: BLE001 — parse errors surface in result
             return QueryResult([], error=f"parse error: {e}")
-        parse_t = _time.perf_counter() - t_parse0
         res = self.exec_logical_plan(plan, planner_params)
-        res.stats.parse_s += parse_t
+        res.stats.parse_s += parsing.dur_s
         return res
 
     def query_instant(self, promql: str, time_s: int,
@@ -244,13 +243,13 @@ class QueryEngine:
         ent = getattr(ctx, "active", None)
         if ent is not None:
             ent.set_phase("planning")
-        t_plan0 = _time.perf_counter()
+        planning = span("query_plan", hist=True)
         try:
-            with span("query_plan"):
+            with planning:
                 ep = self.planner.materialize(plan, ctx)
         except Exception as e:  # noqa: BLE001
             return QueryResult([], error=f"planning error: {e}")
-        plan_t = _time.perf_counter() - t_plan0
+        plan_t = planning.dur_s         # stats.plan_s is the span's clock
         if ent is not None:
             ent.set_phase("executing")
         if isinstance(plan, lp.MetadataQueryPlan):
@@ -293,14 +292,30 @@ class QueryEngine:
         # tree (joins, multi-shard scatter) batches its leaves' fused
         # preflights into one merged dispatch; single-leaf trees keep
         # the leaf's exact standalone path (min_leaves=2)
-        comp = None
+        comp = hoisted = None
         if self._qconfig().exprfuse_enabled:
             from filodb_tpu.query import exprfuse
-            comp = exprfuse.compile_tree(ep, self.source, min_leaves=2)
+            from filodb_tpu.query.execbase import fold_exec_tally
+            from filodb_tpu.utils.metrics import exec_tally
+            # the leaves' gather + preflight and their merged dispatch
+            # run HERE, before the tree: outside every exec node, so the
+            # tree's own tally never sees them.  Tallied like a node, on
+            # the two spans' clock, and merged into the result's stats
+            # (before this, stats.phases lost this work entirely)
+            snap = exec_tally.snapshot()
+            with span("engine.prepare_leaves") as preparing:
+                comp = exprfuse.compile_tree(ep, self.source, min_leaves=2)
+            took = preparing.dur_s
             if comp is not None:
-                exprfuse.finish_prepared(comp.calls)
+                with span("engine.dispatch_leaves") as dispatching:
+                    exprfuse.finish_prepared(comp.calls)
+                took += dispatching.dur_s
+                hoisted = QueryStats()
+                fold_exec_tally(hoisted, took)
+            exec_tally.restore(snap, took)
         res = ep.execute(self.source)
         if comp is not None:
+            res.stats.merge(hoisted)
             res.stats.exprfuse_fused += comp.fused
             res.stats.exprfuse_degraded += comp.degraded
         res.stats.plan_s += plan_t

@@ -564,6 +564,37 @@ class PlanDispatcher:
 QueryResultLike = Tuple[Data, QueryStats]
 
 
+def fold_exec_tally(stats: QueryStats, total_s: float) -> float:
+    """Fold what this thread's exec tally accumulated during `total_s`
+    seconds of work into `stats`; returns the exclusive host seconds.
+    Nested nodes' wall AND the work's own synchronous device/transfer
+    waits are carved out, so the three phase columns (exec/device/
+    transfer) partition wall time instead of double-counting it.  One
+    home for exec nodes (execute_internal) and for the leaf work the
+    engine hoists out of the tree (exprfuse prepare + merged dispatch)."""
+    from filodb_tpu.utils.metrics import exec_tally
+    self_wall = max(total_s - exec_tally.child_wall
+                    - exec_tally.device_s - exec_tally.transfer_s, 0.0)
+    stats.cpu_seconds += self_wall
+    stats.device_seconds += exec_tally.device_s
+    stats.transfer_s += exec_tally.transfer_s
+    stats.bytes_transferred += exec_tally.transfer_bytes
+    stats.mirror_full_rebuilds += exec_tally.mirror_full
+    stats.mirror_incremental += exec_tally.mirror_incremental
+    # per-(device, kernel) split of device_seconds (PR 18): folded
+    # under a flat "dev|kernel" key so the generic dataclass wire
+    # codec ships it unchanged with dispatch replies
+    for (dev, kern), cell in exec_tally.device_calls.items():
+        key = f"{dev}|{kern}"
+        mine = stats.device_calls.get(key)
+        if mine is None:
+            stats.device_calls[key] = [cell[0], cell[1]]
+        else:
+            mine[0] += cell[0]
+            mine[1] += cell[1]
+    return self_wall
+
+
 class InProcessPlanDispatcher(PlanDispatcher):
     """Run the subtree in-process (ref: exec/InProcessPlanDispatcher.scala:89)."""
 
@@ -606,7 +637,7 @@ class ExecPlan:
         device/transfer work the thread accumulated while this node ran
         lands in ITS QueryStats — children's contributions arrive via
         stats.merge, so the root totals are exact sums over nodes."""
-        from filodb_tpu.utils.metrics import exec_tally
+        from filodb_tpu.utils.metrics import exec_tally, span
         # deadline check at every node boundary: a query past its budget
         # stops HERE instead of fanning out more work (getattr: contexts
         # serialized by an older peer lack the field)
@@ -624,38 +655,18 @@ class ExecPlan:
         if tok is not None and tok.cancelled:
             tok.raise_if_cancelled(f"at {type(self).__name__}")
         snap = exec_tally.snapshot()
-        t0 = _time.perf_counter()
+        # the node's span is its clock: `exec.<PlanClass>`, every node
+        node = span("exec." + type(self).__name__)
         try:
-            data, stats = self._execute_impl(source)
+            with node:
+                data, stats = self._execute_impl(source)
         except BaseException:
             # attribution on the error path: the parent sees the whole
             # failed subtree as child time, never as its own cpu
-            exec_tally.restore(snap, _time.perf_counter() - t0)
+            exec_tally.restore(snap, node.dur_s)
             raise
-        total = _time.perf_counter() - t0
-        # exclusive HOST cpu: nested nodes' wall AND this node's own
-        # synchronous device/transfer waits are carved out, so the three
-        # phase columns (exec/device/transfer) partition wall time
-        # instead of double-counting it
-        self_wall = max(total - exec_tally.child_wall
-                        - exec_tally.device_s - exec_tally.transfer_s, 0.0)
-        stats.cpu_seconds += self_wall
-        stats.device_seconds += exec_tally.device_s
-        stats.transfer_s += exec_tally.transfer_s
-        stats.bytes_transferred += exec_tally.transfer_bytes
-        stats.mirror_full_rebuilds += exec_tally.mirror_full
-        stats.mirror_incremental += exec_tally.mirror_incremental
-        # per-(device, kernel) split of device_seconds (PR 18): folded
-        # under a flat "dev|kernel" key so the generic dataclass wire
-        # codec ships it unchanged with dispatch replies
-        for (dev, kern), cell in exec_tally.device_calls.items():
-            key = f"{dev}|{kern}"
-            mine = stats.device_calls.get(key)
-            if mine is None:
-                stats.device_calls[key] = [cell[0], cell[1]]
-            else:
-                mine[0] += cell[0]
-                mine[1] += cell[1]
+        total = node.dur_s
+        self_wall = fold_exec_tally(stats, total)
         rec = getattr(self.ctx, "analyze", None)
         if rec is not None:
             rec.add(self, {
@@ -688,7 +699,7 @@ class ExecPlan:
         from filodb_tpu.utils.metrics import registry, span, trace_context
         try:
             with trace_context(self.ctx.query_id), \
-                    span("execplan", plan=type(self).__name__):
+                    span("execplan", hist=True, plan=type(self).__name__):
                 data, stats = self.execute_internal(source)
         except QueryError as e:
             # typed taxonomy (shard_unavailable / dispatch_timeout /
@@ -701,15 +712,17 @@ class ExecPlan:
             registry.counter("query_errors",
                              plan=type(self).__name__).increment()
             return QueryResult([], QueryStats(), error=f"{type(e).__name__}: {e}")
-        if isinstance(data, AggPartial):
-            data = present_partial(data)
-        if isinstance(data, ScalarResult):
-            data = ResultBlock([RangeVectorKey(())], data.wends,
-                               data.values[None, :])
-        data = remove_nan_series(data)
-        blocks = [data] if data is not None else []
-        limit = self.ctx.planner_params.sample_limit
-        result_samples = sum(int(np.asarray(b.values).size) for b in blocks)
+        with span("engine.present"):
+            if isinstance(data, AggPartial):
+                data = present_partial(data)
+            if isinstance(data, ScalarResult):
+                data = ResultBlock([RangeVectorKey(())], data.wends,
+                                   data.values[None, :])
+            data = remove_nan_series(data)
+            blocks = [data] if data is not None else []
+            limit = self.ctx.planner_params.sample_limit
+            result_samples = sum(int(np.asarray(b.values).size)
+                                 for b in blocks)
         if limit and result_samples > limit:
             return QueryResult([], stats,
                                error=f"sample limit {limit} exceeded "

@@ -39,6 +39,7 @@ from filodb_tpu.query.qos import (SHED_ERROR_CODE, WeightedFairScheduler,
 from filodb_tpu.query.rangevector import (PlannerParams, QueryResult,
                                           remaining_budget)
 from filodb_tpu.query.resultcache import ResultCache, _plan_cacheable
+from filodb_tpu.utils.metrics import current_trace_id, span
 
 
 class _Flight:
@@ -152,6 +153,11 @@ class QueryFrontend:
             promql, (time_s, 1, time_s), pp, tenant, origin)
 
     def _serve(self, key, run, promql, grid, pp, tenant, origin):
+        with span("frontend.serve"):
+            return self._serve_accounted(key, run, promql, grid, pp,
+                                         tenant, origin)
+
+    def _serve_accounted(self, key, run, promql, grid, pp, tenant, origin):
         """Admission -> singleflight -> accounting: the shared serving
         wrapper for both query shapes."""
         from filodb_tpu.query.activequeries import set_pending, verdict_of
@@ -259,7 +265,8 @@ class QueryFrontend:
                                      max(300.0, 3 * self._ask_timeout_s))
             dl = getattr(planner_params, "deadline_unix_s", 0.0) \
                 if planner_params is not None else 0.0
-            completed = flight.done.wait(timeout=bound)
+            with span("frontend.singleflight_wait"):
+                completed = flight.done.wait(timeout=bound)
             if flight.result is not None:
                 shared = flight.result
                 # never inherit the LEADER's deadline expiry OR its
@@ -398,8 +405,11 @@ class QueryFrontend:
         def run(s0, e0):
             return self._run(promql, s0, step_s, e0, pp)
 
-        return cache.query_range(run, promql, start_s, step_s, end_s,
-                                 repr(pp), self._state())
+        # what `run` does is this span's child: its self time is the
+        # lookup, the extent arithmetic and the insert
+        with span("frontend.cache_lookup"):
+            return cache.query_range(run, promql, start_s, step_s, end_s,
+                                     repr(pp), self._state())
 
     def _admit_params(self, pp):
         """Copy of the caller's PlannerParams with the end-to-end
@@ -435,9 +445,13 @@ class QueryFrontend:
         ws = info[0][0] if info is not None else ""
         ent = None
         if info is not None:
+            # behind the HTTP door the request's trace is already open
+            # (its root is http.request): the query takes that id, so the
+            # registry key, ctx.query_id and the trace id stay ONE
             from filodb_tpu.utils.metrics import mint_trace_id
-            ent = active_queries.register(mint_trace_id(), promql=promql,
-                                          tenant=info[0], origin=info[1])
+            ent = active_queries.register(
+                current_trace_id() or mint_trace_id(), promql=promql,
+                tenant=info[0], origin=info[1])
         if ent is None:
             return self._run_scheduled(promql, start_s, step_s, end_s,
                                        pp, None, ws)
@@ -484,7 +498,9 @@ class QueryFrontend:
         # same end-to-end budget as execution.
         dl = getattr(pp, "deadline_unix_s", 0.0) if pp is not None else 0.0
         timeout = remaining_budget(pp, self._ask_timeout_s)
-        adm = sched.admit(ws, timeout, tok, deadline_unix_s=dl)
+        with span("frontend.queue_wait") as waited:
+            adm = sched.admit(ws, timeout, tok, deadline_unix_s=dl)
+        adm.waited_s = waited.dur_s     # one clock: the span's
         if adm.status == "shed":
             return self._shed_result(ws, adm)
         try:

@@ -12,6 +12,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from filodb_tpu.query.execbase import AggPartial
+from filodb_tpu.utils.metrics import span
 
 @dataclasses.dataclass
 class FusedCall:
@@ -85,8 +86,6 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         # histogram panels aggregate over (group, bucket) SLOTS
         return len(calls[i].gkeys) * calls[i].num_buckets
 
-    import time as _time
-
     # two-phase execution: phase A dispatches every merged set's kernel
     # work WITHOUT reading anything back, phase B synchronizes.  With
     # sharded DeviceMirrors a multi-shard query's leaves hold their
@@ -140,31 +139,32 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
                     .increment(launches)
                 registry.counter("fused_batch_merged_panels") \
                     .increment(len(take))
-            _t0 = _time.perf_counter()
-            finisher = pf.fused_leaf_agg_batch(
-                fc0.plan, fc0.values, panels, fc0.fn,
-                precorrected=fc0.precorrected, interpret=fc0.interpret,
-                ragged=fc0.ragged, num_series=fc0.num_series, lazy=True)
-            pending.append((take, finisher, _time.perf_counter() - _t0))
+            with span("leaf.kernel_enqueue") as enqueue:
+                finisher = pf.fused_leaf_agg_batch(
+                    fc0.plan, fc0.values, panels, fc0.fn,
+                    precorrected=fc0.precorrected, interpret=fc0.interpret,
+                    ragged=fc0.ragged, num_series=fc0.num_series, lazy=True)
+            pending.append((take, finisher, enqueue.dur_s))
             idxs = idxs[len(take):]
     from filodb_tpu.utils.devicetelem import telem
     for take, finisher, disp_s in pending:
-        _t0 = _time.perf_counter()
-        comps = finisher()
-        for i, comp in zip(take, comps):
-            out[i] = _present(calls[i], comp)
-        # kernel dispatch + result readback (np conversion in _present
-        # synchronizes), attributed to the node that triggered it AND
-        # recorded in the per-chip kernel ledger (utils/devicetelem) —
-        # record_dispatch feeds the same exec tally note_device_time
-        # did, so QueryStats.device_seconds is unchanged
+        # the np.asarray that blocks on the device and copies out
+        with span("leaf.result_fetch") as fetch:
+            comps = finisher()
+        with span("leaf.present"):
+            for i, comp in zip(take, comps):
+                out[i] = _present(calls[i], comp)
+        # kernel enqueue + result readback, attributed to the node that
+        # triggered it AND recorded in the per-chip kernel ledger
+        # (utils/devicetelem) — record_dispatch feeds the exec tally, so
+        # QueryStats.device_seconds is the two spans' seconds
         fc0 = calls[take[0]]
         telem.record_dispatch(
             f"fused_{fc0.fn}",
             device=pf._committed_device(fc0.values.vals_p),
             shape=(f"S{fc0.num_series}xW{len(fc0.wends)}"
                    f"x{len(take)}p" + (":ragged" if fc0.ragged else "")),
-            seconds=disp_s + (_time.perf_counter() - _t0),
+            seconds=disp_s + fetch.dur_s,
             bytes_in=int(getattr(fc0.values.vals_p, "nbytes", 0)),
             bytes_out=sum(int(getattr(c, "nbytes", 0)) for c in comps))
     for i, j in alias.items():

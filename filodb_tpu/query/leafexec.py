@@ -40,6 +40,7 @@ from filodb_tpu.query.transformers import (
     AggregateMapReduce, PeriodicSamplesMapper, RangeVectorTransformer,
     _group_ids, _group_ids_cached)
 from filodb_tpu.query.fusedbatch import FusedCall, finish_fused_calls
+from filodb_tpu.utils.metrics import span
 
 
 class MultiSchemaPartitionsExec(LeafExecPlan):
@@ -106,6 +107,12 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         (finish_fused_calls), else None.  Either way the gathered data is
         parked on the leaf so phase-3 execution never re-gathers; the
         engine injects the finished AggPartial via inject_fused."""
+        # the leaf's work, hoisted out of the tree by the engine: its own
+        # span per leaf, so the tree still shows which shard took what
+        with span("leaf.prepare", shard=str(self.shard)):
+            return self._prepare_fused(source)
+
+    def _prepare_fused(self, source):
         self._transformer_overrides = {}
         self._fused_cache_key = None
         data, stats = self._do_execute(source)
@@ -149,14 +156,26 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             return None
 
     def _try_fused(self, data, stats, defer: bool = False):
+        """The fused peephole (_build_fused), then, unless deferred, the
+        kernel dispatch of the FusedCall it built."""
+        with span("leaf.fused_prepare"):
+            pre = self._build_fused(data, stats)
+        if defer or not isinstance(pre, FusedCall):
+            return pre
+        self._check_cancel("fused kernel dispatch")
+        return finish_fused_calls([pre])[0]
+
+    def _build_fused(self, data, stats):
         """Peephole: PeriodicSamplesMapper(rate|increase|delta) followed by
         AggregateMapReduce(sum) over a shared-grid fully-finite working set
         collapses into the single-HBM-pass MXU kernel (ops/pallas_fused.py)
         — the leaf analogue of the reference pushing AggregateMapReduce to
         data nodes (ref: AggrOverRangeVectors.scala:76), fused one level
-        further.  Returns the AggPartial or None (general path); with
-        defer=True the matmul-kernel path returns a FusedCall instead so
-        the engine can merge compatible panels into one dispatch."""
+        further.  Returns None (general path), an AggPartial where no
+        merged kernel dispatch is involved (host math, host route,
+        min/max), or for the matmul-kernel path a FusedCall with
+        everything resolved but the dispatch, so that the engine can merge
+        compatible panels into one."""
         if len(self.transformers) < 2 or not isinstance(data, RawBlock) \
                 or not data.keys or data.shared_ts_row is None:
             return None
@@ -245,8 +264,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             if padded_vals is not None:
                 registry.counter("leaf_fused_prep_hits").increment()
         if plan is None:
-            plan = pf.build_plan(data.shared_ts_row.astype(np.int64),
-                                 eval_wends, t0.window_ms)
+            with span("leaf.build_plan"):
+                plan = pf.build_plan(data.shared_ts_row.astype(np.int64),
+                                     eval_wends, t0.window_ms)
             if key is not None:
                 with _FUSED_CACHE_LOCK:
                     for k in [k for k in _FUSED_PLAN_CACHE
@@ -256,8 +276,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                     while len(_FUSED_PLAN_CACHE) > 8:
                         _FUSED_PLAN_CACHE.pop(next(iter(_FUSED_PLAN_CACHE)))
         if gkeys is None:
-            gids, gkeys = _group_ids_cached(data.cache_token, data.keys,
-                                            t1.by, t1.without)
+            with span("leaf.group_ids"):
+                gids, gkeys = _group_ids_cached(data.cache_token, data.keys,
+                                                t1.by, t1.without)
         self._check_group_limit(gkeys)
         B = vals.shape[2] if is_hist else 1
         num_slots = len(gkeys) * B      # hist: one kernel group per (g, b)
@@ -279,11 +300,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                            if vbase is None
                            else jnp.asarray(vbase,
                                             jnp.float32).reshape(-1))
-                padded_vals = pf.pad_values(flat, vb_flat, plan)
+                with span("leaf.pad_values"):
+                    padded_vals = pf.pad_values(flat, vb_flat, plan)
             else:
                 if vbase is None:
                     vbase = np.zeros(vals.shape[0], np.float32)
-                padded_vals = pf.pad_values(vals, vbase, plan)
+                with span("leaf.pad_values"):
+                    padded_vals = pf.pad_values(vals, vbase, plan)
             if key is not None:
                 # a new snapshot generation obsoletes this mirror's older
                 # entries — drop them NOW, not at LRU eviction: each pins a
@@ -294,13 +317,14 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         del _FUSED_VALS_CACHE[k]
                     _vals_cache_insert(key, padded_vals)
         if groups is None:
-            if is_hist:
-                gids_flat = (np.asarray(gids, np.int64)[:, None] * B
-                             + np.arange(B)[None, :]).reshape(-1)
-                groups = pf.pad_groups(gids_flat, vals.shape[0] * B,
-                                       num_slots)
-            else:
-                groups = pf.pad_groups(gids, vals.shape[0], len(gkeys))
+            with span("leaf.pad_groups"):
+                if is_hist:
+                    gids_flat = (np.asarray(gids, np.int64)[:, None] * B
+                                 + np.arange(B)[None, :]).reshape(-1)
+                    groups = pf.pad_groups(gids_flat, vals.shape[0] * B,
+                                           num_slots)
+                else:
+                    groups = pf.pad_groups(gids, vals.shape[0], len(gkeys))
             _group_cache_insert(key, t1.by, t1.without, groups, gkeys)
         registry.counter("leaf_fused_kernel").increment()
         if not is_hist:
@@ -308,7 +332,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             # ragged (validity-weighted) when the working set has NaN
             # holes.  Packaged as a FusedCall so engine.query_range_batch
             # can merge compatible panels into one kernel dispatch; the
-            # single-query path finishes it immediately.
+            # single-query path (_try_fused) finishes it immediately.
             ck = None if key is None else key + (
                 t0.start_ms, t0.step_ms, t0.end_ms, t0.offset_ms,
                 t0.window_ms, data.base_ms)
@@ -319,10 +343,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 ragged=not dense, num_series=vals.shape[0], cache_key=ck,
                 cache_token=agg_token(t1.op, t1.by, t1.without,
                                       data.cache_token))
-            if defer:
-                return fc
-            self._check_cancel("fused kernel dispatch")
-            return finish_fused_calls([fc])[0]
+            return fc
         # histogram leaf (sum(rate(bucket_metric))): (group, bucket)
         # slots ride the same FusedCall machinery so quantile dashboards
         # batch too — identical panels (p50/p90/p99 over one metric)
@@ -339,10 +360,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             bucket_les=data.bucket_les, num_buckets=B,
             cache_token=agg_token("hist_sum", t1.by, t1.without,
                                   data.cache_token))
-        if defer:
-            return fc
-        self._check_cancel("fused hist kernel dispatch")
-        return finish_fused_calls([fc])[0]
+        return fc
 
     def _try_host_routed(self, data, t0, t1, wends, eval_wends, fn,
                          dense, is_hist):
@@ -388,7 +406,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         gids, gkeys = _group_ids_cached(data.cache_token, data.keys,
                                         t1.by, t1.without)
         self._check_group_limit(gkeys)
-        with span("leaf_host_routed", fn=fn, op=t1.op):
+        with span("leaf_host_routed", hist=True, fn=fn, op=t1.op):
             comp = hostleaf.host_leaf_agg(
                 plan, data.values, data.vbase, np.asarray(gids),
                 len(gkeys), fn, t1.op)
@@ -575,16 +593,17 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         shard = source.get_shard(self.dataset, self.shard)
         if shard is None:
             return None, stats
-        lookup = shard.lookup_partitions(self.filters, self.chunk_start_ms,
-                                         self.chunk_end_ms)
-        schema_name = self.schema or lookup.first_schema
-        if schema_name is None:
-            return None, stats
-        pids = lookup.pids_by_schema.get(schema_name)
-        if pids is None or pids.size == 0:
-            return None, stats
-        store = shard.stores[schema_name]
-        rows = shard.rows_for(pids)
+        with span("leaf.index_lookup"):
+            lookup = shard.lookup_partitions(
+                self.filters, self.chunk_start_ms, self.chunk_end_ms)
+            schema_name = self.schema or lookup.first_schema
+            if schema_name is None:
+                return None, stats
+            pids = lookup.pids_by_schema.get(schema_name)
+            if pids is None or pids.size == 0:
+                return None, stats
+            store = shard.stores[schema_name]
+            rows = shard.rows_for(pids)
 
         # Cap data scanned BEFORE materializing (or paging) the [S, T]
         # matrix — a pathological selector must fail fast, not OOM first
@@ -599,8 +618,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         def _check_scan_cap(when: str):
             if not enforced:
                 return
-            to_scan = _estimate_scan(store, rows, self.chunk_start_ms,
-                                     self.chunk_end_ms)
+            with span("leaf.scan_estimate"):
+                to_scan = _estimate_scan(store, rows, self.chunk_start_ms,
+                                         self.chunk_end_ms)
             if to_scan > limit:
                 raise ValueError(
                     f"shard {self.shard}: query would scan ~{to_scan} "
@@ -614,11 +634,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             # loop: a killed query stops paging history mid-scan (the
             # work already paged is kept — valid cache for a retry)
             tok = getattr(self.ctx, "cancel", None)
-            paged = shard.ensure_paged_pids(
-                schema_name, pids, self.chunk_start_ms, self.chunk_end_ms,
-                max_samples=limit if enforced else None,
-                cancel=(None if tok is None else
-                        lambda: self._check_cancel("demand paging")))
+            with span("leaf.page_check"):
+                paged = shard.ensure_paged_pids(
+                    schema_name, pids, self.chunk_start_ms,
+                    self.chunk_end_ms,
+                    max_samples=limit if enforced else None,
+                    cancel=(None if tok is None else
+                            lambda: self._check_cancel("demand paging")))
         except PagedLimitExceeded as e:
             # structured query error, not a 500: the partial paging work
             # is kept (valid cache for a narrower retry) and the error
@@ -687,8 +709,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             import jax as _jax
             if _jax.default_backend() == "tpu" or os.environ.get(
                     "FILODB_TPU_FORCE_HOST_ROUTE"):
-                est = _estimate_scan(store, rows, self.chunk_start_ms,
-                                     self.chunk_end_ms)
+                with span("leaf.scan_estimate"):
+                    est = _estimate_scan(store, rows, self.chunk_start_ms,
+                                         self.chunk_end_ms)
                 route_host = 0 < est <= _route_cap
         if (not route_host
                 and getattr(shard.config.store, "device_mirror_enabled",
@@ -729,41 +752,41 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # ingest/flush can't hand the kernel a torn matrix.
         mirrored = snap = None
         if mirror is not None:
-            ok = mirror.is_fresh(store)
-            if not ok:
-                bg = getattr(shard.config.store,
-                             "mirror_background_rebuild", True)
-                if mirror.can_update_inline(store) or not bg:
-                    with shard._write_locked("mirror_refresh"):
-                        # re-check under the lock: an eviction may bump
-                        # shift_version between the unlocked check and
-                        # lock acquisition, and the full rebuild must
-                        # still not run on this query's critical path
-                        if not bg or mirror.can_update_inline(store):
-                            ok = mirror.ensure_fresh(store)
-                if not ok and bg and not mirror.can_update_inline(store):
-                    # eviction rearranged rows (shift_version moved): the
-                    # full O(S*T) re-upload must not run on THIS query's
-                    # critical path — rebuild in the background and serve
-                    # this query via the host windowed gather below
-                    # (eviction-proof serving; SOAK_LONG_r05's 752 s p99
-                    # was one query paying this inline)
-                    mirror.request_background_refresh(shard, store)
-                    from filodb_tpu.utils.metrics import registry as _reg
-                    _reg.counter(
-                        "device_mirror_query_fallbacks").increment()
+            with span("leaf.mirror_fresh"):
+                ok = mirror.is_fresh(store)
+                if not ok:
+                    bg = getattr(shard.config.store,
+                                 "mirror_background_rebuild", True)
+                    if mirror.can_update_inline(store) or not bg:
+                        with shard._write_locked("mirror_refresh"):
+                            # re-check under the lock: an eviction may bump
+                            # shift_version between the unlocked check and
+                            # lock acquisition, and the full rebuild must
+                            # still not run on this query's critical path
+                            if not bg or mirror.can_update_inline(store):
+                                ok = mirror.ensure_fresh(store)
+                    if not ok and bg and not mirror.can_update_inline(store):
+                        # eviction rearranged rows (shift_version moved): the
+                        # full O(S*T) re-upload must not run on THIS query's
+                        # critical path — rebuild in the background and serve
+                        # this query via the host windowed gather below
+                        # (eviction-proof serving; SOAK_LONG_r05's 752 s p99
+                        # was one query paying this inline)
+                        mirror.request_background_refresh(shard, store)
+                        from filodb_tpu.utils.metrics import registry as _reg
+                        _reg.counter(
+                            "device_mirror_query_fallbacks").increment()
             if ok:
                 # one snapshot read serves gather AND fused-eligibility:
                 # pairing a newer snapshot's grid with an older one's values
                 # would feed the kernel zero-padded phantom columns
                 snap = mirror.snapshot()
                 from filodb_tpu.utils.devicetelem import telem
-                _g0 = _time.perf_counter()
-                mirrored = mirror.gather_cached(rows, snap)
+                with span("leaf.mirror_gather") as gathering:
+                    mirrored = mirror.gather_cached(rows, snap)
                 telem.record_dispatch(
                     "mirror_gather", device=mirror.device,
-                    shape=f"rows{len(rows)}",
-                    seconds=_time.perf_counter() - _g0)
+                    shape=f"rows{len(rows)}", seconds=gathering.dur_s)
         # value column selection: histograms gather [S, T, B]
         shared_ts_row = None
         dense = True
@@ -771,8 +794,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             ts_off, dev_cols, dev_vbases, base = mirrored
             vals = dev_cols[col_name]
             vbase = dev_vbases.get(col_name)
-            counts = shard.snapshot_read(store,
-                                         lambda: store.counts[rows].copy())
+            with span("leaf.counts_copy"):
+                counts = shard.snapshot_read(
+                    store, lambda: store.counts[rows].copy())
             precorrected = counter_col   # mirror corrects counter columns
             shared_ts_row = mirror.fused_eligible(col_name, snap,
                                                   allow_ragged=True)

@@ -348,7 +348,8 @@ class ReplicationManager:
         # events back in the ack, stitching into ONE trace (the same
         # shape the query transport's remote_exec spans use)
         trace = current_trace_id()
-        with metrics_span("replication_fanout", dataset=self.dataset):
+        with metrics_span("replication_fanout", hist=True,
+                          dataset=self.dataset):
             for node in owners:
                 st = self._peer(node)
                 is_primary_target = node == primary_owner
@@ -371,7 +372,8 @@ class ReplicationManager:
                     with st.lock:
                         st.sent += 1
                     try:
-                        with metrics_span("replica_append", peer=node):
+                        with metrics_span("replica_append", hist=True,
+                                          peer=node):
                             reply = st.client.append_record(
                                 self.dataset, body, seq=seq, trace=trace)
                         _restitch_spans(trace, reply)

@@ -42,9 +42,9 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from filodb_tpu.utils.metrics import (NODE_NAME, collector,
-                                      current_trace_id, log_error_once,
-                                      note_device_call, registry)
+from filodb_tpu.utils.metrics import (current_trace_id, log_error_once,
+                                      note_device_call, record_child_event,
+                                      registry)
 
 # process-wide kill switch (bench.py devicetelem stage measures the
 # ledger's own overhead by toggling this off).  The exec-tally feed in
@@ -223,16 +223,14 @@ class DeviceTelemetry:
                 h[1].increment(seconds)
                 h[2].update(util)
                 # span event on the live trace (PR 12): the kernel shows
-                # up inside the query's timeline with device tags, and
+                # up inside the query's tree, under the span open at the
+                # call, with device tags, and
                 # span_kernel_dispatch_seconds carries the exemplar
                 h[5].record(seconds, exemplar=origin or None)
                 if origin:
-                    collector.record(origin, {
-                        "span": "kernel_dispatch",
-                        "dur_s": round(seconds, 6),
-                        "end_unix_s": round(now, 3),
-                        "node": NODE_NAME, "device": dev,
-                        "kernel": kernel, "shape": shape})
+                    record_child_event(origin, "kernel_dispatch", seconds,
+                                       device=dev, kernel=kernel,
+                                       shape=shape)
         except Exception as exc:  # noqa: BLE001 — never break a dispatch
             log_error_once("devicetelem.record_dispatch", exc)
 

@@ -204,12 +204,16 @@ class DoorTrace:
     def __init__(self, origin: str, dataset: str, headers=None,
                  body_bytes: int = 0,
                  threshold_s: Optional[float] = None):
-        from filodb_tpu.utils.metrics import (mint_trace_id,
+        from filodb_tpu.utils.metrics import (current_trace_id,
+                                              mint_trace_id,
                                               parse_traceparent)
         self.headers = {k.lower(): v
                         for k, v in (headers or {}).items()}
+        # behind the HTTP door the trace is already open (http.request
+        # is its root): continue it; the TCP gateway door mints here
         self.trace_id = parse_traceparent(
-            self.headers.get("traceparent")) or mint_trace_id()
+            self.headers.get("traceparent")) or current_trace_id() \
+            or mint_trace_id()
         self.stats = IngestStats(origin=origin, dataset=dataset,
                                  trace_id=self.trace_id,
                                  bytes_in=body_bytes)

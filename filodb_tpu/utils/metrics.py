@@ -8,17 +8,22 @@ and exposes them via reporters — a Prometheus endpoint plus log reporters
 
 Here: a process-wide registry of tagged counters/gauges/histograms with
 Prometheus text exposition (served at /metrics by the HTTP layer), and a
-`span()` context manager that records durations into histograms and feeds
-optional span reporters.  Everything is thread-safe and allocation-light —
-metric lookups are dict hits on interned (name, tags) keys.
+`span()` context manager that records one node of a request's span tree
+(ids, parent, monotonic start and duration) into the trace collector, books
+its self time into per-span counters and mirrors itself into the JAX
+profiler's trace.  Everything is thread-safe and allocation-light — metric
+lookups are dict hits on interned (name, tags) keys.
 """
 from __future__ import annotations
 
 import bisect
+import itertools
 import logging
+import random
+import sys
 import threading
 import time
-from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 TagTuple = Tuple[Tuple[str, str], ...]
 
@@ -189,6 +194,7 @@ class MetricsRegistry:
             self._counters.clear()
             self._gauges.clear()
             self._hists.clear()
+        _span_sites.clear()             # cached handles point at the old
 
     # ----------------------------------------------------- sample snapshot
 
@@ -387,16 +393,11 @@ class _ExecTally(threading.local):
 exec_tally = _ExecTally()
 
 
-def note_device_time(seconds: float) -> None:
-    """Attribute device dispatch/kernel wall time to the current node."""
-    exec_tally.device_s += seconds
-
-
 def note_device_call(device: str, kernel: str, seconds: float) -> None:
     """Attribute one device kernel dispatch to the current node, split by
-    (device, kernel) — the sum over entries equals what note_device_time
-    alone would have accumulated, so QueryStats.device_seconds and the
-    per-device breakdown reconcile by construction."""
+    (device, kernel) — device_s accumulates the same seconds, so
+    QueryStats.device_seconds and the per-device breakdown reconcile by
+    construction."""
     exec_tally.device_s += seconds
     cell = exec_tally.device_calls.get((device, kernel))
     if cell is None:
@@ -423,13 +424,11 @@ def note_mirror_refresh(kind: str) -> None:
 
 # ------------------------------------------------------------------ spans
 
-SpanReporter = Callable[[str, float, Dict[str, str]], None]
-_reporters: List[SpanReporter] = []
 _active = threading.local()
 
 # process-wide span kill switch (bench.py observability stage: measures
 # the span pipeline's own overhead by toggling this off).  Stats tallies
-# are NOT affected — only histogram/trace/reporter work is skipped.
+# are NOT affected — only counter/histogram/trace work is skipped.
 SPANS_ENABLED = True
 
 
@@ -462,6 +461,10 @@ class TraceCollector:
         # set by the query frontend at completion; /admin/traces/<id>
         # carries it so "how did this query end" needs no slowlog join
         self._verdicts: Dict[str, str] = {}
+        # trace -> (unix ns, monotonic ns) read together, once, when the
+        # trace is first seen: the one wall-clock anchor that turns its
+        # events' monotonic `start_ns` into unix time for readers
+        self._anchors: Dict[str, Tuple[int, int]] = {}
         # ids evicted from the bounded ring: /traces/{id} answers "410
         # gone" (the trace existed, the ring recycled it) instead of a
         # 404 indistinguishable from a typo.  Bounded itself so hostile
@@ -489,12 +492,15 @@ class TraceCollector:
             evs = self._traces.get(trace_id)
             if evs is None:
                 evs = self._traces[trace_id] = []
+                self._anchors[trace_id] = (time.time_ns(),
+                                           time.perf_counter_ns())
                 self._order.append(trace_id)
                 while len(self._order) > self.max_traces:
                     old = self._order.pop(0)
                     self._traces.pop(old, None)
                     self._origins.pop(old, None)
                     self._verdicts.pop(old, None)
+                    self._anchors.pop(old, None)
                     if old in self._evicted_set:
                         # a re-registered-then-re-evicted id: refresh
                         # its position instead of duplicating it (a
@@ -512,12 +518,28 @@ class TraceCollector:
                     self._evicted_set.add(old)
                     evicted += 1
             if event is not None and len(evs) < self.max_events:
+                if type(event) is dict and "start_unix_ns" in event:
+                    # shipped here by another node (take()): onto this
+                    # node's monotonic clock, through the trace's anchor
+                    wall, mono = self._anchors[trace_id]
+                    event = dict(event)
+                    event["start_ns"] = \
+                        event.pop("start_unix_ns") - wall + mono
                 evs.append(event)
         if evicted:
             registry.counter("trace_evictions").increment(evicted)
         if event is not None:
             for sink in self._sinks:
-                sink(trace_id, event)
+                sink(trace_id, _as_event(event))
+        return evs
+
+    def events_of(self, trace_id: str) -> list:
+        """The trace's live event list, registered in the ring if new.  A
+        `trace_context` fetches it once; the spans inside append to it
+        without the lock (list.append is atomic), bounded by max_events."""
+        with self._lock:
+            evs = self._traces.get(trace_id)
+        return evs if evs is not None else self.record(trace_id, None)
 
     def note_origin(self, trace_id: str, origin: str) -> None:
         """Tag a trace with its door (query | rule_eval | remote_write).
@@ -556,18 +578,38 @@ class TraceCollector:
                 and trace_id not in self._traces
 
     def trace(self, trace_id: str) -> List[dict]:
+        """The trace's events in start order."""
         with self._lock:
-            return list(self._traces.get(trace_id, ()))
+            evs = list(self._traces.get(trace_id, ()))
+        evs = [_as_event(e) for e in evs]
+        evs.sort(key=lambda e: e.get("start_ns", 0))
+        return evs
+
+    def anchor(self, trace_id: str) -> Optional[Tuple[int, int]]:
+        """(unix ns, monotonic ns) of one instant: an event's unix start
+        is `start_ns - monotonic + unix`."""
+        with self._lock:
+            return self._anchors.get(trace_id)
 
     def take(self, trace_id: str) -> List[dict]:
         """Drain the trace's events (used by the node query server: each
         dispatch reply carries exactly the events recorded since the last
-        one, so the coordinator's merge never duplicates)."""
+        one, so the coordinator's merge never duplicates).  Another
+        node's monotonic clock means nothing here, so the copies carry
+        `start_unix_ns` instead of `start_ns`; record() on the receiving
+        node puts them on its own clock."""
         with self._lock:
             evs = self._traces.get(trace_id)
             if not evs:
                 return []
-            out = list(evs)
+            wall, mono = self._anchors[trace_id]
+            out = []
+            for ev in evs:
+                ev = _as_event(ev)
+                if "start_ns" in ev:
+                    ev = dict(ev)
+                    ev["start_unix_ns"] = ev.pop("start_ns") - mono + wall
+                out.append(ev)
             evs.clear()
             return out
 
@@ -640,11 +682,21 @@ class trace_context:
 
     def __enter__(self):
         self._prev = getattr(_active, "trace_id", None)
+        self._prev_events = getattr(_active, "events", None)
         _active.trace_id = self.trace_id
+        _active.events = collector.events_of(self.trace_id) \
+            if self.trace_id else None
+        # an event's `span` path joins the names entered since the
+        # innermost trace context (as it always has: the contexts used to
+        # be entered at the stack's root), whatever spans now lie above
+        self._prev_base = getattr(_active, "path_base", 0)
+        _active.path_base = len(getattr(_active, "stack", ()))
         return self
 
     def __exit__(self, exc_type, exc, tb):
         _active.trace_id = self._prev
+        _active.events = self._prev_events
+        _active.path_base = self._prev_base
         return False
 
 
@@ -652,57 +704,230 @@ def current_trace_id():
     return getattr(_active, "trace_id", None)
 
 
-def add_span_reporter(rep: SpanReporter) -> None:
-    """ref: KamonSpanLogReporter (KamonLogger.scala:16-40)."""
-    _reporters.append(rep)
+class _SpanSite:
+    """What one span name resolves to, once: its three counter families
+    (`span_<name>_self_seconds_total`, `span_<name>_seconds_total`,
+    `span_<name>_calls_total`, dots written `_`) and its profiler label.
+    One family per span and no `span` label, so that a scrape summed per
+    family still tells the spans apart.  Only `_book` writes these
+    counters, under its one lock.
+
+    A site opened with `hist=True` is one of the older spans whose
+    `span_<name>_seconds` histogram (tagged, with trace exemplars) is
+    documented: it keeps the histogram, whose `_sum` is its seconds, in
+    place of the `_seconds_total` counter.  A name is one site: its
+    first opening decides."""
+    __slots__ = ("label", "self_c", "calls_c", "dur_c", "hists")
+
+    def __init__(self, name: str, hist: bool):
+        flat = name.replace(".", "_")
+        self.label = "filodb:" + name
+        self.self_c = registry.counter(f"span_{flat}_self_seconds")
+        self.calls_c = registry.counter(f"span_{flat}_calls")
+        if hist:
+            self.dur_c = None
+            self.hists = (f"span_{name}_seconds", {})   # tags -> Histogram
+        else:
+            self.dur_c = registry.counter(f"span_{flat}_seconds")
+            self.hists = None
+
+    def hist(self, tags: Dict[str, str]) -> Histogram:
+        family, by_tags = self.hists
+        key = tuple(tags.items())
+        h = by_tags.get(key)
+        if h is None:
+            h = by_tags[key] = registry.histogram(family, **tags)
+        return h
 
 
-def remove_span_reporter(rep: SpanReporter) -> None:
-    if rep in _reporters:
-        _reporters.remove(rep)
+_span_sites: Dict[str, _SpanSite] = {}
+# 64-bit span ids: a process-wide counter from a random start (next() on
+# it is atomic), so ids of two nodes in one stitched trace do not meet
+_span_ids = itertools.count(random.getrandbits(63) | 1)
+_trace_annotation = None        # jax.profiler.TraceAnnotation, once found
+_book_lock = threading.Lock()
+_BOOK_AT = 512                  # exited spans a thread holds unbooked
+
+
+def _find_trace_annotation():
+    """jax.profiler.TraceAnnotation if this process has imported JAX,
+    else None: a process without JAX has no profiler session to annotate,
+    and this module must not be the one that imports it."""
+    global _trace_annotation
+    if "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
+
+
+def _book(done: list) -> None:
+    """Book a thread's exited spans, in exit order (children before their
+    parents): each adds its duration to its parent's children time, then
+    `duration - children` to its site's self seconds.  Run when the
+    thread's outermost span exits, after the work it timed (behind the
+    HTTP door: after the reply is written), in one warm loop; an exit
+    itself touches no counter and takes no lock."""
+    try:
+        with _book_lock:
+            for sp in done:
+                site, dur = sp._site, sp.dur_ns
+                parent = sp._parent
+                if parent is not None:
+                    parent._children_ns += dur
+                site.self_c.value += (dur - sp._children_ns) * 1e-9
+                site.calls_c.value += 1
+                if site.dur_c is not None:
+                    site.dur_c.value += dur * 1e-9
+                else:
+                    # the trace id doubles as the histogram's exemplar
+                    # (histogram spike -> /admin/traces/<id> in one hop)
+                    site.hist(sp.tags).record(dur * 1e-9, exemplar=sp._tid)
+    finally:
+        done.clear()
+
+
+def _event(path: str, name: str, trace_id: str, span_id: int, parent,
+           start_ns: int, dur_ns: int, tags: Dict[str, str]) -> dict:
+    """One collector event; `parent` is the enclosing span or None."""
+    ev = {"span": path, "name": name, "trace_id": trace_id,
+          "span_id": "%016x" % span_id,
+          "parent_id": None if parent is None else "%016x" % parent._id,
+          "start_ns": start_ns, "dur_ns": dur_ns, "dur_s": dur_ns * 1e-9,
+          "node": NODE_NAME}
+    if tags:
+        ev.update(tags)
+    return ev
+
+
+def _as_event(item) -> dict:
+    """A trace's list holds finished spans (made into events only when
+    someone reads the trace) and ready-made events (shipped by another
+    node, or recorded by hand)."""
+    return item if type(item) is dict else item.event()
 
 
 class span:
-    """Duration-recording span (ref: Kamon.spanBuilder threaded through
-    ExecPlan.execute / startODPSpan).  Nesting is tracked per thread so
-    reporters see parent names dotted in."""
+    """One node of a request's span tree (ref: Kamon.spanBuilder threaded
+    through ExecPlan.execute / startODPSpan).
 
-    def __init__(self, name: str, **tags: str):
+    Entering pushes a frame on the thread's stack; the frame below it is
+    the parent.  Exiting stamps the duration and, under a `trace_context`,
+    appends the span to the trace.  When the thread's outermost span
+    exits, every span that exited under it is booked (`_book`): its
+    duration into its parent's children time, and `duration - children`
+    as its SELF time (so the self times of a tree sum to its root's
+    duration).  Read back, a span is one event: `span` (the names entered
+    since the innermost `trace_context`, joined into a path), `name`,
+    `trace_id`, `span_id`, `parent_id`, `start_ns` / `dur_ns` on
+    `time.perf_counter_ns()` (CLOCK_MONOTONIC), `dur_s`, `node` and the
+    tags.  The collector anchors each trace to the wall clock once.
+    Inside a `jax.profiler` session every span is also a
+    `TraceAnnotation("filodb:<name>")` on its thread's line of the host
+    plane, on the clock of the device's operations; the outermost span
+    checks the recorder's flag, and the spans under it do as it did.
+
+    `hist=True` keeps the span's documented `span_<name>_seconds`
+    histogram (see _SpanSite).  After exit `dur_ns` holds the duration,
+    for callers that report it elsewhere (one clock, not a second pair
+    around the same call)."""
+
+    __slots__ = ("name", "tags", "dur_ns", "_site", "_t0", "_children_ns",
+                 "_id", "_parent", "_path_root", "_ann", "_tid")
+
+    def __init__(self, name: str, hist: bool = False, **tags: str):
         self.name = name
         self.tags = tags
+        site = _span_sites.get(name)
+        if site is None:
+            site = _span_sites[name] = _SpanSite(name, hist)
+        self._site = site
+        self.dur_ns = 0
+        self._t0 = None
+
+    @property
+    def dur_s(self) -> float:
+        return self.dur_ns * 1e-9
 
     def __enter__(self):
         if not SPANS_ENABLED:
-            self._t0 = None
+            # the clock still runs: QueryStats phases are filled from
+            # span durations and must not change with the switch
+            self._site = None
+            self._t0 = time.perf_counter_ns()
             return self
-        stack = getattr(_active, "stack", None)
+        local = _active.__dict__
+        stack = local.get("stack")
         if stack is None:
-            stack = _active.stack = []
-        stack.append(self.name)
-        self._t0 = time.perf_counter()
+            stack = local["stack"] = []
+            local["done"] = []
+        if stack:
+            parent = self._parent = stack[-1]
+            annotate = parent._ann is not None
+        else:
+            self._parent = None
+            ann = _trace_annotation or _find_trace_annotation()
+            annotate = ann is not None and ann.is_enabled()
+        self._path_root = len(stack) <= local.get("path_base", 0)
+        self._children_ns = 0
+        self._id = next(_span_ids)      # below 2**64 for any process life
+        stack.append(self)
+        if annotate:
+            ann = self._ann = _trace_annotation(self._site.label)
+            ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._t0 is None:
+        t0 = self._t0
+        if t0 is None:
             return False
-        elapsed = time.perf_counter() - self._t0
-        stack = _active.stack
-        full = ".".join(stack)
+        self.dur_ns = time.perf_counter_ns() - t0
+        if self._site is None:
+            return False
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        local = _active.__dict__
+        stack = local["stack"]
         stack.pop()
-        tid = current_trace_id()
-        # the active trace id doubles as the span histogram's exemplar,
-        # so every span_*_seconds family carries OpenMetrics exemplars
-        # for free (histogram spike -> /admin/traces/<id> in one hop)
-        registry.histogram(f"span_{self.name}_seconds",
-                           **self.tags).record(elapsed, exemplar=tid)
+        tid = self._tid = local.get("trace_id")
         if tid:
-            collector.record(tid, {
-                "span": full, "dur_s": round(elapsed, 6),
-                "end_unix_s": round(time.time(), 3),
-                "node": NODE_NAME, **self.tags})
-        for rep in _reporters:
-            rep(full, elapsed, self.tags)
+            evs = local.get("events")
+            if evs is not None and len(evs) < collector.max_events:
+                evs.append(self)        # made into an event when read
+            for sink in collector._sinks:
+                sink(tid, self.event())
+        done = local["done"]
+        done.append(self)
+        if not stack or len(done) >= _BOOK_AT:
+            _book(done)
         return False
+
+    @property
+    def _path(self) -> str:
+        return self.name if self._path_root \
+            else self._parent._path + "." + self.name
+
+    def event(self) -> dict:
+        return _event(self._path, self.name, self._tid, self._id,
+                      self._parent, self._t0, self.dur_ns, self.tags)
+
+
+def record_child_event(trace_id: str, name: str, dur_s: float,
+                       **tags) -> None:
+    """An event that ENDS now and lasted `dur_s`, as a child of the span
+    open on this thread: for a duration that was measured by other spans
+    and is reported once more under a documented name (the device
+    ledger's `kernel_dispatch`, whose `span` field stays that bare name).
+    It books no counter and no self time: the spans that measured it
+    already did."""
+    stack = getattr(_active, "stack", None)
+    dur = int(dur_s * 1e9)
+    collector.record(trace_id, _event(
+        name, name, trace_id, next(_span_ids), stack[-1] if stack else None,
+        time.perf_counter_ns() - dur, dur, tags))
 
 
 # ----------------------------------------------------- scheduler asserts

@@ -129,8 +129,7 @@ class SlowQueryLog(_RingLog):
         spans: List[dict] = []
         if trace_id:
             # copy NOW: the trace collector's ring recycles old traces
-            spans = sorted(collector.trace(trace_id),
-                           key=lambda e: e.get("end_unix_s", 0))
+            spans = collector.trace(trace_id)
         stats = getattr(result, "stats", None)
         rec = {
             "unix_ts": round(time.time(), 3),
@@ -194,8 +193,7 @@ class IngestSlowLog(_RingLog):
         from filodb_tpu.utils.metrics import collector, registry
         spans: List[dict] = []
         if stats.trace_id:
-            spans = sorted(collector.trace(stats.trace_id),
-                           key=lambda e: e.get("end_unix_s", 0))
+            spans = collector.trace(stats.trace_id)
         rec = stats.to_dict()
         rec["unix_ts"] = round(time.time(), 3)
         rec["spans"] = spans

@@ -42,18 +42,31 @@ def _zipkin_span(trace_id: str, event: dict) -> dict:
                                        for c in tid):
         tid = uuid.uuid5(uuid.NAMESPACE_OID, trace_id).hex
     dur_us = max(int(float(event.get("dur_s", 0.0)) * 1e6), 1)
-    end_s = float(event.get("end_unix_s", time.time()))
-    tags = {k: str(v) for k, v in event.items()
-            if k not in ("span", "dur_s", "end_unix_s", "node")}
-    return {
+    # the trace's one wall-clock anchor turns the event's monotonic start
+    # into unix time; an event without the clock (hand-made, or from a
+    # peer older than the span ids) ends now
+    anchor = collector.anchor(trace_id)
+    if anchor is not None and "start_ns" in event:
+        start_us = (event["start_ns"] - anchor[1] + anchor[0]) // 1000
+    else:
+        start_us = int(time.time() * 1e6) - dur_us
+    tags = {k: str(v) for k, v in event.items() if k not in _NOT_TAGS}
+    out = {
         "traceId": tid,
-        "id": uuid.uuid4().hex[:16],
+        "id": event.get("span_id") or uuid.uuid4().hex[:16],
         "name": str(event.get("span", "span")),
-        "timestamp": int((end_s - dur_us / 1e6) * 1e6),
+        "timestamp": int(start_us),
         "duration": dur_us,
         "localEndpoint": {"serviceName": str(event.get("node") or "filodb")},
         "tags": tags,
     }
+    if event.get("parent_id"):
+        out["parentId"] = event["parent_id"]
+    return out
+
+
+_NOT_TAGS = frozenset(("span", "name", "trace_id", "span_id", "parent_id",
+                       "start_ns", "dur_ns", "dur_s", "node"))
 
 
 class TraceExporter:

@@ -110,7 +110,7 @@ class WalWriter:
         # write-path trace: one span per buffered append (encode + frame
         # + buffer write; the fsync is the committer's and shows up as
         # the caller's wal_commit_wait span instead)
-        with metrics_span("wal_append", dataset=self.dataset):
+        with metrics_span("wal_append", hist=True, dataset=self.dataset):
             return self._append_record(rec)
 
     def _append_record(self, rec: WalRecord) -> int:
@@ -156,7 +156,7 @@ class WalWriter:
         # the group-commit fsync wait: THE write-path latency suspect,
         # so it gets its own span (stitches under the batch's trace) on
         # top of the committer's wal_fsync_seconds histogram
-        with metrics_span("wal_commit_wait", dataset=self.dataset):
+        with metrics_span("wal_commit_wait", hist=True, dataset=self.dataset):
             self._wait_committed(seq, timeout_s)
 
     def _wait_committed(self, seq: int, timeout_s: float = 30.0) -> None:

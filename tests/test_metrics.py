@@ -9,9 +9,8 @@ import pytest
 
 from filodb_tpu.core.memstore import TimeSeriesMemStore
 from filodb_tpu.ingest.generator import gauge_batch
-from filodb_tpu.utils.metrics import (FiloSchedulers, Histogram, registry,
-                                      add_span_reporter, remove_span_reporter,
-                                      span)
+from filodb_tpu.utils.metrics import (FiloSchedulers, Histogram, collector,
+                                      registry, span, trace_context)
 
 START = 1_600_000_020_000
 
@@ -34,18 +33,29 @@ def test_counter_gauge_histogram_basics():
 
 
 def test_span_records_and_reports():
-    seen = []
-    rep = lambda name, dur, tags: seen.append((name, dur, tags))  # noqa: E731
-    add_span_reporter(rep)
-    try:
-        with span("outer", q="1"):
+    """(Was the reporter-hook test; the hook is gone and the collector's
+    events are what a reader of spans gets.)"""
+    self0 = registry.counter("span_outer_self_seconds").value
+    with trace_context("t-span-records"):
+        with span("outer", hist=True, q="1"):
             with span("inner"):
                 pass
-    finally:
-        remove_span_reporter(rep)
-    names = [s[0] for s in seen]
-    assert names == ["outer.inner", "outer"]
+    evs = collector.trace("t-span-records")
+    assert [e["span"] for e in evs] == ["outer", "outer.inner"]  # by start
+    outer, inner = evs
+    assert [e["name"] for e in evs] == ["outer", "inner"]
+    assert inner["parent_id"] == outer["span_id"] and \
+        outer["parent_id"] is None
+    assert outer["q"] == "1" and outer["trace_id"] == "t-span-records"
+    assert outer["start_ns"] <= inner["start_ns"] and \
+        inner["start_ns"] + inner["dur_ns"] \
+        <= outer["start_ns"] + outer["dur_ns"]
     assert registry.histogram("span_outer_seconds", q="1").count >= 1
+    # self time = duration less the children's, booked by the program
+    booked = registry.counter("span_outer_self_seconds").value - self0
+    assert booked == pytest.approx(
+        (outer["dur_ns"] - inner["dur_ns"]) * 1e-9, abs=1e-12)
+    assert registry.counter("span_outer_calls").value >= 1
 
 
 def test_ingest_and_query_emit_metrics():

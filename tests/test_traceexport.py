@@ -14,8 +14,11 @@ from filodb_tpu.utils.traceexport import TraceExporter, _zipkin_span
 
 
 def _event(i=0):
-    return {"span": f"exec.{i}", "dur_s": 0.002,
-            "end_unix_s": time.time(), "node": "n1", "shard": str(i)}
+    return {"span": f"exec.{i}", "name": f"exec.{i}", "trace_id": "t",
+            "span_id": "%016x" % (i + 1),
+            "parent_id": "%016x" % i if i else None,
+            "start_ns": time.perf_counter_ns(), "dur_ns": 2_000_000,
+            "dur_s": 0.002, "node": "n1", "shard": str(i)}
 
 
 # ---------------------------------------------------------------- batching
@@ -47,6 +50,16 @@ def test_zipkin_span_shape():
     assert sp["duration"] == 2000
     assert sp["localEndpoint"]["serviceName"] == "n1"
     assert sp["tags"] == {"shard": "3"}
+    # the event's own ids, not minted ones; a root has no parentId
+    assert sp["id"] == "%016x" % 4 and sp["parentId"] == "%016x" % 3
+    assert "parentId" not in _zipkin_span("a" * 32, _event(0))
+    # timestamp: the monotonic start through the trace's one anchor
+    with trace_context("zipkin-anchor"), span("anchored"):
+        pass
+    real = collector.trace("zipkin-anchor")[0]
+    z = _zipkin_span("zipkin-anchor", real)
+    assert z["id"] == real["span_id"]
+    assert abs(z["timestamp"] / 1e6 - time.time()) < 5.0
     # a non-hex trace id still produces a valid 32-hex id
     weird = _zipkin_span("not-a-uuid!", _event())
     assert len(weird["traceId"]) == 32

@@ -36,9 +36,20 @@ def test_cross_node_query_stitches_one_trace():
         # remote subtree spans crossed the wire, tagged with their plan:
         # with aggregation pushdown the dispatched subtree is the node's
         # RemoteAggregateExec group (one per NODE, not per shard)
-        remotes = [e for e in evs if e["span"].startswith("remote_exec")]
+        remotes = [e for e in evs if e["span"] == "remote_exec"]
         assert remotes and all(
             r.get("plan") == "RemoteAggregateExec" for r in remotes)
+        # ... and each remote root brought its subtree: the remote node's
+        # exec nodes hang under it by parent id, on this node's clock
+        by_id = {e["span_id"]: e for e in evs}
+        for r in remotes:
+            kids = [e for e in evs if e["parent_id"] == r["span_id"]]
+            assert any(k["name"].startswith("exec.") for k in kids)
+            assert all(r["start_ns"] <= k["start_ns"] and
+                       k["start_ns"] + k["dur_ns"]
+                       <= r["start_ns"] + r["dur_ns"] for k in kids)
+        assert all(e["parent_id"] is None or e["parent_id"] in by_id
+                   for e in evs)
         # one per dispatched node group (2 nodes x 2 shards), no
         # duplication from the drain-per-reply protocol
         assert len(remotes) == 2, names
@@ -167,7 +178,7 @@ def test_trace_export_file_and_http(tmp_path):
     exp = TraceExporter(f"file://{path}", flush_interval_s=0.05).start()
     try:
         with trace_context("11111111-2222-3333-4444-555555555555"):
-            with span("execplan", plan="TestExec"):
+            with span("execplan", hist=True, plan="TestExec"):
                 _time.sleep(0.01)
         deadline = _time.time() + 5
         while _time.time() < deadline and not path.exists():
