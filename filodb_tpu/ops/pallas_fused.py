@@ -5,7 +5,8 @@ XLA path several passes over the [S, T] value matrix (validity mask, reset
 correction scan, boundary gathers, then a scatter-add segment sum).  On a
 bandwidth-bound chip the passes are the latency.  This kernel computes the
 whole thing in ONE read of the values, by turning every data-dependent
-access into an MXU matmul against tiny host-built selection matrices:
+access into an MXU matmul against 0/1 selection matrices, built on the
+device from a few host-built window rows (kernel_operands):
 
 - boundary gathers  v[:, first[w]]  ->  v @ O1, O1[t, w] = 1{t == first[w]}
 - cumulative reset corrections      ->  drops @ L1, L1[t, w] = 1{t <= first[w]}
@@ -25,7 +26,6 @@ from __future__ import annotations
 
 import functools
 import os
-import threading
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -204,31 +204,35 @@ def pad_group_count(G: int) -> int:
     return _bucket_up(max(G, 8), 8, 64)
 
 
+# row order of FusedPlan.rows, the one block an enqueue uploads
+_T1, _T2, _N, _N1, _WS, _WE, _I1, _I2 = range(8)
+
+
 class FusedPlan(NamedTuple):
-    """Host-built query plan: selection matrices + shared window scalars."""
-    o1: np.ndarray       # [Tp, Wp] f32  one-hot at first[w]
-    o2: np.ndarray       # [Tp, Wp] f32  one-hot at last[w]
-    l2: np.ndarray       # [Tp, Wp] f32  1{t <= last[w]}  (drops path)
-    l1: np.ndarray       # [Tp, Wp] f32  1{t <= first[w]} (drops path)
+    """Host-built query plan: the shared window rows, packed for upload.
+    The selection / band matrices the matmul kinds read are a pure
+    function of `idx1`, `idx2` and `n1 >= 1` and are built on the device,
+    inside the jitted call (kernel_operands)."""
+    rows: np.ndarray     # [8, Wp] f32  the eight rows below, in this order
     t1: np.ndarray       # [1, Wp] f32   ts at first[w]
     t2: np.ndarray       # [1, Wp] f32   ts at last[w]
-    n: np.ndarray        # [1, Wp] f32   samples in window
+    n: np.ndarray        # [1, Wp] f32   samples in window (>= 2, math-safe)
+    n1: np.ndarray       # [1, Wp] f32   TRUE samples in window (0 empty)
     wstart_x: np.ndarray  # [1, Wp] f32  window start boundary (exclusive-1)
     wend_x: np.ndarray   # [1, Wp] f32
-    wvalid: np.ndarray   # [W] bool      n >= 2 (rate family)
-    wvalid1: np.ndarray  # [W] bool      n >= 1 (*_over_time family)
-    n1: np.ndarray       # [1, Wp] f32   TRUE samples in window (0 empty)
-    W: int
-    Tp: int
+    # boundary slot indices (first[w] / last[w]; 0 sentinel for empty +
+    # padded windows): the gather-strategy kernel selects columns at these
+    # positions, the matmul kinds' one-hot / step matrices are built from
+    # them (gather_default)
+    idx1: np.ndarray     # [1, Wp] f32
+    idx2: np.ndarray     # [1, Wp] f32
     # raw shared-grid timestamps [1, Tp] f32 (0 pad tail): the ragged rate
     # family selects per-series VALID boundary timestamps in-kernel
-    tsrow: np.ndarray = None
-    # boundary slot indices [1, Wp] f32 (first[w] / last[w]; 0 sentinel
-    # for empty + padded windows) — the gather-strategy kernel selects
-    # columns at these host-built positions instead of multiplying the
-    # o1/o2 one-hot matrices (gather_default)
-    idx1: np.ndarray = None
-    idx2: np.ndarray = None
+    tsrow: np.ndarray
+    wvalid: np.ndarray   # [W] bool      n >= 2 (rate family)
+    wvalid1: np.ndarray  # [W] bool      n >= 1 (*_over_time family)
+    W: int
+    Tp: int
 
 
 def build_plan(ts_row: np.ndarray, wends: np.ndarray,
@@ -242,131 +246,82 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
     n = window_counts(ts_row, wend, range_ms)
     W, T = len(wend), len(ts_row)
     Wp, Tp = _pad_to(max(W, 1), _LANE), _pad_to(max(T, 1), _LANE)
-    # selection matrices cover every NON-EMPTY window (n >= 1): the
-    # over_time band needs single-sample windows, and the rate family is
-    # harmless on them (first == last -> delta == 0 -> contributes 0; its
-    # host mask wvalid stays n >= 2)
+    # boundary rows cover every NON-EMPTY window (n >= 1): the over_time
+    # band needs single-sample windows, and the rate family is harmless on
+    # them (first == last -> delta == 0 -> contributes 0; its host mask
+    # wvalid stays n >= 2)
     valid = n >= 1
-
-    def sel(idx, leq):
-        m = np.zeros((Tp, Wp), np.float32)
-        t = np.arange(Tp)[:, None]
-        iw = np.where(valid, np.clip(idx, 0, T - 1), -1)[None, :]
-        body = (t <= iw) if leq else (t == iw)
-        m[:, :W] = body.astype(np.float32)
-        return m
-
-    def row(v):
-        out = np.zeros((1, Wp), np.float32)
-        out[0, :W] = v
-        return out
-
     fi = np.clip(first, 0, T - 1)
     la = np.clip(last, 0, T - 1)
+    rows = np.zeros((8, Wp), np.float32)
+    rows[_T1, :W] = np.where(valid, ts_row[fi], 0)
+    rows[_T2, :W] = np.where(valid, ts_row[la], 0)
+    rows[_N, :W] = np.maximum(n, 2)        # safe: invalid windows masked out
+    rows[_N1, :W] = n
+    rows[_WS, :W] = wstart - 1
+    rows[_WE, :W] = wend
+    rows[_I1, :W] = np.where(valid, fi, 0)
+    rows[_I2, :W] = np.where(valid, la, 0)
     tsr = np.zeros((1, Tp), np.float32)
     tsr[0, :T] = ts_row
-    return FusedPlan(
-        o1=sel(first, False), o2=sel(last, False),
-        l2=sel(last, True), l1=sel(first, True),
-        t1=row(np.where(valid, ts_row[fi], 0)),
-        t2=row(np.where(valid, ts_row[la], 0)),
-        n=row(np.maximum(n, 2)),           # safe: invalid windows masked out
-        wstart_x=row(wstart - 1), wend_x=row(wend),
-        wvalid=(n >= 2), wvalid1=(n >= 1), n1=row(n), W=W, Tp=Tp,
-        tsrow=tsr,
-        idx1=row(np.where(valid, fi, 0)), idx2=row(np.where(valid, la, 0)))
+    return FusedPlan(rows, *(rows[i:i + 1] for i in range(8)), tsrow=tsr,
+                     wvalid=(n >= 2), wvalid1=(n >= 1), W=W, Tp=Tp)
 
 
-_PLAN_MATS_CACHE: dict = {}
-_PLAN_MATS_LOCK = threading.Lock()
+def kernel_operands(rows, tsrow, Tp: int, over_time: bool, gather: bool):
+    """The 12 operands `_kernel` reads after (vals, vbase, gids), from a
+    plan's uploaded rows.  Traceable: `_run` calls it inside its jit (and
+    with it every caller that composes `run_kernel` under its own trace,
+    parallel/mesh.py), so an enqueue ships the [8, Wp] rows and nothing
+    else of the plan.
+
+    The matmul kinds' selection matrices are built here, on the device:
+    o[t, w] = 1{t == idx[w]} and l[t, w] = 1{t <= idx[w]} over the
+    non-empty windows (n1 >= 1), 0 elsewhere.  In gather mode the kernel
+    reads none of them and gets [8, 128] stand-ins, which frees their
+    ~1.5 MB of VMEM for larger series blocks.  `n` resolves to the true
+    counts for the over_time kinds; `tsrow` None (every kind but the
+    ragged rate family leaves it unread) becomes zeros."""
+    def row(i):
+        return rows[i:i + 1]
+
+    if gather:
+        sel = (jnp.zeros((8, _LANE), jnp.float32),) * 4
+    else:
+        t = jax.lax.broadcasted_iota(jnp.int32, (Tp, rows.shape[1]), 0)
+        valid = row(_N1) >= 1.0
+
+        def mat(i, leq):
+            iw = jnp.where(valid, row(i).astype(jnp.int32), -1)
+            return ((t <= iw) if leq else (t == iw)).astype(jnp.float32)
+
+        sel = (mat(_I1, False), mat(_I2, False), mat(_I1, True),
+               mat(_I2, True))
+    if tsrow is None:
+        tsrow = jnp.zeros((1, Tp), jnp.float32)
+    return sel + (row(_T1), row(_T2), row(_N1 if over_time else _N),
+                  row(_WS), row(_WE), tsrow, row(_I1), row(_I2))
 
 
-def plan_device_mats(plan: "FusedPlan", device=None) -> tuple:
-    """Device-resident copies of a plan's selection matrices + window
-    rows, uploaded ONCE per (plan object, device).
-
-    Without it every query re-uploaded ~1.6 MB of numpy plan matrices
-    through `jnp.asarray`, a per-call cost several times the kernel's own
-    device time at 262k x 720.  Keyed by id(plan) with the
-    plan pinned (id-reuse safe), matching the leaf/mesh plan caches'
-    lifetime.  One cache entry per plan holds ALL its per-device uploads
-    (the multi-chip per-device dispatch path pins the same plan on every
-    participating device), so device fan-out can't thrash the LRU."""
-    from filodb_tpu.utils.devicetelem import telem
-    k = id(plan)
-    dk = None if device is None else device
-    with _PLAN_MATS_LOCK:
-        ent = _PLAN_MATS_CACHE.get(k)
-        if ent is not None and ent[0] is plan and dk in ent[1]:
-            # LRU touch: eviction pops the oldest entry, and a hot mesh
-            # plan hit on every query must not age out under mixed
-            # leaf+mesh traffic filling the cap
-            _PLAN_MATS_CACHE.pop(k)
-            _PLAN_MATS_CACHE[k] = ent
-            telem.record_cache_event("plan_mats", "hit")
-            return ent[1][dk]
-    telem.record_cache_event("plan_mats", "miss")
-    W = plan.t1.shape[1]
-    idx1 = plan.idx1 if plan.idx1 is not None else np.zeros((1, W),
-                                                            np.float32)
-    idx2 = plan.idx2 if plan.idx2 is not None else np.zeros((1, W),
-                                                            np.float32)
-    put = (jnp.asarray if device is None
-           else (lambda m: jax.device_put(m, device)))
-    mats = tuple(put(m) for m in
-                 (plan.o1, plan.o2, plan.l1, plan.l2, plan.t1, plan.t2,
-                  plan.n, plan.n1, plan.wstart_x, plan.wend_x, plan.tsrow,
-                  idx1, idx2))
-    released: list = []
-    with _PLAN_MATS_LOCK:
-        ent = _PLAN_MATS_CACHE.get(k)
-        if ent is None or ent[0] is not plan:
-            if ent is not None:
-                released.append(ent)        # id-reuse: old plan replaced
-            ent = (plan, {})
-            _PLAN_MATS_CACHE[k] = ent
-        if dk not in ent[1]:                # a concurrent build may have
-            ent[1][dk] = mats               # won: book each upload once
-            telem.hbm_book(dk, "planmats", _mats_nbytes(mats))
-        while len(_PLAN_MATS_CACHE) > 8:
-            released.append(
-                _PLAN_MATS_CACHE.pop(next(iter(_PLAN_MATS_CACHE))))
-    for _, uploads in released:
-        telem.record_cache_event("plan_mats", "evict")
-        for dk2, mats2 in uploads.items():
-            telem.hbm_book(dk2, "planmats", -_mats_nbytes(mats2))
-    return mats
-
-
-def _mats_nbytes(mats) -> int:
-    """Device bytes of one plan's uploaded matrix set (the 'planmats'
-    HBM occupancy region)."""
-    return int(sum(getattr(m, "nbytes", 0) for m in mats))
-
-
-_SEL_DUMMY: dict = {}
-
-
-def _sel_dummy(device=None):
-    """Tiny stand-in for the unused selection matrices in gather mode —
-    the kernel never reads them, and the small block frees their ~1.5 MB
-    of VMEM for larger series blocks.  One per device: the per-device
-    dispatch path needs every kernel operand committed to ITS chip."""
-    dk = None if device is None else device
-    d = _SEL_DUMMY.get(dk)
-    if d is None:
-        z = np.zeros((8, _LANE), np.float32)
-        d = jnp.asarray(z) if device is None else jax.device_put(z, device)
-        _SEL_DUMMY[dk] = d
-    return d
+def merge_gid_cols(gids, offsets):
+    """Traceable: P panels' [Sp, 1] gid columns -> one [Sp, P] matrix over
+    DISJOINT group-id ranges (panel p's ids shifted by offsets[p], the
+    sum of the earlier panels' group counts; -1 pad rows stay -1).  The
+    kernel epilogue turns the columns into one multi-hot matrix, so P
+    groupings cost ONE dispatch.  One operand passes through unchanged:
+    a single panel's offset is 0, and the mesh merges on the host."""
+    if len(gids) == 1:
+        return gids[0]
+    return jnp.concatenate(
+        [jnp.where(col >= 0, col + offsets[p], -1)
+         for p, col in enumerate(gids)], axis=1)
 
 
 def _committed_device(arr):
     """The single device `arr` is committed to, else None — uncommitted
     arrays follow jax's default placement, no pin needed.  Used to route
-    plan-matrix uploads to the chip that already holds a working set
-    (sharded DeviceMirror mode), so dispatch never drags the ~1.6 MB of
-    selection matrices cross-device per call."""
+    an enqueue's plan rows to the chip that already holds the working set
+    (sharded DeviceMirror mode), so the jit call runs there."""
     try:
         if getattr(arr, "committed", False):
             devs = arr.devices()
@@ -377,15 +332,21 @@ def _committed_device(arr):
     return None
 
 
-def _kernel_mats(plan: "FusedPlan", over_time: bool,
-                 gather: bool = False, device=None) -> tuple:
-    """The 12 operands _run expects after (vals, vbase, gids), with `n`
-    resolved to true counts for the over_time kinds and the o1..l2
-    selection matrices swapped for dummies in gather mode.  `device`
-    pins the upload (per-device dispatch, parallel/mesh.py)."""
-    m = plan_device_mats(plan, device)
-    sel = (_sel_dummy(device),) * 4 if gather else m[:4]
-    return sel + m[4:6] + (m[7] if over_time else m[6],) + m[8:]
+def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
+                     offsets=None) -> tuple:
+    """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
+    takes from the host, put explicitly (so the call itself transfers
+    nothing) and counted, uploads beside enqueues, on /metrics.  `tsrow`
+    rides only where the kernel reads it (the ragged rate family),
+    `offsets` only for a batch of several panels; the others are None."""
+    from filodb_tpu.utils.metrics import registry
+    host = (plan.rows,
+            plan.tsrow if ragged and kind == "rate_family" else None,
+            None if offsets is None else np.asarray(offsets, np.int32))
+    registry.counter("fused_enqueues").increment()
+    registry.counter("fused_enqueue_uploads").increment(
+        sum(x is not None for x in host))
+    return jax.device_put(host, device)     # None is an empty pytree
 
 
 def _shift_r(x, k: int, fill):
@@ -678,7 +639,7 @@ def _epilogue(mm, gids_ref, out, pres, out_refs, num_groups: int,
     groups = jax.lax.broadcasted_iota(jnp.int32, (num_groups, out.shape[0]),
                                       0)
     onehot = (groups == gids[:, 0][None, :]).astype(jnp.float32)
-    # multi-grouping batch (merge_groups): each extra column is another
+    # multi-grouping batch (merge_gid_cols): each extra column is another
     # panel's grouping over DISJOINT group-id ranges, so the sum stays a
     # 0/1 matrix and P dashboard panels ride ONE kernel dispatch
     for p in range(1, gids.shape[1]):
@@ -707,17 +668,24 @@ def _run_shape_sig(vals_p, plan, Gp: int, kind: str, ragged: bool) -> str:
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
     "kind", "ragged", "per_series", "gather"))
-def _run(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
-         idx1, idx2,
+def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
          num_groups: int, is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
          ragged: bool = False, per_series: bool = False,
          gather: bool = False):
+    """One fused dispatch, whole: the group merge (merge_gid_cols), the
+    plan's kernel operands (kernel_operands) and the Pallas call.  `gids`
+    is a tuple of gid columns with `offsets` their traced int32 group-id
+    shifts (None for one operand), so a new group count compiles
+    nothing; `rows` / `tsrow` are the plan's uploaded rows."""
     from jax.experimental.pallas import tpu as pltpu
 
     Sp, Tp = vals_p.shape
-    Wp = t1.shape[1]
+    Wp = rows.shape[1]
     Gp = num_groups
+    gids_p = merge_gid_cols(gids, offsets)
+    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = kernel_operands(
+        rows, tsrow, Tp, kind in OVER_TIME_FNS, gather)
     # adaptive series block: the ragged rate family's scan temporaries
     # scale with bs*Tp, so long rows shrink the block instead of OOMing
     # scoped vmem (or being rejected by the eligibility gate).  All
@@ -741,7 +709,7 @@ def _run(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
     space = {} if interpret else {"memory_space": pltpu.VMEM}
     row_spec = pl.BlockSpec((bs, Tp), lambda i: (i, 0), **space)
     col_spec = pl.BlockSpec((bs, 1), lambda i: (i, 0), **space)
-    # gids may carry P grouping columns (multi-panel batch, merge_groups)
+    # gids may carry P grouping columns (multi-panel batch)
     gid_spec = pl.BlockSpec((bs, gids_p.shape[1]), lambda i: (i, 0), **space)
     fix = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0), **space)  # noqa: E731
     kern = functools.partial(_kernel, num_groups=Gp, is_counter=is_counter,
@@ -757,9 +725,8 @@ def _run(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
         out_shape = jax.ShapeDtypeStruct((Gp, Wp), jnp.float32)
     out_specs = [out_spec, out_spec] if with_counts else out_spec
     out_shapes = [out_shape, out_shape] if with_counts else out_shape
-    # selection-matrix specs follow the operands' actual shapes: gather-
-    # mode callers pass tiny dummies for the unused o1/o2/l1/l2, freeing
-    # their ~1.5 MB of VMEM for larger series blocks
+    # selection-matrix specs follow the operands' actual shapes: gather
+    # mode gets tiny stand-ins for the unused o1/o2/l1/l2
     return pl.pallas_call(
         kern,
         grid=(grid,),
@@ -804,7 +771,7 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int,
         # kept until the next on-chip window re-measures it (conservative
         # = smaller blocks than strictly needed, never an OOM)
         vals += 19 * bs * Tp * 4
-    # multi-panel epilogue (merge_groups): each extra grouping column
+    # multi-panel epilogue (merge_gid_cols): each extra grouping column
     # builds another [Gp, bs] one-hot compare temporary feeding the
     # accumulated multi-hot — a large merged batch that fit the P=1
     # model could still exceed scoped VMEM at Mosaic lowering on-chip
@@ -876,16 +843,9 @@ def can_fuse(fn_name: str, agg_op: str, shared_grid: bool,
 
 # traceable entry for callers composing the kernel inside shard_map (the
 # mesh executor); the jit wrapper inlines under an enclosing trace.
-# idx1/idx2 optional for legacy 13-operand callers (matmul path only).
-def run_kernel(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we,
-               ts, idx1=None, idx2=None, *, gather: bool = False, **kw):
-    if idx1 is None or idx2 is None:
-        if gather:
-            raise ValueError("gather=True requires idx1/idx2 operands")
-        z = jnp.zeros((1, t1.shape[1]), jnp.float32)
-        idx1 = idx2 = z
-    return _run(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we,
-                ts, idx1, idx2, gather=gather, **kw)
+# gids_p is one [Sp, P] matrix, merged by the caller.
+def run_kernel(vals_p, vbase_p, gids_p, rows, tsrow=None, **kw):
+    return _run(vals_p, vbase_p, (gids_p,), None, rows, tsrow, **kw)
 
 
 class PreparedInputs(NamedTuple):
@@ -969,7 +929,7 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
     validity-aware kernel variant instead; counts then come back from the
     kernel's per-cell presence output.
 
-    `device` pins every operand (values, plan mats) to that chip so the
+    `device` pins every operand (values, plan rows) to that chip so the
     jit executes THERE — the per-device unit of the multi-chip dispatch
     path (parallel/mesh.py), which runs this exact function once per
     device and merges the [G, W] partials it returns.
@@ -984,18 +944,18 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
                               device=device)
     elif device is None:
         # caller-prepared inputs may already be pinned (sharded mirror
-        # mode) — keep the plan matrices on the same chip
+        # mode) — put the plan rows on the same chip
         device = _committed_device(prepared.vals_p)
     Gp = pad_group_count(num_groups)
     if gather is None:
-        gather = gather_default(kind) and plan.idx1 is not None
+        gather = gather_default(kind)
     from filodb_tpu.utils.devicetelem import watched_call
-    mats = _kernel_mats(plan, over_time, gather, device=device)
+    rows, tsrow, _ = enqueue_operands(plan, device, kind, ragged)
     res = watched_call(
         "fused_run", _run,
         _run_shape_sig(prepared.vals_p, plan, Gp, kind, ragged),
-        lambda: _run(prepared.vals_p, prepared.vbase_p, prepared.gids_p,
-                     *mats,
+        lambda: _run(prepared.vals_p, prepared.vbase_p,
+                     (prepared.gids_p,), None, rows, tsrow,
                      num_groups=Gp, is_counter=is_counter,
                      is_rate=is_rate, with_drops=with_drops,
                      interpret=interpret, kind=kind, ragged=ragged,
@@ -1195,19 +1155,24 @@ def fused_leaf_agg(plan: FusedPlan, prepared: PreparedInputs,
         num_series=len(gids))[0]
 
 
-def merge_groups(groups_list, num_groups_list):
-    """Stack P panel groupings into one [Sp, P] gid matrix over DISJOINT
-    group-id ranges (panel p's ids are offset by sum of earlier panels'
-    group counts; -1 pad rows stay -1).  The kernel epilogue turns the
-    columns into one multi-hot matrix, so P groupings cost ONE dispatch.
-    Returns (gids_multi, offsets, total_groups)."""
-    cols, offsets, off = [], [], 0
-    for g, n in zip(groups_list, num_groups_list):
-        col = g.gids_p[:, 0]
-        cols.append(jnp.where(col >= 0, col + off, -1))
-        offsets.append(off)
-        off += int(n)
-    return jnp.stack(cols, axis=1), offsets, off
+@functools.partial(jax.jit, static_argnames=(
+    "ops", "num_groups", "S", "W", "minsamp"))
+def _per_series_aggs(res, rows, gids, *, ops, num_groups, S: int, W: int,
+                     minsamp: int):
+    """Finish a per-series-mode run for its min/max panels in one jit:
+    mask the [Sp, Wp] kernel output to present cells (the kernel's
+    presence output on ragged rows, else the shared window validity
+    n1 >= minsamp off the plan rows) and segment-reduce it per panel
+    (ops/agg.map_phase) -> one [G, W, C] partial a panel."""
+    from filodb_tpu.ops import agg as agg_ops
+    if isinstance(res, (tuple, list)):
+        present = res[1][:S, :W] > 0
+        res = res[0]
+    else:
+        present = rows[_N1:_N1 + 1, :W] >= minsamp
+    per = jnp.where(present, res[:S, :W], jnp.nan)
+    return tuple(agg_ops.map_phase(op, per, g[:S, 0], G)
+                 for op, g, G in zip(ops, gids, num_groups))
 
 
 def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
@@ -1222,9 +1187,11 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
 
     panels: [(PaddedGroups, num_groups, agg_op)].  All panels share
     (plan, values, fn_name, precorrected, ragged).  sum/avg/count panels
-    merge into one group-mode run via merge_groups (disjoint id spaces,
-    multi-hot epilogue); min/max panels share one per-series-mode run
-    finished by per-panel XLA segment reductions; dense count panels are
+    merge into one group-mode run (merge_gid_cols inside the jit: disjoint
+    id spaces, multi-hot epilogue); min/max panels share one per-series-
+    mode run finished by one jit of per-panel segment reductions
+    (_per_series_aggs); each run is ONE jit call whose host operands
+    (enqueue_operands) are put explicitly; dense count panels are
     host-only math.  Returns per-panel [G, W, C] float64 components in
     input order (ops/agg.AGGREGATORS layout).
 
@@ -1240,25 +1207,31 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
     kind = fn_name if over_time else "rate_family"
     wvalid = plan.wvalid1 if over_time else plan.wvalid
 
-    gather = gather_default(kind) and plan.idx1 is not None
+    gather = gather_default(kind)
     # sharded DeviceMirror mode: the working set is committed to its
-    # shard's chip — pin the plan matrices there too, or every dispatch
-    # re-ships them from the default device (the per-call upload
-    # pathology plan_device_mats exists to kill)
+    # shard's chip — the plan rows go there too, so the call runs there
     device = _committed_device(values.vals_p)
+    from filodb_tpu.utils.metrics import span_part
 
-    def run(gids_p, Gp, per_series):
+    def run(cols, offsets, Gp, per_series):
+        """One `_run` dispatch over the panels' gid columns: the small
+        host operands put explicitly, then the one jit call."""
         from filodb_tpu.utils.devicetelem import watched_call
-        mats = _kernel_mats(plan, over_time, gather, device=device)
-        return watched_call(
-            "fused_run", _run,
-            _run_shape_sig(values.vals_p, plan, Gp, kind, ragged),
-            lambda: _run(values.vals_p, values.vbase_p, gids_p, *mats,
-                         num_groups=Gp, is_counter=is_counter,
-                         is_rate=is_rate, with_drops=with_drops,
-                         interpret=interpret, kind=kind, ragged=ragged,
-                         per_series=per_series, gather=gather),
-            device=device)
+        with span_part("leaf.enqueue_pack"):
+            rows, tsrow, offs = enqueue_operands(plan, device, kind,
+                                                 ragged, offsets)
+        with span_part("leaf.enqueue_jit"):
+            res = watched_call(
+                "fused_run", _run,
+                _run_shape_sig(values.vals_p, plan, Gp, kind, ragged),
+                lambda: _run(values.vals_p, values.vbase_p, cols, offs,
+                             rows, tsrow,
+                             num_groups=Gp, is_counter=is_counter,
+                             is_rate=is_rate, with_drops=with_drops,
+                             interpret=interpret, kind=kind, ragged=ragged,
+                             per_series=per_series, gather=gather),
+                device=device)
+        return res, rows
 
     def dense_counts(groups):
         return groups.gsize[:, None].astype(np.float64) * \
@@ -1278,30 +1251,26 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
     # read back — all results below are lazy device arrays
     mm_res = offsets = None
     if mm_idx:
-        gids_multi, offsets, total = merge_groups(
-            [panels[i][0] for i in mm_idx], [panels[i][1] for i in mm_idx])
-        Gp = pad_group_count(total)
-        mm_res = run(gids_multi, Gp, per_series=False)
-    ps_comps: dict = {}
+        counts = [int(panels[i][1]) for i in mm_idx]
+        offsets = np.cumsum([0] + counts[:-1])
+        mm_res, _ = run(tuple(panels[i][0].gids_p for i in mm_idx),
+                        offsets if len(mm_idx) > 1 else None,
+                        pad_group_count(sum(counts)), per_series=False)
+    ps_comps = ()
     if ps_idx:
-        from filodb_tpu.ops import agg as agg_ops
         S = num_series
         if S is None:
             gp0 = panels[ps_idx[0]][0].gids_p[:, 0]
             S = int(np.asarray(gp0 >= 0).sum())
         # one shared per-series run: the [S, W] output is group-agnostic
-        res = run(panels[ps_idx[0]][0].gids_p, 8, per_series=True)
-        if ragged:
-            per_raw, pres = res
-            per = jnp.where(pres[:S, :plan.W] > 0, per_raw[:S, :plan.W],
-                            jnp.nan)
-        else:
-            per = jnp.where(jnp.asarray(wvalid)[None, :],
-                            res[:S, :plan.W], jnp.nan)
-        for i in ps_idx:
-            groups, G, op = panels[i]
-            ps_comps[i] = agg_ops.map_phase(op, per, groups.gids_p[:S, 0],
-                                            G)
+        res, rows = run((panels[ps_idx[0]][0].gids_p,), None, 8,
+                        per_series=True)
+        with span_part("leaf.enqueue_jit"):
+            ps_comps = _per_series_aggs(
+                res, rows, tuple(panels[i][0].gids_p for i in ps_idx),
+                ops=tuple(panels[i][2] for i in ps_idx),
+                num_groups=tuple(int(panels[i][1]) for i in ps_idx),
+                S=int(S), W=plan.W, minsamp=1 if over_time else 2)
 
     # ---- finish phase: synchronizing host readbacks + assembly
     def finish():
@@ -1323,8 +1292,8 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
                 else:
                     out[i] = np.stack([sums * (counts > 0), counts],
                                       axis=-1)
-        for i in ps_idx:
-            out[i] = np.asarray(ps_comps[i], np.float64)
+        for i, comp in zip(ps_idx, ps_comps):
+            out[i] = np.asarray(comp, np.float64)
         for i, (groups, G, op) in enumerate(panels):
             if out[i] is None:          # dense count: pure host math
                 out[i] = dense_counts(groups)[..., None]

@@ -290,13 +290,14 @@ def device_put_packed(packed: PackedShards, mesh: Mesh) -> PackedShards:
 @functools.partial(jax.jit, static_argnames=(
     "G", "S", "T", "Tp", "gather", "is_counter", "is_rate", "interpret",
     "kind", "ragged"))
-def _pad_run_single(v, vb, g, mats, *, G: int, S: int, T: int, Tp: int,
+def _pad_run_single(v, vb, g, plan_rows, *, G: int, S: int, T: int, Tp: int,
                     gather: bool, is_counter: bool, is_rate: bool,
                     interpret: bool, kind: str, ragged: bool):
     """Pad ONE device's [S, T] values + [S, P] grouping (P > 1:
     run_agg_batch panels over disjoint group-id ranges, multi-hot kernel
-    epilogue) to kernel tile shapes and run the single-chip kernel — the
-    shared map-phase body of the per-device dispatch
+    epilogue) to kernel tile shapes and run the single-chip kernel over
+    the plan's uploaded `plan_rows` = (rows, tsrow) — the shared
+    map-phase body of the per-device dispatch
     (_device_fused_call) and the legacy fused-in-shard_map A/B probe
     (_mesh_fused_call), so their padding semantics can never diverge.
 
@@ -317,7 +318,7 @@ def _pad_run_single(v, vb, g, mats, *, G: int, S: int, T: int, Tp: int,
     vb = jnp.pad(vb.astype(jnp.float32), (0, Sp - S))[:, None]
     g = jnp.pad(g.astype(jnp.int32), ((0, Sp - S), (0, 0)),
                 constant_values=-1)
-    return pf.run_kernel(v, vb, g, *mats, gather=gather, num_groups=Gp,
+    return pf.run_kernel(v, vb, g, *plan_rows, gather=gather, num_groups=Gp,
                          is_counter=is_counter, is_rate=is_rate,
                          with_drops=False, interpret=interpret, kind=kind,
                          ragged=ragged)
@@ -326,8 +327,7 @@ def _pad_run_single(v, vb, g, mats, *, G: int, S: int, T: int, Tp: int,
 @functools.partial(jax.jit, static_argnames=(
     "mesh", "G", "S", "T", "Tp", "is_counter", "is_rate", "interpret",
     "kind", "ragged"))
-def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase,
-                     o1, o2, l1, l2, t1, t2, n, ws, we, ts, i1, i2, *,
+def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase, rows, tsrow, *,
                      G: int, S: int, T: int, Tp: int,
                      is_counter: bool, is_rate: bool, interpret: bool,
                      kind: str = "rate_family", ragged: bool = False):
@@ -343,9 +343,9 @@ def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase,
     from filodb_tpu.ops import pallas_fused as pf
     gather = pf.gather_default(kind)
 
-    def step(val_blk, gid_blk, vb_blk, *mat_blks):
+    def step(val_blk, gid_blk, vb_blk, rows_blk, ts_blk):
         res = _pad_run_single(val_blk[0], vb_blk[0], gid_blk[0],
-                              tuple(m[0] for m in mat_blks), G=G, S=S,
+                              (rows_blk[0], ts_blk[0]), G=G, S=S,
                               T=T, Tp=Tp, gather=gather,
                               is_counter=is_counter, is_rate=is_rate,
                               interpret=interpret, kind=kind,
@@ -359,14 +359,13 @@ def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase,
     return shard_map(
         step, mesh=mesh,
         in_specs=(P("shard", None, None), P("shard", None, None),
-                  P("shard", None)) + (P("time", None, None),) * 12,
+                  P("shard", None)) + (P("time", None, None),) * 2,
         out_specs=((P(None, "time"), P(None, "time")) if ragged
                    else P(None, "time")),
         # pallas_call's out_shape carries no varying-mesh-axes info, which
         # trips shard_map's vma checker; the psum makes the output
         # replicated over 'shard' by construction
-        check_vma=False)(values, group_ids, vbase,
-                         o1, o2, l1, l2, t1, t2, n, ws, we, ts, i1, i2)
+        check_vma=False)(values, group_ids, vbase, rows, tsrow)
 
 
 # ------------------------------------------------ per-device fused dispatch
@@ -387,11 +386,10 @@ def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase,
 @functools.partial(jax.jit, static_argnames=(
     "G", "S", "T", "Tp", "is_counter", "is_rate", "interpret", "kind",
     "ragged"))
-def _device_fused_call(values, group_ids, vbase, o1, o2, l1, l2, t1, t2,
-                       n, ws, we, ts, i1, i2, *, G: int, S: int, T: int,
-                       Tp: int, is_counter: bool, is_rate: bool,
-                       interpret: bool, kind: str = "rate_family",
-                       ragged: bool = False):
+def _device_fused_call(values, group_ids, vbase, rows, tsrow, *, G: int,
+                       S: int, T: int, Tp: int, is_counter: bool,
+                       is_rate: bool, interpret: bool,
+                       kind: str = "rate_family", ragged: bool = False):
     """One device's share of the multi-chip fused scan: the single-chip
     Pallas kernel over this device's [1, S, T] shard block.  Every
     operand is committed to the owning device, so the jit executes THERE
@@ -399,8 +397,7 @@ def _device_fused_call(values, group_ids, vbase, o1, o2, l1, l2, t1, t2,
     [G, Wlp] group partials leave the chip.  The leading shard axis is
     kept so the pack's addressable shards feed straight in."""
     from filodb_tpu.ops import pallas_fused as pf
-    res = _pad_run_single(values[0], vbase[0], group_ids[0],
-                          (o1, o2, l1, l2, t1, t2, n, ws, we, ts, i1, i2),
+    res = _pad_run_single(values[0], vbase[0], group_ids[0], (rows, tsrow),
                           G=G, S=S, T=T, Tp=Tp,
                           gather=pf.gather_default(kind),
                           is_counter=is_counter, is_rate=is_rate,
@@ -622,8 +619,8 @@ class MeshExecutor:
         # re-upload values but never repack the layout (see
         # lookup_and_pack; mesh_pack_memo_hits counts the wins)
         self._pack_layout_memo: Dict[Tuple, Dict] = {}
-        # fused-path plan/mats cache: (shared_ts_row, wends, range) ->
-        # (device selection matrices, wvalid); see _run_agg_fused
+        # fused-path plan cache: (shared_ts_row, wends, range) ->
+        # (per-time-slice plans, wvalid, wvalid1); see _run_agg_fused
         self._fused_plan_cache: Dict[Tuple, Tuple] = {}
         # run_agg_batch merged-gid cache: (id(pack), panels, fn) -> the
         # device-resident [D, S, P] grouping matrix (+ the pack ref to
@@ -1184,9 +1181,8 @@ class MeshExecutor:
                 return self._finish_count_panels(packed, wends_p, W,
                                                  range_ms, kpanels, out,
                                                  minsamp)
-            # plan cache: per-time-slice plans; the per-(plan, device)
-            # selection-matrix uploads live in pallas_fused's own cache
-            # (plan_device_mats), keyed by these pinned plan objects
+            # plan cache: per-time-slice plans; each dispatch puts its
+            # plan's [8, Wlp] rows on its device (pf.enqueue_operands)
             plan_key = (packed.shared_ts_row.tobytes(), wends_p.tobytes(),
                         range_ms)
             from filodb_tpu.query.exec import _lru_touch
@@ -1252,7 +1248,6 @@ class MeshExecutor:
             # the [Gtot, Wlp] partials then merge (collective on ICI,
             # host reduce otherwise).  The kernel never traces inside
             # shard_map (the MULTICHIP_r05 30x inversion).
-            gather = pf.gather_default(kind_k)
             is_counter = fn_name in ("rate", "increase")
             vblocks = {s.device: s.data
                        for s in packed.values.addressable_shards}
@@ -1280,14 +1275,14 @@ class MeshExecutor:
             for si in range(D):
                 for ti in range(n_time):
                     dev = grid[si, ti]
-                    mats_d = pf._kernel_mats(plans[ti], over_time, gather,
-                                             device=dev)
+                    rows_d, ts_d, _ = pf.enqueue_operands(
+                        plans[ti], dev, kind_k, ragged)
                     _d0 = _time.perf_counter()
                     res = watched_call(
                         "mesh_fused", _device_fused_call, sig,
                         lambda: _device_fused_call(
                             vblocks[dev], gblocks[dev], vbblocks[dev],
-                            *mats_d, G=Gtot, S=S, T=T, Tp=Tp,
+                            rows_d, ts_d, G=Gtot, S=S, T=T, Tp=Tp,
                             is_counter=is_counter,
                             is_rate=(fn_name == "rate"),
                             interpret=interpret,
@@ -1412,8 +1407,6 @@ class MeshExecutor:
             return None
         from filodb_tpu.ops import hostleaf
         plan = self._host_plan(packed, wends_p, W, range_ms)
-        if plan.idx1 is None:
-            return None
         hv = packed.host_values
         hvb = packed.host_vbase
         hg = packed.host_group_ids

@@ -399,8 +399,6 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         plan = pf.build_plan(
             np.asarray(data.shared_ts_row, np.int64), eval_wends,
             t0.window_ms)
-        if plan.idx1 is None:
-            return None
         # token-keyed group cache: the O(S) key.only() loop dominated
         # repeat host-routed leaves (same working set, new panel)
         gids, gkeys = _group_ids_cached(data.cache_token, data.keys,

@@ -14,8 +14,8 @@ module is the device-side twin of the PR 3 query-attribution layer:
     feeds the per-thread exec tally — so QueryStats.device_seconds and
     the ledger's per-query sum reconcile by construction (the parity
     test in tests/test_devicetelem.py).
-  - **HBM occupancy model** — MirrorPlacer bookings, the cold segment
-    cache, and the plan-mats cache feed `hbm_book(device, region, ±n)`,
+  - **HBM occupancy model** — MirrorPlacer bookings and the cold segment
+    cache feed `hbm_book(device, region, ±n)`,
     exposed as `device_hbm_booked_bytes{device,region}` gauges with a
     journaled `device_hbm_high_water` timeline.
   - **compile-cache events** — ops/pallas_fused pushes JIT compiles in
@@ -288,7 +288,7 @@ class DeviceTelemetry:
     def hbm_book(self, device, region: str, delta: int) -> None:
         """Fold a booking delta into the per-device, per-region occupancy
         model.  Regions: 'hot' (live shard mirrors), 'cold'
-        (ColdSegmentCache pages), 'planmats' (fused-plan matrix cache).
+        (ColdSegmentCache pages).
         Gauges clamp at zero — release races round down, never negative."""
         if not delta:
             return

@@ -915,6 +915,50 @@ class span:
                       self._parent, self._t0, self.dur_ns, self.tags)
 
 
+class span_part:
+    """A timed part of the span open on this thread that stays INSIDE
+    that span's self time: for a span that readers take whole by its
+    self time (`leaf.kernel_enqueue`), a nested `span` would move the
+    time out of it.  A part books `span_<name>_seconds_total` and
+    `span_<name>_calls_total` (no self time: the span that holds it has
+    it), joins the trace as a child event of that span, and inside a
+    profiler session that the span annotates is a
+    `TraceAnnotation("filodb-part:<name>")`, a label that readers of the
+    `filodb:` spans pass over.  `dur_ns` as on a span."""
+
+    __slots__ = ("name", "dur_ns", "_t0", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.dur_ns = 0
+
+    def __enter__(self):
+        self._ann = None
+        if SPANS_ENABLED:
+            stack = _active.__dict__.get("stack")
+            if stack and stack[-1]._ann is not None:
+                ann = self._ann = _trace_annotation(
+                    "filodb-part:" + self.name)
+                ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.dur_ns = time.perf_counter_ns() - self._t0
+        if not SPANS_ENABLED:
+            return False
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        flat = self.name.replace(".", "_")
+        registry.counter(f"span_{flat}_seconds").increment(
+            self.dur_ns * 1e-9)
+        registry.counter(f"span_{flat}_calls").increment()
+        tid = current_trace_id()
+        if tid:
+            record_child_event(tid, self.name, self.dur_ns * 1e-9)
+        return False
+
+
 def record_child_event(trace_id: str, name: str, dur_s: float,
                        **tags) -> None:
     """An event that ENDS now and lasted `dur_s`, as a child of the span

@@ -73,13 +73,16 @@ def _compile_run(one_chip, S, G, fn, ragged=False, panels=1):
     plan = _plan()
     over_time = fn in pf.OVER_TIME_FNS
     kind = fn if over_time else "rate_family"
-    gather = pf.gather_default(kind) and plan.idx1 is not None
-    mats = pf._kernel_mats(plan, over_time, gather)
+    gather = pf.gather_default(kind)
     Sp, Gp = pf.pad_series_count(S), pf.pad_group_count(G * panels)
+    with_ts = ragged and kind == "rate_family"
     args = [_sds((Sp, plan.Tp), jnp.float32, one_chip),
             _sds((Sp, 1), jnp.float32, one_chip),
-            _sds((Sp, panels), jnp.int32, one_chip)]
-    args += [_sds(m.shape, m.dtype, one_chip) for m in mats]
+            (_sds((Sp, 1), jnp.int32, one_chip),) * panels,
+            _sds((panels,), jnp.int32, one_chip) if panels > 1 else None,
+            _sds(plan.rows.shape, jnp.float32, one_chip),
+            _sds(plan.tsrow.shape, jnp.float32, one_chip) if with_ts
+            else None]
     is_counter = fn in ("rate", "increase")
     return pf._run.lower(
         *args, num_groups=Gp, is_counter=is_counter, is_rate=fn == "rate",
@@ -108,7 +111,7 @@ def _check(compiled, pallas: bool):
     (S_SHARD, 1000, "sum_over_time", True, 1),
     (S_SHARD, 1000, "avg_over_time", False, 1),
     (S_SHARD, 8192, "rate", False, 1),
-    (S_SHARD, 10, "rate", False, 3),        # multi-panel: gids_p [Sp, 3]
+    (S_SHARD, 10, "rate", False, 3),        # multi-panel: 3 gid columns
 ], ids=["rate-1M", "rate-262k", "rate-ragged", "delta-ragged", "sum_ot",
         "sum_ot-ragged", "avg_ot", "rate-G8192", "rate-3panels"])
 def test_fused_kernel_compiles_for_v5e(one_chip, chip_runtime,

@@ -650,3 +650,211 @@ def test_split_precision_matches_highest_interpret(monkeypatch, mode):
         assert (np.isnan(b) == np.isnan(s)).all()
         np.testing.assert_allclose(s, b, rtol=1e-5, atol=1e-6,
                                    equal_nan=True)
+
+
+# ------------- one fused dispatch = one jit call (ISSUE 28, ROADMAP A1)
+
+def _host_selection_matrices(ts_row, wends, range_ms):
+    """o1, o2, l1, l2 as the host built them before ISSUE 28
+    (`build_plan.sel`): the reference the in-jit construction must equal
+    bit for bit."""
+    from filodb_tpu.ops.pallas_fused import _LANE, _pad_to, window_counts
+    ts_row = np.asarray(ts_row, np.int64)
+    wend = np.asarray(wends, np.int64)
+    first = np.searchsorted(ts_row, wend - int(range_ms) + 1, side="left")
+    last = np.searchsorted(ts_row, wend, side="right") - 1
+    valid = window_counts(ts_row, wend, range_ms) >= 1
+    W, T = len(wend), len(ts_row)
+    Wp, Tp = _pad_to(max(W, 1), _LANE), _pad_to(max(T, 1), _LANE)
+
+    def sel(idx, leq):
+        m = np.zeros((Tp, Wp), np.float32)
+        t = np.arange(Tp)[:, None]
+        iw = np.where(valid, np.clip(idx, 0, T - 1), -1)[None, :]
+        m[:, :W] = ((t <= iw) if leq else (t == iw)).astype(np.float32)
+        return m
+
+    return sel(first, False), sel(last, False), sel(first, True), \
+        sel(last, True)
+
+
+@pytest.mark.parametrize("T,wends,range_ms", [
+    (160, np.arange(40, 151, 6) * START_STEP, 30 * START_STEP),
+    # windows before the data, between samples (empty) and on the grid
+    (130, np.concatenate([[-50 * START_STEP, 3, START_STEP + 1],
+                          np.arange(2, 120, 9) * START_STEP + 5]),
+     START_STEP // 2),
+    # windows hanging past the grid's right edge, the last ones empty
+    (100, np.arange(90, 140, 4) * START_STEP, 7 * START_STEP),
+    # two window tiles (W > 128) over three time tiles
+    (300, np.arange(1, 300, 2) * START_STEP, 12 * START_STEP),
+], ids=["mid", "empty-and-padded", "past-right-edge", "two-window-tiles"])
+def test_kernel_operands_equal_host_selection_matrices(T, wends, range_ms):
+    """The matrices `_run` builds on the device from idx1 / idx2 / n1 are
+    the 0/1 f32 arrays the host used to upload, bit for bit; gather mode
+    builds none; `n` and `tsrow` resolve as the host resolved them."""
+    import jax
+    from filodb_tpu.ops import pallas_fused as pf
+    ts_row = np.arange(T, dtype=np.int64) * START_STEP
+    plan = build_plan(ts_row, wends, range_ms)
+    assert plan.rows.shape == (8, plan.t1.shape[1])
+    assert plan.rows.nbytes + plan.tsrow.nbytes < 16 << 10
+    for i, f in enumerate(("t1", "t2", "n", "n1", "wstart_x", "wend_x",
+                           "idx1", "idx2")):
+        assert np.shares_memory(getattr(plan, f), plan.rows)
+        np.testing.assert_array_equal(getattr(plan, f)[0], plan.rows[i])
+    want = _host_selection_matrices(ts_row, wends, range_ms)
+    assert (plan.n1[0] == 0).any()                   # padded windows
+    build = jax.jit(pf.kernel_operands, static_argnums=(2, 3, 4))
+    ops = build(plan.rows, plan.tsrow, plan.Tp, True, False)
+    for got, ref in zip(ops[:4], want):
+        got = np.asarray(got)
+        assert got.dtype == np.float32 and got.shape == ref.shape
+        assert got.tobytes() == ref.tobytes()
+    np.testing.assert_array_equal(np.asarray(ops[6]), plan.n1)
+    np.testing.assert_array_equal(np.asarray(ops[9]), plan.tsrow)
+    for got, f in zip(ops[4:6] + ops[7:9] + ops[10:],
+                      ("t1", "t2", "wstart_x", "wend_x", "idx1", "idx2")):
+        np.testing.assert_array_equal(np.asarray(got), getattr(plan, f))
+    ops = build(plan.rows, None, plan.Tp, False, True)
+    assert all(o.shape == (8, 128) and not np.asarray(o).any()
+               for o in ops[:4])
+    np.testing.assert_array_equal(np.asarray(ops[6]), plan.n)
+    assert ops[9].shape == plan.tsrow.shape and not np.asarray(ops[9]).any()
+
+
+def _enqueue_counts():
+    from filodb_tpu.utils.metrics import registry
+    return (registry.counter("fused_enqueues").value,
+            registry.counter("fused_enqueue_uploads").value)
+
+
+def _batch_case(fn, aggs, ragged=False, S=96, T=120, hist_buckets=0):
+    """(plan, values, panels, check): `aggs` panels over one working set;
+    check(comps) holds every panel to the general XLA path."""
+    from filodb_tpu.ops.pallas_fused import pad_groups, pad_values
+    if ragged and fn in ("rate", "increase", "delta"):
+        ts_row, raw, _ = _mk_ragged_counters(S=S, T=T)
+    else:
+        ts_row, raw, _ = _mk(S=S, T=T, resets=not ragged)
+        if ragged:
+            raw = raw.copy()
+            raw[np.random.default_rng(3).random(raw.shape) < 0.15] = np.nan
+    range_ms = 30 * START_STEP
+    wends = make_window_ends(40 * START_STEP, (T - 10) * START_STEP,
+                             6 * START_STEP)
+    plan = build_plan(ts_row, wends, range_ms)
+    precor = fn in ("rate", "increase")
+    if ragged:
+        vals32 = raw.astype(np.float32)
+        vb32 = np.zeros(S, np.float32)
+    else:
+        reb, vbase = rebase_values(raw, precor)
+        vals32, vb32 = reb.astype(np.float32), vbase.astype(np.float32)
+    values = pad_values(vals32, vb32, plan)
+    Gs = [5, 1, 12, 7]
+    panels, gid_list = [], []
+    for i, agg in enumerate(aggs):
+        G = Gs[i % len(Gs)]
+        gids = (np.arange(S) % G).astype(np.int32)
+        if hist_buckets:
+            # histogram leaf: one kernel slot per (group, bucket)
+            gids = gids * hist_buckets + np.arange(S) % hist_buckets
+            G *= hist_buckets
+        gid_list.append((gids.astype(np.int32), G))
+        panels.append((pad_groups(gids, S, G), G, agg))
+
+    def check(comps):
+        assert len(comps) == len(aggs)
+        for comp, agg, (gids, G) in zip(comps, aggs, gid_list):
+            got = np.asarray(agg_ops.present(agg, jnp.asarray(comp)))
+            if ragged and fn in ("rate", "increase", "delta"):
+                assert agg == "sum"
+                want = _oracle_group_sum(ts_row, raw, gids, wends, range_ms,
+                                         fn, G)
+            else:
+                want = _general(ts_row, vals32, vb32, gids, wends, range_ms,
+                                fn, agg, G, precor)
+            assert (np.isnan(got) == np.isnan(want)).all()
+            np.testing.assert_allclose(got, want, rtol=5e-4, atol=1e-3,
+                                       equal_nan=True)
+
+    return plan, values, panels, dict(fn_name=fn, precorrected=precor and
+                                      not ragged, interpret=True,
+                                      ragged=ragged, num_series=S), check
+
+
+@pytest.mark.parametrize("fn,aggs,ragged,uploads", [
+    ("rate", ["sum"], False, 1),
+    ("rate", ["sum", "avg", "sum"], False, 2),
+    ("sum_over_time", ["sum"], False, 1),
+    ("sum_over_time", ["sum", "avg", "sum"], False, 2),
+    ("rate", ["sum"], True, 2),            # the ragged rate family reads tsrow
+    ("rate", ["min"], False, 1),           # per-series mode + segment jit
+], ids=["rate-1p", "rate-3p", "sum_ot-1p", "sum_ot-3p", "rate-ragged-1p",
+        "rate-min-1p"])
+def test_enqueue_is_explicit_uploads_and_one_call(fn, aggs, ragged, uploads):
+    """One lazy fused_leaf_agg_batch call makes no implicit host-to-device
+    transfer (5 before ISSUE 28), and puts at most the plan's rows (+ the
+    offsets of a merged batch, + tsrow where the kernel reads it): booked
+    on fused_enqueue_uploads_total beside fused_enqueues_total, on a fresh
+    plan (13 before) and on a repeated one alike."""
+    import jax
+    from filodb_tpu.ops.pallas_fused import fused_leaf_agg_batch
+    plan, values, panels, kw, check = _batch_case(fn, aggs, ragged)
+    for _ in range(2):                      # fresh plan, then the same one
+        e0, u0 = _enqueue_counts()
+        with jax.transfer_guard_host_to_device("disallow"):
+            finisher = fused_leaf_agg_batch(plan, values, panels, lazy=True,
+                                            **kw)
+        e1, u1 = _enqueue_counts()
+        assert e1 - e0 == 1
+        assert u1 - u0 == uploads <= 2
+        check(finisher())
+
+
+@pytest.mark.parametrize("fn,aggs,ragged,hist", [
+    ("rate", ["sum", "avg", "count", "sum"], False, 0),
+    ("sum_over_time", ["sum", "avg", "sum"], False, 0),
+    ("sum_over_time", ["sum", "avg", "count"], True, 0),
+    ("rate", ["sum", "sum", "sum"], True, 0),
+    ("rate", ["sum", "sum"], False, 4),
+    ("rate", ["min", "max", "sum", "max"], False, 0),
+    ("avg_over_time", ["max", "avg", "min"], True, 0),
+], ids=["dense-rate", "dense-sum_ot", "ragged-sum_ot", "ragged-rate",
+        "histogram-slots", "minmax-rate", "minmax-ragged-avg_ot"])
+def test_fused_batch_merged_in_jit_matches_general(fn, aggs, ragged, hist):
+    """Several panels merged INSIDE the one jit call (gid columns as a
+    tuple operand, offsets traced) give every panel the general path's
+    answer: dense, ragged, histogram (group, bucket) slots, and min/max
+    panels riding the per-series run."""
+    from filodb_tpu.ops.pallas_fused import fused_leaf_agg_batch
+    plan, values, panels, kw, check = _batch_case(fn, aggs, ragged,
+                                                  hist_buckets=hist)
+    check(fused_leaf_agg_batch(plan, values, panels, **kw))
+
+
+def test_merge_gid_cols_identity_and_traced_offsets():
+    """One gid operand passes through untouched; several merge to the
+    matrix the eager host merge built; and a batch with other group
+    counts under the same padded total compiles nothing new."""
+    from filodb_tpu.ops import pallas_fused as pf
+    S = 70
+    cols = [pf.pad_groups((np.arange(S) % g).astype(np.int32), S, g).gids_p
+            for g in (5, 1, 12)]
+    assert pf.merge_gid_cols((cols[0],), None) is cols[0]
+    offs = np.array([0, 5, 6], np.int32)
+    got = np.asarray(pf.merge_gid_cols(tuple(cols), jnp.asarray(offs)))
+    want = np.stack([np.where(np.asarray(c)[:, 0] >= 0,
+                              np.asarray(c)[:, 0] + o, -1)
+                     for c, o in zip(cols, offs)], axis=1)
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == np.int32 and (got[S:] == -1).all()
+
+    plan, values, panels, kw, _ = _batch_case("rate", ["sum"] * 3)
+    pf.fused_leaf_agg_batch(plan, values, panels, **kw)     # 5 + 1 + 12
+    compiled = pf._run._cache_size()
+    other = [(pf.pad_groups((np.arange(96) % g).astype(np.int32), 96, g),
+              g, "sum") for g in (6, 2, 11)]                # same Gp = 24
+    pf.fused_leaf_agg_batch(plan, values, other, **kw)
+    assert pf._run._cache_size() == compiled

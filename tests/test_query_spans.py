@@ -115,10 +115,14 @@ def rig():
             os.environ["FILODB_TPU_FUSED_INTERPRET"] = old
 
 
+PARTS = ("leaf.enqueue_pack", "leaf.enqueue_jit")
+
+
 def real(evs):
-    """The spans proper: `kernel_dispatch` reports a duration that two
-    spans already measured, and books no self time."""
-    return [e for e in evs if e["name"] != "kernel_dispatch"]
+    """The spans proper: `kernel_dispatch` and the parts of
+    `leaf.kernel_enqueue` (metrics.span_part) report durations that spans
+    already measured, and book no self time."""
+    return [e for e in evs if e["name"] not in ("kernel_dispatch",) + PARTS]
 
 
 def children_of(evs):
@@ -247,6 +251,42 @@ def test_counters_match_the_tree(rig):
     assert "span_query_parse_seconds_total" not in after
 
 
+def test_enqueue_parts_stay_inside_kernel_enqueue(rig):
+    """The packing and the jit call of an enqueue are child events of
+    `leaf.kernel_enqueue` that leave its self time whole (the benchmark's
+    kernel_enqueue_ms reads it), book seconds and calls but no self
+    family, and ride beside the uploads-per-enqueue counters."""
+    def fused():
+        return (registry.counter("fused_enqueues").value,
+                registry.counter("fused_enqueue_uploads").value)
+
+    before, fused0 = rig.counters(), fused()
+    evs = rig.tree(rig.query()["traceID"])[0]
+    after, fused1 = rig.counters(), fused()
+
+    def delta(name):
+        return after[name] - before.get(name, 0.0)
+
+    enqueues = [e for e in evs if e["name"] == "leaf.kernel_enqueue"]
+    assert len(enqueues) == SHARDS
+    kids = children_of(evs)
+    for enq in enqueues:
+        mine = [k for k in kids[enq["span_id"]] if k["name"] in PARTS]
+        assert [k["name"] for k in mine] == list(PARTS)
+        assert sum(k["dur_ns"] for k in mine) <= enq["dur_ns"]
+        assert all(k["start_ns"] >= enq["start_ns"] for k in mine)
+    self_s = delta("span_leaf_kernel_enqueue_self_seconds_total")
+    assert self_s == pytest.approx(
+        sum(e["dur_ns"] for e in enqueues) * 1e-9, rel=1e-6)
+    for part in PARTS:
+        flat = "span_" + part.replace(".", "_")
+        assert delta(flat + "_calls_total") == SHARDS
+        assert 0.0 < delta(flat + "_seconds_total") <= self_s
+        assert flat + "_self_seconds_total" not in after
+    # one dispatch a shard, and of each plan only its rows go up
+    assert (fused1[0] - fused0[0], fused1[1] - fused0[1]) == (SHARDS, SHARDS)
+
+
 def test_profiler_session_carries_the_spans(rig, tmp_path):
     import jax
     from jax.profiler import ProfileData
@@ -268,6 +308,9 @@ def test_profiler_session_carries_the_spans(rig, tmp_path):
             names = [ev.name for ev in line.events]
             if "filodb:http.request" in names:
                 found.update(n for n in names if n.startswith("filodb:"))
+                parts = [n for n in names if n.startswith("filodb-part:")]
+    assert sorted(parts) == sorted("filodb-part:" + p for p in PARTS
+                                   for _ in range(SHARDS))
     assert found["filodb:leaf.result_fetch"] == SHARDS
     assert found["filodb:http.request"] == 1
     # the request's whole tree is on that one thread's line
