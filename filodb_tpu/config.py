@@ -76,9 +76,11 @@ class QueryConfig:
     batch_window_ms: float = 0.0
     # cost-based host/device leaf routing (round-5 verdict item 6): leaf
     # working sets whose estimated scan is at or below this many samples
-    # evaluate in host numpy (ops/hostleaf) instead of paying a device
-    # dispatch's fixed cost.  The threshold has not been re-measured on an
-    # attached chip (CHANGES.md PR 24; ROADMAP A1/C3 own it).
+    # (values: a histogram sample counts one per bucket,
+    # leafexec.leaf_route) evaluate in host numpy (ops/hostleaf) instead
+    # of paying a device dispatch's fixed cost.  The threshold has not
+    # been re-measured on an attached chip (CHANGES.md PR 24; ROADMAP
+    # A2/C4 own it).
     # 0 disables.  Decision is observable: `leaf_host_routed` counter +
     # the execplan span's route tag.
     host_route_max_samples: int = 2_000_000

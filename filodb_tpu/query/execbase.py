@@ -453,9 +453,16 @@ def reduce_partials(parts: List[AggPartial],
     parts = [p for p in parts if p is not None]
     if not parts:
         return None
+    if parts[0].op == "hist_sum":
+        from filodb_tpu.utils.metrics import span
+        with span("exec.hist_reduce"):
+            return _reduce_aligned(_align_hist_schemes(parts), compress)
+    return _reduce_aligned(parts, compress)
+
+
+def _reduce_aligned(parts: List[AggPartial],
+                    compress: bool) -> AggPartial:
     op = parts[0].op
-    if op == "hist_sum":
-        parts = _align_hist_schemes(parts)
     gmap: Dict[RangeVectorKey, int] = {}
     gkeys: List[RangeVectorKey] = []
     for p in parts:
@@ -489,15 +496,22 @@ def reduce_partials(parts: List[AggPartial],
         W = parts[0].comp.shape[1]
         combs = agg_ops.combiners_for(op, C)
         init = {"sum": 0.0, "min": np.inf, "max": -np.inf}
+        ufuncs = {"sum": np.add, "min": np.minimum, "max": np.maximum}
+        # one combiner for every component (all but min/max): one call a
+        # partial.  A hist_sum partial has a component a bucket, and each
+        # NumPy call is a chance to lose the interpreter lock to another
+        # request for a switch interval
+        whole = len(set(combs)) == 1
         out = np.empty((len(gkeys), W, C))
         for i, comb in enumerate(combs):
             out[..., i] = init[comb]
         for p in parts:
             idx = np.asarray([gmap[k] for k in p.group_keys], dtype=np.int64)
+            if whole:
+                ufuncs[combs[0]].at(out, idx, p.comp)
+                continue
             for i, comb in enumerate(combs):
-                ufunc = {"sum": np.add, "min": np.minimum,
-                         "max": np.maximum}[comb]
-                ufunc.at(out[..., i], idx, p.comp[..., i])
+                ufuncs[comb].at(out[..., i], idx, p.comp[..., i])
         return AggPartial(op, gkeys, wends, comp=out, params=parts[0].params,
                           bucket_les=parts[0].bucket_les,
                           cache_token=_reduced_token(parts))

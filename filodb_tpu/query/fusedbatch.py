@@ -177,6 +177,11 @@ def _present(fc: FusedCall, comp) -> AggPartial:
     if fc.bucket_les is None:
         return AggPartial(fc.op, fc.gkeys, fc.wends, comp=comp,
                           cache_token=fc.cache_token)
+    with span("leaf.hist_finish"):
+        return _present_hist(fc, comp)
+
+
+def _present_hist(fc: FusedCall, comp) -> AggPartial:
     # histogram: comp[..., 0] is the per-(group, bucket)-slot sum, masked
     # where the window has no samples — the hist_sum presenter NaNs those
     # windows via the count column anyway, so the mask is invisible

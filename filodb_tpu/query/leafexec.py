@@ -95,6 +95,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         start = 0
         if fused is not None:
             data, start = fused, 2
+        elif data is not None:
+            from filodb_tpu.utils.metrics import registry
+            registry.counter("leaf_general_path").increment()
         for i, t in enumerate(self.transformers[start:], start):
             t = self._transformer_overrides.get(i, t)
             data = t.apply(data, self.ctx, stats, source)
@@ -294,12 +297,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             if is_hist:
                 # [S, T, B] -> [S*B, T] rows (bucket-major within a series,
                 # same layout PeriodicSamplesMapper flattens to)
-                flat = jnp.moveaxis(jnp.asarray(vals), 2, 1) \
-                    .reshape(vals.shape[0] * B, vals.shape[1])
-                vb_flat = (np.zeros(flat.shape[0], np.float32)
-                           if vbase is None
-                           else jnp.asarray(vbase,
-                                            jnp.float32).reshape(-1))
+                with span("leaf.hist_flatten"):
+                    flat = jnp.moveaxis(jnp.asarray(vals), 2, 1) \
+                        .reshape(vals.shape[0] * B, vals.shape[1])
+                    vb_flat = (np.zeros(flat.shape[0], np.float32)
+                               if vbase is None
+                               else jnp.asarray(vbase,
+                                                jnp.float32).reshape(-1))
                 with span("leaf.pad_values"):
                     padded_vals = pf.pad_values(flat, vb_flat, plan)
             else:
@@ -349,6 +353,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # batch too — identical panels (p50/p90/p99 over one metric)
         # dedup to ONE kernel run (fusedbatch finisher reshapes slots to
         # [G, W, B] and appends the present-series count)
+        registry.counter("leaf_hist_fused").increment()
         ck = None if key is None else key + (
             t0.start_ms, t0.step_ms, t0.end_ms, t0.offset_ms,
             t0.window_ms, data.base_ms, "hist", B)
@@ -710,7 +715,12 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 with span("leaf.scan_estimate"):
                     est = _estimate_scan(store, rows, self.chunk_start_ms,
                                          self.chunk_end_ms)
-                route_host = 0 < est <= _route_cap
+                # a histogram sample is num_buckets values: the cap is
+                # compared with what the leaf would gather and correct
+                per_sample = (store.num_buckets if col_def is not None
+                              and col_def.col_type == "hist" else 1)
+                route_host = leaf_route(est, per_sample,
+                                        _route_cap) == "host"
         if (not route_host
                 and getattr(shard.config.store, "device_mirror_enabled",
                             True)
@@ -810,6 +820,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 self._fused_cache_key = (mirror.serial, snap.gen, col_name,
                                          rows.tobytes())
         else:
+            from filodb_tpu.utils.metrics import registry as _reg
+            _reg.counter("leaf_host_gather").increment()
             # windowed gather: copy only the planner's chunk-scan span —
             # a fraction of the store's full time capacity, and far less
             # seqlock-tear exposure under live ingest (the r4 soak's 9x
@@ -1261,6 +1273,15 @@ def _estimate_scan(store, rows: np.ndarray, start_ms: int,
     est = np.where((cnt > 0) & (hi >= lo), np.maximum(cnt * frac, 1.0), 0.0)
     return int(est.sum())
 
+
+def leaf_route(est_samples: int, values_per_sample: int, cap: int) -> str:
+    """Where a shard leaf gathers its rows: "host" when its estimated
+    working set, in VALUES, is at or under `cap`
+    (query.host_route_max_samples; 0 turns the rule off), else "device"
+    (the mirror).  A scalar sample is one value, a histogram sample one
+    per bucket: a 64-bucket leaf of 400,000 samples is 25.6 M values."""
+    values = est_samples * max(values_per_sample, 1)
+    return "host" if cap > 0 and 0 < values <= cap else "device"
 
 
 # ------------------------------------------------------------- scalar execs

@@ -174,8 +174,10 @@ class InstantVectorFunctionMapper(RangeVectorTransformer):
             # no jnp pre-conversion: host [G, W, B] comps take the
             # numpy twin inside histogram_quantile (a device round trip
             # here cost a ~70 ms dispatch per quantile panel)
-            out = np.asarray(hist_ops.histogram_quantile(
-                q, vals, np.asarray(data.bucket_les)))
+            from filodb_tpu.utils.metrics import span
+            with span("exec.hist_quantile"):
+                out = np.asarray(hist_ops.histogram_quantile(
+                    q, vals, np.asarray(data.bucket_les)))
             return ResultBlock(data.keys, data.wends, out,
                                cache_token=data.cache_token)
         if self.function == "histogram_bucket":

@@ -112,8 +112,12 @@ def _check(compiled, pallas: bool):
     (S_SHARD, 1000, "avg_over_time", False, 1),
     (S_SHARD, 8192, "rate", False, 1),
     (S_SHARD, 10, "rate", False, 3),        # multi-panel: 3 gid columns
+    # histdev-64b-4k's largest shard: 1,235 series x 64 buckets are kernel
+    # rows, 10 groups x 64 buckets are (group, bucket) slots
+    (1_235 * 64, 10 * 64, "rate", False, 1),
 ], ids=["rate-1M", "rate-262k", "rate-ragged", "delta-ragged", "sum_ot",
-        "sum_ot-ragged", "avg_ot", "rate-G8192", "rate-3panels"])
+        "sum_ot-ragged", "avg_ot", "rate-G8192", "rate-3panels",
+        "rate-hist64"])
 def test_fused_kernel_compiles_for_v5e(one_chip, chip_runtime,
                                        S, G, fn, ragged, panels):
     ma = _check(_compile_run(one_chip, S, G, fn, ragged, panels),
@@ -122,6 +126,21 @@ def test_fused_kernel_compiles_for_v5e(one_chip, chip_runtime,
         # the two [Sp, 1] column operands (vbase_p, gids_p) tile to 1 KiB
         # per row: recorded, not repaired here (ISSUE 24)
         assert ma.temp_size_in_bytes >= pf.pad_series_count(S) * 1024
+
+
+def test_histogram_gather_and_flatten_compile_for_v5e(one_chip,
+                                                      chip_runtime):
+    """What a histogram leaf runs before the kernel, at histdev-64b-4k's
+    largest shard: the row gather out of the `[S, T, 64]` mirror and the
+    `[rows, T, B] -> [rows*B, T]` step (leafexec's `leaf.hist_flatten`)."""
+    S, B = 1_235, 64
+    take = jax.jit(lambda a, i: jnp.take(a, i, axis=0)).lower(
+        _sds((S, T, B), jnp.float32, one_chip),
+        _sds((S,), jnp.int32, one_chip)).compile()
+    _check(take, pallas=False)
+    flat = jax.jit(lambda a: jnp.moveaxis(a, 2, 1).reshape(S * B, T)).lower(
+        _sds((S, T, B), jnp.float32, one_chip)).compile()
+    _check(flat, pallas=False)
 
 
 @pytest.mark.parametrize("fn", ["rate", "sum_over_time"])
