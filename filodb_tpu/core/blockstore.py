@@ -40,6 +40,20 @@ class _MutationToken:
         self.cancelled = True
 
 
+def estimate_samples(cnt: np.ndarray, first: np.ndarray, last: np.ndarray,
+                     start_ms: int, end_ms: int) -> int:
+    """Estimated samples in [start_ms, end_ms] of rows with the given
+    extents (DenseSeriesStore.row_extents), under a uniform-spacing
+    assumption — O(S), no [S, T] materialization."""
+    cnt = cnt.astype(np.int64)
+    lo = np.maximum(first, start_ms)
+    hi = np.minimum(last, end_ms)
+    span = np.maximum(last - first, 1).astype(np.float64)
+    frac = np.clip((hi - lo).astype(np.float64) / span, 0.0, 1.0)
+    est = np.where((cnt > 0) & (hi >= lo), np.maximum(cnt * frac, 1.0), 0.0)
+    return int(est.sum())
+
+
 class DenseSeriesStore:
 
     def __init__(self, schema: Schema, initial_series: int = 1024,
@@ -126,6 +140,30 @@ class DenseSeriesStore:
                     self.generation -= 1  # back to the prior even value
                 else:
                     self.generation += 1  # new even value: data changed
+
+    def set_paged(self, row: int, floor: Optional[int] = None,
+                  ceil: Optional[int] = None) -> None:
+        """Record how far disk was consulted for `row` (ensure_paged's
+        bookkeeping).  A mutation like any other: what readers derive
+        from paged_floor / paged_ceil (a leaf's paging verdict,
+        TimeSeriesShard.selection_facts) is stamped with `generation`.
+        One short mutation a write, not one around a paging loop: readers
+        of the seqlock must not wait out chunk reads and decodes."""
+        with self.mutation():
+            if floor is not None:
+                self.paged_floor[row] = floor
+            if ceil is not None:
+                self.paged_ceil[row] = ceil
+
+    def row_extents(self, rows: np.ndarray
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(counts, first ts, last ts) of the given rows; the two
+        timestamps mean nothing where a row's count is 0."""
+        cnt = self.counts[rows]
+        if self.ts.shape[1] == 0:
+            none = np.zeros(cnt.shape, dtype=np.int64)
+            return cnt, none, none
+        return cnt, self.ts[rows, 0], self.ts[rows, np.maximum(cnt - 1, 0)]
 
     # ---- capacity management ----
 

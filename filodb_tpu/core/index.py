@@ -827,6 +827,13 @@ class PartKeyIndex:
         ids = ids[np.argsort(lin.end[ids - lin.base], kind="stable")]
         return ids[:limit] if limit is not None else ids
 
+    def lives_of(self, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """(start, end) times of the given part ids: what
+        part_ids_from_filters compares with a range."""
+        lin = self._lin
+        off = ids - lin.base
+        return lin.start[off], lin.end[off]
+
     # ---- read path: label walks ----
 
     @staticmethod

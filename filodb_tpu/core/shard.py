@@ -56,7 +56,7 @@ def _encode_pool():
 import numpy as np
 
 from filodb_tpu.config import FilodbSettings, settings as default_settings
-from filodb_tpu.core.blockstore import DenseSeriesStore
+from filodb_tpu.core.blockstore import DenseSeriesStore, estimate_samples
 from filodb_tpu.core.index import ColumnFilter, PartKeyIndex, MAX_TIME
 from filodb_tpu.core.partkey import PartKey
 from filodb_tpu.core.ratelimit import (QuotaReachedException,
@@ -120,24 +120,119 @@ class PagedLimitExceeded(ValueError):
             f"data is kept warm for a narrower retry)")
 
 
+_NEVER_MS = int(np.iinfo(np.int64).max)
+
+
+class SelectionFacts:
+    """What a leaf reads off a selection's rows of one store before it
+    dispatches, as of one even `store.generation`: every write to what
+    they are read from (samples, eviction, paging bookkeeping) lies
+    inside `store.mutation()`, so facts whose `generation` is the
+    store's are the store's own.  Arrays are frozen."""
+    __slots__ = ("generation", "counts", "first", "last", "samples",
+                 "uniform", "covered_max", "ceil_min")
+
+    def __init__(self, store: DenseSeriesStore, rows: np.ndarray):
+        self.generation = store.generation
+        cnt, first, last = store.row_extents(rows)
+        for arr in (cnt, first, last):
+            arr.setflags(write=False)
+        self.counts, self.first, self.last = cnt, first, last
+        self.samples = int(cnt.sum())
+        # every row with the same count, first and last timestamp (one
+        # scrape grid): the estimate is then arithmetic on three scalars
+        self.uniform = None
+        if rows.size and (cnt == cnt[0]).all() \
+                and (first == first[0]).all() and (last == last[0]).all():
+            self.uniform = (int(cnt[0]), int(first[0]), int(last[0]))
+        # ensure_paged_pids' two conditions, each reduced over the rows:
+        # a row needs paging below when start < min(paged_floor,
+        # first_mem), a page-only row with samples above when
+        # end > max(paged_ceil, last_mem)
+        has = cnt > 0
+        covered = np.minimum(store.paged_floor[rows],
+                             np.where(has, first, MAX_TIME))
+        self.covered_max = int(covered.max()) if rows.size else -_NEVER_MS
+        above = store.page_only[rows] & has
+        self.ceil_min = int(np.maximum(store.paged_ceil[rows],
+                                       last)[above].min()) \
+            if above.any() else _NEVER_MS
+
+    def estimate(self, start_ms: int, end_ms: int) -> int:
+        """estimate_samples over these rows.  On uniform rows: its
+        formula on one row, in Python, times the row count — a product
+        where it sums S equal floats, so the integer may differ by 1."""
+        if self.uniform is None:
+            return estimate_samples(self.counts, self.first, self.last,
+                                    start_ms, end_ms)
+        cnt, first, last = self.uniform
+        lo, hi = max(first, start_ms), min(last, end_ms)
+        if cnt <= 0 or hi < lo:
+            return 0
+        frac = min(max(float(hi - lo) / float(max(last - first, 1)), 0.0),
+                   1.0)
+        return int(max(cnt * frac, 1.0) * self.counts.size)
+
+    def may_need_paging(self, start_ms: int, end_ms: int) -> bool:
+        return start_ms < self.covered_max or end_ms > self.ceil_min
+
+
+class SchemaSelection:
+    """One schema's series of a lookup, and what the host derives from
+    them alone: store rows, and each array's bytes once, so that cache
+    keys built from them (the fused leaf's, the host leaf's memo,
+    RawBlock.cache_token) hit by identity instead of copying and
+    comparing the arrays; `facts` holds the newest SelectionFacts
+    (TimeSeriesShard.selection_facts)."""
+    __slots__ = ("pids", "rows", "pids_key", "rows_key", "facts")
+
+    def __init__(self, pids: np.ndarray, rows: np.ndarray):
+        rows.setflags(write=False)
+        self.pids, self.rows = pids, rows
+        self.pids_key, self.rows_key = pids.tobytes(), rows.tobytes()
+        self.facts: Optional[SelectionFacts] = None
+
+
 @dataclasses.dataclass
 class PartLookupResult:
     """ref: TimeSeriesShard.scala:212 PartLookupResult.
 
     Hot paths consume the vectorized pid arrays (pids_by_schema) plus the
     shard's pid->row / pid->key tables; parts_by_schema materializes
-    PartitionInfo lists lazily for metadata/maintenance consumers."""
+    PartitionInfo lists lazily for metadata/maintenance consumers.
+    `shared`: the lookup memo hands this object to every request whose
+    range holds every selected series' life."""
     shard: int
     part_ids: np.ndarray
     pids_by_schema: Dict[str, np.ndarray]
     first_schema: Optional[str]
     shard_obj: Optional["TimeSeriesShard"] = None
+    shared: bool = False
+    _selections: Dict[str, SchemaSelection] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
 
     @property
     def parts_by_schema(self) -> Dict[str, List[PartitionInfo]]:
         parts = self.shard_obj.partitions
         return {s: [parts[p] for p in pids.tolist()]
                 for s, pids in self.pids_by_schema.items()}
+
+    def selection(self, schema_name: str) -> SchemaSelection:
+        sel = self._selections.get(schema_name)
+        if sel is None:
+            pids = self.pids_by_schema[schema_name]
+            sel = self._selections[schema_name] = SchemaSelection(
+                pids, self.shard_obj.rows_for(pids))
+        return sel
+
+
+class _Selection:
+    """A lookup memo entry: the series (filters, limit) select on this
+    shard whatever the range, in part_ids_from_filters' end-time order,
+    with their lives; valid while `stamp` is (index.mutations,
+    keys_epoch).  `whole` answers every range that holds all the lives."""
+    __slots__ = ("stamp", "ids", "start", "end", "max_start", "min_end",
+                 "whole")
 
 
 class TimeSeriesShard:
@@ -179,9 +274,10 @@ class TimeSeriesShard:
         # the pinned list ref both validates identity (ids are reused
         # after GC) and bounds the cache to _KEY_RESOLVE_CACHE_MAX tables
         self._key_resolve_cache: Dict[int, tuple] = {}
-        # lookup_partitions result memo (see its docstring): key includes
-        # index.mutations + keys_epoch, so entries self-invalidate
-        self._lookup_cache: Dict[tuple, "PartLookupResult"] = {}
+        # lookup_partitions memo (see its docstring): (filters, limit) ->
+        # _Selection, stamped with index.mutations + keys_epoch
+        self._lookup_cache: Dict[tuple, _Selection] = {}
+        self._lookup_lock = threading.Lock()
         self.stores: Dict[str, DenseSeriesStore] = {}
         # compressed resident tier: sealed chunks kept encoded in host RAM
         # so the dense tier holds only the active tail (memory/resident.py)
@@ -1032,30 +1128,69 @@ class TimeSeriesShard:
         """ref: TimeSeriesShard.lookupPartitions:1521 — index query + schema
         discovery (MultiSchemaPartitionsExec.scala:27-60).
 
-        Results are memoized per (filters, range, index.mutations,
-        keys_epoch): a dashboard's panels repeat the same selector, and
-        the postings intersection + schema split were ~1 ms/panel at 65k
-        series of pure recomputation.  Any index mutation or eviction
-        epoch bump changes the key, so a hit is always current."""
+        Memoized per (filters, limit) and valid while (index.mutations,
+        keys_epoch) stand — not per range: the range enters the index's
+        answer only through each series' life, every write to which moves
+        index.mutations, so a dashboard whose `end` moves one step an
+        open keeps hitting.  A range that holds every selected series'
+        life takes the entry's frozen answer as it is; any other masks
+        the entry's arrays, and a subsequence of a stably sorted sequence
+        is the stably sorted subsequence: part_ids_from_filters' own
+        answer either way."""
         try:
-            ck = (tuple(filters), start_time_ms, end_time_ms, limit,
-                  self.index.mutations, self.keys_epoch)
+            ck = (tuple(filters), limit)
             hash(ck)                  # filters with unhashable fields
         except TypeError:             # (e.g. In with a list): uncached
             ck = None
+        stamp = (self.index.mutations, self.keys_epoch)
+        ent = None
         if ck is not None:
-            # pop-then-reinsert: each dict op is atomic under the GIL, so
-            # two query threads racing the same key at worst both miss
-            # and recompute — never KeyError (queries run on HTTP handler
-            # threads; this path is deliberately lock-free)
-            hit = self._lookup_cache.pop(ck, None)
-            if hit is not None:
-                self._lookup_cache[ck] = hit          # LRU touch
-                if self._traced_pids and hit.part_ids.size:
-                    self._trace_touch("query_lookup", hit.part_ids)
-                return hit
-        ids = self.index.part_ids_from_filters(
-            filters, start_time_ms, end_time_ms, limit)
+            # the lock covers the dict alone (pop + reinsert is the LRU
+            # touch, and six panels of one open race one key); fills run
+            # outside it, so two threads may both fill an entry
+            with self._lookup_lock:
+                ent = self._lookup_cache.pop(ck, None)
+                if ent is not None and ent.stamp == stamp:
+                    self._lookup_cache[ck] = ent
+        if ent is None or ent.stamp != stamp:
+            cause = ("new" if ent is None else
+                     "index" if ent.stamp[0] != stamp[0] else "epoch")
+            metrics_registry.counter("leaf_selection_fills",
+                                     cause=cause).increment()
+            ent = self._fill_selection(filters, limit, stamp)
+            if ck is not None:
+                with self._lookup_lock:
+                    self._lookup_cache[ck] = ent
+                    while len(self._lookup_cache) > _LOOKUP_CACHE_MAX:
+                        self._lookup_cache.pop(next(iter(self._lookup_cache)))
+        if ent.max_start <= end_time_ms and ent.min_end >= start_time_ms:
+            res = ent.whole
+        else:
+            mask = (ent.start <= end_time_ms) & (ent.end >= start_time_ms)
+            res = self._lookup_result(ent.ids[mask][:limit])
+        if self._traced_pids and res.part_ids.size:
+            self._trace_touch("query_lookup", res.part_ids)
+        return res
+
+    def _fill_selection(self, filters: Sequence[ColumnFilter],
+                        limit: Optional[int], stamp: tuple) -> _Selection:
+        ent = _Selection()
+        ent.stamp = stamp
+        ent.ids = self.index.part_ids_from_filters(filters, -_NEVER_MS,
+                                                   _NEVER_MS)
+        ent.start, ent.end = self.index.lives_of(ent.ids)
+        ent.max_start = int(ent.start.max()) if ent.ids.size else -_NEVER_MS
+        ent.min_end = int(ent.end.min()) if ent.ids.size else _NEVER_MS
+        ent.whole = self._lookup_result(ent.ids[:limit])
+        ent.whole.shared = True
+        return ent
+
+    def _lookup_result(self, ids: np.ndarray) -> PartLookupResult:
+        """Schema discovery over index-ordered ids.  The arrays are
+        frozen: the memo hands the SAME result to every hit, so a
+        consumer mutating part_ids / pids_by_schema in place poisons its
+        own copy attempt loudly instead of silently corrupting later
+        queries (ADVICE r5)."""
         if ids.size:
             ids = ids[self._pid_alive[ids]]
         by_schema: Dict[str, np.ndarray] = {}
@@ -1066,25 +1201,33 @@ class TimeSeriesShard:
             for c in np.unique(codes):
                 name = self._schema_names[int(c)]
                 by_schema[name] = ids[codes == c]
-        if self._traced_pids and ids.size:
-            self._trace_touch("query_lookup", ids)
-        res = PartLookupResult(self.shard_num, ids, by_schema, first, self)
-        if ck is not None:
-            # the memo hands the SAME PartLookupResult to every hit:
-            # freeze the arrays so a future consumer mutating part_ids /
-            # pids_by_schema in place poisons its own copy attempt loudly
-            # instead of silently corrupting later queries (ADVICE r5)
-            ids.setflags(write=False)
-            for arr in by_schema.values():
-                arr.setflags(write=False)
-            self._lookup_cache[ck] = res
-            while len(self._lookup_cache) > _LOOKUP_CACHE_MAX:
-                try:
-                    self._lookup_cache.pop(
-                        next(iter(self._lookup_cache)), None)
-                except (StopIteration, RuntimeError):
-                    break             # concurrent trim emptied/resized it
-        return res
+        ids.setflags(write=False)
+        for arr in by_schema.values():
+            arr.setflags(write=False)
+        return PartLookupResult(self.shard_num, ids, by_schema, first, self)
+
+    def selection_facts(self, lookup: PartLookupResult, schema_name: str
+                        ) -> Tuple[SchemaSelection, SelectionFacts]:
+        """A leaf's selection on one schema with facts as of the store's
+        generation now: the memo's while their stamp stands
+        (`leaf_selection_hits`), else read again under the seqlock."""
+        sel = lookup.selection(schema_name)
+        store = self.stores[schema_name]
+        facts = sel.facts
+        if facts is not None and facts.generation == store.generation:
+            if lookup.shared:
+                metrics_registry.counter("leaf_selection_hits").increment()
+            return sel, facts
+        # a shared entry's first facts belong to the fill that
+        # lookup_partitions just counted
+        cause = ("generation" if facts is not None else
+                 None if lookup.shared else "range")
+        if cause is not None:
+            metrics_registry.counter("leaf_selection_fills",
+                                     cause=cause).increment()
+        facts = sel.facts = self.snapshot_read(
+            store, lambda: SelectionFacts(store, sel.rows))
+        return sel, facts
 
     def rows_for(self, pids: np.ndarray) -> np.ndarray:
         """Store rows for a pid array — vectorized pid->row map."""
@@ -1231,24 +1374,31 @@ class TimeSeriesShard:
     def ensure_paged_pids(self, schema_name: str, pids: np.ndarray,
                           start_time_ms: int, end_time_ms: int,
                           max_samples: Optional[int] = None,
-                          cancel=None) -> int:
+                          cancel=None,
+                          facts: Optional[SelectionFacts] = None) -> int:
         """Vectorized ensure_paged precheck: computes which pids actually
         need on-demand paging with numpy over the whole pid array, then runs
         the per-partition paging loop only on that (usually empty) subset —
-        the fully-resident hot path costs O(S) numpy, no Python loop."""
+        the fully-resident hot path costs O(S) numpy, no Python loop; with
+        the pids' `facts` (selection_facts) two scalar comparisons, and
+        the arrays only when those say some row may need paging."""
         if ((isinstance(self.column_store, NullColumnStore)
                 and self.resident.num_chunks == 0) or pids.size == 0):
             return 0
+        if facts is not None and not facts.may_need_paging(start_time_ms,
+                                                           end_time_ms):
+            return 0
+        return self._page_in_needed(schema_name, pids, start_time_ms,
+                                    end_time_ms, max_samples, cancel)
+
+    def _page_in_needed(self, schema_name: str, pids: np.ndarray,
+                        start_time_ms: int, end_time_ms: int,
+                        max_samples: Optional[int], cancel) -> int:
         store = self.stores[schema_name]
         rows = self._pid_row[pids]
-        cnt = store.counts[rows]
-        if store.ts.shape[1] == 0:
-            first_mem = np.full(rows.shape, MAX_TIME, dtype=np.int64)
-            last_mem = np.zeros(rows.shape, dtype=np.int64)
-        else:
-            first_mem = np.where(cnt > 0, store.ts[rows, 0], MAX_TIME)
-            last_mem = np.where(
-                cnt > 0, store.ts[rows, np.maximum(cnt - 1, 0)], 0)
+        cnt, first, last = store.row_extents(rows)
+        first_mem = np.where(cnt > 0, first, MAX_TIME)
+        last_mem = np.where(cnt > 0, last, 0)
         covered = np.minimum(store.paged_floor[rows], first_mem)
         need = start_time_ms < covered
         page_only = store.page_only[rows]
@@ -1355,14 +1505,15 @@ class TimeSeriesShard:
                             parts_paged += 1
                         # trimmed page-ins must not claim full coverage
                         if n == len(ts_all):
-                            store.paged_floor[row] = start_time_ms
+                            store.set_paged(row, floor=start_time_ms)
                         elif n > 0:
-                            store.paged_floor[row] = int(store.ts[row, 0])
+                            store.set_paged(row,
+                                            floor=int(store.ts[row, 0]))
                     else:
-                        store.paged_floor[row] = start_time_ms
+                        store.set_paged(row, floor=start_time_ms)
                     if cnt == 0 and store.page_only[row]:
-                        store.paged_ceil[row] = max(
-                            int(store.paged_ceil[row]), hi)
+                        store.set_paged(row, ceil=max(
+                            int(store.paged_ceil[row]), hi))
             # upper paging: only for rows that have never seen live ingest
             # (live rows' upper coverage is the checkpoint/replay invariant)
             if store.page_only[row] and int(store.counts[row]) > 0:
@@ -1387,12 +1538,12 @@ class TimeSeriesShard:
                             parts_paged += 1
                         # a trimmed page-in must not claim full coverage
                         if n == len(ts_all):
-                            store.paged_ceil[row] = end_time_ms
+                            store.set_paged(row, ceil=end_time_ms)
                         elif n > 0:
-                            store.paged_ceil[row] = int(
-                                store.ts[row, int(store.counts[row]) - 1])
+                            store.set_paged(row, ceil=int(
+                                store.ts[row, int(store.counts[row]) - 1]))
                     else:
-                        store.paged_ceil[row] = end_time_ms
+                        store.set_paged(row, ceil=end_time_ms)
         return paged
 
     def gather_series(self, parts: Sequence[PartitionInfo]):

@@ -17,6 +17,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 import jax.numpy as jnp
 
+from filodb_tpu.core.blockstore import estimate_samples
 from filodb_tpu.core.index import ColumnFilter, Equals
 from filodb_tpu.ops import agg as agg_ops
 from filodb_tpu.ops import hist as hist_ops
@@ -606,7 +607,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             if pids is None or pids.size == 0:
                 return None, stats
             store = shard.stores[schema_name]
-            rows = shard.rows_for(pids)
+            # the selection's rows, cache keys and facts (counts,
+            # extents, paging verdict): the memo's on a hit
+            sel, facts = shard.selection_facts(lookup, schema_name)
+            rows = sel.rows
 
         # Cap data scanned BEFORE materializing (or paging) the [S, T]
         # matrix — a pathological selector must fail fast, not OOM first
@@ -617,13 +621,19 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # against the resident data before ODP and again after paging.
         limit = self.ctx.planner_params.scan_limit
         enforced = limit and self.ctx.planner_params.enforced_limits
+        estimate = []      # one a leaf, for the scan cap and for leaf_route
+
+        def _scan_estimate() -> int:
+            if not estimate:
+                with span("leaf.scan_estimate"):
+                    estimate.append(facts.estimate(self.chunk_start_ms,
+                                                   self.chunk_end_ms))
+            return estimate[0]
 
         def _check_scan_cap(when: str):
             if not enforced:
                 return
-            with span("leaf.scan_estimate"):
-                to_scan = _estimate_scan(store, rows, self.chunk_start_ms,
-                                         self.chunk_end_ms)
+            to_scan = _scan_estimate()
             if to_scan > limit:
                 raise ValueError(
                     f"shard {self.shard}: query would scan ~{to_scan} "
@@ -643,7 +653,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                     self.chunk_end_ms,
                     max_samples=limit if enforced else None,
                     cancel=(None if tok is None else
-                            lambda: self._check_cancel("demand paging")))
+                            lambda: self._check_cancel("demand paging")),
+                    facts=facts)
         except PagedLimitExceeded as e:
             # structured query error, not a 500: the partial paging work
             # is kept (valid cache for a narrower retry) and the error
@@ -653,10 +664,11 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         if paged:
             stats.samples_paged += int(paged)
             stats.cold_tier = "cold_paged"
-            # ODP grew some series' extents, so the resident estimate is
-            # stale; when nothing paged the second O(S) estimate would
-            # be identical to the first — skip it (dashboard panels pay
-            # this twice per panel otherwise)
+            # ODP grew some series' extents and moved the store's
+            # generation: the facts are stale, and so is the estimate
+            # made from them
+            sel, facts = shard.selection_facts(lookup, schema_name)
+            estimate.clear()
             _check_scan_cap("after demand paging")
         schema = shard.schemas[schema_name]
         col_name = (self.columns[0] if self.columns
@@ -712,14 +724,11 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             import jax as _jax
             if _jax.default_backend() == "tpu" or os.environ.get(
                     "FILODB_TPU_FORCE_HOST_ROUTE"):
-                with span("leaf.scan_estimate"):
-                    est = _estimate_scan(store, rows, self.chunk_start_ms,
-                                         self.chunk_end_ms)
                 # a histogram sample is num_buckets values: the cap is
                 # compared with what the leaf would gather and correct
                 per_sample = (store.num_buckets if col_def is not None
                               and col_def.col_type == "hist" else 1)
-                route_host = leaf_route(est, per_sample,
+                route_host = leaf_route(_scan_estimate(), per_sample,
                                         _route_cap) == "host"
         if (not route_host
                 and getattr(shard.config.store, "device_mirror_enabled",
@@ -803,8 +812,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             vals = dev_cols[col_name]
             vbase = dev_vbases.get(col_name)
             with span("leaf.counts_copy"):
-                counts = shard.snapshot_read(
-                    store, lambda: store.counts[rows].copy())
+                if facts.generation != store.generation:
+                    sel, facts = shard.selection_facts(lookup, schema_name)
+                samples = facts.samples
             precorrected = counter_col   # mirror corrects counter columns
             shared_ts_row = mirror.fused_eligible(col_name, snap,
                                                   allow_ragged=True)
@@ -818,7 +828,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # rows bytes, not their hash: a collision would silently
                 # serve another row-set's values)
                 self._fused_cache_key = (mirror.serial, snap.gen, col_name,
-                                         rows.tobytes())
+                                         sel.rows_key)
         else:
             from filodb_tpu.utils.metrics import registry as _reg
             _reg.counter("leaf_host_gather").increment()
@@ -842,7 +852,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             base = self.chunk_start_ms
             _memo_key = (shard.keys_serial, shard.keys_epoch, self.dataset,
                          self.shard, self.chunk_start_ms, self.chunk_end_ms,
-                         col_name, precorrected, rows.tobytes())
+                         col_name, precorrected, sel.rows_key)
             _hit = _hostleaf.memo_get(_memo_key)
             if _hit is not None:
                 ts_off, vals, vbase, counts, dense = _hit
@@ -850,7 +860,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # raw-gather sub-memo: panels that share the span but
                 # differ in column/correction mode (e.g. a gauge window
                 # next to a counter rate) still share the scan itself
-                _raw_key = ("raw",) + _memo_key[:6] + (rows.tobytes(),)
+                _raw_key = ("raw",) + _memo_key[:6] + (sel.rows_key,)
                 _raw = _hostleaf.memo_get(_raw_key)
                 if _raw is not None:
                     ts, cols, counts = _raw
@@ -874,9 +884,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 dense = not bool(np.isnan(vals).any())
                 _hostleaf.memo_put(_memo_key,
                                    (ts_off, vals, vbase, counts, dense))
+            samples = int(counts.sum())
         keys = LazyKeys(shard, pids)
         stats.series_scanned = int(pids.size)
-        stats.samples_scanned = int(counts.sum())
+        stats.samples_scanned = samples
         les = store.bucket_les if vals.ndim == 3 else None
         if route_host and shared_ts_row is None and isinstance(
                 vals, np.ndarray):
@@ -894,7 +905,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         precorrected=precorrected,
                         shared_ts_row=shared_ts_row, dense=dense,
                         cache_token=(shard.keys_serial, shard.keys_epoch,
-                                     pids.tobytes()),
+                                     sel.pids_key),
                         route_host=route_host), stats
 
 
@@ -1259,19 +1270,9 @@ class SelectPersistedSegmentsExec(MultiSchemaPartitionsExec):
 def _estimate_scan(store, rows: np.ndarray, start_ms: int,
                    end_ms: int) -> int:
     """Estimated samples in [start_ms, end_ms] across the given store rows,
-    from per-series extents under a uniform-spacing assumption — O(S), no
-    [S, T] materialization."""
-    cnt = store.counts[rows].astype(np.int64)
-    if store.ts.shape[1] == 0 or not cnt.any():
-        return 0
-    first = store.ts[rows, 0]
-    last = store.ts[rows, np.maximum(cnt - 1, 0)]
-    lo = np.maximum(first, start_ms)
-    hi = np.minimum(last, end_ms)
-    span = np.maximum(last - first, 1).astype(np.float64)
-    frac = np.clip((hi - lo).astype(np.float64) / span, 0.0, 1.0)
-    est = np.where((cnt > 0) & (hi >= lo), np.maximum(cnt * frac, 1.0), 0.0)
-    return int(est.sum())
+    read from the store: what a leaf's SelectionFacts.estimate answers
+    from its memoised extents."""
+    return estimate_samples(*store.row_extents(rows), start_ms, end_ms)
 
 
 def leaf_route(est_samples: int, values_per_sample: int, cap: int) -> str:
