@@ -1,6 +1,6 @@
 """Chaos-bench data node: one OS process owning COPIES of shards.
 
-Spawned (and SIGKILLed, and respawned) by `python bench.py chaos`: for
+Spawned (and SIGKILLed, and respawned) by `python -m bench.drills chaos`: for
 every shard in --shards it builds the same deterministic counter
 dataset any other owner of that shard builds (series are tagged
 `_ns_=s<shard>` — shard-keyed, so primary and replica copies are

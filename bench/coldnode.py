@@ -1,6 +1,6 @@
 """Elastic-read bench node: one STATELESS query-only OS process.
 
-Spawned by `python bench.py objectstore`: it owns NO shards and holds
+Spawned by `python -m bench.drills objectstore`: it owns NO shards and holds
 NO local data — its entire serving state is a mounted manifest snapshot
 over the shared object store (persist/objectstore.py make_query_tier)
 plus a cold cache.  The coordinator scatter-gathers cold leaves here

@@ -34,7 +34,7 @@ def _emit(harness: str, ok: bool, **extra):
 def _overlap_flags(sh):
     """(eviction_in_progress, mirror_rebuild_in_progress) for latency
     attribution: every recorded query latency is tagged with these so a
-    tail outlier (like SOAK_LONG_r05's 752 s p99) is attributable to its
+    tail outlier (like the round-5 soak's 752 s p99) is attributable to its
     overlapping maintenance window from the artifact alone."""
     evicting = bool(getattr(sh, "eviction_in_progress", False))
     rebuilding = any(
@@ -561,7 +561,7 @@ def eviction_window_soak(minutes: float = 2.0, series: int = 20_000,
     overlap flags, and the harness asserts STRUCTURALLY that no query
     thread ever ran a post-eviction full `_refresh` — queries must ride
     the host-gather fallback while the rebuild happens in the background
-    (the SOAK_LONG_r05 752 s p99 was one query paying that rebuild
+    (the round-5 soak's 752 s p99 was one query paying that rebuild
     inline)."""
     import numpy as np
 

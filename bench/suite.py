@@ -9,7 +9,7 @@ HistogramQueryBenchmark; runner run_benchmarks.sh.
 Each benchmark prints one JSON line {"bench", "metric", "value", "unit"}.
 Run all: python -m bench.suite            (add --quick for smoke sizing)
 Run one: python -m bench.suite ingestion
-The headline driver benchmark stays in bench.py at the repo root.
+The benchmark the driver runs is benchmark/run.py (BENCHMARK.json).
 """
 from __future__ import annotations
 

@@ -130,61 +130,10 @@ from filodb_tpu.utils import snappy  # noqa: E402
 
 # ------------------------------------------------------------ f64 reference
 
-
-def ref_windows(ts_row, wends):
-    """First/last sample index and count of each window (wend-range, wend]
-    on one shared timestamp row."""
-    lo = np.searchsorted(ts_row, wends - RANGE_MS + 1, side="left")
-    hi = np.searchsorted(ts_row, wends, side="right") - 1
-    return lo, hi, hi - lo + 1
-
-
-def correct_counters(vals, out):
-    """out <- vals with counter resets corrected by walking each row: a drop
-    adds the full previous value to everything after it (in place in `out`;
-    the [n, T] matrices are reused across chunks, not reallocated)."""
-    np.subtract(vals[:, 1:], vals[:, :-1], out=out[:, 1:])
-    out[:, 0] = 0.0
-    np.multiply(out[:, 1:] < 0, vals[:, :-1], out=out[:, 1:])
-    np.cumsum(out, axis=1, out=out)
-    out += vals
-    return out
-
-
-def ref_increase(ts_row, corr, wends):
-    """increase(v[5m]) per series and window from reset-corrected values,
-    f64: Prometheus' extrapolatedRate.  rate = increase / range seconds."""
-    lo, hi, n = ref_windows(ts_row, wends)
-    out = np.full((corr.shape[0], len(wends)), np.nan)
-    ok = n >= 2
-    lo, hi, n, we = lo[ok], hi[ok], n[ok], wends[ok].astype(np.float64)
-    v1, v2 = corr[:, lo], corr[:, hi]
-    t1, t2 = ts_row[lo].astype(np.float64), ts_row[hi].astype(np.float64)
-    dur_start = np.broadcast_to((t1 - (we - RANGE_MS)) / 1000.0, v1.shape)
-    dur_end = (we - t2) / 1000.0
-    sampled = (t2 - t1) / 1000.0
-    avg = sampled / (n - 1)
-    delta = v2 - v1
-    with np.errstate(divide="ignore", invalid="ignore"):
-        dur_zero = sampled * (v1 / delta)
-    take = (delta > 0) & (v1 >= 0) & (dur_zero < dur_start)
-    dur_start = np.where(take, dur_zero, dur_start)
-    thr = avg * 1.1
-    extrap = sampled + np.where(dur_start < thr, dur_start, avg / 2) \
-        + np.where(dur_end < thr, dur_end, avg / 2)
-    out[:, ok] = delta * (extrap / sampled)
-    return out
-
-
-def ref_sum_over_time(ts_row, csum, wends):
-    """sum_over_time(v[5m]) from per-row running sums `csum`."""
-    lo, hi, n = ref_windows(ts_row, wends)
-    out = np.full((csum.shape[0], len(wends)), np.nan)
-    ok = n >= 1
-    lo, hi = lo[ok], hi[ok]
-    head = np.where(lo > 0, csum[:, np.maximum(lo - 1, 0)], 0.0)
-    out[:, ok] = csum[:, hi] - head
-    return out
+# the benchmark's plain NumPy reference: windows (wend-range, wend] on one
+# shared timestamp row, Prometheus' extrapolatedRate on reset-corrected values
+from benchmark.reference import (correct_counters, ref_increase,  # noqa: E402
+                                 ref_sum_over_time)
 
 
 class GroupSums:
@@ -409,10 +358,11 @@ def load(server, metric, schema, col, S, T, seed, make_chunk, inc=None,
         gids = np.arange(lo, hi) % NUM_APPS
         if sot is not None:
             csum = np.cumsum(vals, axis=1, out=wbuf[:n, :T])
-            sot.add(ref_sum_over_time(ts_row, csum, sot.wends), gids)
+            sot.add(ref_sum_over_time(ts_row, csum, sot.wends, RANGE_MS),
+                    gids)
         if inc is not None:
             corr = correct_counters(vals, wbuf[:n, :T])
-            inc.add(ref_increase(ts_row, corr, inc.wends), gids)
+            inc.add(ref_increase(ts_row, corr, inc.wends, RANGE_MS), gids)
         if written is not None:
             newer, bump, acc = written
             k = max(min(len(newer), hi) - lo, 0)    # written series here
@@ -421,9 +371,10 @@ def load(server, metric, schema, col, S, T, seed, make_chunk, inc=None,
                 newer[lo:lo + k] = vals[:k, -1] + bump[lo:lo + k]
                 wbuf[:k, T] = corr[:k, -1] + bump[lo:lo + k]
                 acc.add(ref_increase(np.append(ts_row, ts_row[-1] + STEP_MS),
-                                     wbuf[:k], acc.wends), gids[:k])
+                                     wbuf[:k], acc.wends, RANGE_MS), gids[:k])
             if k < n:
-                acc.add(ref_increase(ts_row, corr[k:], acc.wends), gids[k:])
+                acc.add(ref_increase(ts_row, corr[k:], acc.wends, RANGE_MS),
+                        gids[k:])
         t2 = time.perf_counter()
         for sh in shards:
             idx = np.flatnonzero(shard_of == sh.shard_num)

@@ -424,7 +424,8 @@ class StoreConfig:
     # run the post-eviction full DeviceMirror re-upload on a background
     # thread instead of the first query's critical path (queries host-
     # gather until the new snapshot publishes) — the 752 s eviction-window
-    # query p99 in SOAK_LONG_r05 was one query paying a 1M-series
+    # query p99 of the round-5 soak (PERF.md section 7, "Before the
+    # chip benchmark") was one query paying a 1M-series
     # re-upload inline.  Incremental (append-only) refreshes and the
     # cold first build stay inline.
     mirror_background_rebuild: bool = True
@@ -509,8 +510,8 @@ class FederationConfig:
     probe_timeout_s: float = 2.0
     # push exactly-mergeable aggregations as [G, W] AggPartials (the
     # cross-cluster pushdown).  False = ship-everything strawman (whole
-    # child series cross the wire) — the wire-ratio baseline bench.py
-    # federation measures against; True is the only production stance.
+    # child series cross the wire) — the wire-ratio baseline `python -m
+    # bench.drills federation` measures against; True is the only production stance.
     push_partials: bool = True
     # remote clusters, dict-shaped because HOCON-lite has no object
     # lists: {name: {host, port, dataset?, match: {label: regex-or-
@@ -688,7 +689,7 @@ class FilodbSettings:
             else:
                 if rest in top_fields:
                     overlay[rest] = parsed
-                # other FILODB_* vars (e.g. FILODB_BENCH_TPU_TIMEOUT) belong
+                # other FILODB_* vars (e.g. FILODB_KAFKA_IT) belong
                 # to sibling tools — not config keys, not typos: ignored
         if overlay:
             s.overlay(overlay, source="environment")

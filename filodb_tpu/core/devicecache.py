@@ -644,8 +644,8 @@ class DeviceMirror:
         re-upload: the cold first build (nothing to serve from anyway)
         and append-only growth (incremental tail upload).  False exactly
         when eviction/compaction REARRANGED cells (shift_version moved) —
-        the case whose inline cost was the 752 s query p99 in
-        SOAK_LONG_r05."""
+        the case whose inline cost was the 752 s query p99 of the
+        round-5 soak (PERF.md section 7)."""
         snap = self._snap
         return snap is None or snap.shift_version == store.shift_version
 

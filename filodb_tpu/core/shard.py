@@ -1190,7 +1190,7 @@ class TimeSeriesShard:
         frozen: the memo hands the SAME result to every hit, so a
         consumer mutating part_ids / pids_by_schema in place poisons its
         own copy attempt loudly instead of silently corrupting later
-        queries (ADVICE r5)."""
+        queries (round-5 review)."""
         if ids.size:
             ids = ids[self._pid_alive[ids]]
         by_schema: Dict[str, np.ndarray] = {}

@@ -1388,7 +1388,7 @@ def _num_param(params: Dict[str, str], key: str,
 
 # the upstream Prometheus duration grammar: units in strictly descending
 # order, each at most once, no fractions — "1h30m" yes, "1.5s"/"1s1s"/"1m1h"
-# 400 (ref: prometheus/common model.ParseDuration; wire parity per ADVICE r5)
+# 400 (ref: prometheus/common model.ParseDuration; wire parity, round-5 review)
 _DURATION_RE = re.compile(
     r"((\d+)y)?((\d+)w)?((\d+)d)?((\d+)h)?((\d+)m)?((\d+)s)?((\d+)ms)?")
 

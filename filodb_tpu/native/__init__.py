@@ -41,12 +41,6 @@ class _NativeLib:
         c.filodb_nibble_unpack.argtypes = [
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t]
-        c.filodb_iter_rate.restype = None
-        c.filodb_iter_rate.argtypes = [
-            ctypes.POINTER(ctypes.c_longlong), ctypes.POINTER(ctypes.c_double),
-            ctypes.c_size_t, ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_longlong), ctypes.c_size_t,
-            ctypes.c_longlong, ctypes.POINTER(ctypes.c_double)]
 
     def xxhash32(self, data: bytes, seed: int = 0) -> int:
         return self._c.filodb_xxhash32(data, len(data), seed)
@@ -74,23 +68,6 @@ class _NativeLib:
             out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)), count)
         if consumed < 0:
             raise ValueError("nibble_unpack: truncated input")
-        return out
-
-    def iter_rate(self, ts_row: np.ndarray, vals: np.ndarray,
-                  wends: np.ndarray, range_ms: int) -> np.ndarray:
-        """Per-(series, window) extrapolated rate, single C core — the
-        compiled ChunkedWindowIterator stand-in (bench baseline)."""
-        ts = np.ascontiguousarray(ts_row, dtype=np.int64)
-        v = np.ascontiguousarray(vals, dtype=np.float64)
-        we = np.ascontiguousarray(wends, dtype=np.int64)
-        S, T = v.shape
-        out = np.empty((S, len(we)), dtype=np.float64)
-        self._c.filodb_iter_rate(
-            ts.ctypes.data_as(ctypes.POINTER(ctypes.c_longlong)),
-            v.ctypes.data_as(ctypes.POINTER(ctypes.c_double)), S, T,
-            we.ctypes.data_as(ctypes.POINTER(ctypes.c_longlong)), len(we),
-            int(range_ms),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_double)))
         return out
 
 

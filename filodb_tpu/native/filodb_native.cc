@@ -15,75 +15,8 @@
 #include <cstdint>
 #include <cstddef>
 #include <cstring>
-#include <cmath>
 
 extern "C" {
-
-// -------------------------------------------------- iterator rate baseline
-//
-// Per-(series, window) Prometheus extrapolated rate over one shared grid
-// — the single-core compiled stand-in for the JVM ChunkedWindowIterator
-// hot loop (ref: query/.../exec/PeriodicSamplesMapper.scala:202-292;
-// jmh/.../QueryInMemoryBenchmark.scala:174-246).  No JVM exists in this
-// environment, so bench.py reports this as `iterator_c_samples_per_sec`:
-// an honest compiled-iterator comparator for the kernel's throughput,
-// replacing the round-4 Python-loop strawman (round-5 verdict item 7).
-// Semantics match bench.numpy_vectorized_baseline (the f64 oracle):
-// window (wend-range, wend], full extrapolation, counter-zero clamp.
-
-static size_t lower_bound_ll(const long long* a, size_t n, long long key) {
-  size_t lo = 0, hi = n;
-  while (lo < hi) {
-    size_t mid = lo + ((hi - lo) >> 1);
-    if (a[mid] < key) lo = mid + 1; else hi = mid;
-  }
-  return lo;
-}
-
-static size_t upper_bound_ll(const long long* a, size_t n, long long key) {
-  size_t lo = 0, hi = n;
-  while (lo < hi) {
-    size_t mid = lo + ((hi - lo) >> 1);
-    if (a[mid] <= key) lo = mid + 1; else hi = mid;
-  }
-  return lo;
-}
-
-void filodb_iter_rate(const long long* ts, const double* vals,
-                      size_t S, size_t T,
-                      const long long* wends, size_t W,
-                      long long range_ms, double* out /* [S*W] */) {
-  for (size_t s = 0; s < S; ++s) {
-    const double* row = vals + s * T;
-    double* orow = out + s * W;
-    for (size_t w = 0; w < W; ++w) {
-      long long wend = wends[w];
-      size_t lo = lower_bound_ll(ts, T, wend - range_ms + 1);
-      size_t hi = upper_bound_ll(ts, T, wend);
-      if (hi < lo + 2) { orow[w] = NAN; continue; }
-      size_t last = hi - 1;
-      double t1 = (double)ts[lo], t2 = (double)ts[last];
-      double sampled = (t2 - t1) / 1000.0;
-      if (!(sampled > 0)) { orow[w] = NAN; continue; }
-      double v1 = row[lo], v2 = row[last];
-      double delta = v2 - v1;
-      double wstart = (double)(wend - range_ms);
-      double dur_start = (t1 - wstart) / 1000.0;
-      double dur_end = ((double)wend - t2) / 1000.0;
-      double avg = sampled / (double)(hi - lo - 1);
-      double ds = dur_start;
-      if (delta > 0 && v1 >= 0) {
-        double dur_zero = sampled * (v1 / delta);
-        if (dur_zero < dur_start) ds = dur_zero;
-      }
-      double threshold = avg * 1.1;
-      double extrap = sampled + (ds < threshold ? ds : avg / 2)
-                              + (dur_end < threshold ? dur_end : avg / 2);
-      orow[w] = delta * (extrap / sampled)
-                / ((double)wend - wstart) * 1000.0;
-    }
-  }
-}
 
 // ----------------------------------------------------------------- xxHash
 

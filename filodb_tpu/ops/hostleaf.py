@@ -1,7 +1,7 @@
 """Host numpy evaluation of small fused-leaf working sets.
 
 On-chip, a leaf query pays a ~65 ms dispatch floor regardless of size
-(TPU_CHAIN_r05.json intercepts), so an 8k-series dashboard panel that
+(round-5 chained-dispatch intercepts; PERF.md section 7, "Before the chip benchmark"), so an 8k-series dashboard panel that
 host numpy evaluates in single-digit ms is ~10x slower on the chip —
 bench r5's `vs_iterator_c = 0.7` at 8k made the crossover explicit.
 This module is the host side of the cost-based router (round-5 verdict

@@ -4,14 +4,17 @@ The headline query shape — `sum by (...) (rate(counter[5m]))` — costs the
 XLA path several passes over the [S, T] value matrix (validity mask, reset
 correction scan, boundary gathers, then a scatter-add segment sum).  On a
 bandwidth-bound chip the passes are the latency.  This kernel computes the
-whole thing in ONE read of the values, by turning every data-dependent
-access into an MXU matmul against 0/1 selection matrices, built on the
-device from a few host-built window rows (kernel_operands):
+whole thing in ONE read of the values, from a few host-built window rows
+(kernel_operands):
 
-- boundary gathers  v[:, first[w]]  ->  v @ O1, O1[t, w] = 1{t == first[w]}
-- cumulative reset corrections      ->  drops @ L1, L1[t, w] = 1{t <= first[w]}
-  (drops[s, t] = max(prev - cur, 0) is local once rows are dense)
-- group segment-sum                 ->  onehot(gids) @ rate  on the MXU
+- boundary selections  v[:, first[w]], v[:, last[w]]  ->  per-128-lane-tile
+  dynamic gathers at the host-built indices (_gather_cols)
+- cumulative reset corrections  ->  an in-row prefix sum of the drops,
+  selected by the same gathers (drops[s, t] = max(prev - cur, 0) is local
+  once rows are dense)
+- *_over_time window sums  ->  v @ band, band[t, w] = 1{first[w] <= t <=
+  last[w]}, a 0/1 matrix built on the device
+- group segment-sum  ->  onehot(gids) @ rate  on the MXU
 
 Preconditions (the caller gates, see `can_fuse`): one shared scrape grid
 across series (the devicecache/shared_grid invariant) and dense rows — no
@@ -36,38 +39,10 @@ from jax.experimental import pallas as pl
 
 _LANE = 128
 _MIN_BS = 32
-try:
-    _BS = int(os.environ.get("FILODB_FUSED_BS", "256"))
-except ValueError:
-    raise ValueError(
-        f"FILODB_FUSED_BS={os.environ['FILODB_FUSED_BS']!r} is not an "
-        f"integer") from None
-"""Series rows per grid step (VMEM-sized).  Env-overridable for on-chip
-block-size sweeps; pick_block still shrinks from here
+_BS = 256
+"""Series rows per grid step (VMEM-sized; a power of two, which padding
+and pick_block's halving both assume).  pick_block shrinks from here
 whenever the VMEM estimate demands it."""
-if _BS < _MIN_BS or (_BS & (_BS - 1)):
-    raise ValueError(
-        f"FILODB_FUSED_BS={_BS} must be a power of two >= {_MIN_BS}: "
-        f"padding (pad_values) and the pick_block halving ladder both "
-        f"assume it, and a block below _MIN_BS would silently drop "
-        f"trailing series rows in interpret mode")
-
-_PRECISION = os.environ.get("FILODB_FUSED_PRECISION", "episplit")
-"""MXU precision strategy for the kernel's matmuls — see _matmuls()."""
-if _PRECISION not in ("highest", "split", "episplit"):
-    raise ValueError(
-        f"FILODB_FUSED_PRECISION={_PRECISION!r}: expected 'highest', "
-        f"'split' or 'episplit' (a typo here would silently mislabel a "
-        f"tuning sweep)")
-
-_GATHER = os.environ.get("FILODB_FUSED_GATHER", "1") != "0"
-"""Boundary selection strategy for the rate family + last_over_time: the
-default replaces the v @ o1 / v @ o2 one-hot selection MATMULS (6-pass
-f32-HIGHEST emulation over a >=99%-zero [Tp, Wp] matrix) with exact
-per-128-lane-tile dynamic gathers at host-built indices — pure data
-movement, bit-identical selections (a tiled tpu.dynamic_gather compiles
-on v5e; cross-vreg gathers do not).  "0" keeps the matmul path for A/B
-measurement."""
 
 
 def kernel_mode() -> Optional[bool]:
@@ -83,20 +58,33 @@ def kernel_mode() -> Optional[bool]:
     return True if os.environ.get("FILODB_TPU_FUSED_INTERPRET") else None
 
 
-def gather_default(kind: str) -> bool:
-    """Whether the gather strategy applies to this kernel kind (the
-    over_time band kinds keep their window-sum matmuls: a cumsum+
-    gather-diff replacement would change f32 summation order)."""
-    return _GATHER and kind in ("rate_family", "last_over_time")
+def _selects_by_gather(kind: str) -> bool:
+    """Which kinds select their window boundaries by exact
+    per-128-lane-tile dynamic gathers at host-built indices (_gather_cols:
+    pure data movement) instead of one-hot selection matmuls: the rate
+    family and last_over_time.  The over_time band kinds keep their
+    window-sum matmuls: a cumsum + gather-difference form would change
+    the f32 summation order."""
+    return kind in ("rate_family", "last_over_time")
 
+
+# The MXU's default single bf16 pass truncates f32 mantissas (1e-2
+# relative error on counter magnitudes).  Every matmul of the kernel has
+# one operand that is exact in bf16 (a 0/1 band, one-hot or validity
+# matrix), so each gets the fewest passes that keep the other side's bits.
+# (Mosaic lowers only DEFAULT and HIGHEST; Precision.HIGH and per-operand
+# precision tuples are rejected.)
 
 def _dot_hi(a, b):
+    """values x band (the over_time kinds' window sums): full f32
+    emulation, about six bf16 passes that Mosaic fuses into one schedule."""
     return jnp.dot(a, b, preferred_element_type=jnp.float32,
                    precision=jax.lax.Precision.HIGHEST)
 
 
 def _dot_1p(a, b):
-    """One bf16 MXU pass (f32 operands truncated), f32 accumulation."""
+    """binary x binary (validity and presence counts): one bf16 MXU pass
+    with f32 accumulation is exact on 0/1 operands."""
     return jnp.dot(a, b, preferred_element_type=jnp.float32,
                    precision=jax.lax.Precision.DEFAULT)
 
@@ -111,57 +99,14 @@ def _split3(x):
     return hi, mid, r - mid
 
 
-def _matmuls():
-    """Per-operand MXU precision for the kernel's matmuls.
-
-    Every matmul in this kernel has at least one exact-in-bf16 operand:
-    the 0/1 selection/band/one-hot matrices, or a 0/1 validity mask.
-    Full f32 emulation (HIGHEST ~ 6 bf16 MXU passes) therefore wastes
-    passes on a side that cannot lose bits.  "split" mode decomposes the
-    VALUES operand into 3 bf16 terms (Mosaic rejects per-operand
-    `precision` tuples, so the decomposition HIGHEST would do internally
-    is spelled out) and runs 3 single-pass matmuls against the binary
-    operand: the hi/mid passes are exact, the lo pass carries ~f32-
-    epsilon truncation — the same |v|*2^-24 error the f32 *storage* of
-    the values already imposes on every path.  Binary x binary matmuls
-    (validity counts) are exact at DEFAULT outright (0/1 products, f32
-    MXU accumulation): 1 pass.  Returns (mmv, mmg, mmb): values x
-    binary, binary x values (group epilogue), binary x binary.
-
-    Measured on a v5e in round 4: at
-    262k x 720 full "split" is NOT faster — dense p50 regressed ~20%
-    (three separate single-pass dots + the VPU decomposition schedule
-    worse than Mosaic's fused multi-pass emulation) and ragged gained
-    only ~6%, while results stayed bit-identical (max_rel_err 0.0).
-    That regression was the since-removed selection matmuls' schedule,
-    not the epilogue's: "episplit" (round 5, the DEFAULT) applies the
-    decomposition ONLY to the group epilogue (mmg) and keeps the
-    over_time band matmuls (mmv) at HIGHEST — with gather selections
-    the default for the rate family, mmg is that kernel's only large
-    matmul.  Measured (TPU_CHAIN_r05.json *_episplit vs *_gather):
-    epilogue attribution 1.84 -> 1.18 ms at 262k, 7.40 -> 4.52 ms at
-    1M; total device time at the 1M north star 15.95 -> 13.15 ms
-    (55.0B samples/s device rate).  mmb (binary x binary presence
-    counts) is single-pass in every mode: 0/1 operands are exact in
-    bf16 and the MXU accumulates in f32, so DEFAULT is mathematically
-    exact there — emulation passes on it buy nothing.
-
-    (Mosaic lowers only DEFAULT and HIGHEST; Precision.HIGH and
-    per-operand precision tuples are rejected.)"""
-    def mmg_split(a, b):
-        hi, mid, lo = _split3(b)
-        return _dot_1p(a, hi) + _dot_1p(a, mid) + _dot_1p(a, lo)
-
-    if _PRECISION == "episplit":
-        return _dot_hi, mmg_split, _dot_1p
-    if _PRECISION != "split":
-        return _dot_hi, _dot_hi, _dot_1p
-
-    def mmv(a, b):
-        hi, mid, lo = _split3(a)
-        return _dot_1p(hi, b) + _dot_1p(mid, b) + _dot_1p(lo, b)
-
-    return mmv, mmg_split, _dot_1p
+def _dot_split3(a, b):
+    """binary x values (the group epilogue, the rate family's only large
+    matmul): the decomposition HIGHEST would do internally, spelled out on
+    the values side only, so three single passes instead of six.  The hi
+    and mid passes are exact; the lo pass carries the |v|*2^-24 truncation
+    that f32 storage of the values already imposes on every path."""
+    hi, mid, lo = _split3(b)
+    return _dot_1p(a, hi) + _dot_1p(a, mid) + _dot_1p(a, lo)
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -176,8 +121,9 @@ def _bucket_up(n: int, quantum: int, exact_below: int) -> int:
 
     Under live ingest the series count drifts every snapshot refresh;
     without bucketing each drift changes Sp and every query pays a full
-    XLA recompile (measured 43-73 s at 262k-1M, BENCH_r04.json) — the
-    prime suspect for SOAK_r04's 9x query degradation.  Below
+    XLA recompile (measured 43-73 s at 262k-1M in round 4) — the
+    prime suspect for that round's 9x query degradation under a soak
+    (PERF.md section 7, "Before the chip benchmark").  Below
     `exact_below` the plain quantum pad is kept: small shapes are cheap
     to compile and common in tests that assert exact padding."""
     if n <= exact_below:
@@ -221,9 +167,9 @@ class FusedPlan(NamedTuple):
     wstart_x: np.ndarray  # [1, Wp] f32  window start boundary (exclusive-1)
     wend_x: np.ndarray   # [1, Wp] f32
     # boundary slot indices (first[w] / last[w]; 0 sentinel for empty +
-    # padded windows): the gather-strategy kernel selects columns at these
-    # positions, the matmul kinds' one-hot / step matrices are built from
-    # them (gather_default)
+    # padded windows): the gather kinds select columns at these positions,
+    # the band kinds' one-hot / step matrices are built from them
+    # (_selects_by_gather)
     idx1: np.ndarray     # [1, Wp] f32
     idx2: np.ndarray     # [1, Wp] f32
     # raw shared-grid timestamps [1, Tp] f32 (0 pad tail): the ragged rate
@@ -268,24 +214,25 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
                      wvalid=(n >= 2), wvalid1=(n >= 1), W=W, Tp=Tp)
 
 
-def kernel_operands(rows, tsrow, Tp: int, over_time: bool, gather: bool):
+def kernel_operands(rows, tsrow, Tp: int, kind: str):
     """The 12 operands `_kernel` reads after (vals, vbase, gids), from a
     plan's uploaded rows.  Traceable: `_run` calls it inside its jit (and
     with it every caller that composes `run_kernel` under its own trace,
     parallel/mesh.py), so an enqueue ships the [8, Wp] rows and nothing
     else of the plan.
 
-    The matmul kinds' selection matrices are built here, on the device:
+    The band kinds' selection matrices are built here, on the device:
     o[t, w] = 1{t == idx[w]} and l[t, w] = 1{t <= idx[w]} over the
-    non-empty windows (n1 >= 1), 0 elsewhere.  In gather mode the kernel
-    reads none of them and gets [8, 128] stand-ins, which frees their
-    ~1.5 MB of VMEM for larger series blocks.  `n` resolves to the true
-    counts for the over_time kinds; `tsrow` None (every kind but the
-    ragged rate family leaves it unread) becomes zeros."""
+    non-empty windows (n1 >= 1), 0 elsewhere.  The gather kinds
+    (_selects_by_gather) read none of them and get [8, 128] stand-ins,
+    which frees their ~1.5 MB of VMEM for larger series blocks.  `n`
+    resolves to the true counts for the over_time kinds; `tsrow` None
+    (every kind but the ragged rate family leaves it unread) becomes
+    zeros."""
     def row(i):
         return rows[i:i + 1]
 
-    if gather:
+    if _selects_by_gather(kind):
         sel = (jnp.zeros((8, _LANE), jnp.float32),) * 4
     else:
         t = jax.lax.broadcasted_iota(jnp.int32, (Tp, rows.shape[1]), 0)
@@ -299,7 +246,8 @@ def kernel_operands(rows, tsrow, Tp: int, over_time: bool, gather: bool):
                mat(_I2, True))
     if tsrow is None:
         tsrow = jnp.zeros((1, Tp), jnp.float32)
-    return sel + (row(_T1), row(_T2), row(_N1 if over_time else _N),
+    return sel + (row(_T1), row(_T2),
+                  row(_N1 if kind in OVER_TIME_FNS else _N),
                   row(_WS), row(_WE), tsrow, row(_I1), row(_I2))
 
 
@@ -440,44 +388,30 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             *out_refs,
             num_groups: int, is_counter: bool, is_rate: bool,
             with_drops: bool, kind: str = "rate_family",
-            ragged: bool = False, per_series: bool = False,
-            gather: bool = False):
+            ragged: bool = False, per_series: bool = False):
     v = vals_ref[:]                                   # [BS, Tp]
-    # The MXU's default single bf16 pass truncates f32 mantissas (1e-2
-    # relative error on counter magnitudes); _matmuls() picks multi-pass
-    # f32 decompositions per operand — see its docstring.
-    mmv, mmg, mmb = _matmuls()
     if kind == "last_over_time":
         # instant-vector selector (`sum by (x) (metric)` with staleness
-        # lookback): the last sample in each window is the o2 one-hot
-        # gather; empty windows contribute 0 and are masked by counts.
+        # lookback): the last sample in each window, gathered at last[w];
+        # empty windows contribute 0 and are masked by counts.
         # Ragged keeps SLOT semantics deliberately — a NaN in the newest
         # slot is a staleness marker that makes the series absent, not a
         # hole to skip (unlike the rate family's range-vector filtering)
         if ragged:
             m = v == v
-            if gather:
-                idx2 = i2_ref[:].astype(jnp.int32)
-                sel = _gather_cols(jnp.where(m, v, 0.0), idx2)
-                # empty windows gather column idx 0 (a plan sentinel):
-                # the true-count mask zeroes their presence, matching
-                # the all-zero o2 column the matmul form relied on
-                pres = _gather_cols(m.astype(jnp.float32), idx2) \
-                    * jnp.minimum(n_ref[:], 1.0)
-            else:
-                sel = mmv(jnp.where(m, v, 0.0), o2_ref[:])
-                pres = mmb(m.astype(jnp.float32), o2_ref[:])
-            out = (sel + vbase_ref[:]) * pres
-            _epilogue(mmg, gids_ref, out, pres, out_refs, num_groups,
-                      per_series, mmb=mmb)
-            return
-        if gather:
-            sel = _gather_cols(v, i2_ref[:].astype(jnp.int32)) \
+            idx2 = i2_ref[:].astype(jnp.int32)
+            sel = _gather_cols(jnp.where(m, v, 0.0), idx2)
+            # empty windows gather column idx 0 (a plan sentinel): the
+            # true-count mask zeroes their presence
+            pres = _gather_cols(m.astype(jnp.float32), idx2) \
                 * jnp.minimum(n_ref[:], 1.0)
-        else:
-            sel = mmv(v, o2_ref[:])
+            out = (sel + vbase_ref[:]) * pres
+            _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
+            return
+        sel = _gather_cols(v, i2_ref[:].astype(jnp.int32)) \
+            * jnp.minimum(n_ref[:], 1.0)
         out = sel + vbase_ref[:] * jnp.minimum(n_ref[:], 1.0)
-        _epilogue(mmg, gids_ref, out, None, out_refs, num_groups, per_series)
+        _epilogue(gids_ref, out, None, out_refs, num_groups, per_series)
         return
     if kind in ("sum_over_time", "avg_over_time", "count_over_time"):
         # window sums as ONE matmul against the band matrix
@@ -489,11 +423,11 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         band = l2_ref[:] - l1_ref[:] + o1_ref[:]
         if ragged:
             validf = (v == v).astype(jnp.float32)     # NaN-aware
-            s = mmv(jnp.where(v == v, v, 0.0), band)
-            n = mmb(validf, band)                      # [BS, Wp] valid counts
+            s = _dot_hi(jnp.where(v == v, v, 0.0), band)
+            n = _dot_1p(validf, band)                  # [BS, Wp] valid counts
             pres = (n > 0).astype(jnp.float32)
         else:
-            s = mmv(v, band)
+            s = _dot_hi(v, band)
             n = n_ref[:]                              # [1, Wp] true counts
             pres = None
         if kind == "sum_over_time":
@@ -509,8 +443,7 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
                 # exist but hold only NaN emits 0, not absent (ref:
                 # AggrOverTimeFunctions.scala:367-382), unlike sum/avg
                 pres = (n_ref[:] > 0).astype(jnp.float32) * jnp.ones_like(s)
-        _epilogue(mmg, gids_ref, out, pres, out_refs, num_groups,
-                  per_series, mmb=mmb)
+        _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
         return
     pres = None
     if ragged:
@@ -518,9 +451,9 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # filters staleness markers out of range vectors before the rate
         # math, ref: RateFunctions.scala:140-196 iterates stored samples
         # only) — so the boundaries are each series' first/last VALID
-        # sample inside the window.  Forward/backward fill scans reduce
-        # the per-series boundary search to the same shared one-hot
-        # matmuls as the dense path, keeping everything in one HBM pass.
+        # sample inside the window.  Forward/backward fill scans make the
+        # shared first/last window slots carry those boundary values, so
+        # the same gathers as the dense path select them, in one HBM pass.
         m = v == v
         vz = jnp.where(m, v, 0.0)
         if with_drops:
@@ -537,62 +470,39 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         tsb = jnp.where(m, jnp.broadcast_to(ts_ref[:], v.shape), 0.0)
         f_c, f_t, _ = _fill_scan2(c, tsb, m, left=False)
         b_c, b_t, _ = _fill_scan2(c, tsb, m, left=True)
-        if gather:
-            # exact selections at first/last window slots (the fill scans
-            # made those slots carry the boundary VALID values), and the
-            # validity count as a cumsum difference — all integer-in-f32,
-            # bit-identical to the matmul form.  Empty windows gather
-            # slot 0: nv <= 1 there, so presence masks them exactly as
-            # the all-zero selection columns did.
-            idx1 = i1_ref[:].astype(jnp.int32)
-            idx2 = i2_ref[:].astype(jnp.int32)
-            mf = m.astype(jnp.float32)
-            cs_m = _cumsum_lanes(mf)
-            nv = _gather_cols(cs_m, idx2) - _gather_cols(cs_m, idx1) \
-                + _gather_cols(mf, idx1)
-            v1 = _gather_cols(b_c, idx1)
-            v2 = _gather_cols(f_c, idx2)
-            t1 = _gather_cols(b_t, idx1)
-            t2 = _gather_cols(f_t, idx2)
-        else:
-            band = l2_ref[:] - l1_ref[:] + o1_ref[:]
-            nv = mmb(m.astype(jnp.float32), band)      # [BS, Wp] valid count
-            v1 = mmv(b_c, o1_ref[:])
-            v2 = mmv(f_c, o2_ref[:])
-            t1 = mmv(b_t, o1_ref[:])
-            t2 = mmv(f_t, o2_ref[:])
+        # exact selections at the first/last window slots, and the
+        # validity count as a cumsum difference — all integer-in-f32.
+        # Empty windows gather slot 0: nv <= 1 there, so presence masks
+        # them.
+        idx1 = i1_ref[:].astype(jnp.int32)
+        idx2 = i2_ref[:].astype(jnp.int32)
+        mf = m.astype(jnp.float32)
+        cs_m = _cumsum_lanes(mf)
+        nv = _gather_cols(cs_m, idx2) - _gather_cols(cs_m, idx1) \
+            + _gather_cols(mf, idx1)
+        v1 = _gather_cols(b_c, idx1)
+        v2 = _gather_cols(f_c, idx2)
+        t1 = _gather_cols(b_t, idx1)
+        t2 = _gather_cols(f_t, idx2)
         n = jnp.maximum(nv, 2.0)                      # math-safe; masked
         pres = (nv >= 2.0).astype(jnp.float32)
     else:
-        if gather:
-            idx1 = i1_ref[:].astype(jnp.int32)
-            idx2 = i2_ref[:].astype(jnp.int32)
-            if with_drops:
-                prev = jnp.concatenate([v[:, :1], v[:, :-1]], axis=1)
-                d = jnp.where(v < prev, prev + vbase_ref[:], 0.0)
-                col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-                d = jnp.where(col == 0, 0.0, d)
-                # v@o1 + d@l1 == (v + cumsum(d)) selected at first[w]
-                # (l1 is the <=first[w] step matrix); ditto last[w]
-                c = v + _cumsum_lanes(d)
-            else:
-                c = v
-            v1 = _gather_cols(c, idx1)                 # [BS, Wp]
-            v2 = _gather_cols(c, idx2)
+        idx1 = i1_ref[:].astype(jnp.int32)
+        idx2 = i2_ref[:].astype(jnp.int32)
+        if with_drops:
+            # the first column has no predecessor.  A reset adds the FULL
+            # previous RAW value = prev + vbase (rebased rows; ref:
+            # DoubleVector.scala:328); the corrections up to first[w] /
+            # last[w] are the row's prefix sum selected there
+            prev = jnp.concatenate([v[:, :1], v[:, :-1]], axis=1)
+            d = jnp.where(v < prev, prev + vbase_ref[:], 0.0)
+            col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+            d = jnp.where(col == 0, 0.0, d)
+            c = v + _cumsum_lanes(d)
         else:
-            v1 = mmv(v, o1_ref[:])                     # [BS, Wp]
-            v2 = mmv(v, o2_ref[:])
-            if with_drops:
-                prev = jnp.concatenate([v[:, :1], v[:, :-1]], axis=1)
-                # first column has no predecessor; padded tail columns
-                # are never selected by l1/l2 (first/last < T <= padded
-                # region).  A reset adds the FULL previous RAW value =
-                # prev + vbase (rebased rows; ref: DoubleVector.scala:328)
-                d = jnp.where(v < prev, prev + vbase_ref[:], 0.0)
-                col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-                d = jnp.where(col == 0, 0.0, d)
-                v1 = v1 + mmv(d, l1_ref[:])
-                v2 = v2 + mmv(d, l2_ref[:])
+            c = v
+        v1 = _gather_cols(c, idx1)                     # [BS, Wp]
+        v2 = _gather_cols(c, idx2)
         t1, t2 = t1_ref[:], t2_ref[:]                 # [1, Wp]
         n = n_ref[:]
     ws, we = ws_ref[:], we_ref[:]
@@ -617,12 +527,11 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
     if pres is not None:
         out = out * pres                              # no NaN into the MXU
 
-    _epilogue(mmg, gids_ref, out, pres, out_refs, num_groups,
-              per_series, mmb=mmb)
+    _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
 
 
-def _epilogue(mm, gids_ref, out, pres, out_refs, num_groups: int,
-              per_series: bool, mmb=None):
+def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
+              per_series: bool):
     """Shared epilogue.  Group mode: one-hot segment-sum on the MXU,
     accumulated across sequential grid steps (pad rows carry gid -1: no
     match); `pres` (ragged presence [BS, Wp]) feeds a second accumulated
@@ -644,7 +553,7 @@ def _epilogue(mm, gids_ref, out, pres, out_refs, num_groups: int,
     # 0/1 matrix and P dashboard panels ride ONE kernel dispatch
     for p in range(1, gids.shape[1]):
         onehot = onehot + (groups == gids[:, p][None, :]).astype(jnp.float32)
-    part = mm(onehot, out)                            # [Gp, Wp]
+    part = _dot_split3(onehot, out)                   # [Gp, Wp]
 
     @pl.when(pl.program_id(0) == 0)
     def _():
@@ -652,8 +561,7 @@ def _epilogue(mm, gids_ref, out, pres, out_refs, num_groups: int,
             r[:] = jnp.zeros_like(r)
     out_refs[0][:] += part
     if pres is not None:
-        # presence is 0/1 x 0/1: the binary matmul is exact in one pass
-        out_refs[1][:] += (mmb or mm)(onehot, pres)
+        out_refs[1][:] += _dot_1p(onehot, pres)
 
 
 def _run_shape_sig(vals_p, plan, Gp: int, kind: str, ragged: bool) -> str:
@@ -667,12 +575,11 @@ def _run_shape_sig(vals_p, plan, Gp: int, kind: str, ragged: bool) -> str:
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
-    "kind", "ragged", "per_series", "gather"))
+    "kind", "ragged", "per_series"))
 def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
          num_groups: int, is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
-         ragged: bool = False, per_series: bool = False,
-         gather: bool = False):
+         ragged: bool = False, per_series: bool = False):
     """One fused dispatch, whole: the group merge (merge_gid_cols), the
     plan's kernel operands (kernel_operands) and the Pallas call.  `gids`
     is a tuple of gid columns with `offsets` their traced int32 group-id
@@ -685,15 +592,13 @@ def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
     Gp = num_groups
     gids_p = merge_gid_cols(gids, offsets)
     o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = kernel_operands(
-        rows, tsrow, Tp, kind in OVER_TIME_FNS, gather)
+        rows, tsrow, Tp, kind)
     # adaptive series block: the ragged rate family's scan temporaries
     # scale with bs*Tp, so long rows shrink the block instead of OOMing
     # scoped vmem (or being rejected by the eligibility gate).  All
     # shapes here are static at trace time; Sp is padded to _BS, which
     # every smaller power-of-two block divides.
-    bs = pick_block(Tp, Wp, Gp, kind in OVER_TIME_FNS,
-                    ragged and kind == "rate_family",
-                    panels=gids_p.shape[1], gather=gather)
+    bs = pick_block(Tp, Wp, Gp, kind, ragged, panels=gids_p.shape[1])
     if bs is None:
         if interpret:
             bs = _MIN_BS            # no scoped-vmem limit off-chip
@@ -714,8 +619,7 @@ def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
     fix = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0), **space)  # noqa: E731
     kern = functools.partial(_kernel, num_groups=Gp, is_counter=is_counter,
                              is_rate=is_rate, with_drops=with_drops,
-                             kind=kind, ragged=ragged, per_series=per_series,
-                             gather=gather)
+                             kind=kind, ragged=ragged, per_series=per_series)
     with_counts = ragged                 # presence rides a second output
     if per_series:
         out_spec = pl.BlockSpec((bs, Wp), lambda i: (i, 0), **space)
@@ -725,8 +629,8 @@ def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
         out_shape = jax.ShapeDtypeStruct((Gp, Wp), jnp.float32)
     out_specs = [out_spec, out_spec] if with_counts else out_spec
     out_shapes = [out_shape, out_shape] if with_counts else out_shape
-    # selection-matrix specs follow the operands' actual shapes: gather
-    # mode gets tiny stand-ins for the unused o1/o2/l1/l2
+    # selection-matrix specs follow the operands' actual shapes: the
+    # gather kinds get tiny stand-ins for the unread o1/o2/l1/l2
     return pl.pallas_call(
         kern,
         grid=(grid,),
@@ -745,15 +649,14 @@ def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
 VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
 
 
-def vmem_estimate(Tp: int, Wp: int, Gp: int,
-                  over_time: bool = False,
-                  ragged_rate: bool = False, bs: int = _BS,
-                  panels: int = 1, gather: bool = False) -> int:
-    """Rough resident-bytes model for one grid step: the 4 selection
-    matrices (plus the over_time kinds' band temporary), the
-    double-buffered values block, the group one-hot + accumulator, and
-    [bs, Wp] f32 temporaries.  The ragged rate family's fill/prefix
-    scans keep ~19 [bs, Tp] temporaries live (calibrated against the
+def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
+                  ragged: bool = False, bs: int = _BS,
+                  panels: int = 1) -> int:
+    """Rough resident-bytes model for one grid step: the band kinds' 4
+    selection matrices and band temporary (the gather kinds ship 4 KB
+    stand-ins), the double-buffered values block, the group one-hot +
+    accumulator, and [bs, Wp] f32 temporaries.  The ragged rate family's
+    fill/prefix scans keep ~19 [bs, Tp] temporaries live (calibrated against the
     Mosaic scoped-vmem allocation report on a real v5e: 21.36 MiB at
     bs=256, Tp=768, Wp=128, Gp=1000 — the first on-chip ragged compile
     OOM'd scoped vmem where the old 8-temporary model predicted 13 MiB).
@@ -761,12 +664,10 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int,
     instead of failing at kernel lowering; _run shrinks its series block
     (pick_block) before giving up, so the gate must test the SMALLEST
     block, not _BS."""
-    # gather mode ships 4 KB dummies instead of the o1..l2 matrices
-    # (the over_time band kinds still need them — gather never applies)
-    sel = 4 * 8 * _LANE * 4 if gather else \
-        (5 if over_time else 4) * Tp * Wp * 4
+    sel = 4 * 8 * _LANE * 4 if _selects_by_gather(kind) else \
+        5 * Tp * Wp * 4
     vals = 2 * bs * Tp * 4
-    if ragged_rate:
+    if ragged and kind == "rate_family":
         # 19 was calibrated BEFORE _fill_scan2 halved the scan carries;
         # kept until the next on-chip window re-measures it (conservative
         # = smaller blocks than strictly needed, never an OOM)
@@ -780,9 +681,8 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int,
     return sel + vals + group + inter
 
 
-def pick_block(Tp: int, Wp: int, Gp: int, over_time: bool = False,
-               ragged_rate: bool = False, panels: int = 1,
-               gather: bool = False) -> Optional[int]:
+def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
+               ragged: bool = False, panels: int = 1) -> Optional[int]:
     """Largest series-block size whose vmem_estimate fits VMEM_BUDGET
     (None when even _MIN_BS doesn't — the caller must divert to the
     general path).  The ragged rate family's scan temporaries scale with
@@ -791,9 +691,8 @@ def pick_block(Tp: int, Wp: int, Gp: int, over_time: bool = False,
     falling off the fused path entirely."""
     bs = _BS
     while bs >= _MIN_BS:
-        if vmem_estimate(Tp, Wp, Gp, over_time, ragged_rate,
-                         bs=bs, panels=panels,
-                         gather=gather) <= VMEM_BUDGET:
+        if vmem_estimate(Tp, Wp, Gp, kind, ragged, bs=bs,
+                         panels=panels) <= VMEM_BUDGET:
             return bs
         bs //= 2
     return None
@@ -916,7 +815,6 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
                         interpret: bool = False,
                         prepared: Optional[PreparedInputs] = None,
                         ragged: bool = False,
-                        gather: Optional[bool] = None,
                         device=None
                         ) -> Tuple[jax.Array, np.ndarray]:
     """-> (sums [G, W] device array, counts [G, W] numpy).
@@ -947,8 +845,6 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
         # mode) — put the plan rows on the same chip
         device = _committed_device(prepared.vals_p)
     Gp = pad_group_count(num_groups)
-    if gather is None:
-        gather = gather_default(kind)
     from filodb_tpu.utils.devicetelem import watched_call
     rows, tsrow, _ = enqueue_operands(plan, device, kind, ragged)
     res = watched_call(
@@ -958,8 +854,7 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
                      (prepared.gids_p,), None, rows, tsrow,
                      num_groups=Gp, is_counter=is_counter,
                      is_rate=is_rate, with_drops=with_drops,
-                     interpret=interpret, kind=kind, ragged=ragged,
-                     gather=gather),
+                     interpret=interpret, kind=kind, ragged=ragged),
         device=device)
     if ragged:
         sums, cnts = res
@@ -1207,7 +1102,6 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
     kind = fn_name if over_time else "rate_family"
     wvalid = plan.wvalid1 if over_time else plan.wvalid
 
-    gather = gather_default(kind)
     # sharded DeviceMirror mode: the working set is committed to its
     # shard's chip — the plan rows go there too, so the call runs there
     device = _committed_device(values.vals_p)
@@ -1229,7 +1123,7 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
                              num_groups=Gp, is_counter=is_counter,
                              is_rate=is_rate, with_drops=with_drops,
                              interpret=interpret, kind=kind, ragged=ragged,
-                             per_series=per_series, gather=gather),
+                             per_series=per_series),
                 device=device)
         return res, rows
 

@@ -285,21 +285,35 @@ def device_put_packed(packed: PackedShards, mesh: Mesh) -> PackedShards:
                         if keep_host else None))
 
 
-# ------------------------------------------------------------ SPMD kernels
+# ------------------------------------------------ per-device fused dispatch
+#
+# The multi-chip fused scan.  The kernel is never traced under shard_map:
+# there it and its grid loop are re-traced and scheduled per mesh program
+# instead of dispatched as the single-chip binary, which measured ~30x
+# slower than the general path on an 8-device mesh (25.3 s against 0.88 s
+# warm; PERF.md section 7, "Before the chip benchmark").  Instead device
+# (s, t) runs the SINGLE-CHIP kernel over its committed [S, T] shard block
+# with time-slice t's plan, and only the [G, Wl] group partials cross
+# chips — one tiny psum collective on ICI, a host-side
+# ops/agg.reduce_phase merge otherwise.  That is exactly the reference's
+# 3-phase map/reduce/present contract (doc/query-engine.md :311-330) with
+# the map phase on-chip and the reduce over partials only.
 
 @functools.partial(jax.jit, static_argnames=(
-    "G", "S", "T", "Tp", "gather", "is_counter", "is_rate", "interpret",
-    "kind", "ragged"))
-def _pad_run_single(v, vb, g, plan_rows, *, G: int, S: int, T: int, Tp: int,
-                    gather: bool, is_counter: bool, is_rate: bool,
-                    interpret: bool, kind: str, ragged: bool):
-    """Pad ONE device's [S, T] values + [S, P] grouping (P > 1:
-    run_agg_batch panels over disjoint group-id ranges, multi-hot kernel
-    epilogue) to kernel tile shapes and run the single-chip kernel over
-    the plan's uploaded `plan_rows` = (rows, tsrow) — the shared
-    map-phase body of the per-device dispatch
-    (_device_fused_call) and the legacy fused-in-shard_map A/B probe
-    (_mesh_fused_call), so their padding semantics can never diverge.
+    "G", "S", "T", "Tp", "is_counter", "is_rate", "interpret", "kind",
+    "ragged"))
+def _device_fused_call(values, group_ids, vbase, rows, tsrow, *, G: int,
+                       S: int, T: int, Tp: int, is_counter: bool,
+                       is_rate: bool, interpret: bool,
+                       kind: str = "rate_family", ragged: bool = False):
+    """One device's share of the multi-chip fused scan: pad this device's
+    [1, S, T] values + [1, S, P] grouping (P > 1: run_agg_batch panels
+    over disjoint group-id ranges, multi-hot kernel epilogue) to kernel
+    tile shapes and run the single-chip Pallas kernel over the plan's
+    uploaded (rows, tsrow).  Every operand is committed to the owning
+    device, so the jit executes THERE (device-pinned dispatch) and only
+    the [G, Wlp] group partials leave the chip.  The leading shard axis
+    is kept so the pack's addressable shards feed straight in.
 
     Dense packs: NaN cells are exactly pad rows / beyond-count columns,
     zeroed they contribute nothing (pack pad rows carry gid 0 but add +0
@@ -310,98 +324,18 @@ def _pad_run_single(v, vb, g, plan_rows, *, G: int, S: int, T: int, Tp: int,
     from filodb_tpu.ops import pallas_fused as pf
     Gp = pf.pad_group_count(G)
     Sp = pf.pad_series_count(S)
-    v = v.astype(jnp.float32)
+    v = values[0].astype(jnp.float32)
     if ragged:
         v = jnp.pad(v, ((0, Sp - S), (0, Tp - T)), constant_values=np.nan)
     else:
         v = jnp.pad(jnp.nan_to_num(v), ((0, Sp - S), (0, Tp - T)))
-    vb = jnp.pad(vb.astype(jnp.float32), (0, Sp - S))[:, None]
-    g = jnp.pad(g.astype(jnp.int32), ((0, Sp - S), (0, 0)),
+    vb = jnp.pad(vbase[0].astype(jnp.float32), (0, Sp - S))[:, None]
+    g = jnp.pad(group_ids[0].astype(jnp.int32), ((0, Sp - S), (0, 0)),
                 constant_values=-1)
-    return pf.run_kernel(v, vb, g, *plan_rows, gather=gather, num_groups=Gp,
-                         is_counter=is_counter, is_rate=is_rate,
-                         with_drops=False, interpret=interpret, kind=kind,
-                         ragged=ragged)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "G", "S", "T", "Tp", "is_counter", "is_rate", "interpret",
-    "kind", "ragged"))
-def _mesh_fused_call(mesh: Mesh, values, group_ids, vbase, rows, tsrow, *,
-                     G: int, S: int, T: int, Tp: int,
-                     is_counter: bool, is_rate: bool, interpret: bool,
-                     kind: str = "rate_family", ragged: bool = False):
-    """LEGACY A/B path: the Pallas fused kernel traced INSIDE shard_map.
-
-    Kept only for measurement tooling (the driver dryrun, bench.py
-    multichip's inversion probe): on a multi-device
-    mesh this composition collapses ~30x vs the general path
-    (MULTICHIP_r05.json) because the kernel re-traces and schedules per
-    mesh program.  Production queries route through the per-device
-    dispatch below (_device_fused_call + merge_device_partials), which
-    never puts the kernel under shard_map; see doc/multichip.md."""
-    from filodb_tpu.ops import pallas_fused as pf
-    gather = pf.gather_default(kind)
-
-    def step(val_blk, gid_blk, vb_blk, rows_blk, ts_blk):
-        res = _pad_run_single(val_blk[0], vb_blk[0], gid_blk[0],
-                              (rows_blk[0], ts_blk[0]), G=G, S=S,
-                              T=T, Tp=Tp, gather=gather,
-                              is_counter=is_counter, is_rate=is_rate,
-                              interpret=interpret, kind=kind,
-                              ragged=ragged)
-        if ragged:
-            sums, cnts = res
-            return (jax.lax.psum(sums[:G], "shard"),
-                    jax.lax.psum(cnts[:G], "shard"))
-        return jax.lax.psum(res[:G], "shard")          # [G, Wlp]
-
-    return shard_map(
-        step, mesh=mesh,
-        in_specs=(P("shard", None, None), P("shard", None, None),
-                  P("shard", None)) + (P("time", None, None),) * 2,
-        out_specs=((P(None, "time"), P(None, "time")) if ragged
-                   else P(None, "time")),
-        # pallas_call's out_shape carries no varying-mesh-axes info, which
-        # trips shard_map's vma checker; the psum makes the output
-        # replicated over 'shard' by construction
-        check_vma=False)(values, group_ids, vbase, rows, tsrow)
-
-
-# ------------------------------------------------ per-device fused dispatch
-#
-# The multi-chip fused scan.  Tracing the Pallas kernel INSIDE shard_map
-# (the _mesh_fused_call path above, kept for A/B tooling) inverted the
-# kernel's single-chip win ~30x on an 8-device mesh (MULTICHIP_r05.json:
-# warm 25.3 s fused vs 0.88 s general): the kernel + its grid loop were
-# re-traced and scheduled per mesh program instead of dispatched as the
-# single-chip binary.  The production path below never puts the kernel
-# under shard_map: device (s, t) runs the SINGLE-CHIP kernel over its
-# committed [S, T] shard block with time-slice t's plan, and only the
-# [G, Wl] group partials cross chips — one tiny psum collective on ICI,
-# a host-side ops/agg.reduce_phase merge otherwise.  That is exactly the
-# reference's 3-phase map/reduce/present contract (doc/query-engine.md
-# :311-330) with the map phase on-chip and the reduce over partials only.
-
-@functools.partial(jax.jit, static_argnames=(
-    "G", "S", "T", "Tp", "is_counter", "is_rate", "interpret", "kind",
-    "ragged"))
-def _device_fused_call(values, group_ids, vbase, rows, tsrow, *, G: int,
-                       S: int, T: int, Tp: int, is_counter: bool,
-                       is_rate: bool, interpret: bool,
-                       kind: str = "rate_family", ragged: bool = False):
-    """One device's share of the multi-chip fused scan: the single-chip
-    Pallas kernel over this device's [1, S, T] shard block.  Every
-    operand is committed to the owning device, so the jit executes THERE
-    (device-pinned dispatch — never inside shard_map) and only the
-    [G, Wlp] group partials leave the chip.  The leading shard axis is
-    kept so the pack's addressable shards feed straight in."""
-    from filodb_tpu.ops import pallas_fused as pf
-    res = _pad_run_single(values[0], vbase[0], group_ids[0], (rows, tsrow),
-                          G=G, S=S, T=T, Tp=Tp,
-                          gather=pf.gather_default(kind),
-                          is_counter=is_counter, is_rate=is_rate,
-                          interpret=interpret, kind=kind, ragged=ragged)
+    res = pf.run_kernel(v, vb, g, rows, tsrow, num_groups=Gp,
+                        is_counter=is_counter, is_rate=is_rate,
+                        with_drops=False, interpret=interpret, kind=kind,
+                        ragged=ragged)
     if ragged:
         return res[0][:G], res[1][:G]
     return res[:G]
@@ -1105,8 +1039,8 @@ class MeshExecutor:
         its committed shard block with time-slice t's selection-matrix
         plan, and only the [G] group partials merge across chips
         (merge_device_partials — psum collective on ICI, host reduce
-        otherwise).  The kernel is NEVER traced inside shard_map: that
-        composition inverted the single-chip win ~30x (MULTICHIP_r05).
+        otherwise).  The kernel is never traced inside shard_map (see
+        the section comment above _device_fused_call).
         One HBM pass per device instead of the general path's several.
         NaN-holed (ragged) packs run the kernel's valid-boundary variant
         with per-cell presence merged as a second partial (r4).  On a
@@ -1159,12 +1093,8 @@ class MeshExecutor:
                 Gtot += kpanels[i][1]
             # padded group count, matching _run's recomputation exactly
             kind_k = fn_name if over_time else "rate_family"
-            if pf.pick_block(
-                    Tp, Wlp, pf.pad_group_count(Gtot),
-                    over_time,
-                    ragged and fn_name in ("rate", "increase", "delta"),
-                    panels=max(len(kidx), 1),
-                    gather=pf.gather_default(kind_k)) is None:
+            if pf.pick_block(Tp, Wlp, pf.pad_group_count(Gtot), kind_k,
+                             ragged, panels=max(len(kidx), 1)) is None:
                 return None
             interpret = pf.kernel_mode()
             if interpret is None:
@@ -1246,8 +1176,7 @@ class MeshExecutor:
             # plan — all D*n_time dispatches are issued before any
             # result is touched, so the chips compute concurrently; only
             # the [Gtot, Wlp] partials then merge (collective on ICI,
-            # host reduce otherwise).  The kernel never traces inside
-            # shard_map (the MULTICHIP_r05 30x inversion).
+            # host reduce otherwise).
             is_counter = fn_name in ("rate", "increase")
             vblocks = {s.device: s.data
                        for s in packed.values.addressable_shards}

@@ -42,7 +42,8 @@ class ReplicatedCluster:
     the real cross-node transport, replication doors on the real framed
     protocol, ingest fanned out by a ReplicationManager (distributor
     mode), queries planned through ReplicaFailoverDispatchers.  The
-    shared fixture of the replication tests AND `bench.py replication`."""
+    shared fixture of the replication tests AND `python -m bench.drills
+    replication`."""
     dataset: str
     engine: QueryEngine
     mapper: ShardMapper
@@ -169,8 +170,8 @@ def make_fanout_cluster(batches: Iterable = (), num_shards: int = 4,
     """N node processes (in-process servers), shards round-split across
     them, coordinator holding NO data with remote dispatchers — the
     multi-JVM IngestionAndRecoverySpec shape generalized for the
-    distributed-execution fan-out bench (`bench.py distexec` drives a
-    4-node shape through exactly this wiring)."""
+    distributed-execution fan-out tests (tests/test_distexec.py drives
+    a 4-node shape through exactly this wiring)."""
     nodes = list(nodes)
     mapper = ShardMapper(num_shards)
     spread = SpreadProvider(default_spread=default_spread)
@@ -207,8 +208,8 @@ class ColdReadCluster:
     (persist/objectstore.py): the data node nominally owns every shard,
     query-only nodes own NOTHING — all of them serve cold leaves from
     the shared tier, walked round-robin by the cold dispatcher.  The
-    shared fixture of the query-only-node tests AND the `bench.py
-    objectstore` elastic-read gate."""
+    shared fixture of the query-only-node tests AND the `python -m
+    bench.drills objectstore` elastic-read gate."""
     dataset: str
     engine: QueryEngine
     mapper: ShardMapper
@@ -282,7 +283,7 @@ def make_cold_read_cluster(object_store, num_shards: int = 4,
 class FederatedPair:
     """Two FULL FiloServer clusters federated over their doors, plus a
     single-store ground truth holding every series — the shared fixture
-    of tests/test_federation.py AND `bench.py federation`.
+    of tests/test_federation.py AND `python -m bench.drills federation`.
 
     `east` owns region="east" series and is the coordinator the tests
     query; `west` owns region="west".  Each cluster's config declares

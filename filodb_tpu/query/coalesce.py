@@ -7,8 +7,8 @@ coalesces concurrent `query_range` calls over the same window grid into
 one `engine.query_range_batch` — merged kernel dispatches for clients
 that know nothing about batching.  The trade is explicit: a request may
 wait up to `window_s` for peers to arrive, in exchange for the panels
-sharing one dispatch (measured 4.7-5.5x for 8 panels,
-TPU_BATCH_r04.json / bench.py dashboard_batch).
+sharing one dispatch (measured 4.7-5.5x for 8 panels in rounds 4 and 5;
+PERF.md section 7, "Before the chip benchmark").
 
 No reference analogue — the iterator engine has nothing to amortize;
 this is the TPU-shaped server feature enabled by

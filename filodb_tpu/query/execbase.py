@@ -49,7 +49,7 @@ class LazyKeys:
     pids may no longer name the snapshot's series — fall back to
     resolving each pid defensively (keys_for already yields a sentinel
     key for pruned slots) and count the event so the race is observable
-    instead of silent (ADVICE r5)."""
+    instead of silent (round-5 review)."""
     __slots__ = ("_shard", "_pids", "_keys", "_epoch")
 
     def __init__(self, shard, pids):
@@ -145,7 +145,7 @@ def _fused_vals_budget() -> int:
     """Byte budget for the padded-values cache.  Configurable via
     FILODB_TPU_FUSED_CACHE_BYTES; otherwise derived from the device's
     reported HBM minus the live mirror budget so mirror + this cache +
-    headroom cannot exceed the chip (ADVICE r2: the old fixed 4 GiB
+    headroom cannot exceed the chip (round-2 review: the old fixed 4 GiB
     ignored the mirror's budget).  Resolved lazily — the backend is
     already initialized by the time the first fused query inserts."""
     global _FUSED_VALS_CACHE_BYTES

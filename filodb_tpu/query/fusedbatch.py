@@ -109,20 +109,16 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
             if len(idxs) > 1:
                 Tp = fc0.plan.Tp
                 Wp = pf._pad_to(max(fc0.plan.W, 1), pf._LANE)
-                over_time = fc0.fn in pf.OVER_TIME_FNS
-                ragged_rate = fc0.ragged and fc0.fn in ("rate", "increase",
-                                                        "delta")
-                kind = fc0.fn if over_time else "rate_family"
-                gmode = pf.gather_default(kind)
+                kind = (fc0.fn if fc0.fn in pf.OVER_TIME_FNS
+                        else "rate_family")
                 while len(take) > 1:
                     n_group = sum(1 for i in take if in_group_mode(i))
                     total = sum(slots(i) for i in take
                                 if in_group_mode(i))
                     if total == 0 or pf.pick_block(
-                            Tp, Wp, pf.pad_group_count(total),
-                            over_time, ragged_rate,
-                            panels=max(n_group, 1),
-                            gather=gmode) is not None:
+                            Tp, Wp, pf.pad_group_count(total), kind,
+                            fc0.ragged,
+                            panels=max(n_group, 1)) is not None:
                         break
                     take = take[:max(1, len(take) // 2)]
             panels = [(calls[i].groups, slots(i), calls[i].op)

@@ -245,12 +245,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # not fail at kernel lowering
         Tp = pf._pad_to(vals.shape[1], pf._LANE)
         Wp = pf._pad_to(eval_wends.size, pf._LANE)
-        over_time = t0.function in pf.OVER_TIME_FNS
-        ragged_rate = not dense and fn in ("rate", "increase", "delta")
         kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
-        gather = pf.gather_default(kind)
-        if pf.pick_block(Tp, Wp, 8, over_time, ragged_rate,
-                         gather=gather) is None:
+        if pf.pick_block(Tp, Wp, 8, kind, not dense) is None:
             return None
         from filodb_tpu.utils.metrics import registry
         # plan + prepared-input caches: a repeat query over an unchanged
@@ -290,8 +286,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # BEFORE the padded device copy, so diverted queries cost nothing
         # same padded group count _run will use — a gate tested on the
         # unpadded count could accept a shape _run then rejects
-        if pf.pick_block(Tp, Wp, pf.pad_group_count(num_slots),
-                         over_time, ragged_rate, gather=gather) is None:
+        if pf.pick_block(Tp, Wp, pf.pad_group_count(num_slots), kind,
+                         not dense) is None:
             return None
         if padded_vals is None:
             vbase = data.vbase
@@ -787,8 +783,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         # full O(S*T) re-upload must not run on THIS query's
                         # critical path — rebuild in the background and serve
                         # this query via the host windowed gather below
-                        # (eviction-proof serving; SOAK_LONG_r05's 752 s p99
-                        # was one query paying this inline)
+                        # (eviction-proof serving; the round-5 soak's 752 s p99
+                        # was one query paying this inline: PERF.md section 7)
                         mirror.request_background_refresh(shard, store)
                         from filodb_tpu.utils.metrics import registry as _reg
                         _reg.counter(

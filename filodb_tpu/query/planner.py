@@ -200,7 +200,7 @@ class SingleClusterPlanner(QueryPlanner):
             # so aggregation_pushdown=false restores exactly today's
             # path).  The one exception is the bench-only ship_raw_series
             # strawman, which forces remote leaves to reply with FULL
-            # per-series blocks so bench.py distexec can measure the
+            # per-series blocks so tests/test_distexec.py can measure the
             # ship-everything wire cost; the map then runs on the
             # coordinator (ReduceAggregateExec.compose).  Local children
             # always map in place — there is no wire to win by hoisting.

@@ -342,7 +342,7 @@ class PlannerParams:
         default=None, repr=False)
     # benchmark-only strawman: suppress the leaf-side map phase so
     # remote children ship FULL per-series blocks (the "ship everything"
-    # baseline bench.py distexec measures wire bytes against).  Off
+    # baseline tests/test_distexec.py measures wire bytes against).  Off
     # (False) is the only supported production value — pushdown=False
     # already restores the per-shard dispatch where every shard still
     # replies with its [G, W] map partial.

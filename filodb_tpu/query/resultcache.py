@@ -5,8 +5,8 @@ queryrange/results_cache.go, thanos-io queryfrontend — PAPERS.md survey
 of serving stacks), adapted to this store's consistency machinery: a
 dashboard re-poll of `query_range` recomputes only the windows the
 append horizon hasn't frozen yet and merges them with the cached prefix,
-instead of rescanning the full range.  BENCH_r05 shows the per-query
-floor (~75 ms) is flat from 8k to 1M series — so for a 30-window re-poll
+instead of rescanning the full range.  Round 5 measured a per-query
+floor (~75 ms) that is flat from 8k to 1M series — so for a 30-window re-poll
 where 28 windows are cache-final, this turns 30 windows of work into 2.
 
 Soundness model (why a cached window can be reused at all):

@@ -586,7 +586,7 @@ def _merge_relabeled(keys, data, fn_name: str):
         # presence is NaN-only (the staleness convention everywhere else:
         # nonleaf dedup, absent()): +/-Inf is a legal sample value (1/0,
         # histogram_quantile overflow) and must collide/merge like any
-        # other sample, not vanish (ADVICE r5, medium)
+        # other sample, not vanish (round-5 review)
         present = ~np.isnan(sub)
         if sub.ndim == 3:
             present = present.any(axis=-1)

@@ -29,8 +29,7 @@ lands in the plain metrics registry — the `_self_` self-scrape, so ruler
 alerts fire on HBM pressure without extra plumbing.
 
 Overhead stance: `record_dispatch` is a dict update + deque append + two
-counter increments per KERNEL dispatch (not per series), bounded by the
-bench gate `bench.py devicetelem` (≤2% on concurrent QPS).  The
+counter increments per KERNEL dispatch (not per series).  The
 `set_enabled(False)` kill switch skips ledger/metrics/span work but
 NEVER the exec-tally feed — stats correctness is not optional.
 """
@@ -46,8 +45,8 @@ from filodb_tpu.utils.metrics import (current_trace_id, log_error_once,
                                       note_device_call, record_child_event,
                                       registry)
 
-# process-wide kill switch (bench.py devicetelem stage measures the
-# ledger's own overhead by toggling this off).  The exec-tally feed in
+# process-wide kill switch (for measuring the ledger's own overhead by
+# toggling it off; only tests flip it now: ROADMAP C10).  The exec-tally feed in
 # record_dispatch is NOT affected — only ring/metrics/span work.
 TELEM_ENABLED = True
 

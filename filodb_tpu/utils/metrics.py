@@ -426,8 +426,8 @@ def note_mirror_refresh(kind: str) -> None:
 
 _active = threading.local()
 
-# process-wide span kill switch (bench.py observability stage: measures
-# the span pipeline's own overhead by toggling this off).  Stats tallies
+# process-wide span kill switch (for measuring the span pipeline's own
+# overhead by toggling it off; only tests flip it now: ROADMAP C10).  Stats tallies
 # are NOT affected — only counter/histogram/trace work is skipped.
 SPANS_ENABLED = True
 

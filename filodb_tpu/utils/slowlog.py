@@ -20,7 +20,7 @@ aggregate histograms.
 
 This is the MySQL-slow-log / Monarch-query-annal shape: when the p99
 spikes, the operator reads the actual offending operations with their
-breakdown.  SOAK_LONG_r05's 752 s eviction-window query is exactly the
+breakdown.  The round-5 soak's 752 s eviction-window query (PERF.md section 7) is exactly the
 record the query ring would have captured.
 """
 from __future__ import annotations

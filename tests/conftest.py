@@ -48,7 +48,7 @@ def pytest_configure(config):
         "markers", "chaos: fault-injection / node-kill chaos tests "
         "(subprocess clusters, SIGKILL, wall-clock waits). Implies slow, "
         "so tier-1's -m 'not slow' excludes them; run explicitly with "
-        "-m chaos or via `python bench.py chaos`.")
+        "-m chaos or via `python -m bench.drills chaos`.")
     config.addinivalue_line(
         "markers", "multichip: multi-device equivalence tests (per-device "
         "fused dispatch, sharded DeviceMirror, partial merges). Auto-skip "
@@ -59,7 +59,7 @@ def pytest_configure(config):
         "store clusters under live ingest+query traffic, handoff drills, "
         "wall-clock waits). Implies slow, so tier-1's -m 'not slow' "
         "excludes them; run explicitly with -m replication or via "
-        "`python bench.py replication`.")
+        "`python -m bench.drills replication`.")
 
 
 def pytest_collection_modifyitems(config, items):

@@ -69,11 +69,10 @@ def _sds(shape, dtype, sharding):
 
 def _compile_run(one_chip, S, G, fn, ragged=False, panels=1):
     """Lower + compile pallas_fused._run exactly as fused_leaf_agg_batch
-    calls it (interpret=False, gather as gather_default picks it)."""
+    calls it (interpret=False)."""
     plan = _plan()
     over_time = fn in pf.OVER_TIME_FNS
     kind = fn if over_time else "rate_family"
-    gather = pf.gather_default(kind)
     Sp, Gp = pf.pad_series_count(S), pf.pad_group_count(G * panels)
     with_ts = ragged and kind == "rate_family"
     args = [_sds((Sp, plan.Tp), jnp.float32, one_chip),
@@ -86,8 +85,8 @@ def _compile_run(one_chip, S, G, fn, ragged=False, panels=1):
     is_counter = fn in ("rate", "increase")
     return pf._run.lower(
         *args, num_groups=Gp, is_counter=is_counter, is_rate=fn == "rate",
-        with_drops=False, interpret=False, kind=kind, ragged=ragged,
-        gather=gather).compile()
+        with_drops=False, interpret=False, kind=kind,
+        ragged=ragged).compile()
 
 
 def _check(compiled, pallas: bool):

@@ -90,7 +90,7 @@ def test_env_layer_overrides_file(tmp_path):
 def test_env_durations_and_foreign_vars():
     s = FilodbSettings.load(None, env={
         "FILODB_STORE_FLUSH_INTERVAL_MS": "30 minutes",
-        "FILODB_BENCH_TPU_TIMEOUT": "600",    # sibling tool's var: ignored
+        "FILODB_KAFKA_IT": "1",               # sibling tool's var: ignored
         "FILODB_TPU_CONFIG": "/nonexistent",  # the pointer itself: ignored
     })
     assert s.store.flush_interval_ms == 30 * 60 * 1000

@@ -1,6 +1,6 @@
 """Fault-injection layer (utils/faults.py): arming, determinism, seeded
 plans, and the production fault points actually firing where they claim
-to.  The chaos bench (`python bench.py chaos`) is the macro counterpart;
+to.  The chaos drill (`python -m bench.drills chaos`) is the macro counterpart;
 these are the fast deterministic guarantees the tier-1 gate holds."""
 import json
 import socket

@@ -322,7 +322,7 @@ def test_percentile_interpolates_and_estimates_overflow():
     h = Histogram(bounds=(1.0, 10.0))
     for _ in range(99):
         h.record(5.0)
-    h.record(752.0)                      # the SOAK_LONG_r05 outlier shape
+    h.record(752.0)                      # the round-5 soak's outlier shape
     # p50 interpolated inside (1, 10], not snapped to 10
     assert 1.0 < h.percentile(0.5) < 10.0
     # p100 reaches toward the observed max instead of capping at 10

@@ -5,6 +5,9 @@ driver bench exercises it on the real chip).  The XLA path
 (evaluate_range_function + agg.aggregate) is oracle-verified elsewhere
 (tests/test_rangefns.py, test_query_engine.py), so agreement here chains
 the conformance."""
+import functools
+import os
+
 import numpy as np
 import pytest
 
@@ -364,7 +367,7 @@ def test_fused_minmax_right_edge_padding():
 def test_fused_large_ts_offset_precision():
     """ts offsets near the 2^30 guard (f32 ulp there is 64 ms): the
     extrapolation thresholds must stay within tolerance of the f64 oracle
-    (ADVICE r2 — previously only ~2.4e6 ms offsets were exercised)."""
+    (round-2 review — previously only ~2.4e6 ms offsets were exercised)."""
     import sys
     sys.path.insert(0, "tests")
     from oracle import eval_series
@@ -558,18 +561,17 @@ def test_pick_block_adaptive():
     said 13M — the calibrated model must divert THAT shape to a smaller
     block and keep the dense kernel at the full block."""
     from filodb_tpu.ops import pallas_fused as pf
-    if pf._BS != 256:
-        pytest.skip("FILODB_FUSED_BS overrides the block this test models")
-    assert pf.pick_block(768, 128, 1000, False, False) == pf._BS
-    bs = pf.pick_block(768, 128, 1000, False, True)
+    assert pf._BS == 256
+    assert pf.pick_block(768, 128, 1000, "rate_family", False) == pf._BS
+    bs = pf.pick_block(768, 128, 1000, "rate_family", True)
     assert bs is not None and bs < pf._BS
-    assert pf.vmem_estimate(768, 128, 1000, False, True,
+    assert pf.vmem_estimate(768, 128, 1000, "rate_family", True,
                             bs=bs) <= pf.VMEM_BUDGET
     # the calibrated model rejects the shape that actually OOM'd on chip
-    assert pf.vmem_estimate(768, 128, 1000, False, True,
+    assert pf.vmem_estimate(768, 128, 1000, "rate_family", True,
                             bs=256) > pf.VMEM_BUDGET
     # tiny shapes keep the full block (interpret-mode tests stay fast)
-    assert pf.pick_block(256, 128, 8, False, True) == pf._BS
+    assert pf.pick_block(256, 128, 8, "rate_family", True) == pf._BS
 
 
 def test_fused_ragged_rate_long_rows():
@@ -605,15 +607,16 @@ def test_fused_ragged_rate_long_rows():
                                equal_nan=True)
 
 
-@pytest.mark.parametrize("mode", ["split", "episplit"])
+@pytest.mark.parametrize("mode", ["episplit"])
 def test_split_precision_matches_highest_interpret(monkeypatch, mode):
-    """The FILODB_FUSED_PRECISION=split/episplit decompositions
-    (ops/pallas_fused._matmuls) must produce the same results as the
-    all-HIGHEST default — in interpret mode, so a future edit that
-    breaks the mmv/mmg operand-order convention (or _split3 itself)
-    fails here instead of only as wrong numbers in the next on-chip
-    sweep.  jit caches don't key on the module-level knob, so they are
-    cleared around each flip."""
+    """The group epilogue's three-pass decomposition
+    (ops/pallas_fused._dot_split3) must produce the same results as an
+    all-HIGHEST epilogue — in interpret mode, so a future edit that
+    breaks its operand-order convention (or _split3 itself) fails here
+    instead of only as wrong numbers on the chip.  The reference is the
+    same kernel with the epilogue's matmul swapped for _dot_hi; jit
+    caches don't key on a module attribute, so they are cleared around
+    the swap."""
     import jax
     from filodb_tpu.ops import pallas_fused as pf
     ts_row, raw, gids = _mk(S=48, T=96, G=4)
@@ -636,11 +639,11 @@ def test_split_precision_matches_highest_interpret(monkeypatch, mode):
             out.append(present_sum(sums, counts))
         return out
 
-    monkeypatch.setattr(pf, "_PRECISION", "highest")
+    monkeypatch.setattr(pf, "_dot_split3", pf._dot_hi)
     jax.clear_caches()
     try:
         base = run_all()
-        monkeypatch.setattr(pf, "_PRECISION", mode)
+        monkeypatch.undo()
         jax.clear_caches()
         split = run_all()
     finally:
@@ -650,6 +653,57 @@ def test_split_precision_matches_highest_interpret(monkeypatch, mode):
         assert (np.isnan(b) == np.isnan(s)).all()
         np.testing.assert_allclose(s, b, rtol=1e-5, atol=1e-6,
                                    equal_nan=True)
+
+
+# ------------- one kernel strategy, chosen by nothing outside the kernel
+
+_JAXPR_PROBE = """
+import numpy as np, jax
+from filodb_tpu.ops import pallas_fused as pf
+S, T, G, step = 40, 96, 4, 10_000
+ts_row = np.arange(T, dtype=np.int64) * step
+plan = pf.build_plan(ts_row, np.arange(40, 91, 6, dtype=np.int64) * step,
+                     30 * step)
+gids = (np.arange(S) % G).astype(np.int32)
+for fn in ("rate", "sum_over_time"):
+    print(jax.make_jaxpr(lambda v: pf.fused_rate_groupsum(
+        v, np.zeros(S, np.float32), gids, plan, G, fn, precorrected=True,
+        interpret=True)[0])(np.zeros((S, T), np.float32)))
+"""
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_jaxprs(name=None, value=None):
+    """The jaxprs of one small interpret-mode `rate` and `sum_over_time`
+    dispatch (padding, kernel operands and kernel body included), traced
+    in a fresh process whose environment holds `name=value` before the
+    import."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("FILODB_FUSED_")}
+    env.update(PYTHONPATH=repo, JAX_PLATFORMS="cpu")
+    if name:
+        env[name] = value
+    p = subprocess.run([sys.executable, "-c", _JAXPR_PROBE], cwd=repo,
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return p.stdout
+
+
+@pytest.mark.parametrize("name,value", [
+    ("FILODB_FUSED_GATHER", "0"),
+    ("FILODB_FUSED_PRECISION", "split"),
+    ("FILODB_FUSED_BS", "64"),
+])
+def test_no_kernel_strategy_comes_from_the_environment(name, value):
+    """The selection strategy, the matmul precision and the series block
+    were once read from these variables at import; the program a
+    dispatch traces must be the same whether or not they are set."""
+    base = _fused_jaxprs()
+    assert "pallas_call" in base
+    assert _fused_jaxprs(name, value) == base
 
 
 # ------------- one fused dispatch = one jit call (ISSUE 28, ROADMAP A1)
@@ -691,8 +745,8 @@ def _host_selection_matrices(ts_row, wends, range_ms):
 ], ids=["mid", "empty-and-padded", "past-right-edge", "two-window-tiles"])
 def test_kernel_operands_equal_host_selection_matrices(T, wends, range_ms):
     """The matrices `_run` builds on the device from idx1 / idx2 / n1 are
-    the 0/1 f32 arrays the host used to upload, bit for bit; gather mode
-    builds none; `n` and `tsrow` resolve as the host resolved them."""
+    the 0/1 f32 arrays the host used to upload, bit for bit; the gather
+    kinds build none; `n` and `tsrow` resolve as the host resolved them."""
     import jax
     from filodb_tpu.ops import pallas_fused as pf
     ts_row = np.arange(T, dtype=np.int64) * START_STEP
@@ -705,8 +759,8 @@ def test_kernel_operands_equal_host_selection_matrices(T, wends, range_ms):
         np.testing.assert_array_equal(getattr(plan, f)[0], plan.rows[i])
     want = _host_selection_matrices(ts_row, wends, range_ms)
     assert (plan.n1[0] == 0).any()                   # padded windows
-    build = jax.jit(pf.kernel_operands, static_argnums=(2, 3, 4))
-    ops = build(plan.rows, plan.tsrow, plan.Tp, True, False)
+    build = jax.jit(pf.kernel_operands, static_argnums=(2, 3))
+    ops = build(plan.rows, plan.tsrow, plan.Tp, "sum_over_time")
     for got, ref in zip(ops[:4], want):
         got = np.asarray(got)
         assert got.dtype == np.float32 and got.shape == ref.shape
@@ -716,7 +770,7 @@ def test_kernel_operands_equal_host_selection_matrices(T, wends, range_ms):
     for got, f in zip(ops[4:6] + ops[7:9] + ops[10:],
                       ("t1", "t2", "wstart_x", "wend_x", "idx1", "idx2")):
         np.testing.assert_array_equal(np.asarray(got), getattr(plan, f))
-    ops = build(plan.rows, None, plan.Tp, False, True)
+    ops = build(plan.rows, None, plan.Tp, "rate_family")
     assert all(o.shape == (8, 128) and not np.asarray(o).any()
                for o in ops[:4])
     np.testing.assert_array_equal(np.asarray(ops[6]), plan.n)

@@ -2,7 +2,7 @@
 per-peer circuit breakers, end-to-end deadlines, and the
 never-cache-partials contract.  In-process "kills" (NodeQueryServer.stop
 -> connection refused) give the same socket-level failure signature as a
-SIGKILL without subprocess cost; the chaos bench (`python bench.py
+SIGKILL without subprocess cost; the chaos drill (`python -m bench.drills
 chaos`) covers the real-SIGKILL macro run."""
 import socket
 import threading
@@ -518,7 +518,7 @@ def test_chaos_sigkill_gates():
     (the surviving owner held it; WAL-segment catch-up repairs the
     respawn), and no result ever claims to be full while missing a
     shard's group.  Excluded from tier-1 (chaos implies slow); also
-    runnable standalone: `python bench.py chaos`."""
+    runnable standalone: `python -m bench.drills chaos`."""
     import json as _json
     import os
     import subprocess
@@ -526,8 +526,7 @@ def test_chaos_sigkill_gates():
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "chaos",
-         "--quick"],
+        [sys.executable, "-m", "bench.drills", "chaos", "--quick"],
         capture_output=True, text=True, timeout=600, cwd=repo)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.strip().splitlines()
@@ -542,6 +541,24 @@ def test_chaos_sigkill_gates():
     # the respawned node was repaired through WAL-segment catch-up and
     # full results kept flowing
     assert r["chaos_recovered_full_results"] > 0, r
+
+
+def test_drills_entry_lists_four_drills():
+    """`python -m bench.drills` is the one entry of the correctness
+    drills, and its help names exactly the four it runs."""
+    import os
+    import re
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-m", "bench.drills", "--help"],
+        capture_output=True, text=True, timeout=120, cwd=repo)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    listed = re.search(r"\{([a-z,]+)\}", proc.stdout).group(1).split(",")
+    assert sorted(listed) == ["chaos", "federation", "objectstore",
+                              "replication"]
 
 
 def test_result_cache_partial_tail_drops_entry_and_reruns():

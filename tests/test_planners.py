@@ -549,7 +549,7 @@ def test_ha_planner_routes_pinned_failures_remote():
 
 def test_multi_partition_pinned_spanning_partitions_errors():
     """A pinned (@) read whose data range spans partitions must raise,
-    not silently evaluate locally with partial data (ADVICE r2)."""
+    not silently evaluate locally with partial data (round-2 review)."""
     local = _RecordingPlanner("local")
     start_ms = START_S * 1000
     mid = start_ms + 1800_000

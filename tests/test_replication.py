@@ -5,7 +5,7 @@ state machine, health/admin surfaces.
 
 Fast in-process tests run in tier-1; traffic-under-chaos drills carry
 the `replication` marker (implies slow) and run via -m replication or
-`python bench.py replication`.
+`python -m bench.drills replication`.
 """
 import json
 import os
