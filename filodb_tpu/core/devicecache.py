@@ -13,6 +13,11 @@ same cost the uncached path paid per query, so live-ingest workloads are
 never worse off).  Timestamp offsets are rebased once to the mirror's
 base, so every query shares the cached int32 offset matrix regardless of
 its own chunk-scan window.
+
+The gather is by need (MirrorGather): a leaf gets a handle that knows every
+array's shape from the snapshot and its row count, and an array's rows are
+taken out of the mirror when somebody first reads that array.  A fused leaf
+whose padded values are cached reads none, so it launches no take.
 """
 from __future__ import annotations
 
@@ -31,7 +36,7 @@ from filodb_tpu.ops.timewindow import PAD_TS
 class _MirrorSnapshot:
     """One immutable upload generation.  _refresh builds a complete snapshot
     and publishes it with a single attribute assignment, so a lock-free
-    gather_cached racing a refresh sees either the old snapshot or the new
+    gather_cached racing a refresh pins either the old snapshot or the new
     one in full — never a half-replaced mix of fields."""
     gen: int
     base_ms: int
@@ -987,29 +992,118 @@ class DeviceMirror:
                     and snap.col_finite.get(col_name, False))
 
     def gather_cached(self, rows: np.ndarray, snap=None
-                      ) -> Optional[Tuple[object, Dict[str, object],
-                                          Dict[str, object], int]]:
-        """(ts_off [R, T], cols, vbases, base_ms) device arrays for the
-        requested rows from the current snapshot — no host reads, no
-        freshness check, so it runs outside any lock: the snapshot is
-        immutable and was fresh when ensure_fresh validated it (a concurrent
-        refresh just publishes a new snapshot; this query keeps its own).
-        Offsets are relative to the returned base_ms; values rebased by
-        vbases.  Pass `snap` (from .snapshot()) to pin a specific snapshot
-        when pairing with other per-snapshot reads."""
-        import jax.numpy as jnp
+                      ) -> Optional["MirrorGather"]:
+        """The requested rows of the current snapshot as a MirrorGather —
+        no device work, no host reads, no freshness check, so it runs
+        outside any lock: the snapshot is immutable and was fresh when
+        ensure_fresh validated it (a concurrent refresh just publishes a
+        new snapshot; the handle keeps its own).  An array's rows are
+        taken when it is first read.  Offsets are relative to the handle's
+        base_ms; values rebased by the vbases.  Pass `snap` (from
+        .snapshot()) to pin a specific snapshot when pairing with other
+        per-snapshot reads.  None before the first refresh."""
         snap = snap if snap is not None else self._snap
         if snap is None:
             return None
-        idx = jnp.asarray(rows.astype(np.int32))
-        ts_off = jnp.take(snap.ts_off, idx, axis=0)
-        cols = {name: jnp.take(arr, idx, axis=0)
-                for name, arr in snap.cols.items()}
-        vbases = {name: jnp.take(vb, idx, axis=0)
-                  for name, vb in snap.vbases.items()}
-        return ts_off, cols, vbases, snap.base_ms
+        from filodb_tpu.utils.metrics import registry
+        registry.counter("mirror_gather_deferred").increment()
+        return MirrorGather(self.device, snap, rows)
 
     @property
     def base_ms(self) -> int:
         snap = self._snap
         return snap.base_ms if snap is not None else 0
+
+
+class DeferredRows:
+    """One array of a MirrorGather that nobody has read yet: its shape,
+    dtype and ndim are known, its rows leave the mirror at resolve()."""
+    __slots__ = ("_gather", "_array", "_col", "shape", "dtype")
+
+    def __init__(self, gather: "MirrorGather", array: str,
+                 col: Optional[str]):
+        self._gather, self._array, self._col = gather, array, col
+        self.shape, self.dtype = gather.spec(array, col)
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    def resolve(self):
+        return self._gather._take(self._array, self._col)
+
+
+class MirrorGather:
+    """Rows of one mirror snapshot, gathered by need.
+
+    Made by DeviceMirror.gather_cached with no device work: every array's
+    shape and dtype follow from the snapshot and the row count.  The
+    handle pins the snapshot it was made from — the one the leaf
+    validated fresh — so a refresh that publishes a newer one before an
+    array is read changes nothing that is read.  Each array (`ts_off`
+    [R, T]; a column under `values`, [R, T] or [R, T, B]; its `vbase`,
+    [R] or [R, B]) is taken on its first read, that array only, with the
+    row index uploaded once for all of them; a take that raised is not
+    remembered, so the next read tries again.  The results live on the
+    handle: keep it no longer than the leaf's execution and in no cache
+    (it holds a whole snapshot alive)."""
+    __slots__ = ("device", "snap", "rows", "_idx", "_taken")
+
+    def __init__(self, device, snap: _MirrorSnapshot, rows: np.ndarray):
+        self.device = device
+        self.snap = snap
+        self.rows = rows
+        self._idx = None
+        self._taken: Dict[Tuple[str, Optional[str]], object] = {}
+
+    @property
+    def base_ms(self) -> int:
+        return self.snap.base_ms
+
+    def _source(self, array: str, col: Optional[str]):
+        if array == "ts_off":
+            return self.snap.ts_off
+        return (self.snap.cols if array == "values"
+                else self.snap.vbases)[col]
+
+    def spec(self, array: str, col: Optional[str] = None):
+        """(shape, dtype) the array will have once taken."""
+        src = self._source(array, col)
+        return (len(self.rows),) + tuple(src.shape[1:]), src.dtype
+
+    def deferred(self, array: str, col: Optional[str] = None
+                 ) -> Optional[DeferredRows]:
+        """The array as a leaf's block carries it: taken when the block's
+        field of that name is first read, and counted under it.  None for
+        a column the snapshot keeps no vbase of."""
+        if array == "vbase" and col not in self.snap.vbases:
+            return None
+        return DeferredRows(self, array, col)
+
+    def read(self, array: str, col: Optional[str] = None):
+        """The array itself, for a reader that is no block's field (a
+        column beside the leaf's own): counted as `other`."""
+        return self._take(array, col,
+                          counted="ts_off" if array == "ts_off" else "other")
+
+    def _take(self, array: str, col: Optional[str],
+              counted: Optional[str] = None):
+        got = self._taken.get((array, col))
+        if got is not None:
+            return got
+        import jax.numpy as jnp
+
+        from filodb_tpu.utils.devicetelem import telem
+        from filodb_tpu.utils.metrics import registry, span
+        src = self._source(array, col)
+        with span("leaf.mirror_gather") as taking:
+            if self._idx is None:
+                self._idx = jnp.asarray(self.rows.astype(np.int32))
+            got = jnp.take(src, self._idx, axis=0)
+        self._taken[(array, col)] = got
+        registry.counter("mirror_gather_takes",
+                         array=counted or array).increment()
+        telem.record_dispatch("mirror_gather", device=self.device,
+                              shape=f"rows{len(self.rows)}",
+                              seconds=taking.dur_s)
+        return got

@@ -18,6 +18,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 import jax.numpy as jnp
 
+from filodb_tpu.core.devicecache import DeferredRows
 from filodb_tpu.core.index import ColumnFilter, Equals
 from filodb_tpu.ops import agg as agg_ops
 from filodb_tpu.ops import hist as hist_ops
@@ -86,7 +87,12 @@ class RawBlock:
 
     values are REBASED per series (absolute value - vbase[s]) so counter
     deltas survive the f32 device downcast; vbase is the per-series base
-    in f64 (None = not rebased).  See ops/timewindow.series_value_base."""
+    in f64 (None = not rebased).  See ops/timewindow.series_value_base.
+
+    A leaf over the device mirror hands `ts_off`, `values` and `vbase` as
+    DeferredRows (core/devicecache.MirrorGather): each is gathered out of
+    the mirror when that field is first read, and every reader gets the
+    array it would have got.  `values_shape` answers without a read."""
     keys: List[RangeVectorKey]
     ts_off: np.ndarray                  # int32 [S, T] offsets from base_ms
     values: np.ndarray                  # [S, T] or [S, T, B]
@@ -112,6 +118,32 @@ class RawBlock:
     # gather then stays host-side and _try_fused evaluates in numpy
     # (ops/hostleaf) instead of paying the ~65 ms device dispatch floor
     route_host: bool = False
+
+    @property
+    def values_shape(self) -> Tuple[int, ...]:
+        """Shape of `values` ([S, T] or [S, T, B]; () for what has none),
+        known without gathering rows nobody has read yet."""
+        return tuple(getattr(self.__dict__["_values"], "shape", ()))
+
+
+def _resolved_on_read(field: str) -> property:
+    """A RawBlock field that may be set to a DeferredRows and reads as its
+    array.  Still a dataclass field: the constructor, `dataclasses.replace`
+    and the wire formats (parallel/serialize.py, streams.py) see the name."""
+    slot = "_" + field
+
+    def get(self):
+        held = self.__dict__[slot]
+        return held.resolve() if isinstance(held, DeferredRows) else held
+
+    def put(self, value):
+        self.__dict__[slot] = value
+
+    return property(get, put)
+
+
+for _field in ("ts_off", "values", "vbase"):
+    setattr(RawBlock, _field, _resolved_on_read(_field))
 
 
 # Fused-leaf caches (see MultiSchemaPartitionsExec._try_fused): entries are

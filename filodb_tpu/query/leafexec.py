@@ -189,8 +189,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 or not isinstance(t1, AggregateMapReduce):
             return None
         from filodb_tpu.ops import pallas_fused as pf
-        vals = data.values
-        ndim = getattr(vals, "ndim", 0)
+        # the shape only: a mirrored block's rows are gathered when its
+        # values are read, which a hit on the padded-values cache never does
+        shape = data.values_shape
+        ndim = len(shape)
         is_hist = ndim == 3
         if ndim not in (2, 3) or t0.function_args or t1.params:
             return None
@@ -243,7 +245,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # VMEM guard, part 1 (group count not yet known — use the minimum):
         # very long ranges with many windows must take the general path,
         # not fail at kernel lowering
-        Tp = pf._pad_to(vals.shape[1], pf._LANE)
+        Tp = pf._pad_to(shape[1], pf._LANE)
         Wp = pf._pad_to(eval_wends.size, pf._LANE)
         kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
         if pf.pick_block(Tp, Wp, 8, kind, not dense) is None:
@@ -280,7 +282,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 gids, gkeys = _group_ids_cached(data.cache_token, data.keys,
                                                 t1.by, t1.without)
         self._check_group_limit(gkeys)
-        B = vals.shape[2] if is_hist else 1
+        B = shape[2] if is_hist else 1
         num_slots = len(gkeys) * B      # hist: one kernel group per (g, b)
         # VMEM guard, part 2: full estimate now that group count is known —
         # BEFORE the padded device copy, so diverted queries cost nothing
@@ -290,13 +292,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                          not dense) is None:
             return None
         if padded_vals is None:
-            vbase = data.vbase
+            vals, vbase = data.values, data.vbase
             if is_hist:
                 # [S, T, B] -> [S*B, T] rows (bucket-major within a series,
                 # same layout PeriodicSamplesMapper flattens to)
                 with span("leaf.hist_flatten"):
                     flat = jnp.moveaxis(jnp.asarray(vals), 2, 1) \
-                        .reshape(vals.shape[0] * B, vals.shape[1])
+                        .reshape(shape[0] * B, shape[1])
                     vb_flat = (np.zeros(flat.shape[0], np.float32)
                                if vbase is None
                                else jnp.asarray(vbase,
@@ -305,7 +307,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                     padded_vals = pf.pad_values(flat, vb_flat, plan)
             else:
                 if vbase is None:
-                    vbase = np.zeros(vals.shape[0], np.float32)
+                    vbase = np.zeros(shape[0], np.float32)
                 with span("leaf.pad_values"):
                     padded_vals = pf.pad_values(vals, vbase, plan)
             if key is not None:
@@ -322,10 +324,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 if is_hist:
                     gids_flat = (np.asarray(gids, np.int64)[:, None] * B
                                  + np.arange(B)[None, :]).reshape(-1)
-                    groups = pf.pad_groups(gids_flat, vals.shape[0] * B,
+                    groups = pf.pad_groups(gids_flat, shape[0] * B,
                                            num_slots)
                 else:
-                    groups = pf.pad_groups(gids, vals.shape[0], len(gkeys))
+                    groups = pf.pad_groups(gids, shape[0], len(gkeys))
             _group_cache_insert(key, t1.by, t1.without, groups, gkeys)
         registry.counter("leaf_fused_kernel").increment()
         if not is_hist:
@@ -341,7 +343,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 plan=plan, values=padded_vals, groups=groups, gkeys=gkeys,
                 wends=wends, fn=fn, op=t1.op,
                 precorrected=data.precorrected, interpret=interpret,
-                ragged=not dense, num_series=vals.shape[0], cache_key=ck,
+                ragged=not dense, num_series=shape[0], cache_key=ck,
                 cache_token=agg_token(t1.op, t1.by, t1.without,
                                       data.cache_token))
             return fc
@@ -358,7 +360,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             plan=plan, values=padded_vals,
             groups=groups, gkeys=gkeys, wends=wends, fn=fn, op="sum",
             precorrected=data.precorrected, interpret=interpret,
-            ragged=not dense, num_series=vals.shape[0] * B, cache_key=ck,
+            ragged=not dense, num_series=shape[0] * B, cache_key=ck,
             bucket_les=data.bucket_les, num_buckets=B,
             cache_token=agg_token("hist_sum", t1.by, t1.without,
                                   data.cache_token))
@@ -794,19 +796,18 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # pairing a newer snapshot's grid with an older one's values
                 # would feed the kernel zero-padded phantom columns
                 snap = mirror.snapshot()
-                from filodb_tpu.utils.devicetelem import telem
-                with span("leaf.mirror_gather") as gathering:
-                    mirrored = mirror.gather_cached(rows, snap)
-                telem.record_dispatch(
-                    "mirror_gather", device=mirror.device,
-                    shape=f"rows{len(rows)}", seconds=gathering.dur_s)
+                # no device work yet: the rows of an array leave the mirror
+                # when the block's field is first read (MirrorGather books
+                # the span and the dispatch of each take where it runs)
+                mirrored = mirror.gather_cached(rows, snap)
         # value column selection: histograms gather [S, T, B]
         shared_ts_row = None
         dense = True
         if mirrored is not None:
-            ts_off, dev_cols, dev_vbases, base = mirrored
-            vals = dev_cols[col_name]
-            vbase = dev_vbases.get(col_name)
+            base = mirrored.base_ms
+            ts_off = mirrored.deferred("ts_off")
+            vals = mirrored.deferred("values", col_name)
+            vbase = mirrored.deferred("vbase", col_name)
             with span("leaf.counts_copy"):
                 if facts.generation != store.generation:
                     sel, facts = shard.selection_facts(lookup, schema_name)

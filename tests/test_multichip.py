@@ -146,7 +146,7 @@ def test_mirror_shard_partition_roundtrip():
     rows = np.arange(store.num_series)
     got = mirror.gather_cached(rows, snap)
     assert got is not None
-    ts_off, cols, vbases, base = got
+    ts_off, base = got.read("ts_off"), got.base_ms
     # round-trip: device copy == host truth (offsets + absolute values)
     s, t = store.num_series, store.time_used
     want_ts = store.ts[:s, :t]
@@ -156,8 +156,8 @@ def test_mirror_shard_partition_roundtrip():
     valid = pos < counts[:, None]
     np.testing.assert_array_equal(got_ts[valid] + base, want_ts[valid])
     name = store.schema.value_column
-    got_vals = np.asarray(cols[name], np.float64) \
-        + np.asarray(vbases[name], np.float64)[:, None]
+    got_vals = np.asarray(got.read("values", name), np.float64) \
+        + np.asarray(got.read("vbase", name), np.float64)[:, None]
     # the mirror reset-corrects counter columns in f64 before rebasing,
     # so the host truth is the corrected column
     from filodb_tpu.ops.counter import host_counter_correct

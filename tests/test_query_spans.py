@@ -152,9 +152,12 @@ def test_one_request_is_one_tree_under_http_request(rig):
             "query_parse", "query_plan", "execplan", "engine.present",
             "exec.ReduceAggregateExec", "exec.MultiSchemaPartitionsExec",
             "leaf.index_lookup", "leaf.scan_estimate", "leaf.page_check",
-            "leaf.mirror_fresh", "leaf.mirror_gather", "leaf.counts_copy",
+            "leaf.mirror_fresh", "leaf.counts_copy",
             "leaf.fused_prepare", "leaf.kernel_enqueue", "leaf.result_fetch",
             "leaf.present"} <= names
+    # the padded values are cached: nobody reads the mirror's rows, so
+    # no row is gathered out of it (ISSUE 34)
+    assert "leaf.mirror_gather" not in names
     # the legacy path: the names entered since the innermost trace context
     assert any(e["span"] == "execplan" for e in evs)
     # one wall-clock anchor a trace, and events in start order
@@ -205,7 +208,7 @@ def test_four_leaves_each_hold_enqueue_and_fetch(rig, hoisted):
                    (next(p for p in evs if p["span_id"] == e["parent_id"])
                     for e in prep))
         for e in prep:
-            assert {"leaf.index_lookup", "leaf.mirror_gather",
+            assert {"leaf.index_lookup", "leaf.mirror_fresh",
                     "leaf.counts_copy", "leaf.fused_prepare"} <= set(under(e))
         disp = next(e for e in evs if e["name"] == "engine.dispatch_leaves")
         got = collections.Counter(under(disp))
@@ -223,6 +226,23 @@ def test_four_leaves_each_hold_enqueue_and_fetch(rig, hoisted):
             assert got["leaf.kernel_enqueue"] == 1
             assert got["leaf.result_fetch"] == 1
             assert got["leaf.fused_prepare"] == 1
+
+
+def test_a_miss_gathers_inside_fused_prepare(rig):
+    """With no padded copy cached (a new snapshot generation, or the first
+    query) the leaf reads its values and their vbase: two takes a leaf,
+    each a `leaf.mirror_gather` where it runs, inside `leaf.fused_prepare`
+    and beside `leaf.pad_values`; nothing reads `ts_off`."""
+    from filodb_tpu.query.execbase import _FUSED_CACHE_LOCK, _FUSED_VALS_CACHE
+    with _FUSED_CACHE_LOCK:
+        _FUSED_VALS_CACHE.clear()
+    evs = real(rig.tree(rig.query()["traceID"])[0])
+    by_id = {e["span_id"]: e for e in evs}
+    takes = [e for e in evs if e["name"] == "leaf.mirror_gather"]
+    assert len(takes) == 2 * SHARDS
+    assert {by_id[e["parent_id"]]["name"] for e in takes} \
+        == {"leaf.fused_prepare"}
+    assert sum(e["name"] == "leaf.pad_values" for e in evs) == SHARDS
 
 
 def test_counters_match_the_tree(rig):
