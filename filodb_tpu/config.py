@@ -78,9 +78,10 @@ class QueryConfig:
     # working sets whose estimated scan is at or below this many samples
     # (values: a histogram sample counts one per bucket,
     # leafexec.leaf_route) evaluate in host numpy (ops/hostleaf) instead
-    # of paying a device dispatch's fixed cost.  The threshold has not
-    # been re-measured on an attached chip (CHANGES.md PR 24; ROADMAP
-    # A2/C4 own it).
+    # of paying a device dispatch's fixed cost.  On a TPU backend a leaf
+    # that may read the device mirror is never routed to the host (PR 35:
+    # 8 ms a dispatch against 1.9 s); the cap routes the leaves that
+    # cannot (mirror off, a non-counter function over a counter column).
     # 0 disables.  Decision is observable: `leaf_host_routed` counter +
     # the execplan span's route tag.
     host_route_max_samples: int = 2_000_000

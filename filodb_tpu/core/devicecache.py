@@ -41,8 +41,9 @@ class _MirrorSnapshot:
     gen: int
     base_ms: int
     t_used: int
-    ts_off: object                      # jax i32 [S_live, T_used]
-    cols: Dict[str, object]             # jax f [S_live, T_used(, B)]
+    # device arrays: _mirror_rows(S_live) rows, the ones past S_live padding
+    ts_off: object                      # jax i32 [rows, T_used]
+    cols: Dict[str, object]             # jax f [rows, T_used(, B)]
     # per-series value bases subtracted in f64 before upload, so counter
     # deltas survive the f32 downcast (ops/timewindow.series_value_base)
     vbases: Dict[str, object]
@@ -133,6 +134,27 @@ def _note_rebuild(delta: int) -> None:
 # mirrored by config.device_mirror_hbm_limit and subtracted by the fused
 # padded-values cache budget in query/exec._fused_vals_budget).
 DEFAULT_HBM_LIMIT_BYTES = 8 << 30
+
+
+def _mirror_rows(series: int) -> int:
+    """Rows a mirror's device arrays get for a store of `series` series:
+    the next rung of the fused kernel's series ladder.  A program that
+    reads a mirror array (the row take of every leaf that misses its
+    padded values) compiles once a SHAPE; with the exact series count
+    that is once a shard, 30 takes of 0.6 s in the first query of a chip's
+    32-shard share (PERF.md section 6, PR 35), and again whenever a
+    shard's series count drifts.  The rows past the store's are never
+    indexed: PAD_TS offsets, NaN values, zero bases."""
+    from filodb_tpu.ops.pallas_fused import pad_series_count
+    return pad_series_count(series)
+
+
+def _pad_rows(x: np.ndarray, rows: int, fill) -> np.ndarray:
+    if x.shape[0] >= rows:
+        return x
+    out = np.full((rows,) + x.shape[1:], fill, x.dtype)
+    out[:x.shape[0]] = x
+    return out
 
 
 def store_nbytes(store) -> int:
@@ -552,6 +574,7 @@ class DeviceMirror:
         col_finite: Dict[str, bool] = {}
         uniform = bool(s > 0 and (counts == counts[0]).all()
                        and (ts_off == ts_off[0:1]).all())
+        dev_rows = _mirror_rows(s)
         for name, arr in store.cols.items():
             if arr is not None:
                 # counter columns are reset-corrected in f64 BEFORE rebasing
@@ -560,8 +583,8 @@ class DeviceMirror:
                 is_counter = name in counter_cols
                 rebased, vb, corrected = rebase_values(
                     arr[:s, :t], is_counter, return_corrected=True)
-                cols[name] = dput(rebased)
-                vbases[name] = dput(vb)
+                cols[name] = dput(_pad_rows(rebased, dev_rows, np.nan))
+                vbases[name] = dput(_pad_rows(np.asarray(vb), dev_rows, 0))
                 host_vbases[name] = np.asarray(vb, np.float64)
                 fin = np.isfinite(corrected)
                 vbase_valid[name] = fin.any(axis=1)
@@ -578,7 +601,9 @@ class DeviceMirror:
                     cum_drop[name] = cd
         # single publication point (GIL-atomic): see _MirrorSnapshot
         self._snap = _MirrorSnapshot(gen0, base_ms, t,
-                                     dput(ts_off), cols, vbases,
+                                     dput(_pad_rows(ts_off, dev_rows,
+                                                    PAD_TS)),
+                                     cols, vbases,
                                      shift_version=store.shift_version,
                                      counts=counts, host_vbases=host_vbases,
                                      tail_last_raw=last_raw,
@@ -781,10 +806,14 @@ class DeviceMirror:
         # the per-query transfer attribution names actual device work
         xfer_s = 0.0
         dS, dT = s_new - s_old, t_new - snap.t_used
+        # the device arrays hold _mirror_rows(series) rows: they grow by
+        # rungs of that ladder, not by series
+        dev_rows = _mirror_rows(s_new)
+        dR = max(dev_rows - snap.ts_off.shape[0], 0)
         _td = _time.perf_counter()
         ts_dev = snap.ts_off
-        if dS or dT:
-            ts_dev = jnp.pad(ts_dev, ((0, dS), (0, dT)),
+        if dR or dT:
+            ts_dev = jnp.pad(ts_dev, ((0, dR), (0, dT)),
                              constant_values=PAD_TS)
         ts_dev = ts_dev.at[idx_r, idx_p].set(off.astype(np.int32))
         xfer_s += _time.perf_counter() - _td
@@ -866,15 +895,16 @@ class DeviceMirror:
                                     and np.isfinite(flat).all())
             _td = _time.perf_counter()
             col_dev = dev
-            if dS or dT:
-                pad = ((0, dS), (0, dT)) + (((0, 0),) if hist else ())
+            if dR or dT:
+                pad = ((0, dR), (0, dT)) + (((0, 0),) if hist else ())
                 col_dev = jnp.pad(col_dev, pad, constant_values=np.nan)
             new_cols[name] = col_dev.at[idx_r, idx_p].set(
                 flat.astype(col_dev.dtype))
             vb_dev = snap.vbases[name]
             if dS or vb_changed:
                 new_vbases[name] = jax.device_put(
-                    vb_new.astype(vb_dev.dtype), self.device)
+                    _pad_rows(vb_new.astype(vb_dev.dtype), dev_rows, 0),
+                    self.device)
             else:
                 new_vbases[name] = vb_dev
             xfer_s += _time.perf_counter() - _td
@@ -917,33 +947,31 @@ class DeviceMirror:
         from filodb_tpu.utils.metrics import registry as metrics_registry
         dS, dT = s_new - snap.counts.shape[0], t_new - snap.t_used
         s_old = snap.counts.shape[0]
-        ts_dev = jnp.pad(snap.ts_off, ((0, dS), (0, dT)),
-                         constant_values=PAD_TS) if (dS or dT) else snap.ts_off
+        dev_rows = _mirror_rows(s_new)      # as _refresh_incremental
+        dR = max(dev_rows - snap.ts_off.shape[0], 0)
+        ts_dev = jnp.pad(snap.ts_off, ((0, dR), (0, dT)),
+                         constant_values=PAD_TS) if (dR or dT) else snap.ts_off
         new_cols, new_vbases = {}, {}
         host_vbases, last_raw = dict(snap.host_vbases), dict(snap.tail_last_raw)
         cum_drop, vbase_valid = dict(snap.tail_cum_drop), dict(snap.vbase_valid)
 
-        def grow(a, fill, dtype=None):
-            out = np.full((s_new,) + a.shape[1:], fill, dtype or a.dtype)
-            out[:s_old] = a
-            return out
-
         for name, dev in snap.cols.items():
-            if dS or dT:
-                pad = ((0, dS), (0, dT)) + \
+            if dR or dT:
+                pad = ((0, dR), (0, dT)) + \
                     (((0, 0),) if dev.ndim == 3 else ())
                 dev = jnp.pad(dev, pad, constant_values=np.nan)
             new_cols[name] = dev
-            host_vbases[name] = grow(host_vbases[name], 0.0)
-            vbase_valid[name] = grow(vbase_valid[name], False)
+            host_vbases[name] = _pad_rows(host_vbases[name], s_new, 0.0)
+            vbase_valid[name] = _pad_rows(vbase_valid[name], s_new, False)
             if name in last_raw:
-                last_raw[name] = grow(last_raw[name], np.nan)
-                cum_drop[name] = grow(cum_drop[name], 0.0)
+                last_raw[name] = _pad_rows(last_raw[name], s_new, np.nan)
+                cum_drop[name] = _pad_rows(cum_drop[name], s_new, 0.0)
             vb_dev = snap.vbases[name]
             if dS:
                 import jax
                 vb_dev = jax.device_put(
-                    host_vbases[name].astype(vb_dev.dtype), self.device)
+                    _pad_rows(host_vbases[name].astype(vb_dev.dtype),
+                              dev_rows, 0), self.device)
             new_vbases[name] = vb_dev
 
         counts_new = np.zeros(s_new, dtype=np.int32)
@@ -1029,8 +1057,10 @@ class DeferredRows:
     def ndim(self) -> int:
         return len(self.shape)
 
-    def resolve(self):
-        return self._gather._take(self._array, self._col)
+    def resolve(self, rows_to: Optional[int] = None):
+        """The array; with `rows_to`, its rows followed by zero rows up to
+        that count (MirrorGather._take)."""
+        return self._gather._take(self._array, self._col, rows_to=rows_to)
 
 
 class MirrorGather:
@@ -1053,8 +1083,8 @@ class MirrorGather:
         self.device = device
         self.snap = snap
         self.rows = rows
-        self._idx = None
-        self._taken: Dict[Tuple[str, Optional[str]], object] = {}
+        self._idx: Dict[Optional[int], object] = {}   # by `rows_to`
+        self._taken: Dict[Tuple, object] = {}
 
     @property
     def base_ms(self) -> int:
@@ -1087,8 +1117,14 @@ class MirrorGather:
                           counted="ts_off" if array == "ts_off" else "other")
 
     def _take(self, array: str, col: Optional[str],
-              counted: Optional[str] = None):
-        got = self._taken.get((array, col))
+              counted: Optional[str] = None, rows_to: Optional[int] = None):
+        """`rows_to`: take that many rows, the handle's own and then rows
+        of zeros.  The fused leaf asks for its padded row count
+        (pallas_fused.pad_series_count), so that the take and everything
+        made from it compile once a rung of that ladder and not once a
+        row count: a chip's 30 shards, or a selector's 20 row subsets, are
+        a handful of programs."""
+        got = self._taken.get((array, col, rows_to))
         if got is not None:
             return got
         import jax.numpy as jnp
@@ -1097,10 +1133,18 @@ class MirrorGather:
         from filodb_tpu.utils.metrics import registry, span
         src = self._source(array, col)
         with span("leaf.mirror_gather") as taking:
-            if self._idx is None:
-                self._idx = jnp.asarray(self.rows.astype(np.int32))
-            got = jnp.take(src, self._idx, axis=0)
-        self._taken[(array, col)] = got
+            idx = self._idx.get(rows_to)
+            if idx is None:
+                rows = self.rows.astype(np.int32)
+                if rows_to is not None:
+                    # past every mirror's rows: such an index reads as the
+                    # fill value
+                    rows = np.concatenate([rows, np.full(
+                        rows_to - rows.size, np.iinfo(np.int32).max,
+                        np.int32)])
+                idx = self._idx[rows_to] = jnp.asarray(rows)
+            got = jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
+        self._taken[(array, col, rows_to)] = got
         registry.counter("mirror_gather_takes",
                          array=counted or array).increment()
         telem.record_dispatch("mirror_gather", device=self.device,

@@ -21,6 +21,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+from filodb_tpu.utils.heap import settle_heap
 from filodb_tpu.utils.metrics import span
 
 _log = logging.getLogger("filodb.flush")
@@ -189,6 +190,12 @@ class FlushScheduler:
                         # (per-shard streaks/backoff are tracked separately
                         # above and unaffected)
                         jt.skip()
+            # a pass seals chunks and retires write buffers on every shard:
+            # the one moment a minute this thread takes the collector's
+            # full pass on itself, so that no request's thread has to
+            # (utils/heap.py)
+            with span("flush.heap_settle"):
+                settle_heap()
             group += 1
             if group >= n_groups:
                 group = 0

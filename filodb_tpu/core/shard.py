@@ -26,6 +26,10 @@ _SHARD_KEYS_SERIAL = itertools.count(1)  # see TimeSeriesShard.keys_serial
 # with zero samples accepts arbitrary-time appends).  Shared with the
 # query frontend's cache-bypass check — one constant, not two literals.
 NO_HORIZON_MS = -(1 << 62)
+# ... and of a shard that holds no row at all: there is nothing an append
+# could extend, so it bounds nothing (a first series moves the shard's
+# index.mutations, which the cache's token carries)
+NO_ROWS_HORIZON_MS = 1 << 62
 _KEY_RESOLVE_CACHE_MAX = 4               # live key tables per shard (schemas)
 _LOOKUP_CACHE_MAX = 32                   # memoized lookup_partitions results
 
@@ -1241,7 +1245,10 @@ class TimeSeriesShard:
         at or before T as immutable.  Registered rows with zero samples
         accept arbitrary timestamps, so their presence collapses the
         horizon (NO_HORIZON_MS; series-SET changes are tracked separately
-        via keys_epoch/index.mutations).
+        via keys_epoch/index.mutations).  A shard with no row at all bounds
+        nothing (NO_ROWS_HORIZON_MS): of a 128-shard layout's shards some
+        are empty, and one empty shard must not switch the dataset's
+        result cache off.
 
         Memoized per store generation: the frontend calls this on EVERY
         request including sub-ms cache hits, and the O(S) scan would
@@ -1265,7 +1272,7 @@ class TimeSeriesShard:
                      else int(store.last_ts[:s].min()))
                 self._horizon_memo[name] = (gen, h)
             horizon = h if horizon is None else min(horizon, h)
-        return horizon if horizon is not None else NO_HORIZON_MS
+        return horizon if horizon is not None else NO_ROWS_HORIZON_MS
 
     def keys_for(self, pids: np.ndarray) -> List:
         """RangeVectorKeys for a pid array, built once per partition lifetime

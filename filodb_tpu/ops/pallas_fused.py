@@ -794,10 +794,12 @@ def pad_groups(gids, S: int, num_groups: int,
                device=None) -> PaddedGroups:
     Sp = pad_series_count(S)
     gids_np = np.asarray(gids, np.int32)
-    g = (jnp.asarray(gids_np) if device is None
-         else jax.device_put(gids_np, device))
-    gids_p = jnp.full((Sp, 1), -1, jnp.int32)
-    gids_p = gids_p.at[:S, 0].set(g)
+    # padded on the host: one upload of a [Sp, 1] column, and no program
+    # that would compile once a row count
+    col = np.full((Sp, 1), -1, np.int32)
+    col[:S, 0] = gids_np
+    gids_p = (jnp.asarray(col) if device is None
+              else jax.device_put(col, device))
     gsize = np.bincount(gids_np, minlength=num_groups)[:num_groups]
     return PaddedGroups(gids_p, gsize)
 

@@ -28,7 +28,7 @@ from filodb_tpu.query.execbase import (  # noqa: F401
     _FUSED_VALS_CACHE,
     _align_hist_schemes, _block_empty, _fused_vals_budget,
     _group_cache_insert, _group_cache_lookup, _lru_touch,
-    _note_mirror_limit, _union_scheme, _vals_cache_insert, _vals_nbytes,
+    _note_mirror_limit, _union_scheme, _vals_nbytes,
     present_partial, reduce_partials)
 from filodb_tpu.query.transformers import (  # noqa: F401
     AbsentFunctionMapper, AggregateMapReduce, AggregatePresenter,

@@ -125,6 +125,15 @@ class RawBlock:
         known without gathering rows nobody has read yet."""
         return tuple(getattr(self.__dict__["_values"], "shape", ()))
 
+    def rows_padded(self, field: str, rows_to: int):
+        """`field` (`values` or `vbase`) for the fused leaf's padded working
+        set: rows still in the mirror are taken with zero rows up to
+        `rows_to` behind them (DeferredRows.resolve: one program a padded
+        row count); an array already here comes as it is."""
+        held = self.__dict__["_" + field]
+        return held.resolve(rows_to) if isinstance(held, DeferredRows) \
+            else held
+
 
 def _resolved_on_read(field: str) -> property:
     """A RawBlock field that may be set to a DeferredRows and reads as its
@@ -146,16 +155,89 @@ for _field in ("ts_off", "values", "vbase"):
     setattr(RawBlock, _field, _resolved_on_read(_field))
 
 
-# Fused-leaf caches (see MultiSchemaPartitionsExec._try_fused): entries are
-# keyed by (mirror serial, snapshot gen, ...) so any ingest naturally
-# misses.  The VALUES cache holds the full padded device copies — shared
-# across grouping variants (they depend only on the working set) and
-# bounded in BYTES, since this HBM lives outside the DeviceMirror's own
-# hbm_limit_bytes accounting.  The GROUP cache holds the small per-grouping
-# gid arrays.
-_FUSED_PLAN_CACHE: Dict[Tuple, object] = {}
-_FUSED_VALS_CACHE: Dict[Tuple, object] = {}
-_FUSED_GROUP_CACHE: Dict[Tuple, Tuple] = {}
+# Fused-leaf caches (see MultiSchemaPartitionsExec._build_fused).  Each is
+# a _FusedCache: a dict in LRU order (oldest first), bounded by BYTES alone,
+# so that it holds what a chip's deployment holds: one padded working set
+# and a few groupings for every shard and selector value the traffic
+# touches, however many that is.  The VALUES and GROUP caches are keyed by
+# (mirror serial, snapshot gen, column, rows) so any ingest naturally
+# misses, and a new generation's first insert drops its mirror's older
+# entries.  The VALUES cache holds the full padded device copies — shared
+# across grouping variants (they depend only on the working set); this HBM
+# lives outside the DeviceMirror's own hbm_limit_bytes accounting.  The
+# GROUP cache holds the small per-grouping gid arrays.  The PLAN cache is
+# keyed by what a plan is built from (the shared timestamp row, the grid,
+# the window, the base) and by nothing of the shard, so the leaves of a
+# request and the panels of an open share one build.
+
+
+class _FusedCache(dict):
+    """key -> entry, least recently used first.  Every method that reads
+    or writes is called under _FUSED_CACHE_LOCK.  `nbytes(entry)` weighs
+    an entry, `budget()` is the most the cache may hold; the entry just
+    added always stays.  Books fused_cache_lookups_total{cache, result},
+    fused_cache_evictions_total{cache, cause} and the gauges
+    fused_cache_bytes{cache} / fused_cache_entries{cache}."""
+
+    def __init__(self, name: str, nbytes: Callable[[object], int],
+                 budget: Callable[[], int], generations: bool = True):
+        super().__init__()
+        self.name, self._nbytes, self._budget = name, nbytes, budget
+        # whether keys start (mirror serial, snapshot generation, ...)
+        self._generations = generations
+
+    def lookup(self, key):
+        """The entry, moved to the newest end; None on a miss."""
+        from filodb_tpu.utils.metrics import registry
+        val = _lru_touch(self, key)
+        registry.counter("fused_cache_lookups", cache=self.name,
+                         result="miss" if val is None else "hit").increment()
+        return val
+
+    def insert(self, key, val) -> None:
+        """Add an entry; where keys carry generations, first drop the
+        entries of the same mirror (key[0]) under another snapshot
+        generation (key[1]): each pins device arrays that nothing can ask
+        for again.  Then the oldest entries go until the cache fits its
+        budget."""
+        from filodb_tpu.utils.metrics import registry
+        if self._generations:
+            stale = [k for k in self if k[0] == key[0] and k[1] != key[1]]
+            for k in stale:
+                del self[k]
+            if stale:
+                registry.counter("fused_cache_evictions", cache=self.name,
+                                 cause="generation").increment(len(stale))
+        self[key] = val
+        held = sum(self._nbytes(v) for v in self.values())
+        budget, evicted = self._budget(), 0
+        while held > budget and len(self) > 1:
+            held -= self._nbytes(self.pop(next(iter(self))))
+            evicted += 1
+        if evicted:
+            registry.counter("fused_cache_evictions", cache=self.name,
+                             cause="bytes").increment(evicted)
+        registry.gauge("fused_cache_bytes", cache=self.name).update(held)
+        registry.gauge("fused_cache_entries",
+                       cache=self.name).update(len(self))
+
+
+def _plan_nbytes(plan) -> int:
+    return int(plan.rows.nbytes + plan.tsrow.nbytes + plan.wvalid.nbytes
+               + plan.wvalid1.nbytes)
+
+
+def _vals_nbytes(v) -> int:
+    return int(v.vals_p.size * 4 + v.vbase_p.size * 4)
+
+
+def _groups_nbytes(ent) -> int:
+    groups, gkeys = ent
+    # the [Sp, 1] gid column, the group sizes, and a group key's labels
+    return int(groups.gids_p.size * 4 + groups.gsize.nbytes
+               + 256 * len(gkeys))
+
+
 # NaN-padded device copies for the reduce_window path's end=now shape,
 # keyed (working set, t_needed) — small cap: each entry pins a full copy
 _FUSED_MINMAX_PAD_CACHE: Dict[Tuple, object] = {}
@@ -205,6 +287,15 @@ def _fused_vals_budget() -> int:
         pass
     _FUSED_VALS_CACHE_BYTES = budget
     return budget
+
+
+_FUSED_PLAN_CACHE = _FusedCache("plan", _plan_nbytes, lambda: 16 << 20,
+                                generations=False)
+_FUSED_VALS_CACHE = _FusedCache("values", _vals_nbytes, _fused_vals_budget)
+# a grouping's gid column is a 768th of its working set's padded values:
+# a sixteenth of their budget holds a dozen groupings for each of them
+_FUSED_GROUP_CACHE = _FusedCache("groups", _groups_nbytes,
+                                 lambda: _fused_vals_budget() // 16)
 # queries run on HTTP worker threads (http/server.py ThreadingHTTPServer) —
 # every cache read-modify-write holds this lock; the kernel runs outside it
 _FUSED_CACHE_LOCK = threading.Lock()
@@ -268,10 +359,6 @@ def _lru_touch(cache: Dict, key) -> object:
     return val
 
 
-def _vals_nbytes(v) -> int:
-    return int(v.vals_p.size * 4 + v.vbase_p.size * 4)
-
-
 def _group_cache_lookup(key, by, without):
     """Cached (PaddedGroups, gkeys) for this working set + grouping, or
     (None, None).  Pairs with _group_cache_insert — the two halves of the
@@ -279,35 +366,18 @@ def _group_cache_lookup(key, by, without):
     if key is None:
         return None, None
     with _FUSED_CACHE_LOCK:
-        ent = _lru_touch(_FUSED_GROUP_CACHE, key + (by, without))
+        ent = _FUSED_GROUP_CACHE.lookup(key + (by, without))
     return ent if ent is not None else (None, None)
 
 
 def _group_cache_insert(key, by, without, groups, gkeys) -> None:
-    """Insert a (PaddedGroups, gkeys) entry, evicting entries from older
-    snapshot generations of the same mirror (each pins device arrays) and
-    capping the cache.  The single home of the group-cache write rules —
-    used by both the kernel path and the reduce_window path."""
+    """Insert a (PaddedGroups, gkeys) entry.  The single home of the
+    group-cache write rules — used by both the kernel path and the
+    reduce_window path."""
     if key is None:
         return
-    group_key = key + (by, without)
     with _FUSED_CACHE_LOCK:
-        for k in [k for k in _FUSED_GROUP_CACHE
-                  if k[0] == key[0] and k[1] != key[1]]:
-            del _FUSED_GROUP_CACHE[k]
-        _FUSED_GROUP_CACHE[group_key] = (groups, gkeys)
-        while len(_FUSED_GROUP_CACHE) > 16:
-            _FUSED_GROUP_CACHE.pop(next(iter(_FUSED_GROUP_CACHE)))
-
-
-def _vals_cache_insert(key, v) -> None:
-    _FUSED_VALS_CACHE[key] = v
-    while len(_FUSED_VALS_CACHE) > 4 or sum(
-            _vals_nbytes(e) for e in _FUSED_VALS_CACHE.values()
-            ) > _fused_vals_budget():
-        if len(_FUSED_VALS_CACHE) == 1:
-            break                        # always keep the entry just added
-        _FUSED_VALS_CACHE.pop(next(iter(_FUSED_VALS_CACHE)))
+        _FUSED_GROUP_CACHE.insert(key + (by, without), (groups, gkeys))
 
 
 @dataclasses.dataclass

@@ -35,8 +35,7 @@ from filodb_tpu.query.execbase import (
     QueryError, QueryResultLike, RawBlock, ScalarResult,
     _FUSED_CACHE_LOCK, _FUSED_MINMAX_PAD_CACHE, _FUSED_PLAN_CACHE,
     _FUSED_VALS_CACHE, _block_empty, _group_cache_insert,
-    _group_cache_lookup, _lru_touch, _note_mirror_limit,
-    _vals_cache_insert, agg_token)
+    _group_cache_lookup, _lru_touch, _note_mirror_limit, agg_token)
 from filodb_tpu.query.transformers import (
     AggregateMapReduce, PeriodicSamplesMapper, RangeVectorTransformer,
     _group_ids, _group_ids_cached)
@@ -162,6 +161,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
     def _try_fused(self, data, stats, defer: bool = False):
         """The fused peephole (_build_fused), then, unless deferred, the
         kernel dispatch of the FusedCall it built."""
+        if data is None:
+            return None                  # an empty leaf has nothing to fuse
         with span("leaf.fused_prepare"):
             pre = self._build_fused(data, stats)
         if defer or not isinstance(pre, FusedCall):
@@ -257,11 +258,15 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         key = self._fused_cache_key
         plan = padded_vals = groups = gkeys = None
         if key is not None:
-            plan_key = key[:3] + (t0.start_ms, t0.step_ms, t0.end_ms,
-                                  t0.offset_ms, t0.window_ms, data.base_ms)
+            # a plan is built from the shared timestamp row, the grid, the
+            # window and the base, and from nothing of the shard: a
+            # request's leaves and an open's panels share one build
+            plan_key = ("plan", data.shared_ts_row.tobytes(), t0.start_ms,
+                        t0.step_ms, t0.end_ms, t0.offset_ms, t0.window_ms,
+                        data.base_ms)
             with _FUSED_CACHE_LOCK:
-                plan = _lru_touch(_FUSED_PLAN_CACHE, plan_key)
-                padded_vals = _lru_touch(_FUSED_VALS_CACHE, key)
+                plan = _FUSED_PLAN_CACHE.lookup(plan_key)
+                padded_vals = _FUSED_VALS_CACHE.lookup(key)
             groups, gkeys = _group_cache_lookup(key, t1.by, t1.without)
             if padded_vals is not None:
                 registry.counter("leaf_fused_prep_hits").increment()
@@ -271,12 +276,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                                      eval_wends, t0.window_ms)
             if key is not None:
                 with _FUSED_CACHE_LOCK:
-                    for k in [k for k in _FUSED_PLAN_CACHE
-                              if k[0] == key[0] and k[1] != key[1]]:
-                        del _FUSED_PLAN_CACHE[k]
-                    _FUSED_PLAN_CACHE[plan_key] = plan
-                    while len(_FUSED_PLAN_CACHE) > 8:
-                        _FUSED_PLAN_CACHE.pop(next(iter(_FUSED_PLAN_CACHE)))
+                    _FUSED_PLAN_CACHE.insert(plan_key, plan)
         if gkeys is None:
             with span("leaf.group_ids"):
                 gids, gkeys = _group_ids_cached(data.cache_token, data.keys,
@@ -292,8 +292,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                          not dense) is None:
             return None
         if padded_vals is None:
-            vals, vbase = data.values, data.vbase
             if is_hist:
+                vals, vbase = data.values, data.vbase
                 # [S, T, B] -> [S*B, T] rows (bucket-major within a series,
                 # same layout PeriodicSamplesMapper flattens to)
                 with span("leaf.hist_flatten"):
@@ -306,19 +306,22 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 with span("leaf.pad_values"):
                     padded_vals = pf.pad_values(flat, vb_flat, plan)
             else:
+                # rows out of the mirror come padded to the ladder's rung
+                # already: the take, the pad and the kernel then compile
+                # once a rung, not once a row count
+                Sp = pf.pad_series_count(shape[0])
+                vals = data.rows_padded("values", Sp)
+                vbase = data.rows_padded("vbase", Sp)
                 if vbase is None:
-                    vbase = np.zeros(shape[0], np.float32)
+                    vbase = np.zeros(vals.shape[0], np.float32)
                 with span("leaf.pad_values"):
                     padded_vals = pf.pad_values(vals, vbase, plan)
             if key is not None:
                 # a new snapshot generation obsoletes this mirror's older
-                # entries — drop them NOW, not at LRU eviction: each pins a
-                # full padded copy of the working set in HBM
+                # entries — the insert drops them NOW, not at LRU eviction:
+                # each pins a full padded copy of the working set in HBM
                 with _FUSED_CACHE_LOCK:
-                    for k in [k for k in _FUSED_VALS_CACHE
-                              if k[0] == key[0] and k[1] != key[1]]:
-                        del _FUSED_VALS_CACHE[k]
-                    _vals_cache_insert(key, padded_vals)
+                    _FUSED_VALS_CACHE.insert(key, padded_vals)
         if groups is None:
             with span("leaf.pad_groups"):
                 if is_hist:
@@ -599,10 +602,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             lookup = shard.lookup_partitions(
                 self.filters, self.chunk_start_ms, self.chunk_end_ms)
             schema_name = self.schema or lookup.first_schema
-            if schema_name is None:
-                return None, stats
-            pids = lookup.pids_by_schema.get(schema_name)
+            pids = None if schema_name is None else \
+                lookup.pids_by_schema.get(schema_name)
             if pids is None or pids.size == 0:
+                # an empty shard's leaf: no series after the index lookup,
+                # so no gather, no working set and no dispatch
+                from filodb_tpu.utils.metrics import registry
+                registry.counter("leaf_empty").increment()
                 return None, stats
             store = shard.stores[schema_name]
             # the selection's rows, cache keys and facts (counts,
@@ -708,6 +714,12 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # of re-shipping the matrix every query (ref: block-memory working
         # set, BlockManager.scala; see core/devicecache.py)
         mirror = None
+        # whether this leaf may read the device mirror at all: the mirror
+        # holds counter columns corrected, so only counter functions read
+        # those from it
+        mirrorable = (getattr(shard.config.store, "device_mirror_enabled",
+                              True)
+                      and (not counter_col or fn_is_counter))
         # cost-based router (round-5 item 6): an estimated working set at
         # or below query.host_route_max_samples skips the device mirror —
         # the host gather is cheap at that size, and _try_fused then
@@ -719,19 +731,16 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             # only where the per-dispatch floor exists: on the CPU
             # backend the "device" path is already host-side, and the
             # interpret-mode tests exercise the kernel deliberately
-            import jax as _jax
-            if _jax.default_backend() == "tpu" or os.environ.get(
-                    "FILODB_TPU_FORCE_HOST_ROUTE"):
+            forced = bool(os.environ.get("FILODB_TPU_FORCE_HOST_ROUTE"))
+            if forced or _attached_chip():
                 # a histogram sample is num_buckets values: the cap is
                 # compared with what the leaf would gather and correct
                 per_sample = (store.num_buckets if col_def is not None
                               and col_def.col_type == "hist" else 1)
-                route_host = leaf_route(_scan_estimate(), per_sample,
-                                        _route_cap) == "host"
-        if (not route_host
-                and getattr(shard.config.store, "device_mirror_enabled",
-                            True)
-                and (not counter_col or fn_is_counter)):
+                route_host = leaf_route(
+                    _scan_estimate(), per_sample, _route_cap,
+                    mirrored=mirrorable and not forced) == "host"
+        if not route_host and mirrorable:
             mirror = getattr(store, "device_mirror", None)
             if mirror is None:
                 from filodb_tpu.core.devicecache import (
@@ -1272,12 +1281,28 @@ def _estimate_scan(store, rows: np.ndarray, start_ms: int,
     return estimate_samples(*store.row_extents(rows), start_ms, end_ms)
 
 
-def leaf_route(est_samples: int, values_per_sample: int, cap: int) -> str:
+def _attached_chip() -> bool:
+    """Whether this process's leaves dispatch to an attached TPU."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def leaf_route(est_samples: int, values_per_sample: int, cap: int,
+               mirrored: bool = False) -> str:
     """Where a shard leaf gathers its rows: "host" when its estimated
     working set, in VALUES, is at or under `cap`
     (query.host_route_max_samples; 0 turns the rule off), else "device"
     (the mirror).  A scalar sample is one value, a histogram sample one
-    per bucket: a 64-bucket leaf of 400,000 samples is 25.6 M values."""
+    per bucket: a 64-bucket leaf of 400,000 samples is 25.6 M values.
+
+    `mirrored`: the leaf may read the device mirror of an attached chip.
+    Such a leaf is never small enough for the host: a fused dispatch over
+    the mirror costs the host about 8 ms whatever its rows, and the host
+    route reset-corrects whole stored rows, 1.9 s for a 3,000-series shard
+    (PERF.md section 6, PR 35).  The cap then routes only leaves that
+    cannot read the mirror."""
+    if mirrored:
+        return "device"
     values = est_samples * max(values_per_sample, 1)
     return "host" if cap > 0 and 0 < values <= cap else "device"
 
