@@ -281,17 +281,19 @@ def _committed_device(arr):
 
 
 def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
-                     offsets=None) -> tuple:
+                     offsets=None, sets: int = 1) -> tuple:
     """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
     takes from the host, put explicitly (so the call itself transfers
-    nothing) and counted, uploads beside enqueues, on /metrics.  `tsrow`
-    rides only where the kernel reads it (the ragged rate family),
-    `offsets` only for a batch of several panels; the others are None."""
+    nothing) and counted, uploads and working sets beside enqueues, on
+    /metrics.  `tsrow` rides only where the kernel reads it (the ragged
+    rate family), `offsets` only where some set has several panels; the
+    others are None.  `sets`: the working sets the call carries."""
     from filodb_tpu.utils.metrics import registry
     host = (plan.rows,
             plan.tsrow if ragged and kind == "rate_family" else None,
             None if offsets is None else np.asarray(offsets, np.int32))
     registry.counter("fused_enqueues").increment()
+    registry.counter("fused_enqueue_sets").increment(sets)
     registry.counter("fused_enqueue_uploads").increment(
         sum(x is not None for x in host))
     return jax.device_put(host, device)     # None is an empty pytree
@@ -564,35 +566,76 @@ def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
         out_refs[1][:] += _dot_1p(onehot, pres)
 
 
-def _run_shape_sig(vals_p, plan, Gp: int, kind: str, ragged: bool) -> str:
+def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool) -> str:
     """The compile-cache shape signature recorded with jit compile
     events (utils/devicetelem): the padded dims + static flags that key
-    the trace cache, so a recompile storm names the shape that drove it."""
-    Sp, Tp = vals_p.shape
-    return (f"S{Sp}xT{Tp}xW{plan.t1.shape[1]}xG{Gp}:{kind}"
-            + (":ragged" if ragged else ""))
+    the trace cache, so a recompile storm names the shape that drove it.
+    A call of several sets names their summed rows and groups and how
+    many they were."""
+    Sp = sum(vals_p.shape[0] for vals_p, _, _ in sets)
+    return (f"S{Sp}xT{plan.Tp}xW{plan.t1.shape[1]}xG{sum(num_groups)}:{kind}"
+            + (":ragged" if ragged else "")
+            + (f":{len(sets)}sets" if len(sets) > 1 else ""))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
     "kind", "ragged", "per_series"))
-def _run(vals_p, vbase_p, gids, offsets, rows, tsrow, *,
-         num_groups: int, is_counter: bool, is_rate: bool,
+def _run(sets, offsets, rows, tsrow, *,
+         num_groups: Tuple[int, ...], is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
          ragged: bool = False, per_series: bool = False):
-    """One fused dispatch, whole: the group merge (merge_gid_cols), the
-    plan's kernel operands (kernel_operands) and the Pallas call.  `gids`
-    is a tuple of gid columns with `offsets` their traced int32 group-id
-    shifts (None for one operand), so a new group count compiles
-    nothing; `rows` / `tsrow` are the plan's uploaded rows."""
+    """One fused dispatch, whole: the plan's kernel operands
+    (kernel_operands), built once, then for every working set of `sets`
+    its group merge (merge_gid_cols) and its own Pallas call, in one
+    trace.  A set is `(vals_p, vbase_p, gid columns)` with its padded
+    group count in `num_groups`; `offsets` holds the traced int32
+    group-id shifts of every set's columns one after the other (None
+    when no set has more than one), so a new group count compiles
+    nothing; `rows` / `tsrow` are the plan's uploaded rows, shared by all
+    sets.  Returns the sets' outputs concatenated on the group axis (a
+    pair of them when `ragged`): set i's rows start at
+    sum(num_groups[:i]).  A set's block is what a call of that set alone
+    returns, bit for bit: the sets meet only in the concatenation."""
+    Tp = sets[0][0].shape[1]
+    operands = kernel_operands(rows, tsrow, Tp, kind)
+    outs, p0 = [], 0
+    for (vals_p, vbase_p, gids), Gp in zip(sets, num_groups):
+        offs = None
+        if offsets is not None and len(gids) > 1:
+            offs = offsets[p0:p0 + len(gids)]
+        p0 += len(gids)
+        outs.append(_run_set(
+            vals_p, vbase_p, merge_gid_cols(gids, offs), operands,
+            rows.shape[1], Gp, is_counter=is_counter, is_rate=is_rate,
+            with_drops=with_drops, interpret=interpret, kind=kind,
+            ragged=ragged, per_series=per_series))
+    if len(outs) == 1:
+        return tuple(outs[0]) if ragged else outs[0]
+    if ragged:
+        return tuple(jnp.concatenate([o[k] for o in outs], axis=0)
+                     for k in (0, 1))
+    return jnp.concatenate(outs, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "Wp", "Gp", "is_counter", "is_rate", "with_drops", "interpret", "kind",
+    "ragged", "per_series"))
+def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int, *,
+             is_counter: bool, is_rate: bool, with_drops: bool,
+             interpret: bool, kind: str, ragged: bool, per_series: bool):
+    """One working set's Pallas call inside `_run`'s trace: its own
+    series block, grid and group count over the shared plan operands.
+    Called from `_run` and nowhere else.  It is a jit only so that sets
+    of one shape are traced ONCE inside that trace: a request's 30 sets
+    sit on five rungs of the row ladder, and tracing the kernel body 30
+    times is 1.5 s under the interpreter lock at every process start
+    (0.2 s so; no persistent cache keeps a trace).  The device program
+    is still `_run`'s alone."""
     from jax.experimental.pallas import tpu as pltpu
 
     Sp, Tp = vals_p.shape
-    Wp = rows.shape[1]
-    Gp = num_groups
-    gids_p = merge_gid_cols(gids, offsets)
-    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = kernel_operands(
-        rows, tsrow, Tp, kind)
+    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = operands
     # adaptive series block: the ragged rate family's scan temporaries
     # scale with bs*Tp, so long rows shrink the block instead of OOMing
     # scoped vmem (or being rejected by the eligibility gate).  All
@@ -742,9 +785,11 @@ def can_fuse(fn_name: str, agg_op: str, shared_grid: bool,
 
 # traceable entry for callers composing the kernel inside shard_map (the
 # mesh executor); the jit wrapper inlines under an enclosing trace.
-# gids_p is one [Sp, P] matrix, merged by the caller.
-def run_kernel(vals_p, vbase_p, gids_p, rows, tsrow=None, **kw):
-    return _run(vals_p, vbase_p, (gids_p,), None, rows, tsrow, **kw)
+# One working set; gids_p is one [Sp, P] matrix, merged by the caller.
+def run_kernel(vals_p, vbase_p, gids_p, rows, tsrow=None, *,
+               num_groups: int, **kw):
+    return _run(((vals_p, vbase_p, (gids_p,)),), None, rows, tsrow,
+                num_groups=(num_groups,), **kw)
 
 
 class PreparedInputs(NamedTuple):
@@ -834,11 +879,7 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
     path (parallel/mesh.py), which runs this exact function once per
     device and merges the [G, W] partials it returns.
     """
-    is_counter = fn_name in ("rate", "increase")
-    is_rate = fn_name == "rate"
-    with_drops = is_counter and not precorrected
     over_time = fn_name in OVER_TIME_FNS
-    kind = fn_name if over_time else "rate_family"
     if prepared is None:
         prepared = pad_inputs(vals, vbase, gids, plan, num_groups,
                               device=device)
@@ -847,17 +888,10 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
         # mode) — put the plan rows on the same chip
         device = _committed_device(prepared.vals_p)
     Gp = pad_group_count(num_groups)
-    from filodb_tpu.utils.devicetelem import watched_call
-    rows, tsrow, _ = enqueue_operands(plan, device, kind, ragged)
-    res = watched_call(
-        "fused_run", _run,
-        _run_shape_sig(prepared.vals_p, plan, Gp, kind, ragged),
-        lambda: _run(prepared.vals_p, prepared.vbase_p,
-                     (prepared.gids_p,), None, rows, tsrow,
-                     num_groups=Gp, is_counter=is_counter,
-                     is_rate=is_rate, with_drops=with_drops,
-                     interpret=interpret, kind=kind, ragged=ragged),
-        device=device)
+    res, _ = _enqueue_run(
+        plan, device, ((prepared.vals_p, prepared.vbase_p,
+                        (prepared.gids_p,)),), None, (Gp,),
+        **_flavor(fn_name, precorrected, interpret, ragged)._asdict())
     if ragged:
         sums, cnts = res
         counts = np.asarray(cnts, np.float64)[:num_groups, :plan.W]
@@ -1072,11 +1106,164 @@ def _per_series_aggs(res, rows, gids, *, ops, num_groups, S: int, W: int,
                  for op, g, G in zip(ops, gids, num_groups))
 
 
+def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
+                 **flags):
+    """One `_run` dispatch: the small host operands put explicitly
+    (enqueue_operands), then the one jit call over `sets`, compile-
+    watched.  -> (the call's lazy result, the uploaded rows)."""
+    from filodb_tpu.utils.devicetelem import watched_call
+    from filodb_tpu.utils.metrics import span_part
+    kind, ragged = flags["kind"], flags["ragged"]
+    with span_part("leaf.enqueue_pack"):
+        rows, tsrow, offs = enqueue_operands(plan, device, kind, ragged,
+                                             offsets, sets=len(sets))
+    with span_part("leaf.enqueue_jit"):
+        res = watched_call(
+            "fused_run", _run,
+            _run_shape_sig(sets, plan, num_groups, kind, ragged),
+            lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
+                         **flags),
+            device=device)
+    return res, rows
+
+
+class _FlavorFlags(NamedTuple):
+    is_counter: bool
+    is_rate: bool
+    with_drops: bool
+    interpret: bool
+    kind: str
+    ragged: bool
+
+
+def _flavor(fn_name: str, precorrected: bool, interpret: bool,
+            ragged: bool) -> _FlavorFlags:
+    """`_run`'s static flags of one (function, precorrected, interpret,
+    ragged) flavor."""
+    is_counter = fn_name in ("rate", "increase")
+    return _FlavorFlags(
+        is_counter, fn_name == "rate", is_counter and not precorrected,
+        interpret, fn_name if fn_name in OVER_TIME_FNS else "rate_family",
+        ragged)
+
+
+class FusedDispatch:
+    """The group-mode panels (`sum`, `avg`, ragged `count`) of the working
+    sets that share a plan, a function flavor and a device, as ONE `_run`
+    call, ONE blocking readback and ONE presentation: `add` each set's
+    panels (fused_leaf_agg_batch does), `enqueue` once, `fetch` once,
+    then `comps(k)` hands set k its panels' [G, W, C] partials, views of
+    the one array.  What a request's shard leaves cost the host is then
+    one dispatch, not one a shard (ROADMAP A1 [A7]); one set alone is
+    the same path.
+
+    The sets of a call are ordered by (Sp, Gp, panels) before the jit
+    call, so the trace cache is keyed by the multiset of their shapes
+    and not by the order the leaves were prepared in.  Every set is its
+    own Mosaic kernel in the one XLA program; 30 sets compile cold in
+    8.7 to 9.3 s on the chip's host against the server's 120 s query
+    budget (PERF.md section 6, PR 36), so a call is never split."""
+
+    def __init__(self, plan: FusedPlan, fn_name: str,
+                 precorrected: bool = False, interpret: bool = False,
+                 ragged: bool = False, device=None):
+        self.plan = plan
+        self.key = (fn_name, precorrected, interpret, ragged)
+        self.device = device
+        self.flags = _flavor(*self.key)
+        self._sets: list = []       # (values, [(groups, G, op)], offsets)
+        self._res = None
+        self._counts = None         # dense rows: [rows, W] f64
+        self._lo: list = []         # per set: its panels' first rows
+        self._comps = None
+
+    def __len__(self):
+        return len(self._sets)
+
+    def add(self, values: PaddedValues, panels) -> int:
+        """Queue one working set's group-mode panels
+        [(PaddedGroups, num_groups, agg_op)]; -> its index for `comps`."""
+        counts = [int(G) for _, G, _ in panels]
+        self._sets.append((values, list(panels),
+                           np.cumsum([0] + counts[:-1])))
+        return len(self._sets) - 1
+
+    def enqueue(self) -> None:
+        """Issue the one jit call over everything added; reads nothing
+        back.  Nothing added (every panel min/max or a dense count): no
+        call."""
+        if not self._sets or self._res is not None:
+            return
+        totals = [int(offs[-1]) + int(panels[-1][1])
+                  for _, panels, offs in self._sets]
+        gps = [pad_group_count(G) for G in totals]
+        order = sorted(range(len(self._sets)), key=lambda k: (
+            self._sets[k][0].vals_p.shape[0], gps[k],
+            len(self._sets[k][1])))
+        sets, pieces, offsets = [], [], []
+        self._lo = [None] * len(order)
+        base = 0
+        for k in order:
+            values, panels, offs = self._sets[k]
+            sets.append((values.vals_p, values.vbase_p,
+                         tuple(g.gids_p for g, _, _ in panels)))
+            offsets.append(offs)
+            self._lo[k] = base + offs
+            # the set's rows of the output: its panels' groups, then pad
+            pieces += [g.gsize for g, _, _ in panels]
+            pieces.append(np.zeros(gps[k] - totals[k], np.int64))
+            base += gps[k]
+        multi = any(len(o) > 1 for o in offsets)
+        self._res, _ = _enqueue_run(
+            self.plan, self.device, tuple(sets),
+            np.concatenate(offsets) if multi else None,
+            tuple(gps[k] for k in order), **self.flags._asdict())
+        if not self.flags.ragged:
+            # dense rows: the counts are |group| x the shared window
+            # validity, nothing of the result: made while the device works
+            plan = self.plan
+            wvalid = (plan.wvalid1 if self.flags.kind in OVER_TIME_FNS
+                      else plan.wvalid)
+            self._counts = np.concatenate(pieces).astype(np.float64)[
+                :, None] * wvalid[None, :].astype(np.float64)
+
+    def fetch(self) -> None:
+        """The one synchronizing readback, and the panels' presentation
+        over the whole array: sums masked to the present cells beside
+        their counts (the kernel's presence output on ragged rows, else
+        |group| x the shared window validity), f64 on the host."""
+        if self._comps is not None or self._res is None:
+            return
+        W = self.plan.W
+        if self.flags.ragged:
+            sums, counts = (r[:, :W] for r in jax.device_get(self._res))
+        else:
+            sums, counts = np.asarray(self._res)[:, :W], self._counts
+        # f32 sums x 0/1 and f32 counts widen exactly: written straight
+        # into the f64 [rows, W, 2] block (at 6 in flight every NumPy
+        # call that lets the interpreter lock go costs a hand-off)
+        comps = np.empty(sums.shape + (2,), np.float64)
+        np.multiply(sums, counts > 0, out=comps[..., 0])
+        comps[..., 1] = counts
+        self._comps = comps
+
+    def comps(self, k: int) -> list:
+        """Set k's per-panel [G, W, C] partials (ops/agg.AGGREGATORS
+        layout), in the order they were added."""
+        self.fetch()
+        out = []
+        for (_, G, op), lo in zip(self._sets[k][1], self._lo[k]):
+            blk = self._comps[lo:lo + G]
+            out.append(blk[..., 1:] if op == "count" else blk)
+        return out
+
+
 def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
                          fn_name: str, precorrected: bool = False,
                          interpret: bool = False, ragged: bool = False,
                          num_series: Optional[int] = None,
-                         lazy: bool = False):
+                         lazy: bool = False,
+                         dispatch: Optional[FusedDispatch] = None):
     """Evaluate P aggregation panels over ONE working set in at most two
     kernel dispatches — the dashboard case (same metric + window grid,
     different `by (...)` groupings / agg ops), where the per-call
@@ -1092,42 +1279,29 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
     host-only math.  Returns per-panel [G, W, C] float64 components in
     input order (ops/agg.AGGREGATORS layout).
 
+    `dispatch`: a FusedDispatch of the same plan, flavor and device that
+    the CALLER enqueues once every working set has been through here:
+    this set's group-mode run then rides that one call beside the other
+    sets' (query/fusedbatch.py: a request's shard leaves).  Without one
+    the set is a call of its own, enqueued before returning.
+
     lazy=True returns a zero-arg finisher instead: the kernel work is
-    DISPATCHED before returning, but the synchronizing host readback
-    waits until the finisher is called — so a multi-shard batch whose
-    working sets live on different chips (sharded DeviceMirror mode)
-    dispatches everything first and the chips compute concurrently."""
-    is_counter = fn_name in ("rate", "increase")
-    is_rate = fn_name == "rate"
-    with_drops = is_counter and not precorrected
+    DISPATCHED before returning (or by the caller's `dispatch.enqueue`),
+    but the synchronizing host readback waits until the finisher is
+    called — so a multi-shard batch whose working sets live on different
+    chips (sharded DeviceMirror mode) dispatches everything first and
+    the chips compute concurrently."""
     over_time = fn_name in OVER_TIME_FNS
-    kind = fn_name if over_time else "rate_family"
     wvalid = plan.wvalid1 if over_time else plan.wvalid
-
-    # sharded DeviceMirror mode: the working set is committed to its
-    # shard's chip — the plan rows go there too, so the call runs there
-    device = _committed_device(values.vals_p)
-    from filodb_tpu.utils.metrics import span_part
-
-    def run(cols, offsets, Gp, per_series):
-        """One `_run` dispatch over the panels' gid columns: the small
-        host operands put explicitly, then the one jit call."""
-        from filodb_tpu.utils.devicetelem import watched_call
-        with span_part("leaf.enqueue_pack"):
-            rows, tsrow, offs = enqueue_operands(plan, device, kind,
-                                                 ragged, offsets)
-        with span_part("leaf.enqueue_jit"):
-            res = watched_call(
-                "fused_run", _run,
-                _run_shape_sig(values.vals_p, plan, Gp, kind, ragged),
-                lambda: _run(values.vals_p, values.vbase_p, cols, offs,
-                             rows, tsrow,
-                             num_groups=Gp, is_counter=is_counter,
-                             is_rate=is_rate, with_drops=with_drops,
-                             interpret=interpret, kind=kind, ragged=ragged,
-                             per_series=per_series),
-                device=device)
-        return res, rows
+    own = dispatch is None
+    if own:
+        # sharded DeviceMirror mode: the working set is committed to its
+        # shard's chip — the plan rows go there too, so the call runs there
+        dispatch = FusedDispatch(plan, fn_name, precorrected, interpret,
+                                 ragged, _committed_device(values.vals_p))
+    elif dispatch.plan is not plan or dispatch.key != (
+            fn_name, precorrected, interpret, ragged):
+        raise ValueError("fused dispatch shared across plans or flavors")
 
     def dense_counts(groups):
         return groups.gsize[:, None].astype(np.float64) * \
@@ -1143,24 +1317,27 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
         raise ValueError(f"unsupported fused agg {bad[0]}")
 
     out: list = [None] * len(panels)
-    # ---- dispatch phase: every device call is issued here, nothing is
+    # ---- dispatch phase: every device call is issued here (the group-
+    # mode one by the caller when the dispatch is shared), nothing is
     # read back — all results below are lazy device arrays
-    mm_res = offsets = None
+    slot = None
     if mm_idx:
-        counts = [int(panels[i][1]) for i in mm_idx]
-        offsets = np.cumsum([0] + counts[:-1])
-        mm_res, _ = run(tuple(panels[i][0].gids_p for i in mm_idx),
-                        offsets if len(mm_idx) > 1 else None,
-                        pad_group_count(sum(counts)), per_series=False)
+        slot = dispatch.add(values, [panels[i] for i in mm_idx])
+        if own:
+            dispatch.enqueue()
     ps_comps = ()
     if ps_idx:
+        from filodb_tpu.utils.metrics import span_part
         S = num_series
         if S is None:
             gp0 = panels[ps_idx[0]][0].gids_p[:, 0]
             S = int(np.asarray(gp0 >= 0).sum())
         # one shared per-series run: the [S, W] output is group-agnostic
-        res, rows = run((panels[ps_idx[0]][0].gids_p,), None, 8,
-                        per_series=True)
+        res, rows = _enqueue_run(
+            plan, dispatch.device,
+            ((values.vals_p, values.vbase_p,
+              (panels[ps_idx[0]][0].gids_p,)),), None, (8,),
+            per_series=True, **dispatch.flags._asdict())
         with span_part("leaf.enqueue_jit"):
             ps_comps = _per_series_aggs(
                 res, rows, tuple(panels[i][0].gids_p for i in ps_idx),
@@ -1170,24 +1347,9 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
 
     # ---- finish phase: synchronizing host readbacks + assembly
     def finish():
-        if mm_idx:
-            if ragged:
-                sums_all, cnts_all = (np.asarray(r, np.float64)
-                                      for r in mm_res)
-            else:
-                sums_all = np.asarray(mm_res, np.float64)
-                cnts_all = None
-            for j, i in enumerate(mm_idx):
-                groups, G, op = panels[i]
-                lo = offsets[j]
-                sums = sums_all[lo:lo + G, :plan.W]
-                counts = (cnts_all[lo:lo + G, :plan.W] if ragged
-                          else dense_counts(groups))
-                if op == "count":
-                    out[i] = counts[..., None]
-                else:
-                    out[i] = np.stack([sums * (counts > 0), counts],
-                                      axis=-1)
+        if slot is not None:
+            for i, comp in zip(mm_idx, dispatch.comps(slot)):
+                out[i] = comp
         for i, comp in zip(ps_idx, ps_comps):
             out[i] = np.asarray(comp, np.float64)
         for i, (groups, G, op) in enumerate(panels):

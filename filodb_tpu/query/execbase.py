@@ -194,13 +194,21 @@ class _FusedCache(dict):
                          result="miss" if val is None else "hit").increment()
         return val
 
-    def insert(self, key, val) -> None:
-        """Add an entry; where keys carry generations, first drop the
+    def insert(self, key, val):
+        """Add an entry and return the one the cache holds: a key that
+        another thread filled since the caller's miss keeps ITS entry, so
+        concurrent builders of one key end up sharing one object (the
+        leaves of a request ride one device call only while they hold
+        the same plan object: query/fusedbatch.py).  Where keys carry
+        generations, first drop the
         entries of the same mirror (key[0]) under another snapshot
         generation (key[1]): each pins device arrays that nothing can ask
         for again.  Then the oldest entries go until the cache fits its
         budget."""
         from filodb_tpu.utils.metrics import registry
+        held = self.get(key)
+        if held is not None:
+            return held
         if self._generations:
             stale = [k for k in self if k[0] == key[0] and k[1] != key[1]]
             for k in stale:
@@ -220,6 +228,7 @@ class _FusedCache(dict):
         registry.gauge("fused_cache_bytes", cache=self.name).update(held)
         registry.gauge("fused_cache_entries",
                        cache=self.name).update(len(self))
+        return val
 
 
 def _plan_nbytes(plan) -> int:

@@ -56,12 +56,17 @@ class FusedCall:
 
 
 def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
-    """Phase-2 of engine.query_range_batch: dispatch every FusedCall,
-    merging compatible ones into single kernel launches.  A merged set
-    whose combined group count would blow the VMEM budget is split back
-    into singleton dispatches instead of degrading to the general path
-    (the per-panel gate in _try_fused already passed)."""
+    """Phase-2 of engine.query_range_batch and of a single request's
+    hoisted leaves: dispatch every FusedCall.  Compatible calls (one
+    working set) merge into one kernel run; a merged set whose combined
+    group count would blow the VMEM budget is split back into singleton
+    runs instead of degrading to the general path (the per-panel gate in
+    _try_fused already passed).  The sets that share a plan object, a
+    flavor and a device then ride ONE device program and ONE readback
+    (pf.FusedDispatch): a request's shard leaves cost the host one
+    dispatch, not one a shard."""
     from filodb_tpu.ops import pallas_fused as pf
+    from filodb_tpu.utils.metrics import registry
     out: List[Optional[AggPartial]] = [None] * len(calls)
     # dedup identical panels first — a quantile dashboard's p50/p90/p99
     # queries differ only ABOVE the leaf (histogram_quantile transformer),
@@ -75,7 +80,6 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         else:
             prim[k] = i
     if alias:
-        from filodb_tpu.utils.metrics import registry
         registry.counter("fused_batch_deduped").increment(len(alias))
     by_key: Dict[tuple, List[int]] = {}
     for i, fc in enumerate(calls):
@@ -86,26 +90,19 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         # histogram panels aggregate over (group, bucket) SLOTS
         return len(calls[i].gkeys) * calls[i].num_buckets
 
-    # two-phase execution: phase A dispatches every merged set's kernel
-    # work WITHOUT reading anything back, phase B synchronizes.  With
-    # sharded DeviceMirrors a multi-shard query's leaves hold their
-    # working sets on different chips — dispatching everything first
-    # lets those chips compute concurrently instead of serializing on
-    # each set's host readback (the per-device dispatch contract,
-    # doc/multichip.md).
-    pending = []
+    def in_group_mode(i):
+        # which panels join the merged group-mode dispatch: min/max
+        # run per-series (Gp-independent) and dense count is host
+        # math, so neither counts toward the multi-hot group total
+        op = calls[i].op
+        return op in ("sum", "avg") or (op == "count" and calls[i].ragged)
+
+    # the working sets: per compat key the panels one kernel run can hold
+    sets: List[List[int]] = []
     for idxs in by_key.values():
         fc0 = calls[idxs[0]]
         while idxs:
             take = idxs
-
-            def in_group_mode(i):
-                # which panels join the merged group-mode dispatch: min/max
-                # run per-series (Gp-independent) and dense count is host
-                # math, so neither counts toward the multi-hot group total
-                op = calls[i].op
-                return op in ("sum", "avg") or (op == "count" and fc0.ragged)
-
             if len(idxs) > 1:
                 Tp = fc0.plan.Tp
                 Wp = pf._pad_to(max(fc0.plan.W, 1), pf._LANE)
@@ -121,13 +118,10 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
                             panels=max(n_group, 1)) is not None:
                         break
                     take = take[:max(1, len(take) // 2)]
-            panels = [(calls[i].groups, slots(i), calls[i].op)
-                      for i in take]
             if len(take) > 1:
                 # observability of the batching win: actual kernel
                 # launches this merged set costs (group-mode + per-series
                 # mode), and how many panels shared them
-                from filodb_tpu.utils.metrics import registry
                 launches = (any(in_group_mode(i) for i in take)
                             + any(calls[i].op in ("min", "max")
                                   for i in take))
@@ -135,34 +129,72 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
                     .increment(launches)
                 registry.counter("fused_batch_merged_panels") \
                     .increment(len(take))
-            with span("leaf.kernel_enqueue") as enqueue:
-                finisher = pf.fused_leaf_agg_batch(
-                    fc0.plan, fc0.values, panels, fc0.fn,
-                    precorrected=fc0.precorrected, interpret=fc0.interpret,
-                    ragged=fc0.ragged, num_series=fc0.num_series, lazy=True)
-            pending.append((take, finisher, enqueue.dur_s))
+            sets.append(take)
             idxs = idxs[len(take):]
+
+    # one device program for the sets that share a plan, a flavor and a
+    # device: a request's shard leaves (one plan object since PR 35, the
+    # shards' working sets on one chip) are ONE jit call and ONE readback,
+    # not one of each a shard.  Sharded DeviceMirrors give each chip its
+    # own call.
+    by_call: Dict[tuple, List[List[int]]] = {}
+    for take in sets:
+        fc0 = calls[take[0]]
+        by_call.setdefault(
+            (id(fc0.plan), fc0.fn, fc0.precorrected, fc0.interpret,
+             fc0.ragged, pf._committed_device(fc0.values.vals_p)),
+            []).append(take)
+
+    # two-phase execution: phase A dispatches every call's kernel work
+    # WITHOUT reading anything back, phase B synchronizes.  With sharded
+    # DeviceMirrors a multi-shard query's leaves hold their working sets
+    # on different chips — dispatching everything first lets those chips
+    # compute concurrently instead of serializing on each call's host
+    # readback (the per-device dispatch contract, doc/multichip.md).
+    pending = []
+    for (*_, device), takes in by_call.items():
+        fc0 = calls[takes[0][0]]
+        with span("leaf.kernel_enqueue") as enqueue:
+            disp = pf.FusedDispatch(fc0.plan, fc0.fn, fc0.precorrected,
+                                    fc0.interpret, fc0.ragged, device)
+            finishers = []
+            for take in takes:
+                fc = calls[take[0]]
+                finishers.append(pf.fused_leaf_agg_batch(
+                    fc.plan, fc.values,
+                    [(calls[i].groups, slots(i), calls[i].op)
+                     for i in take], fc.fn,
+                    precorrected=fc.precorrected, interpret=fc.interpret,
+                    ragged=fc.ragged, num_series=fc.num_series, lazy=True,
+                    dispatch=disp))
+            disp.enqueue()
+        pending.append((takes, finishers, disp, enqueue.dur_s))
     from filodb_tpu.utils.devicetelem import telem
-    for take, finisher, disp_s in pending:
-        # the np.asarray that blocks on the device and copies out
+    for takes, finishers, disp, disp_s in pending:
+        # the np.asarray that blocks on the device and copies out, once
+        # for the call, and the panels' presentation over the whole array
         with span("leaf.result_fetch") as fetch:
-            comps = finisher()
+            disp.fetch()
+            comps = [finisher() for finisher in finishers]
         with span("leaf.present"):
-            for i, comp in zip(take, comps):
-                out[i] = _present(calls[i], comp)
+            for take, set_comps in zip(takes, comps):
+                for i, comp in zip(take, set_comps):
+                    out[i] = _present(calls[i], comp)
         # kernel enqueue + result readback, attributed to the node that
         # triggered it AND recorded in the per-chip kernel ledger
         # (utils/devicetelem) — record_dispatch feeds the exec tally, so
         # QueryStats.device_seconds is the two spans' seconds
-        fc0 = calls[take[0]]
+        fc0 = calls[takes[0][0]]
         telem.record_dispatch(
-            f"fused_{fc0.fn}",
-            device=pf._committed_device(fc0.values.vals_p),
-            shape=(f"S{fc0.num_series}xW{len(fc0.wends)}"
-                   f"x{len(take)}p" + (":ragged" if fc0.ragged else "")),
+            f"fused_{fc0.fn}", device=disp.device,
+            shape=(f"S{sum(calls[t[0]].num_series for t in takes)}"
+                   f"xW{len(fc0.wends)}x{sum(map(len, takes))}p"
+                   f"x{len(takes)}s" + (":ragged" if fc0.ragged else "")),
             seconds=disp_s + fetch.dur_s,
-            bytes_in=int(getattr(fc0.values.vals_p, "nbytes", 0)),
-            bytes_out=sum(int(getattr(c, "nbytes", 0)) for c in comps))
+            bytes_in=sum(int(getattr(calls[t[0]].values.vals_p, "nbytes", 0))
+                         for t in takes),
+            bytes_out=sum(int(getattr(c, "nbytes", 0))
+                          for set_comps in comps for c in set_comps))
     for i, j in alias.items():
         src = out[j]
         out[i] = dataclasses.replace(src) if src is not None else None

@@ -276,7 +276,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                                      eval_wends, t0.window_ms)
             if key is not None:
                 with _FUSED_CACHE_LOCK:
-                    _FUSED_PLAN_CACHE.insert(plan_key, plan)
+                    # the first build of a grid stays: a request whose
+                    # leaves held two equal plans would be two device calls
+                    plan = _FUSED_PLAN_CACHE.insert(plan_key, plan)
         if gkeys is None:
             with span("leaf.group_ids"):
                 gids, gkeys = _group_ids_cached(data.cache_token, data.keys,

@@ -68,25 +68,29 @@ def _sds(shape, dtype, sharding):
 
 
 def _compile_run(one_chip, S, G, fn, ragged=False, panels=1):
-    """Lower + compile pallas_fused._run exactly as fused_leaf_agg_batch
-    calls it (interpret=False)."""
+    """Lower + compile pallas_fused._run exactly as a FusedDispatch calls
+    it (interpret=False).  `S` and `G` may be tuples: one working set
+    each, all in the one program."""
     plan = _plan()
-    over_time = fn in pf.OVER_TIME_FNS
-    kind = fn if over_time else "rate_family"
-    Sp, Gp = pf.pad_series_count(S), pf.pad_group_count(G * panels)
-    with_ts = ragged and kind == "rate_family"
-    args = [_sds((Sp, plan.Tp), jnp.float32, one_chip),
-            _sds((Sp, 1), jnp.float32, one_chip),
-            (_sds((Sp, 1), jnp.int32, one_chip),) * panels,
-            _sds((panels,), jnp.int32, one_chip) if panels > 1 else None,
+    # precorrected (no drop correction in the kernel), as the mirror serves
+    flags = pf._flavor(fn, True, False, ragged)
+    Ss = S if isinstance(S, tuple) else (S,)
+    Gs = G if isinstance(G, tuple) else (G,) * len(Ss)
+    with_ts = ragged and flags.kind == "rate_family"
+    sets = tuple(
+        (_sds((pf.pad_series_count(s), plan.Tp), jnp.float32, one_chip),
+         _sds((pf.pad_series_count(s), 1), jnp.float32, one_chip),
+         (_sds((pf.pad_series_count(s), 1), jnp.int32, one_chip),) * panels)
+        for s in Ss)
+    args = [sets,
+            _sds((panels * len(Ss),), jnp.int32, one_chip) if panels > 1
+            else None,
             _sds(plan.rows.shape, jnp.float32, one_chip),
             _sds(plan.tsrow.shape, jnp.float32, one_chip) if with_ts
             else None]
-    is_counter = fn in ("rate", "increase")
     return pf._run.lower(
-        *args, num_groups=Gp, is_counter=is_counter, is_rate=fn == "rate",
-        with_drops=False, interpret=False, kind=kind,
-        ragged=ragged).compile()
+        *args, num_groups=tuple(pf.pad_group_count(g * panels) for g in Gs),
+        **flags._asdict()).compile()
 
 
 def _check(compiled, pallas: bool):
@@ -114,13 +118,21 @@ def _check(compiled, pallas: bool):
     # histdev-64b-4k's largest shard: 1,235 series x 64 buckets are kernel
     # rows, 10 groups x 64 buckets are (group, bucket) slots
     (1_235 * 64, 10 * 64, "rate", False, 1),
+    # one request's working sets in ONE program (ISSUE 36): the 4-shard
+    # cells' four, mixed group counts; two sets of three panels each
+    ((79_042, 78_244, 52_281, 52_577), (10, 10, 1, 20), "rate", False, 1),
+    ((79_042, 52_281), 10, "sum_over_time", True, 3),
 ], ids=["rate-1M", "rate-262k", "rate-ragged", "delta-ragged", "sum_ot",
         "sum_ot-ragged", "avg_ot", "rate-G8192", "rate-3panels",
-        "rate-hist64"])
+        "rate-hist64", "rate-4sets", "sum_ot-ragged-2sets-3panels"])
 def test_fused_kernel_compiles_for_v5e(one_chip, chip_runtime,
                                        S, G, fn, ragged, panels):
-    ma = _check(_compile_run(one_chip, S, G, fn, ragged, panels),
-                pallas=True)
+    compiled = _compile_run(one_chip, S, G, fn, ragged, panels)
+    ma = _check(compiled, pallas=True)
+    if isinstance(S, tuple):
+        # every set keeps its own Pallas call inside the one program
+        assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
+            == len(S)
     if S == S_FLAGSHIP:
         # the two [Sp, 1] column operands (vbase_p, gids_p) tile to 1 KiB
         # per row: recorded, not repaired here (ISSUE 24)

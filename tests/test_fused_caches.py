@@ -133,6 +133,21 @@ def ask_open(rig, n):
     time.sleep(0.3)         # the last request's spans are booked behind it
 
 
+@pytest.mark.parametrize("name", CACHES)
+def test_the_first_insert_of_a_key_stays_and_insert_says_what_is_held(name):
+    """Two threads that missed one key both build: the second insert gets
+    the first's entry back and leaves the cache as it was, so both go on
+    with ONE object (a request's leaves ride one device call only while
+    they hold the same plan object, ISSUE 36)."""
+    cache = cache_of(name, 100)
+    first, second = (25, "first"), (25, "second")
+    assert cache.insert(("k", 1), first) is first
+    held = gauge("fused_cache_bytes", name)
+    assert cache.insert(("k", 1), second) is first
+    assert cache.lookup(("k", 1)) is first and len(cache) == 1
+    assert gauge("fused_cache_bytes", name) == held == 25
+
+
 def misses():
     return {c: lookups(c, "miss") for c in CACHES}
 
@@ -164,6 +179,45 @@ def test_the_leaves_of_a_request_share_one_plan_build(rig):
     assert registry.counter("span_leaf_build_plan_calls").value - built == 1
     with _FUSED_CACHE_LOCK:
         assert all(k[0] == "plan" for k in execbase._FUSED_PLAN_CACHE)
+
+
+def test_two_requests_that_build_one_grids_plan_at_once_are_a_call_each(
+        rig, monkeypatch):
+    """The six panels of an open share a grid and are in flight together:
+    when two of them miss the plan cache at once, both build, the first
+    insert stays, and every leaf of BOTH requests holds that one object:
+    each request is still one device call of 30 working sets."""
+    import threading
+    from filodb_tpu.ops import pallas_fused as pf
+    with _FUSED_CACHE_LOCK:
+        execbase._FUSED_PLAN_CACHE.clear()
+    real, gate = pf.build_plan, threading.Barrier(2, timeout=60)
+
+    def build_plan(*a, **kw):
+        gate.wait()                  # both requests are past their miss
+        return real(*a, **kw)
+
+    monkeypatch.setattr(pf, "build_plan", build_plan)
+    time.sleep(0.3)
+    enq = registry.counter("fused_enqueues").value
+    sets = registry.counter("fused_enqueue_sets").value
+    built = registry.counter("span_leaf_build_plan_calls").value
+    reqs, out = rig.open(2)[:2], []
+    threads = [threading.Thread(target=lambda r=r: out.append(rig.ask(r)))
+               for r in reqs]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    assert len(out) == 2 and all(why is None and err <= TOL
+                                 for (err, why), _ in out)
+    time.sleep(0.3)
+    assert registry.counter("span_leaf_build_plan_calls").value - built == 2
+    assert registry.counter("fused_enqueues").value - enq == 2
+    assert registry.counter("fused_enqueue_sets").value - sets \
+        == 2 * rig.populated
+    with _FUSED_CACHE_LOCK:
+        assert len(execbase._FUSED_PLAN_CACHE) == 1
 
 
 def test_a_budget_of_three_working_sets_answers_right_and_keeps_the_newest(

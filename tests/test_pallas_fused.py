@@ -912,3 +912,144 @@ def test_merge_gid_cols_identity_and_traced_offsets():
               g, "sum") for g in (6, 2, 11)]                # same Gp = 24
     pf.fused_leaf_agg_batch(plan, values, other, **kw)
     assert pf._run._cache_size() == compiled
+
+
+# ------------------------------------------- one call for several working sets
+# (ISSUE 36: a request's shard leaves share a plan and a device, so their
+# group-mode runs are ONE `_run` call and ONE readback)
+
+def _sets_case(fn, ragged, specs):
+    """[(values, panels)] over ONE plan object, one working set a spec
+    (S, aggs, hist_buckets): mixed row rungs, group counts and panel
+    counts, as a request's shards bring them."""
+    plan, kw, sets = None, None, []
+    for S, aggs, hist in specs:
+        p, values, panels, kw, _ = _batch_case(fn, aggs, ragged, S=S,
+                                               hist_buckets=hist)
+        plan = plan or p            # same grid: the rows are equal
+        assert p.rows.tobytes() == plan.rows.tobytes()
+        sets.append((values, panels))
+    return plan, sets, kw
+
+
+def _dispatch_sets(plan, sets, kw, order=None):
+    """The sets through ONE FusedDispatch, as fusedbatch runs them: every
+    set added, one enqueue, then each set's finisher."""
+    from filodb_tpu.ops import pallas_fused as pf
+    flavor = {k: kw[k] for k in ("precorrected", "interpret", "ragged")}
+    disp = pf.FusedDispatch(plan, kw["fn_name"], **flavor)
+    order = list(order or range(len(sets)))
+    fins = {k: pf.fused_leaf_agg_batch(plan, *sets[k], lazy=True,
+                                       dispatch=disp, **kw) for k in order}
+    disp.enqueue()
+    return [fins[k]() for k in range(len(sets))], disp
+
+
+_SETS = {
+    "dense-rate-mixed-rungs": ("rate", False, [
+        (96, ["sum"], 0), (300, ["sum", "avg"], 0), (600, ["sum"], 0),
+        (96, ["avg", "sum", "sum", "sum"], 0)]),
+    "dense-sum_ot-with-host-counts": ("sum_over_time", False, [
+        (96, ["sum", "count"], 0), (300, ["avg"], 0)]),
+    "ragged-sum_ot": ("sum_over_time", True, [
+        (96, ["sum", "avg", "count"], 0), (300, ["count"], 0),
+        (96, ["sum"], 0)]),
+    "ragged-rate": ("rate", True, [(64, ["sum"], 0),
+                                   (300, ["sum", "sum"], 0)]),
+    "histogram-slots-mixed-gp": ("rate", False, [
+        (96, ["sum"], 4), (300, ["sum", "sum"], 16), (96, ["sum"], 0)]),
+    "minmax-ride-beside": ("rate", False, [
+        (96, ["min", "sum"], 0), (300, ["max", "avg", "sum"], 0)]),
+    "one-set-alone": ("rate", False, [(96, ["sum", "avg"], 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SETS))
+def test_sets_in_one_call_are_bit_identical_to_a_call_each(case):
+    """Every panel's partial out of the merged call equals, bit for bit,
+    what its working set's own call returns; the merged call books ONE
+    enqueue for its group-mode sets (a min/max set adds its per-series
+    run, as it did alone) and the sets it carried."""
+    from filodb_tpu.ops import pallas_fused as pf
+    from filodb_tpu.utils.metrics import registry
+    fn, ragged, specs = _SETS[case]
+    plan, sets, kw = _sets_case(fn, ragged, specs)
+    want = [pf.fused_leaf_agg_batch(plan, v, p, **kw) for v, p in sets]
+    e0, _ = _enqueue_counts()
+    s0 = registry.counter("fused_enqueue_sets").value
+    got, disp = _dispatch_sets(plan, sets, kw)
+    per_series = sum(any(op in ("min", "max") for _, _, op in p)
+                     for _, p in sets)
+    assert _enqueue_counts()[0] - e0 == 1 + per_series
+    assert registry.counter("fused_enqueue_sets").value - s0 \
+        == len(disp) + per_series
+    assert len(disp) == len(sets)
+    for w_set, g_set, (_, panels) in zip(want, got, sets):
+        assert len(w_set) == len(g_set) == len(panels)
+        for w, g in zip(w_set, g_set):
+            assert g.dtype == w.dtype == np.float64 and g.shape == w.shape
+            assert g.tobytes() == w.tobytes()
+
+
+def test_leaf_order_adds_no_entry_to_the_trace_cache():
+    """The sets of a call are ordered by shape before the jit call: the
+    same working sets prepared in another order are the same program, and
+    every set still gets its own rows back."""
+    from filodb_tpu.ops import pallas_fused as pf
+    plan, sets, kw = _sets_case("rate", False, [
+        (300, ["sum"], 0), (96, ["sum", "avg"], 0), (600, ["sum"], 0),
+        (96, ["sum"], 4), (300, ["avg"], 0)])
+    first, _ = _dispatch_sets(plan, sets, kw)
+    compiled = pf._run._cache_size()
+    for order in ([4, 3, 2, 1, 0], [2, 0, 4, 1, 3]):
+        again, _ = _dispatch_sets(plan, sets, kw, order=order)
+        assert pf._run._cache_size() == compiled, order
+        for a_set, f_set in zip(again, first):
+            for a, f in zip(a_set, f_set):
+                assert a.tobytes() == f.tobytes()
+
+
+def test_a_dispatch_is_not_shared_across_plans_or_flavors():
+    from filodb_tpu.ops import pallas_fused as pf
+    plan, sets, kw = _sets_case("rate", False, [(96, ["sum"], 0)])
+    other = pf.FusedDispatch(plan, "increase", kw["precorrected"],
+                             kw["interpret"], kw["ragged"])
+    with pytest.raises(ValueError, match="shared across"):
+        pf.fused_leaf_agg_batch(plan, *sets[0], dispatch=other, **kw)
+    empty = pf.FusedDispatch(plan, "rate", kw["precorrected"],
+                             kw["interpret"], kw["ragged"])
+    e0, _ = _enqueue_counts()
+    empty.enqueue()                          # nothing added: no call
+    assert _enqueue_counts()[0] == e0 and len(empty) == 0
+
+
+@pytest.mark.parametrize("fn,ragged", [("rate", False), ("sum_over_time", True),
+                                       ("rate", True)],
+                         ids=["dense", "ragged-sum_ot", "ragged-rate"])
+def test_the_one_presentation_is_the_per_panel_formula(fn, ragged):
+    """The whole-array presentation (f32 sums x present mask and the
+    counts, widened into one f64 block) gives every panel what the
+    per-panel finisher computed from a raw `_run` call: f64 sums masked
+    by `counts > 0`, stacked beside the f64 counts."""
+    from filodb_tpu.ops import pallas_fused as pf
+    plan, sets, kw = _sets_case(fn, ragged, [(96, ["sum", "avg"], 0),
+                                             (300, ["sum"], 4)])
+    got, _ = _dispatch_sets(plan, sets, kw)
+    flags = pf._flavor(kw["fn_name"], kw["precorrected"], True, ragged)
+    wvalid = plan.wvalid1 if flags.kind in pf.OVER_TIME_FNS else plan.wvalid
+    for (values, panels), g_set in zip(sets, got):
+        for (groups, G, _), g in zip(panels, g_set):
+            res = pf.run_kernel(
+                values.vals_p, values.vbase_p, groups.gids_p, plan.rows,
+                plan.tsrow if ragged and flags.kind == "rate_family" else None,
+                num_groups=pf.pad_group_count(G), **flags._asdict())
+            if ragged:
+                sums, counts = (np.asarray(r, np.float64)[:G, :plan.W]
+                                for r in res)
+            else:
+                sums = np.asarray(res, np.float64)[:G, :plan.W]
+                counts = groups.gsize[:, None].astype(np.float64) \
+                    * wvalid[None, :].astype(np.float64)
+            want = np.stack([sums * (counts > 0), counts], axis=-1)
+            assert g.dtype == want.dtype and g.shape == want.shape
+            assert g.tobytes() == want.tobytes()

@@ -274,3 +274,109 @@ def test_coalescer_separates_planner_params(fused_env):
     assert results["loose"].error is None
     assert results["tight"].error is not None \
         and "limit" in results["tight"].error
+
+
+# ----------------------------------------------- one device program a request
+# (ISSUE 36: the shard leaves of a request share a plan object and a device)
+
+def _fused_counts():
+    return {c: registry.counter(c).value for c in (
+        "fused_enqueues", "fused_enqueue_sets", "fused_enqueue_uploads",
+        "leaf_fused_kernel", "leaf_fused_errors")}
+
+
+def _delta(before):
+    after = _fused_counts()
+    return {c: after[c] - before[c] for c in before}
+
+
+@pytest.mark.parametrize("query,per_series", [
+    ('sum(rate(request_total{_ws_="demo"}[5m])) by (_ns_)', 0),
+    ('avg(rate(request_total{_ws_="demo"}[5m])) by (dc)', 0),
+    ('max(rate(request_total{_ws_="demo"}[5m])) by (dc)', 1),
+], ids=["sum", "avg", "max-stays-a-call-a-shard"])
+def test_a_four_shard_query_is_one_enqueue_of_four_sets(fused_env, query,
+                                                        per_series):
+    """Four shards, one request: the four leaves' group-mode runs are ONE
+    jit call carrying four working sets; a min/max leaf keeps its own
+    per-series run, one a shard.  The answer is the one the leaves give
+    when each finishes alone (whole-expression compilation off)."""
+    from filodb_tpu.config import settings
+    engine = _mk_engine([counter_batch(120, T, start_ms=START_MS,
+                                       resets=True)], num_shards=4)
+    args = (START_S + 600, 60, END_S)
+    engine.query_range(query, *args)            # mirrors, caches, compiles
+    before = _fused_counts()
+    got = _series_map(engine.query_range(query, *args))
+    d = _delta(before)
+    assert d["leaf_fused_kernel"] == 4 and d["leaf_fused_errors"] == 0
+    if per_series:
+        assert (d["fused_enqueues"], d["fused_enqueue_sets"]) == (4, 4)
+    else:
+        assert (d["fused_enqueues"], d["fused_enqueue_sets"]) == (1, 4)
+        assert d["fused_enqueue_uploads"] == 1   # the plan's rows, once
+    q = settings().query
+    old, q.exprfuse_enabled = q.exprfuse_enabled, False
+    try:
+        before = _fused_counts()
+        want = _series_map(engine.query_range(query, *args))
+        d = _delta(before)
+    finally:
+        q.exprfuse_enabled = old
+    assert (d["fused_enqueues"], d["fused_enqueue_sets"]) == (4, 4)
+    assert set(got) == set(want)
+    for k in want:
+        assert got[k].tobytes() == want[k].tobytes(), k
+
+
+def test_a_batch_of_panels_over_two_shards_is_one_call(fused_env):
+    """query_range_batch: each shard's panels merge into one working set
+    (merge_gid_cols, as before), and the two shards' sets ride ONE call."""
+    engine = _mk_engine([counter_batch(60, T, start_ms=START_MS,
+                                       resets=True)], num_shards=2)
+    args = (START_S + 600, 60, END_S)
+    queries = PANELS[:3]                        # sum, avg, sum: group mode
+    want = [_series_map(r) for r in engine.query_range_batch(queries, *args)]
+    before = _fused_counts()
+    got = engine.query_range_batch(queries, *args)
+    d = _delta(before)
+    assert d["leaf_fused_kernel"] == 6
+    assert (d["fused_enqueues"], d["fused_enqueue_sets"]) == (1, 2)
+    assert d["fused_enqueue_uploads"] == 2      # rows + the panels' offsets
+    for w, g in zip(want, got):
+        g = _series_map(g)
+        assert set(g) == set(w)
+        for k in w:
+            assert g[k].tobytes() == w[k].tobytes()
+
+
+def test_a_failed_merged_call_leaves_every_leaf_to_finish_alone(
+        fused_env, monkeypatch):
+    """A batch-level failure of the one call (here: its enqueue raises
+    whenever it carries more than one set) loses nothing: every FusedCall
+    stays parked and its leaf finishes standalone, one call a leaf."""
+    from filodb_tpu.ops import pallas_fused as pf
+    engine = _mk_engine([counter_batch(120, T, start_ms=START_MS,
+                                       resets=True)], num_shards=4)
+    args = (START_S + 600, 60, END_S)
+    query = PANELS[0]
+    want = _series_map(engine.query_range(query, *args))
+    real = pf.FusedDispatch.enqueue
+    seen = []
+
+    def enqueue(self):
+        seen.append(len(self))
+        if len(self) > 1:
+            raise RuntimeError("merged call down")
+        return real(self)
+
+    monkeypatch.setattr(pf.FusedDispatch, "enqueue", enqueue)
+    before = _fused_counts()
+    got = _series_map(engine.query_range(query, *args))
+    d = _delta(before)
+    assert seen == [4, 1, 1, 1, 1]
+    assert (d["fused_enqueues"], d["fused_enqueue_sets"]) == (4, 4)
+    assert d["leaf_fused_kernel"] == 4
+    assert set(got) == set(want)
+    for k in want:
+        assert got[k].tobytes() == want[k].tobytes(), k

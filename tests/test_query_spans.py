@@ -183,8 +183,9 @@ def test_four_leaves_each_hold_enqueue_and_fetch(rig, hoisted):
     """With whole-expression compilation (the default, and what the
     benchmark's cells run) the engine runs every leaf's gather + preflight
     and one merged dispatch BEFORE the tree: `engine.prepare_leaves` holds
-    one `leaf.prepare` a shard, `engine.dispatch_leaves` the four enqueues
-    and then the four fetches.  Without it each
+    one `leaf.prepare` a shard, `engine.dispatch_leaves` ONE enqueue and
+    then ONE fetch for the four shards' working sets (ISSUE 36: they share
+    a plan and a device, so they are one device program).  Without it each
     `exec.MultiSchemaPartitionsExec` holds its own."""
     rig.cfg.query.exprfuse_enabled = hoisted
     try:
@@ -212,8 +213,9 @@ def test_four_leaves_each_hold_enqueue_and_fetch(rig, hoisted):
                     "leaf.counts_copy", "leaf.fused_prepare"} <= set(under(e))
         disp = next(e for e in evs if e["name"] == "engine.dispatch_leaves")
         got = collections.Counter(under(disp))
-        assert got["leaf.kernel_enqueue"] == SHARDS
-        assert got["leaf.result_fetch"] == SHARDS
+        assert got["leaf.kernel_enqueue"] == 1
+        assert got["leaf.result_fetch"] == 1
+        assert got["leaf.present"] == 1
         # phase A enqueues everything before phase B reads anything back
         enq = [e for e in evs if e["name"] == "leaf.kernel_enqueue"]
         fet = [e for e in evs if e["name"] == "leaf.result_fetch"]
@@ -277,8 +279,8 @@ def test_enqueue_parts_stay_inside_kernel_enqueue(rig):
     kernel_enqueue_ms reads it), book seconds and calls but no self
     family, and ride beside the uploads-per-enqueue counters."""
     def fused():
-        return (registry.counter("fused_enqueues").value,
-                registry.counter("fused_enqueue_uploads").value)
+        return tuple(registry.counter(c).value for c in (
+            "fused_enqueues", "fused_enqueue_sets", "fused_enqueue_uploads"))
 
     before, fused0 = rig.counters(), fused()
     evs = rig.tree(rig.query()["traceID"])[0]
@@ -288,7 +290,7 @@ def test_enqueue_parts_stay_inside_kernel_enqueue(rig):
         return after[name] - before.get(name, 0.0)
 
     enqueues = [e for e in evs if e["name"] == "leaf.kernel_enqueue"]
-    assert len(enqueues) == SHARDS
+    assert len(enqueues) == 1           # one call for the request's leaves
     kids = children_of(evs)
     for enq in enqueues:
         mine = [k for k in kids[enq["span_id"]] if k["name"] in PARTS]
@@ -300,11 +302,12 @@ def test_enqueue_parts_stay_inside_kernel_enqueue(rig):
         sum(e["dur_ns"] for e in enqueues) * 1e-9, rel=1e-6)
     for part in PARTS:
         flat = "span_" + part.replace(".", "_")
-        assert delta(flat + "_calls_total") == SHARDS
+        assert delta(flat + "_calls_total") == 1
         assert 0.0 < delta(flat + "_seconds_total") <= self_s
         assert flat + "_self_seconds_total" not in after
-    # one dispatch a shard, and of each plan only its rows go up
-    assert (fused1[0] - fused0[0], fused1[1] - fused0[1]) == (SHARDS, SHARDS)
+    # one dispatch for the request, carrying a working set a shard, and
+    # of the plan only its rows go up, once
+    assert tuple(b - a for a, b in zip(fused0, fused1)) == (1, SHARDS, 1)
 
 
 def test_profiler_session_carries_the_spans(rig, tmp_path):
@@ -329,9 +332,9 @@ def test_profiler_session_carries_the_spans(rig, tmp_path):
             if "filodb:http.request" in names:
                 found.update(n for n in names if n.startswith("filodb:"))
                 parts = [n for n in names if n.startswith("filodb-part:")]
-    assert sorted(parts) == sorted("filodb-part:" + p for p in PARTS
-                                   for _ in range(SHARDS))
-    assert found["filodb:leaf.result_fetch"] == SHARDS
+    assert sorted(parts) == sorted("filodb-part:" + p for p in PARTS)
+    assert found["filodb:leaf.kernel_enqueue"] == 1
+    assert found["filodb:leaf.result_fetch"] == 1
     assert found["filodb:http.request"] == 1
     # the request's whole tree is on that one thread's line
     assert sum(found.values()) == len(evs)

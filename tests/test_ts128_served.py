@@ -77,7 +77,11 @@ def test_every_populated_leaf_is_a_fused_dispatch_and_an_empty_one_is_none(
         return after.get(name, 0.0) - before.get(name, 0.0)
     assert delta("leaf_fused_kernel_total") == 6 * rig.populated == 180
     assert delta("leaf_empty_total") == 6 * 2
-    assert delta("fused_enqueues_total") == 6 * rig.populated
+    # a request's 30 working sets ride ONE device program (ISSUE 36)
+    assert delta("fused_enqueues_total") == 6
+    assert delta("fused_enqueue_sets_total") == 6 * rig.populated
+    assert delta("span_leaf_kernel_enqueue_calls_total") == 6
+    assert delta("span_leaf_result_fetch_calls_total") == 6
     for fam in ("leaf_host_routed_total", "leaf_host_gather_total",
                 "leaf_general_path_total", "leaf_fused_errors_total"):
         assert delta(fam) == 0, fam
