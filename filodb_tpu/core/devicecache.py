@@ -65,11 +65,25 @@ class _MirrorSnapshot:
     vbase_valid: Dict[str, np.ndarray] = dataclasses.field(
         default_factory=dict)                  # bool [S(, B)]
     # --- fused-kernel eligibility (ops/pallas_fused.py preconditions) ---
-    # every row shares one scrape grid (identical ts offsets + counts)
-    uniform_grid: bool = False
-    ts_row0: Optional[np.ndarray] = None       # int32 [T] row-0 offsets
+    # the phase grid: every row holds the same count of samples and lies
+    # on ONE base row shifted by its own phase, ts_off[s] == ts_row0 +
+    # phase[s] over the counted region, 0 <= phase[s] < the base row's
+    # least gap (Prometheus' per-target scrape offset inside the scrape
+    # interval).  ts_row0 None: the rows fit no such grid.  One shared
+    # timestamp row is the grid whose phases are all zero (phase_rows 0).
+    ts_row0: Optional[np.ndarray] = None       # int32 [T] base-row offsets
+    phase: Optional[np.ndarray] = None         # int32 [S] ms, host
+    phase_rows: int = 0                        # rows with a phase != 0
+    # [rows, 1] f32 on the device, taken by need as vbase is; None where
+    # no row has a phase
+    phase_dev: object = None
     # per column: no NaN anywhere in the counted region
     col_finite: Dict[str, bool] = dataclasses.field(default_factory=dict)
+
+    @property
+    def uniform_grid(self) -> bool:
+        """One shared timestamp row: the phase grid with every phase 0."""
+        return self.ts_row0 is not None and self.phase_rows == 0
 
 
 def _tail_state(raw: np.ndarray, corrected: np.ndarray
@@ -110,6 +124,73 @@ def _tails_matrix(col: np.ndarray, rows: np.ndarray, counts_old: np.ndarray,
     else:
         tails = np.where(valid, tails, np.nan)
     return tails, valid
+
+
+# f32 holds whole milliseconds exactly below this: the kernel's timestamps
+# are offsets from the mirror's base plus a phase
+_F32_EXACT_MS = 1 << 24
+_PHASE_BLOCK = 8192         # rows compared at once (bounds the temporaries)
+
+
+def _detect_phase_grid(ts_off: np.ndarray, counts: np.ndarray,
+                       base_ms: int = 0
+                       ) -> Tuple[Optional[np.ndarray], Optional[np.ndarray],
+                                  int]:
+    """(ts_row0, phase, offgrid_rows) of a store's offsets [S, T] from
+    `base_ms` (PAD_TS beyond counts).  On a phase grid (see
+    _MirrorSnapshot): the base row (int32 [T], PAD_TS beyond the count)
+    and every row's phase off it.  The base row is the earliest row's,
+    moved back to the whole multiple of its least gap since the epoch
+    where every phase stays under that gap: Prometheus scrapes a target
+    at `offset + k x interval` since the epoch, so the shards of one
+    deployment then find ONE base row, whichever target each holds
+    first, and their leaves share a plan (offsets may start below 0).
+    Else (None, None, n): n rows hold another count of samples than row
+    0, or do not lie on the earliest row shifted, or lie a whole gap or
+    more behind it."""
+    s = ts_off.shape[0]
+    if s == 0:
+        return None, None, 0
+    c = int(counts[0])
+    # (the first column first: rows at offsets of their own differ there,
+    # and are spared the whole-array compare)
+    if (counts == c).all() and (ts_off[:, 0] == ts_off[0, 0]).all() \
+            and (ts_off == ts_off[0:1]).all():
+        # one shared timestamp row, the cheapest case first: what every
+        # store loaded from a test producer is
+        return ts_off[0].copy(), np.zeros(s, np.int32), 0
+    if c == 0:
+        return None, None, int((counts != 0).sum())
+    first = ts_off[:, 0].astype(np.int64)
+    r0 = int(np.argmin(np.where(counts == c, first, np.iinfo(np.int64).max)))
+    base = ts_off[r0].copy()
+    phase = first - int(base[0])
+    gap = int(np.diff(base[:c]).min()) if c > 1 else _F32_EXACT_MS
+    on = (counts == c) & (phase >= 0) & (phase < min(gap, _F32_EXACT_MS))
+    for lo in range(0, s, _PHASE_BLOCK):
+        blk = slice(lo, lo + _PHASE_BLOCK)
+        on[blk] &= (ts_off[blk, :c] - phase[blk, None].astype(np.int32)
+                    == base[None, :c]).all(axis=1)
+    off = int(s - on.sum())
+    if off:
+        return None, None, off
+    back = (base_ms + int(base[0])) % gap if c > 1 else 0
+    if back and int(phase.max()) + back < min(gap, _F32_EXACT_MS):
+        base[:c] -= back
+        phase += back
+    return base, phase.astype(np.int32), 0
+
+
+def _note_phase_grid(shard_num: Optional[int], phase: Optional[np.ndarray],
+                     offgrid_rows: int) -> int:
+    """Book a build's grid by shard; -> the rows with a phase."""
+    from filodb_tpu.utils.metrics import registry
+    phase_rows = int(np.count_nonzero(phase)) if phase is not None else 0
+    shard = str(-1 if shard_num is None else shard_num)
+    registry.gauge("device_mirror_phase_rows", shard=shard).update(phase_rows)
+    registry.gauge("device_mirror_offgrid_rows",
+                   shard=shard).update(offgrid_rows)
+    return phase_rows
 
 
 _mirror_serial = itertools.count(1)
@@ -572,8 +653,11 @@ class DeviceMirror:
         counts = store.counts[:s].copy()
         vbase_valid: Dict[str, np.ndarray] = {}
         col_finite: Dict[str, bool] = {}
-        uniform = bool(s > 0 and (counts == counts[0]).all()
-                       and (ts_off == ts_off[0:1]).all())
+        from filodb_tpu.utils.metrics import span
+        with span("mirror.phase_detect"):
+            ts_row0, phase, offgrid = _detect_phase_grid(ts_off, counts,
+                                                         base_ms)
+        phase_rows = _note_phase_grid(self.shard_num, phase, offgrid)
         dev_rows = _mirror_rows(s)
         for name, arr in store.cols.items():
             if arr is not None:
@@ -609,9 +693,10 @@ class DeviceMirror:
                                      tail_last_raw=last_raw,
                                      tail_cum_drop=cum_drop,
                                      vbase_valid=vbase_valid,
-                                     uniform_grid=uniform,
-                                     ts_row0=(ts_off[0].copy() if uniform
-                                              else None),
+                                     ts_row0=ts_row0, phase=phase,
+                                     phase_rows=phase_rows,
+                                     phase_dev=self._phase_dev(
+                                         phase, phase_rows, dev_rows, dput),
                                      col_finite=col_finite)
         # the histogram records the WHOLE refresh wall (host prep +
         # uploads: the operational "how long did the rebuild take");
@@ -632,6 +717,15 @@ class DeviceMirror:
                               bytes_in=nbytes, kind="transfer",
                               note=False)
         return True
+
+    @staticmethod
+    def _phase_dev(phase, phase_rows: int, dev_rows: int, dput):
+        """The phases as the fused kernel takes them: a [rows, 1] f32
+        column on the device, 0 on the rows past the store's.  None where
+        every phase is 0: the unphased variant reads none."""
+        if not phase_rows:
+            return None
+        return dput(_pad_rows(phase.astype(np.float32)[:, None], dev_rows, 0))
 
     def is_fresh(self, store) -> bool:
         snap = self._snap
@@ -818,20 +912,31 @@ class DeviceMirror:
         ts_dev = ts_dev.at[idx_r, idx_p].set(off.astype(np.int32))
         xfer_s += _time.perf_counter() - _td
 
-        # uniform-grid preservation: every row appended the same offsets
-        uniform = (snap.uniform_grid and s_new == s_old
-                   and rows.size == s_new
-                   and bool((delta == delta[0]).all()))
+        # phase-grid preservation: every row appended as many samples, each
+        # the base row's new offsets plus its own phase, and the base row's
+        # least gap still exceeds every phase
         ts_row0 = None
-        if uniform:
-            off2 = off.reshape(s_new, -1)
-            uniform = bool((off2 == off2[0:1]).all())
-            if uniform:
+        whole = s_new == s_old and rows.size == s_new
+        if snap.ts_row0 is not None and whole \
+                and bool((delta == delta[0]).all()):
+            off2 = off.reshape(s_new, -1) - snap.phase[:, None]
+            start0, k = int(counts_old[0]), off2.shape[1]
+            grown = np.concatenate([snap.ts_row0[max(start0 - 1, 0):start0],
+                                    off2[0]])
+            if bool((off2 == off2[0:1]).all()) and (
+                    not snap.phase_rows or grown.size < 2
+                    or int(np.diff(grown).min()) > int(snap.phase.max())):
                 ts_row0 = np.full(t_new, PAD_TS, np.int32)
                 ts_row0[:snap.t_used] = snap.ts_row0
-                k = off2.shape[1]
-                start0 = int(counts_old[0])
                 ts_row0[start0:start0 + k] = off2[0].astype(np.int32)
+        kept = ts_row0 is not None
+        if snap.ts_row0 is not None and not kept:
+            # the grid is lost; which rows left it is the next full
+            # build's to say: here, the rows that appended otherwise than
+            # most did
+            usual = int(np.bincount(delta).argmax())
+            _note_phase_grid(self.shard_num, None,
+                             max(int((delta != usual).sum()), 1))
 
         counter_cols = {c.name for c in store.schema.data_columns
                         if c.detect_drops or c.counter}
@@ -917,7 +1022,11 @@ class DeviceMirror:
             shift_version=store.shift_version, counts=counts_new,
             host_vbases=host_vbases, tail_last_raw=last_raw,
             tail_cum_drop=cum_drop, vbase_valid=vbase_valid,
-            uniform_grid=uniform, ts_row0=ts_row0, col_finite=col_finite)
+            ts_row0=ts_row0, col_finite=col_finite,
+            # the phases are the rows' own: they stand while the grid does
+            phase=snap.phase if kept else None,
+            phase_rows=snap.phase_rows if kept else 0,
+            phase_dev=snap.phase_dev if kept else None)
         # appended-tail transfer size: int32 ts offsets + each column's
         # per-cell bytes over the new cells only
         per_cell = 4 + sum(
@@ -980,13 +1089,15 @@ class DeviceMirror:
         self._book(self._nbytes(store))
         # pad-only is only reachable with new (empty) rows — dS > 0, since
         # time_used == counts.max() makes pure time growth impossible with
-        # zero new cells — and empty rows always break grid uniformity
+        # zero new cells — and empty rows always break the grid (ts_row0
+        # stays None)
+        if snap.ts_row0 is not None:
+            _note_phase_grid(self.shard_num, None, max(dS, 1))
         self._snap = _MirrorSnapshot(
             gen0, snap.base_ms, t_new, ts_dev, new_cols, new_vbases,
             shift_version=store.shift_version, counts=counts_new,
             host_vbases=host_vbases, tail_last_raw=last_raw,
             tail_cum_drop=cum_drop, vbase_valid=vbase_valid,
-            uniform_grid=False, ts_row0=None,
             col_finite=dict(snap.col_finite))
         return True
 
@@ -999,15 +1110,18 @@ class DeviceMirror:
 
     def fused_eligible(self, col_name: str, snap=None,
                        allow_ragged: bool = False) -> Optional[np.ndarray]:
-        """Row-0 ts offsets (int32 [T], PAD_TS beyond counts) when the
-        snapshot meets the pallas_fused preconditions for this column —
-        one shared scrape grid and (unless allow_ragged) a fully-finite
-        counted region — else None.  allow_ragged admits NaN-holed values
-        on a shared grid: the validity-weighted fused kinds handle those
+        """The base row's ts offsets (int32 [T], PAD_TS beyond counts)
+        when the snapshot meets the pallas_fused preconditions for this
+        column — its rows on one phase grid (_MirrorSnapshot) and (unless
+        allow_ragged) a fully-finite counted region — else None.
+        allow_ragged admits NaN-holed values on the grid: the
+        validity-weighted fused kinds handle those
         (ops/pallas_fused.can_fuse dense=False).  Any row subset of a
-        uniform grid is itself uniform."""
+        phase grid lies on the same base row with the same phases; a
+        leaf takes its rows' phases with MirrorGather.deferred("phase")
+        (None where the snapshot has none: one shared timestamp row)."""
         snap = snap if snap is not None else self._snap
-        if snap is None or not snap.uniform_grid or snap.ts_row0 is None:
+        if snap is None or snap.ts_row0 is None:
             return None
         if not snap.col_finite.get(col_name, False) and not allow_ragged:
             return None
@@ -1062,6 +1176,11 @@ class DeferredRows:
         that count (MirrorGather._take)."""
         return self._gather._take(self._array, self._col, rows_to=rows_to)
 
+    def host(self) -> np.ndarray:
+        """The rows of the snapshot's host copy (`phase` keeps one): no
+        device work."""
+        return self._gather.snap.phase[self._gather.rows]
+
 
 class MirrorGather:
     """Rows of one mirror snapshot, gathered by need.
@@ -1072,11 +1191,11 @@ class MirrorGather:
     validated fresh — so a refresh that publishes a newer one before an
     array is read changes nothing that is read.  Each array (`ts_off`
     [R, T]; a column under `values`, [R, T] or [R, T, B]; its `vbase`,
-    [R] or [R, B]) is taken on its first read, that array only, with the
-    row index uploaded once for all of them; a take that raised is not
-    remembered, so the next read tries again.  The results live on the
-    handle: keep it no longer than the leaf's execution and in no cache
-    (it holds a whole snapshot alive)."""
+    [R] or [R, B]; `phase`, [R, 1] f32) is taken on its first read, that
+    array only, with the row index uploaded once for all of them; a take
+    that raised is not remembered, so the next read tries again.  The
+    results live on the handle: keep it no longer than the leaf's
+    execution and in no cache (it holds a whole snapshot alive)."""
     __slots__ = ("device", "snap", "rows", "_idx", "_taken")
 
     def __init__(self, device, snap: _MirrorSnapshot, rows: np.ndarray):
@@ -1093,6 +1212,8 @@ class MirrorGather:
     def _source(self, array: str, col: Optional[str]):
         if array == "ts_off":
             return self.snap.ts_off
+        if array == "phase":
+            return self.snap.phase_dev
         return (self.snap.cols if array == "values"
                 else self.snap.vbases)[col]
 
@@ -1105,8 +1226,11 @@ class MirrorGather:
                  ) -> Optional[DeferredRows]:
         """The array as a leaf's block carries it: taken when the block's
         field of that name is first read, and counted under it.  None for
-        a column the snapshot keeps no vbase of."""
+        a column the snapshot keeps no vbase of, and for the phases of a
+        snapshot none of whose rows has one."""
         if array == "vbase" and col not in self.snap.vbases:
+            return None
+        if array == "phase" and self.snap.phase_dev is None:
             return None
         return DeferredRows(self, array, col)
 

@@ -33,30 +33,6 @@ NO_ROWS_HORIZON_MS = 1 << 62
 _KEY_RESOLVE_CACHE_MAX = 4               # live key tables per shard (schemas)
 _LOOKUP_CACHE_MAX = 32                   # memoized lookup_partitions results
 
-# shared flush-encode pool: chunk encoding is NumPy (releases the GIL), so
-# slab-parallel encode overlaps with live ingest on the other cores.  One
-# process-wide pool — flushes across shards share it rather than each
-# spawning threads.  Lazy: tests that never flush big groups pay nothing.
-_ENCODE_POOL = None
-_ENCODE_POOL_WORKERS = 0
-_ENCODE_POOL_LOCK = threading.Lock()
-_ENCODE_MIN_PARALLEL = 16                # serial below this many partitions
-
-
-def _encode_pool():
-    """-> (executor, worker_count)."""
-    global _ENCODE_POOL, _ENCODE_POOL_WORKERS
-    if _ENCODE_POOL is None:
-        with _ENCODE_POOL_LOCK:
-            if _ENCODE_POOL is None:
-                import concurrent.futures
-                import os
-                _ENCODE_POOL_WORKERS = max(2, min(4, os.cpu_count() or 1))
-                _ENCODE_POOL = concurrent.futures.ThreadPoolExecutor(
-                    max_workers=_ENCODE_POOL_WORKERS,
-                    thread_name_prefix="filodb-flush-encode")
-    return _ENCODE_POOL, _ENCODE_POOL_WORKERS
-
 import numpy as np
 
 from filodb_tpu.config import FilodbSettings, settings as default_settings
@@ -69,7 +45,7 @@ from filodb_tpu.core.records import RecordBatch
 from filodb_tpu.core.schemas import Schemas, DEFAULT_SCHEMAS
 from filodb_tpu.core.store import (ColumnStore, MetaStore, NullColumnStore,
                                    InMemoryMetaStore, PartKeyRecord)
-from filodb_tpu.memory.chunks import ChunkSet, encode_chunkset
+from filodb_tpu.memory.chunks import ChunkSet, encode_chunksets
 from filodb_tpu.memory.histogram import HistogramBuckets
 from filodb_tpu.utils.faults import faults
 from filodb_tpu.utils.metrics import (registry as metrics_registry,
@@ -143,12 +119,16 @@ class SelectionFacts:
             arr.setflags(write=False)
         self.counts, self.first, self.last = cnt, first, last
         self.samples = int(cnt.sum())
-        # every row with the same count, first and last timestamp (one
-        # scrape grid): the estimate is then arithmetic on three scalars
+        # every row with the same count and the same extent from its
+        # first to its last timestamp (one scrape grid, each row on it or
+        # behind it by its own phase): the estimate is then arithmetic on
+        # four scalars.  (count, earliest first, latest first, extent)
         self.uniform = None
-        if rows.size and (cnt == cnt[0]).all() \
-                and (first == first[0]).all() and (last == last[0]).all():
-            self.uniform = (int(cnt[0]), int(first[0]), int(last[0]))
+        if rows.size and (cnt == cnt[0]).all():
+            extent = last - first
+            if (extent == extent[0]).all():
+                self.uniform = (int(cnt[0]), int(first.min()),
+                                int(first.max()), int(extent[0]))
         # ensure_paged_pids' two conditions, each reduced over the rows:
         # a row needs paging below when start < min(paged_floor,
         # first_mem), a page-only row with samples above when
@@ -166,10 +146,18 @@ class SelectionFacts:
         """estimate_samples over these rows.  On uniform rows: its
         formula on one row, in Python, times the row count — a product
         where it sums S equal floats, so the integer may differ by 1."""
-        if self.uniform is None:
+        alike = False
+        if self.uniform is not None:
+            cnt, first, first_hi, extent = self.uniform
+            last = first + extent
+            # rows behind the grid by a phase: one row stands for all
+            # where the range clips every row alike, which is inside the
+            # span that all rows cover (a dashboard's range is)
+            alike = first_hi == first or (start_ms >= first_hi
+                                          and end_ms <= last)
+        if not alike:
             return estimate_samples(self.counts, self.first, self.last,
                                     start_ms, end_ms)
-        cnt, first, last = self.uniform
         lo, hi = max(first, start_ms), min(last, end_ms)
         if cnt <= 0 or hi < lo:
             return 0
@@ -861,38 +849,33 @@ class TimeSeriesShard:
             self._key_resolve_cache.clear()
         return len(pruned)
 
-    def _encode_one(self, info: PartitionInfo, ts, cols, les,
-                    ingestion_time_ms: int):
-        schema = self.schemas[info.schema_name]
-        col_types = {c.name: c.col_type for c in schema.data_columns}
-        scheme = HistogramBuckets.custom(les) if les is not None else None
-        return encode_chunkset(ts, cols, col_types, ingestion_time_ms,
-                               scheme)
-
-    def _encode_pending(self, pending, ingestion_time_ms: int) -> list:
-        """Encode the copied flush slices into ChunkSets, in `pending`
-        order.  Large groups split into per-worker SLABS on the shared
-        thread pool — NumPy codec work drops the GIL, so encode overlaps
-        flush's own persist loop and live ingest; slab granularity (not
-        per-partition tasks) keeps executor overhead off the millions of
-        small chunks a 1M-series flush produces.  Persist + downsample
-        stay on the flush thread: store writers and the downsampler are
-        not thread-safe, and their ordering is part of the checkpoint
+    def _encode_blocks(self, blocks, n: int, ingestion_time_ms: int) -> list:
+        """Encode the copied flush slices into `n` ChunkSets, in `pending`
+        order.  Each block of the copy (its place in `pending`, schema,
+        padded snapshot and the rows' lengths) is encoded whole, the rows
+        of one length together (`encode_chunksets`: a few NumPy calls and
+        one codec call a column): a scrape cycle gives the series of a
+        group the same count, and a call a series, on this thread or on a
+        pool's, is an interpreter-lock hand-off a series beside live
+        queries (PERF.md section 6, PR 37).  Persist + downsample stay
+        with the caller: store writers and the downsampler are not
+        thread-safe, and their ordering is part of the checkpoint
         contract."""
-        if len(pending) < _ENCODE_MIN_PARALLEL:
-            return [self._encode_one(info, ts, cols, les, ingestion_time_ms)
-                    for _, info, _, ts, cols, les in pending]
-        pool, workers = _encode_pool()
-
-        def encode_slab(slab):
-            return [self._encode_one(info, ts, cols, les, ingestion_time_ms)
-                    for _, info, _, ts, cols, les in slab]
-
-        step = (len(pending) + workers - 1) // workers
-        slabs = [pending[i:i + step] for i in range(0, len(pending), step)]
-        out: list = []
-        for fut in [pool.submit(encode_slab, s) for s in slabs]:
-            out.extend(fut.result())
+        out: list = [None] * n
+        for lo, schema_name, ts_pad, col_pads, les, lens in blocks:
+            col_types = {c.name: c.col_type
+                         for c in self.schemas[schema_name].data_columns}
+            scheme = HistogramBuckets.custom(les) if les is not None else None
+            for ln in np.unique(lens).tolist():
+                rows = np.flatnonzero(lens == ln)
+                chunksets = encode_chunksets(
+                    ts_pad[rows, :ln],
+                    {name: (np.zeros((rows.size, ln, 0)) if pad is None
+                            else pad[rows, :ln])
+                     for name, pad in col_pads.items()},
+                    col_types, ingestion_time_ms, scheme)
+                for r, cs in zip(rows.tolist(), chunksets):
+                    out[lo + r] = cs
         return out
 
     def _do_flush_group(self, group: int, ingestion_time_ms: int,
@@ -907,7 +890,7 @@ class TimeSeriesShard:
         an eviction SHIFTED a store's rows during phase 2 (shift_version
         moved), its seals are skipped — the next flush re-reads and
         re-writes those slices; chunk writes are idempotent."""
-        pending = []
+        pending, blocks = [], []
         with self._write_locked("flush_copy"):
             self._prune_tombstones()
             # Snapshot the replay watermark BEFORE reading any data: the
@@ -993,6 +976,8 @@ class TimeSeriesShard:
                             col_pads[c.name] = arr[rs[:, None], posc, :]
                         else:
                             col_pads[c.name] = arr[rs[:, None], posc]
+                    blocks.append((len(pending), schema_name, ts_pad,
+                                   col_pads, les, lens[start:end]))
                     for i in range(start, end):
                         pending.append((int(pids[i]),
                                         self.partitions[int(pids[i])],
@@ -1010,7 +995,8 @@ class TimeSeriesShard:
             for pid, info, hi_i, ts_pad, col_pads_, les, r, ln in pending]
         written = 0
         encoded = []
-        chunksets = self._encode_pending(pending, ingestion_time_ms)
+        chunksets = self._encode_blocks(blocks, len(pending),
+                                        ingestion_time_ms)
         if pending:
             faults.fire("flush.persist")
         for (pid, info, hi, ts, cols, les), cs in zip(pending, chunksets):

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -138,6 +138,93 @@ def encode_chunkset(ts: np.ndarray,
         else:
             raise ValueError(f"unsupported column type {t!r}")
     return ChunkSet(info, encoded, bucket_scheme)
+
+
+# |first|, |last| under this keep a row's slope exact in float64 division,
+# as the one-series encoder's Python integers are
+_DD_EXACT = 1 << 52
+
+
+def _dd_chunks(kind: str, t: np.ndarray) -> List[ColumnChunk]:
+    """delta_delta_encode + pack_i64 of every row of `t` [R, n] int64."""
+    n = t.shape[1]
+    base = t[:, 0]
+    if n > 1:
+        slope = np.rint((t[:, -1] - base) / (n - 1)).astype(np.int64)
+    else:
+        slope = np.zeros(len(t), np.int64)
+    line = base[:, None] + slope[:, None] * np.arange(n, dtype=np.int64)
+    payloads = nibblepack.pack_rows(nibblepack.zigzag_encode(t - line))
+    return [ColumnChunk(kind, p, base=b, slope=s)
+            for p, b, s in zip(payloads, base.tolist(), slope.tolist())]
+
+
+def _long_rows(kind: str, t: np.ndarray, one) -> List[ColumnChunk]:
+    """`one(row)` of every row of an integer block, the rows whose slope
+    float64 holds exactly in whole-block calls."""
+    out: List[Optional[ColumnChunk]] = [None] * len(t)
+    ends = t[:, [0, -1]]
+    exact = ((ends > -_DD_EXACT) & (ends < _DD_EXACT)).all(axis=1)
+    idx = np.flatnonzero(exact)
+    for i, c in zip(idx.tolist(), _dd_chunks(kind, t[idx])):
+        out[i] = c
+    for i in np.flatnonzero(~exact).tolist():
+        out[i] = one(t[i])
+    return out
+
+
+def _double_rows(vals: np.ndarray) -> List[ColumnChunk]:
+    """encode_double_column of every row of `vals` [R, n]."""
+    v = np.asarray(vals, dtype=np.float64)
+    out: List[Optional[ColumnChunk]] = [None] * len(v)
+    integral = (np.isfinite(v) & (v == np.floor(v))
+                & (np.abs(v) < 2.0**53)).all(axis=1)
+    idx = np.flatnonzero(integral)
+    if idx.size:
+        for i, c in zip(idx.tolist(), _long_rows(
+                "f64-i64dd", v[idx].astype(np.int64),
+                encode_double_column)):
+            out[i] = c
+    idx = np.flatnonzero(~integral)
+    if idx.size:
+        bits = np.ascontiguousarray(v[idx]).view(np.uint64)
+        xored = bits.copy()
+        xored[:, 1:] ^= bits[:, :-1]
+        for i, p in zip(idx.tolist(), nibblepack.pack_rows(xored)):
+            out[i] = ColumnChunk("f64-xor", p)
+    return out
+
+
+def encode_chunksets(ts: np.ndarray, columns: Dict[str, np.ndarray],
+                     col_types: Dict[str, str], ingestion_time_ms: int,
+                     bucket_scheme: Optional[HistogramBuckets] = None
+                     ) -> List[ChunkSet]:
+    """encode_chunkset of every row of a block: `ts` [R, n] with n >= 1,
+    each column [R, n] (a histogram's [R, n, B]).  The same chunks bit for
+    bit (chunk ids in row order), in a few whole-block NumPy calls and one
+    codec call a column where encode_chunkset makes some forty a series: a
+    flush pass beside six requests in flight costs what it hands the
+    interpreter lock over, not what it computes (PERF.md section 6)."""
+    ts = np.asarray(ts, dtype=np.int64)
+    encoded = {"timestamp": _long_rows("ts-dd", ts, encode_ts_column)}
+    for name, vals in columns.items():
+        t = col_types[name]
+        if t == "double":
+            encoded[name] = _double_rows(vals)
+        elif t == "long":
+            encoded[name] = _long_rows("i64-dd", np.asarray(
+                vals, dtype=np.int64), encode_long_column)
+        elif t == "hist":
+            encoded[name] = [encode_hist_column(m) for m in vals]
+        else:
+            raise ValueError(f"unsupported column type {t!r}")
+    n = ts.shape[1]
+    return [ChunkSet(ChunkSetInfo(make_chunk_id(), ingestion_time_ms, n,
+                                  first, last),
+                     {name: col[r] for name, col in encoded.items()},
+                     bucket_scheme)
+            for r, (first, last) in enumerate(zip(ts[:, 0].tolist(),
+                                                  ts[:, -1].tolist()))]
 
 
 def decode_chunkset(cs: ChunkSet) -> Dict[str, np.ndarray]:

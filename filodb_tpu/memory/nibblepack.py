@@ -93,6 +93,15 @@ def pack(values: np.ndarray) -> bytes:
     return _pack_vec(values)
 
 
+def pack_rows(values: np.ndarray) -> list:
+    """pack() of every row of a [R, n] uint64 array: one codec call for the
+    block where the C library is built (a flush seals thousands of series of
+    one length at a time, and a call a series is a lock hand-off a series)."""
+    if _native is not None and len(values):
+        return _native.nibble_pack_rows(values)
+    return [pack(row) for row in values]
+
+
 def _pack_py(values: np.ndarray) -> bytes:
     vals = np.asarray(values, dtype=np.uint64)
     n = len(vals)

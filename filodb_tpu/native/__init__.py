@@ -37,6 +37,11 @@ class _NativeLib:
         c.filodb_nibble_pack.argtypes = [
             ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t]
+        c.filodb_nibble_pack_rows.restype = ctypes.c_long
+        c.filodb_nibble_pack_rows.argtypes = [
+            ctypes.POINTER(ctypes.c_uint64), ctypes.c_size_t,
+            ctypes.c_size_t, ctypes.POINTER(ctypes.c_uint8),
+            ctypes.c_size_t, ctypes.POINTER(ctypes.c_int64)]
         c.filodb_nibble_unpack.restype = ctypes.c_long
         c.filodb_nibble_unpack.argtypes = [
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t,
@@ -59,6 +64,23 @@ class _NativeLib:
         if written < 0:
             raise ValueError("nibble_pack: output buffer overflow")
         return out[:written].tobytes()
+
+    def nibble_pack_rows(self, values: np.ndarray) -> list:
+        """nibble_pack of every row of a [R, n] array, in one call."""
+        vals = np.ascontiguousarray(values, dtype=np.uint64)
+        rows, n = vals.shape
+        cap = rows * ((n + 7) // 8) * 66
+        out = np.empty(cap, dtype=np.uint8)
+        ends = np.empty(rows, dtype=np.int64)
+        written = self._c.filodb_nibble_pack_rows(
+            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)), rows, n,
+            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), cap,
+            ends.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        if written < 0:
+            raise ValueError("nibble_pack_rows: output buffer overflow")
+        buf = out[:written].tobytes()
+        ends = ends.tolist()
+        return [buf[a:b] for a, b in zip([0] + ends[:-1], ends)]
 
     def nibble_unpack(self, data: bytes, count: int) -> np.ndarray:
         buf = np.frombuffer(data, dtype=np.uint8)

@@ -197,6 +197,21 @@ long filodb_nibble_pack(const uint64_t* vals, size_t n, uint8_t* out,
   return (long)pos;
 }
 
+// filodb_nibble_pack of each of `rows` rows of `n` values (row-major), one
+// after another into `out`; ends[r] is where row r's bytes end.  One call
+// a flush block instead of one a series.  Returns bytes written, or -1.
+long filodb_nibble_pack_rows(const uint64_t* vals, size_t rows, size_t n,
+                             uint8_t* out, size_t out_cap, int64_t* ends) {
+  size_t pos = 0;
+  for (size_t r = 0; r < rows; ++r) {
+    long w = filodb_nibble_pack(vals + r * n, n, out + pos, out_cap - pos);
+    if (w < 0) return -1;
+    pos += (size_t)w;
+    ends[r] = (int64_t)pos;
+  }
+  return (long)pos;
+}
+
 // Returns bytes consumed, or -1 on truncated input.
 long filodb_nibble_unpack(const uint8_t* data, size_t len, uint64_t* out,
                           size_t count) {

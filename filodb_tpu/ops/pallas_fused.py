@@ -16,9 +16,11 @@ whole thing in ONE read of the values, from a few host-built window rows
   last[w]}, a 0/1 matrix built on the device
 - group segment-sum  ->  onehot(gids) @ rate  on the MXU
 
-Preconditions (the caller gates, see `can_fuse`): one shared scrape grid
-across series (the devicecache/shared_grid invariant) and dense rows — no
-NaN inside the counted region.  Anything else falls back to the general
+Preconditions (the caller gates, see `can_fuse`): one scrape grid across
+series (the devicecache invariant: every row on one base timestamp row, or
+behind it by a phase of its own, which the `phased` variant takes) and
+dense rows — no NaN inside the counted region (the `ragged` variants take
+NaN-holed ones).  Anything else falls back to the general
 XLA path in ops/rangefns.py; semantics here match it bit-for-bit in f32
 (same extrapolation rules, ref: RateFunctions.scala:37-76; same 3-phase
 aggregate contract, ref: exec/AggrOverRangeVectors.scala:17-125).
@@ -152,6 +154,14 @@ def pad_group_count(G: int) -> int:
 
 # row order of FusedPlan.rows, the one block an enqueue uploads
 _T1, _T2, _N, _N1, _WS, _WE, _I1, _I2 = range(8)
+# ... and of the eight rows FusedPlan.prows adds below them, which only a
+# phased dispatch uploads and reads: the boundary slots without the empty
+# windows' 0 sentinel (a row's own window may hold a sample where the base
+# row's holds none), the base row's timestamps at those slots and at the
+# slots before them, and the two slacks a row's phase is compared with
+_PI1, _PI2, _PT1, _PT2, _PT1M, _PT2M, _PS1, _PS2 = range(8, 16)
+# a slack no phase passes: the slot before the first has nothing to take
+_NO_SLOT = np.float32(1 << 30)
 
 
 class FusedPlan(NamedTuple):
@@ -179,6 +189,14 @@ class FusedPlan(NamedTuple):
     wvalid1: np.ndarray  # [W] bool      n >= 1 (*_over_time family)
     W: int
     Tp: int
+    # [16, Wp] f32: `rows`, then the phased rows (_PI1 ... _PS2).  Rows of
+    # a phase grid lie at `ts_row + phase[s]`, 0 <= phase[s] < the row's
+    # least gap, so with edges ws < t <= we a row's first / last slot is
+    # the shared one or the slot before it, by one compare against a slack
+    # that depends on the window alone: first = idx1 - (phase > ws -
+    # ts_row[idx1 - 1]), last = idx2 - (phase > we - ts_row[idx2]).  What
+    # a dispatch of phased working sets uploads in place of `rows`.
+    prows: Optional[np.ndarray] = None
 
 
 def build_plan(ts_row: np.ndarray, wends: np.ndarray,
@@ -210,16 +228,33 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
     rows[_I2, :W] = np.where(valid, la, 0)
     tsr = np.zeros((1, Tp), np.float32)
     tsr[0, :T] = ts_row
+    # the phased rows: slots unclipped by emptiness (clipped to the row
+    # only), and the slot before each
+    fm = np.clip(first - 1, 0, T - 1)
+    lm = np.clip(last - 1, 0, T - 1)
+    prows = np.zeros((16, Wp), np.float32)
+    prows[:8] = rows
+    # (the first slot may lie one past the row: the slot a row that starts
+    # early takes is then the row's last)
+    prows[_PI1, :W], prows[_PI2, :W] = np.clip(first, 0, T), la
+    prows[_PT1, :W], prows[_PT2, :W] = ts_row[fi], ts_row[la]
+    prows[_PT1M, :W], prows[_PT2M, :W] = ts_row[fm], ts_row[lm]
+    prows[_PS1, :W] = np.where(first >= 1, wstart - 1 - ts_row[fm], _NO_SLOT)
+    prows[_PS2, :W] = np.where(last >= 0, wend - ts_row[la], _NO_SLOT)
     return FusedPlan(rows, *(rows[i:i + 1] for i in range(8)), tsrow=tsr,
-                     wvalid=(n >= 2), wvalid1=(n >= 1), W=W, Tp=Tp)
+                     wvalid=(n >= 2), wvalid1=(n >= 1), W=W, Tp=Tp,
+                     prows=prows)
 
 
-def kernel_operands(rows, tsrow, Tp: int, kind: str):
+def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False):
     """The 12 operands `_kernel` reads after (vals, vbase, gids), from a
-    plan's uploaded rows.  Traceable: `_run` calls it inside its jit (and
-    with it every caller that composes `run_kernel` under its own trace,
-    parallel/mesh.py), so an enqueue ships the [8, Wp] rows and nothing
-    else of the plan.
+    plan's uploaded rows; `phased` (rows is the plan's [16, Wp] `prows`):
+    13, the last being the rows themselves for the kernel's slacks, with
+    the boundary slots and timestamps those of the phased rows and `n` the
+    base row's true count for every kind.  Traceable: `_run` calls it
+    inside its jit (and with it every caller that composes `run_kernel`
+    under its own trace, parallel/mesh.py), so an enqueue ships the
+    [8, Wp] rows and nothing else of the plan.
 
     The band kinds' selection matrices are built here, on the device:
     o[t, w] = 1{t == idx[w]} and l[t, w] = 1{t <= idx[w]} over the
@@ -246,6 +281,9 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str):
                mat(_I2, True))
     if tsrow is None:
         tsrow = jnp.zeros((1, Tp), jnp.float32)
+    if phased:
+        return sel + (row(_PT1), row(_PT2), row(_N1), row(_WS), row(_WE),
+                      tsrow, row(_PI1), row(_PI2), rows)
     return sel + (row(_T1), row(_T2),
                   row(_N1 if kind in OVER_TIME_FNS else _N),
                   row(_WS), row(_WE), tsrow, row(_I1), row(_I2))
@@ -281,15 +319,17 @@ def _committed_device(arr):
 
 
 def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
-                     offsets=None, sets: int = 1) -> tuple:
+                     offsets=None, sets: int = 1,
+                     phased: bool = False) -> tuple:
     """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
     takes from the host, put explicitly (so the call itself transfers
     nothing) and counted, uploads and working sets beside enqueues, on
     /metrics.  `tsrow` rides only where the kernel reads it (the ragged
     rate family), `offsets` only where some set has several panels; the
-    others are None.  `sets`: the working sets the call carries."""
+    others are None.  `sets`: the working sets the call carries.
+    `phased`: the rows are the plan's [16, Wp] `prows`."""
     from filodb_tpu.utils.metrics import registry
-    host = (plan.rows,
+    host = (plan.prows if phased else plan.rows,
             plan.tsrow if ragged and kind == "rate_family" else None,
             None if offsets is None else np.asarray(offsets, np.int32))
     registry.counter("fused_enqueues").increment()
@@ -363,7 +403,9 @@ def _cumsum_lanes(x):
 
 
 def _gather_cols(x, idx):
-    """out[s, w] = x[s, idx[0, w]] — the one-hot selection matmul as pure
+    """out[s, w] = x[s, idx[0, w]], or x[s, idx[s, w]] where `idx` has a
+    row a series (the phased variant: a row's slot is the shared one or
+    the one before it) — the one-hot selection matmul as pure
     data movement.  Mosaic lowers take_along_axis to tpu.dynamic_gather
     only within one 128-lane vreg (the cross-vreg form fails to compile),
     so the row is gathered per 128-lane tile and
@@ -390,8 +432,25 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             *out_refs,
             num_groups: int, is_counter: bool, is_rate: bool,
             with_drops: bool, kind: str = "rate_family",
-            ragged: bool = False, per_series: bool = False):
+            ragged: bool = False, per_series: bool = False,
+            phased: bool = False):
     v = vals_ref[:]                                   # [BS, Tp]
+    if phased:
+        # rows of a phase grid (FusedPlan.prows): after the 12 operands
+        # come the plan's [16, Wp] rows and the working set's [BS, 1] phase
+        # column.  Which of the two slots a row takes at either edge is one
+        # compare of its phase with the window's slack; its slot count
+        # follows, and with it presence, a row a cell (the second output,
+        # as on ragged rows)
+        pr_ref, ph_ref, *out_refs = out_refs
+        ph = ph_ref[:]                                # [BS, 1]
+        early1 = ph > pr_ref[_PS1:_PS1 + 1, :]        # [BS, Wp] first - 1
+        early2 = ph > pr_ref[_PS2:_PS2 + 1, :]        #          last - 1
+        e1 = early1.astype(jnp.float32)
+        e2 = early2.astype(jnp.float32)
+        idx1 = i1_ref[:].astype(jnp.int32) - early1.astype(jnp.int32)
+        idx2 = i2_ref[:].astype(jnp.int32) - early2.astype(jnp.int32)
+        slots = n_ref[:] + e1 - e2                    # [BS, Wp] own count
     if kind == "last_over_time":
         # instant-vector selector (`sum by (x) (metric)` with staleness
         # lookback): the last sample in each window, gathered at last[w];
@@ -399,21 +458,22 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # Ragged keeps SLOT semantics deliberately — a NaN in the newest
         # slot is a staleness marker that makes the series absent, not a
         # hole to skip (unlike the rate family's range-vector filtering)
+        if not phased:
+            idx2, slots = i2_ref[:].astype(jnp.int32), n_ref[:]
         if ragged:
             m = v == v
-            idx2 = i2_ref[:].astype(jnp.int32)
             sel = _gather_cols(jnp.where(m, v, 0.0), idx2)
             # empty windows gather column idx 0 (a plan sentinel): the
             # true-count mask zeroes their presence
             pres = _gather_cols(m.astype(jnp.float32), idx2) \
-                * jnp.minimum(n_ref[:], 1.0)
+                * jnp.minimum(slots, 1.0)
             out = (sel + vbase_ref[:]) * pres
             _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
             return
-        sel = _gather_cols(v, i2_ref[:].astype(jnp.int32)) \
-            * jnp.minimum(n_ref[:], 1.0)
-        out = sel + vbase_ref[:] * jnp.minimum(n_ref[:], 1.0)
-        _epilogue(gids_ref, out, None, out_refs, num_groups, per_series)
+        sel = _gather_cols(v, idx2) * jnp.minimum(slots, 1.0)
+        out = sel + vbase_ref[:] * jnp.minimum(slots, 1.0)
+        _epilogue(gids_ref, out, jnp.minimum(slots, 1.0) if phased else None,
+                  out_refs, num_groups, per_series)
         return
     if kind in ("sum_over_time", "avg_over_time", "count_over_time"):
         # window sums as ONE matmul against the band matrix
@@ -423,10 +483,30 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # holes, take per-(series, window) counts from a second matmul of
         # the validity mask against the same band (VERDICT r2 item 2).
         band = l2_ref[:] - l1_ref[:] + o1_ref[:]
+        if phased:
+            # the band is the base row's; a row that starts a slot early
+            # adds the sample before it, one that ends a slot early takes
+            # the band's last away: two gathered corrections to the one
+            # band product (an empty band leaves the one sample a row may
+            # hold, at last == first - 1, to them alone)
+            before = jnp.maximum(i1_ref[:].astype(jnp.int32) - 1, 0)
+            at_end = i2_ref[:].astype(jnp.int32)
+
+            def corrected(total, x):
+                return total \
+                    + jnp.where(early1, _gather_cols(x, before), 0.0) \
+                    - jnp.where(early2, _gather_cols(x, at_end), 0.0)
         if ragged:
             validf = (v == v).astype(jnp.float32)     # NaN-aware
             s = _dot_hi(jnp.where(v == v, v, 0.0), band)
             n = _dot_1p(validf, band)                  # [BS, Wp] valid counts
+            if phased:
+                s = corrected(s, jnp.where(v == v, v, 0.0))
+                n = corrected(n, validf)
+            pres = (n > 0).astype(jnp.float32)
+        elif phased:
+            s = corrected(_dot_hi(v, band), v)
+            n = slots
             pres = (n > 0).astype(jnp.float32)
         else:
             s = _dot_hi(v, band)
@@ -436,7 +516,7 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             out = s + vbase_ref[:] * n
         elif kind == "avg_over_time":
             out = s / jnp.maximum(n, 1.0) + vbase_ref[:]
-            if ragged:
+            if pres is not None:
                 out = out * pres      # no vbase leak into absent cells
         else:                                         # count_over_time
             out = n * jnp.ones_like(s)
@@ -444,7 +524,8 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
                 # count's presence is SLOT-based: a window whose grid slots
                 # exist but hold only NaN emits 0, not absent (ref:
                 # AggrOverTimeFunctions.scala:367-382), unlike sum/avg
-                pres = (n_ref[:] > 0).astype(jnp.float32) * jnp.ones_like(s)
+                pres = ((slots if phased else n_ref[:]) > 0).astype(
+                    jnp.float32) * jnp.ones_like(s)
         _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
         return
     pres = None
@@ -469,15 +550,17 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             c = vz + _cumsum_lanes(d)
         else:
             c = vz
-        tsb = jnp.where(m, jnp.broadcast_to(ts_ref[:], v.shape), 0.0)
+        tsb = jnp.where(m, ts_ref[:] + ph if phased
+                        else jnp.broadcast_to(ts_ref[:], v.shape), 0.0)
         f_c, f_t, _ = _fill_scan2(c, tsb, m, left=False)
         b_c, b_t, _ = _fill_scan2(c, tsb, m, left=True)
         # exact selections at the first/last window slots, and the
         # validity count as a cumsum difference — all integer-in-f32.
         # Empty windows gather slot 0: nv <= 1 there, so presence masks
-        # them.
-        idx1 = i1_ref[:].astype(jnp.int32)
-        idx2 = i2_ref[:].astype(jnp.int32)
+        # them (a phased row without a slot has last == first - 1: nv 0).
+        if not phased:
+            idx1 = i1_ref[:].astype(jnp.int32)
+            idx2 = i2_ref[:].astype(jnp.int32)
         mf = m.astype(jnp.float32)
         cs_m = _cumsum_lanes(mf)
         nv = _gather_cols(cs_m, idx2) - _gather_cols(cs_m, idx1) \
@@ -488,9 +571,14 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         t2 = _gather_cols(f_t, idx2)
         n = jnp.maximum(nv, 2.0)                      # math-safe; masked
         pres = (nv >= 2.0).astype(jnp.float32)
+        if phased:
+            # a first slot one past the row gathers nothing, not the count
+            # there: a row holds no more samples than slots
+            pres = pres * (slots >= 2.0).astype(jnp.float32)
     else:
-        idx1 = i1_ref[:].astype(jnp.int32)
-        idx2 = i2_ref[:].astype(jnp.int32)
+        if not phased:
+            idx1 = i1_ref[:].astype(jnp.int32)
+            idx2 = i2_ref[:].astype(jnp.int32)
         if with_drops:
             # the first column has no predecessor.  A reset adds the FULL
             # previous RAW value = prev + vbase (rebased rows; ref:
@@ -505,8 +593,16 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             c = v
         v1 = _gather_cols(c, idx1)                     # [BS, Wp]
         v2 = _gather_cols(c, idx2)
-        t1, t2 = t1_ref[:], t2_ref[:]                 # [1, Wp]
-        n = n_ref[:]
+        if phased:
+            # a row's boundary timestamps: the base row's at its own slot,
+            # plus its phase (exact in f32: offsets stay under 2^24 ms)
+            t1 = jnp.where(early1, pr_ref[_PT1M:_PT1M + 1, :], t1_ref[:]) + ph
+            t2 = jnp.where(early2, pr_ref[_PT2M:_PT2M + 1, :], t2_ref[:]) + ph
+            n = jnp.maximum(slots, 2.0)               # math-safe; masked
+            pres = (slots >= 2.0).astype(jnp.float32)
+        else:
+            t1, t2 = t1_ref[:], t2_ref[:]             # [1, Wp]
+            n = n_ref[:]
     ws, we = ws_ref[:], we_ref[:]
 
     dur_start = (t1 - ws) / 1000.0
@@ -527,7 +623,9 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
     if is_rate:
         out = out / jnp.maximum(we - ws, 1.0) * 1000.0
     if pres is not None:
-        out = out * pres                              # no NaN into the MXU
+        # no NaN into the MXU (a phased row's masked cell divides by the
+        # floor of `sampled`: an inf there must not meet the 0)
+        out = jnp.where(pres > 0, out, 0.0) if phased else out * pres
 
     _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
 
@@ -566,25 +664,27 @@ def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
         out_refs[1][:] += _dot_1p(onehot, pres)
 
 
-def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool) -> str:
+def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
+                   phased: bool = False) -> str:
     """The compile-cache shape signature recorded with jit compile
     events (utils/devicetelem): the padded dims + static flags that key
     the trace cache, so a recompile storm names the shape that drove it.
     A call of several sets names their summed rows and groups and how
     many they were."""
-    Sp = sum(vals_p.shape[0] for vals_p, _, _ in sets)
+    Sp = sum(st[0].shape[0] for st in sets)
     return (f"S{Sp}xT{plan.Tp}xW{plan.t1.shape[1]}xG{sum(num_groups)}:{kind}"
-            + (":ragged" if ragged else "")
+            + (":ragged" if ragged else "") + (":phased" if phased else "")
             + (f":{len(sets)}sets" if len(sets) > 1 else ""))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
-    "kind", "ragged", "per_series"))
+    "kind", "ragged", "per_series", "phased"))
 def _run(sets, offsets, rows, tsrow, *,
          num_groups: Tuple[int, ...], is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
-         ragged: bool = False, per_series: bool = False):
+         ragged: bool = False, per_series: bool = False,
+         phased: bool = False):
     """One fused dispatch, whole: the plan's kernel operands
     (kernel_operands), built once, then for every working set of `sets`
     its group merge (merge_gid_cols) and its own Pallas call, in one
@@ -593,26 +693,33 @@ def _run(sets, offsets, rows, tsrow, *,
     group-id shifts of every set's columns one after the other (None
     when no set has more than one), so a new group count compiles
     nothing; `rows` / `tsrow` are the plan's uploaded rows, shared by all
-    sets.  Returns the sets' outputs concatenated on the group axis (a
-    pair of them when `ragged`): set i's rows start at
+    sets.  `phased`: every set is a working set on a phase grid and
+    carries its [Sp, 1] phase column as a fourth member, and `rows` is
+    the plan's [16, Wp] `prows`; unphased sets, operands and program are
+    what they were before the variant existed.  Returns the sets' outputs
+    concatenated on the group axis (a pair of them, sums and present
+    counts, when `ragged` or `phased`): set i's rows start at
     sum(num_groups[:i]).  A set's block is what a call of that set alone
     returns, bit for bit: the sets meet only in the concatenation."""
     Tp = sets[0][0].shape[1]
-    operands = kernel_operands(rows, tsrow, Tp, kind)
+    operands = kernel_operands(rows, tsrow, Tp, kind, phased)
     outs, p0 = [], 0
-    for (vals_p, vbase_p, gids), Gp in zip(sets, num_groups):
+    for st, Gp in zip(sets, num_groups):
+        vals_p, vbase_p, gids = st[:3]
         offs = None
         if offsets is not None and len(gids) > 1:
             offs = offsets[p0:p0 + len(gids)]
         p0 += len(gids)
         outs.append(_run_set(
             vals_p, vbase_p, merge_gid_cols(gids, offs), operands,
-            rows.shape[1], Gp, is_counter=is_counter, is_rate=is_rate,
+            rows.shape[1], Gp, st[3] if phased else None,
+            is_counter=is_counter, is_rate=is_rate,
             with_drops=with_drops, interpret=interpret, kind=kind,
-            ragged=ragged, per_series=per_series))
+            ragged=ragged, per_series=per_series, phased=phased))
+    paired = ragged or phased
     if len(outs) == 1:
-        return tuple(outs[0]) if ragged else outs[0]
-    if ragged:
+        return tuple(outs[0]) if paired else outs[0]
+    if paired:
         return tuple(jnp.concatenate([o[k] for o in outs], axis=0)
                      for k in (0, 1))
     return jnp.concatenate(outs, axis=0)
@@ -620,10 +727,12 @@ def _run(sets, offsets, rows, tsrow, *,
 
 @functools.partial(jax.jit, static_argnames=(
     "Wp", "Gp", "is_counter", "is_rate", "with_drops", "interpret", "kind",
-    "ragged", "per_series"))
-def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int, *,
+    "ragged", "per_series", "phased"))
+def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
+             phase_p=None, *,
              is_counter: bool, is_rate: bool, with_drops: bool,
-             interpret: bool, kind: str, ragged: bool, per_series: bool):
+             interpret: bool, kind: str, ragged: bool, per_series: bool,
+             phased: bool = False):
     """One working set's Pallas call inside `_run`'s trace: its own
     series block, grid and group count over the shared plan operands.
     Called from `_run` and nowhere else.  It is a jit only so that sets
@@ -635,13 +744,14 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int, *,
     from jax.experimental.pallas import tpu as pltpu
 
     Sp, Tp = vals_p.shape
-    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = operands
+    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = operands[:12]
     # adaptive series block: the ragged rate family's scan temporaries
     # scale with bs*Tp, so long rows shrink the block instead of OOMing
     # scoped vmem (or being rejected by the eligibility gate).  All
     # shapes here are static at trace time; Sp is padded to _BS, which
     # every smaller power-of-two block divides.
-    bs = pick_block(Tp, Wp, Gp, kind, ragged, panels=gids_p.shape[1])
+    bs = pick_block(Tp, Wp, Gp, kind, ragged, panels=gids_p.shape[1],
+                    phased=phased)
     if bs is None:
         if interpret:
             bs = _MIN_BS            # no scoped-vmem limit off-chip
@@ -662,8 +772,9 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int, *,
     fix = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0), **space)  # noqa: E731
     kern = functools.partial(_kernel, num_groups=Gp, is_counter=is_counter,
                              is_rate=is_rate, with_drops=with_drops,
-                             kind=kind, ragged=ragged, per_series=per_series)
-    with_counts = ragged                 # presence rides a second output
+                             kind=kind, ragged=ragged, per_series=per_series,
+                             phased=phased)
+    with_counts = ragged or phased       # presence rides a second output
     if per_series:
         out_spec = pl.BlockSpec((bs, Wp), lambda i: (i, 0), **space)
         out_shape = jax.ShapeDtypeStruct((Sp, Wp), jnp.float32)
@@ -681,12 +792,14 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int, *,
                   fix(o1.shape), fix(o2.shape), fix(l1.shape),
                   fix(l2.shape),
                   fix((1, Wp)), fix((1, Wp)), fix((1, Wp)), fix((1, Wp)),
-                  fix((1, Wp)), fix((1, Tp)), fix((1, Wp)), fix((1, Wp))],
+                  fix((1, Wp)), fix((1, Tp)), fix((1, Wp)), fix((1, Wp))]
+        # the phased variant's two: the plan's rows, the set's phases
+        + ([fix(operands[12].shape), col_spec] if phased else []),
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
     )(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
-      idx1, idx2)
+      idx1, idx2, *((operands[12], phase_p) if phased else ()))
 
 
 VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
@@ -694,7 +807,7 @@ VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
 
 def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
                   ragged: bool = False, bs: int = _BS,
-                  panels: int = 1) -> int:
+                  panels: int = 1, phased: bool = False) -> int:
     """Rough resident-bytes model for one grid step: the band kinds' 4
     selection matrices and band temporary (the gather kinds ship 4 KB
     stand-ins), the double-buffered values block, the group one-hot +
@@ -720,12 +833,15 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     # accumulated multi-hot — a large merged batch that fit the P=1
     # model could still exceed scoped VMEM at Mosaic lowering on-chip
     group = Gp * (Wp * 8 + bs * 4 * max(panels, 1))
-    inter = 12 * bs * Wp * 4
-    return sel + vals + group + inter
+    # the phased variant keeps a row's own slots, counts and presence
+    # beside them: eight more [bs, Wp] temporaries, and the second output
+    inter = (20 if phased else 12) * bs * Wp * 4
+    return sel + vals + group + inter + (Gp * Wp * 8 if phased else 0)
 
 
 def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
-               ragged: bool = False, panels: int = 1) -> Optional[int]:
+               ragged: bool = False, panels: int = 1,
+               phased: bool = False) -> Optional[int]:
     """Largest series-block size whose vmem_estimate fits VMEM_BUDGET
     (None when even _MIN_BS doesn't — the caller must divert to the
     general path).  The ragged rate family's scan temporaries scale with
@@ -735,7 +851,7 @@ def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     bs = _BS
     while bs >= _MIN_BS:
         if vmem_estimate(Tp, Wp, Gp, kind, ragged, bs=bs,
-                         panels=panels) <= VMEM_BUDGET:
+                         panels=panels, phased=phased) <= VMEM_BUDGET:
             return bs
         bs //= 2
     return None
@@ -799,13 +915,18 @@ class PreparedInputs(NamedTuple):
     vbase_p: jax.Array   # [Sp, 1] f32
     gids_p: jax.Array    # [Sp, 1] int32 (-1 pad rows)
     gsize: np.ndarray    # [num_groups] series per group
+    phase_p: Optional[jax.Array] = None   # as PaddedValues.phase_p
 
 
 class PaddedValues(NamedTuple):
     """The grouping-independent (and byte-dominant) half of PreparedInputs
-    — cacheable once per (working set, column) across grouping variants."""
+    — cacheable once per (working set, column) across grouping variants.
+    `phase_p` says which kernel variant the set runs: None for rows on
+    one shared timestamp row (every phase zero), else the rows' phases on
+    the plan's base row and the phased variant."""
     vals_p: jax.Array    # [Sp, Tp] f32
     vbase_p: jax.Array   # [Sp, 1] f32
+    phase_p: Optional[jax.Array] = None   # [Sp, 1] f32 ms (0 pad rows)
 
 
 class PaddedGroups(NamedTuple):
@@ -814,9 +935,21 @@ class PaddedGroups(NamedTuple):
     gsize: np.ndarray    # [num_groups]
 
 
-def pad_values(vals, vbase, plan: FusedPlan, device=None) -> PaddedValues:
+def pad_values(vals, vbase, plan: FusedPlan, device=None,
+               phase=None) -> PaddedValues:
+    """`phase`: the rows' phases on the plan's base row, a host [S] array
+    of whole milliseconds (all zero is None: the data decide the variant)
+    or the [Sp, 1] f32 column already on the device."""
     S = vals.shape[0]
     Sp = pad_series_count(S)
+    phase_p = None
+    if getattr(phase, "ndim", 1) == 2:
+        phase_p = phase
+    elif phase is not None and np.any(phase):
+        col = np.zeros((Sp, 1), np.float32)
+        col[:S, 0] = phase
+        phase_p = (jnp.asarray(col) if device is None
+                   else jax.device_put(col, device))
     if device is not None:
         # commit the inputs straight to the owning chip so the pad
         # computes (and its result lives) there — staging through
@@ -832,7 +965,7 @@ def pad_values(vals, vbase, plan: FusedPlan, device=None) -> PaddedValues:
     vals_p = vals_p.at[:S, :vals.shape[1]].set(v)
     vbase_p = jnp.zeros((Sp, 1), jnp.float32)
     vbase_p = vbase_p.at[:S, 0].set(vb)
-    return PaddedValues(vals_p, vbase_p)
+    return PaddedValues(vals_p, vbase_p, phase_p)
 
 
 def pad_groups(gids, S: int, num_groups: int,
@@ -850,10 +983,10 @@ def pad_groups(gids, S: int, num_groups: int,
 
 
 def pad_inputs(vals, vbase, gids, plan: FusedPlan,
-               num_groups: int, device=None) -> PreparedInputs:
-    v = pad_values(vals, vbase, plan, device=device)
+               num_groups: int, device=None, phase=None) -> PreparedInputs:
+    v = pad_values(vals, vbase, plan, device=device, phase=phase)
     g = pad_groups(gids, vals.shape[0], num_groups, device=device)
-    return PreparedInputs(v.vals_p, v.vbase_p, g.gids_p, g.gsize)
+    return PreparedInputs(v.vals_p, v.vbase_p, g.gids_p, g.gsize, v.phase_p)
 
 
 def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
@@ -862,7 +995,7 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
                         interpret: bool = False,
                         prepared: Optional[PreparedInputs] = None,
                         ragged: bool = False,
-                        device=None
+                        device=None, phase=None
                         ) -> Tuple[jax.Array, np.ndarray]:
     """-> (sums [G, W] device array, counts [G, W] numpy).
 
@@ -872,7 +1005,9 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
     dense/shared-grid precondition: counts[g, w] = |group g| * 1{n[w] >= 2}
     — NaN where 0, matching ops/agg.py present().  ragged=True runs the
     validity-aware kernel variant instead; counts then come back from the
-    kernel's per-cell presence output.
+    kernel's per-cell presence output.  `phase` ([S] whole ms, see
+    pad_values): rows at `plan`'s timestamp row plus their phase; counts
+    come back from the kernel then too.
 
     `device` pins every operand (values, plan rows) to that chip so the
     jit executes THERE — the per-device unit of the multi-chip dispatch
@@ -882,17 +1017,18 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
     over_time = fn_name in OVER_TIME_FNS
     if prepared is None:
         prepared = pad_inputs(vals, vbase, gids, plan, num_groups,
-                              device=device)
+                              device=device, phase=phase)
     elif device is None:
         # caller-prepared inputs may already be pinned (sharded mirror
         # mode) — put the plan rows on the same chip
         device = _committed_device(prepared.vals_p)
     Gp = pad_group_count(num_groups)
+    phased = prepared.phase_p is not None
     res, _ = _enqueue_run(
-        plan, device, ((prepared.vals_p, prepared.vbase_p,
-                        (prepared.gids_p,)),), None, (Gp,),
-        **_flavor(fn_name, precorrected, interpret, ragged)._asdict())
-    if ragged:
+        plan, device, (_kernel_set(prepared, (prepared.gids_p,)),), None,
+        (Gp,),
+        **_flavor(fn_name, precorrected, interpret, ragged, phased)._asdict())
+    if ragged or phased:
         sums, cnts = res
         counts = np.asarray(cnts, np.float64)[:num_groups, :plan.W]
     else:
@@ -1078,7 +1214,8 @@ def fused_leaf_agg(plan: FusedPlan, prepared: PreparedInputs,
     agg min/max use the kernel's per-series output mode plus an XLA
     segment reduction (ops/agg.map_phase) on the small [S, W] result.
     Single-panel form of fused_leaf_agg_batch."""
-    values = PaddedValues(prepared.vals_p, prepared.vbase_p)
+    values = PaddedValues(prepared.vals_p, prepared.vbase_p,
+                          prepared.phase_p)
     groups = PaddedGroups(prepared.gids_p, prepared.gsize)
     return fused_leaf_agg_batch(
         plan, values, [(groups, num_groups, agg_op)], fn_name,
@@ -1113,14 +1250,15 @@ def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
     watched.  -> (the call's lazy result, the uploaded rows)."""
     from filodb_tpu.utils.devicetelem import watched_call
     from filodb_tpu.utils.metrics import span_part
-    kind, ragged = flags["kind"], flags["ragged"]
+    kind, ragged, phased = flags["kind"], flags["ragged"], flags["phased"]
     with span_part("leaf.enqueue_pack"):
         rows, tsrow, offs = enqueue_operands(plan, device, kind, ragged,
-                                             offsets, sets=len(sets))
+                                             offsets, sets=len(sets),
+                                             phased=phased)
     with span_part("leaf.enqueue_jit"):
         res = watched_call(
             "fused_run", _run,
-            _run_shape_sig(sets, plan, num_groups, kind, ragged),
+            _run_shape_sig(sets, plan, num_groups, kind, ragged, phased),
             lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
                          **flags),
             device=device)
@@ -1134,17 +1272,25 @@ class _FlavorFlags(NamedTuple):
     interpret: bool
     kind: str
     ragged: bool
+    phased: bool
 
 
 def _flavor(fn_name: str, precorrected: bool, interpret: bool,
-            ragged: bool) -> _FlavorFlags:
+            ragged: bool, phased: bool = False) -> _FlavorFlags:
     """`_run`'s static flags of one (function, precorrected, interpret,
-    ragged) flavor."""
+    ragged, phased) flavor."""
     is_counter = fn_name in ("rate", "increase")
     return _FlavorFlags(
         is_counter, fn_name == "rate", is_counter and not precorrected,
         interpret, fn_name if fn_name in OVER_TIME_FNS else "rate_family",
-        ragged)
+        ragged, phased)
+
+
+def _kernel_set(values, gid_cols: tuple) -> tuple:
+    """One working set as `_run` takes it: (vals_p, vbase_p, gid columns),
+    and the phase column behind them where the set has one."""
+    st = (values.vals_p, values.vbase_p, gid_cols)
+    return st if values.phase_p is None else st + (values.phase_p,)
 
 
 class FusedDispatch:
@@ -1166,9 +1312,9 @@ class FusedDispatch:
 
     def __init__(self, plan: FusedPlan, fn_name: str,
                  precorrected: bool = False, interpret: bool = False,
-                 ragged: bool = False, device=None):
+                 ragged: bool = False, device=None, phased: bool = False):
         self.plan = plan
-        self.key = (fn_name, precorrected, interpret, ragged)
+        self.key = (fn_name, precorrected, interpret, ragged, phased)
         self.device = device
         self.flags = _flavor(*self.key)
         self._sets: list = []       # (values, [(groups, G, op)], offsets)
@@ -1205,8 +1351,8 @@ class FusedDispatch:
         base = 0
         for k in order:
             values, panels, offs = self._sets[k]
-            sets.append((values.vals_p, values.vbase_p,
-                         tuple(g.gids_p for g, _, _ in panels)))
+            sets.append(_kernel_set(
+                values, tuple(g.gids_p for g, _, _ in panels)))
             offsets.append(offs)
             self._lo[k] = base + offs
             # the set's rows of the output: its panels' groups, then pad
@@ -1218,9 +1364,10 @@ class FusedDispatch:
             self.plan, self.device, tuple(sets),
             np.concatenate(offsets) if multi else None,
             tuple(gps[k] for k in order), **self.flags._asdict())
-        if not self.flags.ragged:
-            # dense rows: the counts are |group| x the shared window
-            # validity, nothing of the result: made while the device works
+        if not (self.flags.ragged or self.flags.phased):
+            # dense rows on one timestamp row: the counts are |group| x
+            # the shared window validity, nothing of the result: made
+            # while the device works
             plan = self.plan
             wvalid = (plan.wvalid1 if self.flags.kind in OVER_TIME_FNS
                       else plan.wvalid)
@@ -1230,12 +1377,12 @@ class FusedDispatch:
     def fetch(self) -> None:
         """The one synchronizing readback, and the panels' presentation
         over the whole array: sums masked to the present cells beside
-        their counts (the kernel's presence output on ragged rows, else
-        |group| x the shared window validity), f64 on the host."""
+        their counts (the kernel's presence output on ragged or phased rows,
+        else |group| x the shared window validity), f64 on the host."""
         if self._comps is not None or self._res is None:
             return
         W = self.plan.W
-        if self.flags.ragged:
+        if self.flags.ragged or self.flags.phased:
             sums, counts = (r[:, :W] for r in jax.device_get(self._res))
         else:
             sums, counts = np.asarray(self._res)[:, :W], self._counts
@@ -1293,14 +1440,18 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
     the chips compute concurrently."""
     over_time = fn_name in OVER_TIME_FNS
     wvalid = plan.wvalid1 if over_time else plan.wvalid
+    # rows on a phase grid: presence is a row's own, so the kernel counts
+    # it (as on ragged rows) and `count` rides the group-mode run
+    phased = values.phase_p is not None
     own = dispatch is None
     if own:
         # sharded DeviceMirror mode: the working set is committed to its
         # shard's chip — the plan rows go there too, so the call runs there
         dispatch = FusedDispatch(plan, fn_name, precorrected, interpret,
-                                 ragged, _committed_device(values.vals_p))
+                                 ragged, _committed_device(values.vals_p),
+                                 phased)
     elif dispatch.plan is not plan or dispatch.key != (
-            fn_name, precorrected, interpret, ragged):
+            fn_name, precorrected, interpret, ragged, phased):
         raise ValueError("fused dispatch shared across plans or flavors")
 
     def dense_counts(groups):
@@ -1308,7 +1459,8 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
             wvalid[None, :].astype(np.float64)
 
     mm_idx = [i for i, (_, _, op) in enumerate(panels)
-              if op in ("sum", "avg") or (op == "count" and ragged)]
+              if op in ("sum", "avg")
+              or (op == "count" and (ragged or phased))]
     ps_idx = [i for i, (_, _, op) in enumerate(panels)
               if op in ("min", "max")]
     bad = [op for _, _, op in panels
@@ -1335,9 +1487,8 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
         # one shared per-series run: the [S, W] output is group-agnostic
         res, rows = _enqueue_run(
             plan, dispatch.device,
-            ((values.vals_p, values.vbase_p,
-              (panels[ps_idx[0]][0].gids_p,)),), None, (8,),
-            per_series=True, **dispatch.flags._asdict())
+            (_kernel_set(values, (panels[ps_idx[0]][0].gids_p,)),), None,
+            (8,), per_series=True, **dispatch.flags._asdict())
         with span_part("leaf.enqueue_jit"):
             ps_comps = _per_series_aggs(
                 res, rows, tuple(panels[i][0].gids_p for i in ps_idx),

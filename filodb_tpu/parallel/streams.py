@@ -36,7 +36,7 @@ from filodb_tpu.query.rangevector import ResultBlock
 # constant fields).  Optional row arrays (vbase, comp vs sketch) are
 # simply absent from a begin's templates when None.
 _SPECS: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...], Tuple[str, ...]]] = {
-    "RawBlock": (("keys",), ("ts_off", "values", "vbase"),
+    "RawBlock": (("keys",), ("ts_off", "values", "vbase", "phase"),
                  ("base_ms", "bucket_les", "samples", "precorrected",
                   "shared_ts_row", "dense", "route_host")),
     "ResultBlock": (("keys",), ("values",), ("wends", "bucket_les")),

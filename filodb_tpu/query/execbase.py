@@ -92,7 +92,10 @@ class RawBlock:
     A leaf over the device mirror hands `ts_off`, `values` and `vbase` as
     DeferredRows (core/devicecache.MirrorGather): each is gathered out of
     the mirror when that field is first read, and every reader gets the
-    array it would have got.  `values_shape` answers without a read."""
+    array it would have got.  `values_shape` answers without a read.
+    `phase` is held the same way and reads as the snapshot's HOST copy of
+    the rows' phases (no device work); `rows_padded("phase", n)` is the
+    device column the fused kernel takes."""
     keys: List[RangeVectorKey]
     ts_off: np.ndarray                  # int32 [S, T] offsets from base_ms
     values: np.ndarray                  # [S, T] or [S, T, B]
@@ -118,6 +121,11 @@ class RawBlock:
     # gather then stays host-side and _try_fused evaluates in numpy
     # (ops/hostleaf) instead of paying the ~65 ms device dispatch floor
     route_host: bool = False
+    # rows on a phase grid (core/devicecache._MirrorSnapshot): row s lies
+    # at shared_ts_row + phase[s], int32 [S] whole ms.  None: every row
+    # lies on shared_ts_row itself.  Only the fused leaf reads a grid with
+    # phases; every other reader takes `shared_grid` below
+    phase: Optional[np.ndarray] = None
 
     @property
     def values_shape(self) -> Tuple[int, ...]:
@@ -125,9 +133,21 @@ class RawBlock:
         known without gathering rows nobody has read yet."""
         return tuple(getattr(self.__dict__["_values"], "shape", ()))
 
+    @property
+    def phased(self) -> bool:
+        """Whether the rows may lie off `shared_ts_row` by a phase (known
+        without reading the phases)."""
+        return self.__dict__["_phase"] is not None
+
+    @property
+    def shared_grid(self) -> bool:
+        """Every row lies on `shared_ts_row` itself: ONE timestamp row
+        stands for all of them."""
+        return self.shared_ts_row is not None and not self.phased
+
     def rows_padded(self, field: str, rows_to: int):
-        """`field` (`values` or `vbase`) for the fused leaf's padded working
-        set: rows still in the mirror are taken with zero rows up to
+        """`field` (`values`, `vbase` or `phase`) for the fused leaf's
+        padded working set: rows still in the mirror are taken with zero rows up to
         `rows_to` behind them (DeferredRows.resolve: one program a padded
         row count); an array already here comes as it is."""
         held = self.__dict__["_" + field]
@@ -137,13 +157,16 @@ class RawBlock:
 
 def _resolved_on_read(field: str) -> property:
     """A RawBlock field that may be set to a DeferredRows and reads as its
-    array.  Still a dataclass field: the constructor, `dataclasses.replace`
-    and the wire formats (parallel/serialize.py, streams.py) see the name."""
+    array (`phase`: as the host copy of its rows).  Still a dataclass
+    field: the constructor, `dataclasses.replace` and the wire formats
+    (parallel/serialize.py, streams.py) see the name."""
     slot = "_" + field
 
     def get(self):
         held = self.__dict__[slot]
-        return held.resolve() if isinstance(held, DeferredRows) else held
+        if not isinstance(held, DeferredRows):
+            return held
+        return held.host() if field == "phase" else held.resolve()
 
     def put(self, value):
         self.__dict__[slot] = value
@@ -151,7 +174,7 @@ def _resolved_on_read(field: str) -> property:
     return property(get, put)
 
 
-for _field in ("ts_off", "values", "vbase"):
+for _field in ("ts_off", "values", "vbase", "phase"):
     setattr(RawBlock, _field, _resolved_on_read(_field))
 
 
@@ -232,12 +255,13 @@ class _FusedCache(dict):
 
 
 def _plan_nbytes(plan) -> int:
-    return int(plan.rows.nbytes + plan.tsrow.nbytes + plan.wvalid.nbytes
+    return int(plan.prows.nbytes + plan.tsrow.nbytes + plan.wvalid.nbytes
                + plan.wvalid1.nbytes)
 
 
 def _vals_nbytes(v) -> int:
-    return int(v.vals_p.size * 4 + v.vbase_p.size * 4)
+    return int(v.vals_p.size * 4 + v.vbase_p.size * 4
+               + (0 if v.phase_p is None else v.phase_p.size * 4))
 
 
 def _groups_nbytes(ent) -> int:

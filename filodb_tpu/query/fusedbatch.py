@@ -48,6 +48,12 @@ class FusedCall:
     # hit the exprfuse index-map cache like the host-routed ones
     cache_token: Optional[tuple] = None
 
+    @property
+    def phased(self) -> bool:
+        """The working set's rows lie on a phase grid: the kernel's
+        phased variant, which counts presence a row (pf.PaddedValues)."""
+        return self.values.phase_p is not None
+
     def compat_key(self):
         base = (self.fn, self.precorrected, self.interpret, self.ragged)
         if self.cache_key is not None:
@@ -95,7 +101,8 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         # run per-series (Gp-independent) and dense count is host
         # math, so neither counts toward the multi-hot group total
         op = calls[i].op
-        return op in ("sum", "avg") or (op == "count" and calls[i].ragged)
+        return op in ("sum", "avg") or (
+            op == "count" and (calls[i].ragged or calls[i].phased))
 
     # the working sets: per compat key the panels one kernel run can hold
     sets: List[List[int]] = []
@@ -114,8 +121,8 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
                                 if in_group_mode(i))
                     if total == 0 or pf.pick_block(
                             Tp, Wp, pf.pad_group_count(total), kind,
-                            fc0.ragged,
-                            panels=max(n_group, 1)) is not None:
+                            fc0.ragged, panels=max(n_group, 1),
+                            phased=fc0.phased) is not None:
                         break
                     take = take[:max(1, len(take) // 2)]
             if len(take) > 1:
@@ -142,7 +149,8 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         fc0 = calls[take[0]]
         by_call.setdefault(
             (id(fc0.plan), fc0.fn, fc0.precorrected, fc0.interpret,
-             fc0.ragged, pf._committed_device(fc0.values.vals_p)),
+             fc0.ragged, fc0.phased,
+             pf._committed_device(fc0.values.vals_p)),
             []).append(take)
 
     # two-phase execution: phase A dispatches every call's kernel work
@@ -156,7 +164,8 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         fc0 = calls[takes[0][0]]
         with span("leaf.kernel_enqueue") as enqueue:
             disp = pf.FusedDispatch(fc0.plan, fc0.fn, fc0.precorrected,
-                                    fc0.interpret, fc0.ragged, device)
+                                    fc0.interpret, fc0.ragged, device,
+                                    fc0.phased)
             finishers = []
             for take in takes:
                 fc = calls[take[0]]
@@ -189,7 +198,8 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
             f"fused_{fc0.fn}", device=disp.device,
             shape=(f"S{sum(calls[t[0]].num_series for t in takes)}"
                    f"xW{len(fc0.wends)}x{sum(map(len, takes))}p"
-                   f"x{len(takes)}s" + (":ragged" if fc0.ragged else "")),
+                   f"x{len(takes)}s" + (":ragged" if fc0.ragged else "")
+                   + (":phased" if fc0.phased else "")),
             seconds=disp_s + fetch.dur_s,
             bytes_in=sum(int(getattr(calls[t[0]].values.vals_p, "nbytes", 0))
                          for t in takes),

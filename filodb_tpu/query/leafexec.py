@@ -209,6 +209,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         dense = data.dense
         if not pf.can_fuse(fn, t1.op, True, dense):
             return None
+        # rows of a phase grid hold their own count of samples a window:
+        # what follows from ONE row's counts (the host-only count paths,
+        # the host route, reduce_window's window geometry) is not theirs.
+        # The matmul kernel's phased variant is, for scalar columns
+        phased = data.phased
+        if phased and (is_hist or fn in pf.MINMAX_FNS):
+            return None
         if is_hist:
             # histogram buckets are counters too: flatten [S, T, B] into
             # S*B kernel rows with per-(group, bucket) slots — the hist
@@ -223,10 +230,11 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # host-only fast paths: under the dense shared grid every series
         # has IDENTICAL per-window sample counts, so count_over_time and
         # the count aggregate are pure host math — no device work at all
-        if dense and not is_hist and fn == "count_over_time":
-            return self._fused_count_over_time(data, t0, t1)
-        if dense and not is_hist and t1.op == "count":
-            return self._fused_count_agg(data, t0, t1)
+        if dense and not is_hist and not phased:
+            if fn == "count_over_time":
+                return self._fused_count_over_time(data, t0, t1)
+            if t1.op == "count":
+                return self._fused_count_agg(data, t0, t1)
         wends = make_window_ends(t0.start_ms, t0.end_ms, t0.step_ms)
         eval_wends = wends - t0.offset_ms - data.base_ms
         if eval_wends.size == 0 or abs(eval_wends).max() >= (1 << 30):
@@ -249,7 +257,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         Tp = pf._pad_to(shape[1], pf._LANE)
         Wp = pf._pad_to(eval_wends.size, pf._LANE)
         kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
-        if pf.pick_block(Tp, Wp, 8, kind, not dense) is None:
+        if pf.pick_block(Tp, Wp, 8, kind, not dense, phased=phased) is None:
             return None
         from filodb_tpu.utils.metrics import registry
         # plan + prepared-input caches: a repeat query over an unchanged
@@ -257,13 +265,20 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # rebuild AND the full padded device copy (PreparedInputs contract)
         key = self._fused_cache_key
         plan = padded_vals = groups = gkeys = None
+        # a plan reads differences of timestamps only: built from the row
+        # moved to start at 0 (the window ends with it), two shards whose
+        # mirrors have other bases under one absolute row share it
+        plan_row = data.shared_ts_row
+        plan_shift = int(plan_row[0]) if plan_row.size else 0
+        if plan_shift:
+            plan_row = plan_row - plan_row.dtype.type(plan_shift)
         if key is not None:
             # a plan is built from the shared timestamp row, the grid, the
             # window and the base, and from nothing of the shard: a
             # request's leaves and an open's panels share one build
-            plan_key = ("plan", data.shared_ts_row.tobytes(), t0.start_ms,
+            plan_key = ("plan", plan_row.tobytes(), t0.start_ms,
                         t0.step_ms, t0.end_ms, t0.offset_ms, t0.window_ms,
-                        data.base_ms)
+                        data.base_ms + plan_shift)
             with _FUSED_CACHE_LOCK:
                 plan = _FUSED_PLAN_CACHE.lookup(plan_key)
                 padded_vals = _FUSED_VALS_CACHE.lookup(key)
@@ -272,8 +287,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 registry.counter("leaf_fused_prep_hits").increment()
         if plan is None:
             with span("leaf.build_plan"):
-                plan = pf.build_plan(data.shared_ts_row.astype(np.int64),
-                                     eval_wends, t0.window_ms)
+                plan = pf.build_plan(plan_row.astype(np.int64),
+                                     eval_wends - plan_shift, t0.window_ms)
             if key is not None:
                 with _FUSED_CACHE_LOCK:
                     # the first build of a grid stays: a request whose
@@ -291,7 +306,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # same padded group count _run will use — a gate tested on the
         # unpadded count could accept a shape _run then rejects
         if pf.pick_block(Tp, Wp, pf.pad_group_count(num_slots), kind,
-                         not dense) is None:
+                         not dense, phased=phased) is None:
             return None
         if padded_vals is None:
             if is_hist:
@@ -316,8 +331,14 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 vbase = data.rows_padded("vbase", Sp)
                 if vbase is None:
                     vbase = np.zeros(vals.shape[0], np.float32)
+                # the kernel variant follows from the data: the phased one
+                # where some row of THIS working set has a phase
+                phase = None
+                if phased and data.phase.any():
+                    phase = data.rows_padded("phase", Sp)
                 with span("leaf.pad_values"):
-                    padded_vals = pf.pad_values(vals, vbase, plan)
+                    padded_vals = pf.pad_values(vals, vbase, plan,
+                                                phase=phase)
             if key is not None:
                 # a new snapshot generation obsoletes this mirror's older
                 # entries — the insert drops them NOW, not at LRU eviction:
@@ -335,6 +356,8 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                     groups = pf.pad_groups(gids, shape[0], len(gkeys))
             _group_cache_insert(key, t1.by, t1.without, groups, gkeys)
         registry.counter("leaf_fused_kernel").increment()
+        if padded_vals.phase_p is not None:
+            registry.counter("leaf_phase_fused").increment()
         if not is_hist:
             # broadened matmul path: any fusable (fn, agg) combination,
             # ragged (validity-weighted) when the working set has NaN
@@ -378,7 +401,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         via RawBlock.route_host).  Returns an AggPartial or None to
         continue onto the device paths."""
         if not (data.route_host and dense and not is_hist
-                and data.shared_ts_row is not None
+                and data.shared_grid
                 and t1.op in ("sum", "avg", "count", "min", "max")
                 and isinstance(data.values, np.ndarray)):
             return None
@@ -812,7 +835,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # the span and the dispatch of each take where it runs)
                 mirrored = mirror.gather_cached(rows, snap)
         # value column selection: histograms gather [S, T, B]
-        shared_ts_row = None
+        shared_ts_row = phase = None
         dense = True
         if mirrored is not None:
             base = mirrored.base_ms
@@ -826,6 +849,14 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             precorrected = counter_col   # mirror corrects counter columns
             shared_ts_row = mirror.fused_eligible(col_name, snap,
                                                   allow_ragged=True)
+            if shared_ts_row is not None:
+                phase = mirrored.deferred("phase")
+            elif snap.counts.size:
+                # the store's rows fit no phase grid (a hole, a late
+                # scrape, targets that come and go): the leaf's
+                # transformers run on the general path
+                from filodb_tpu.utils.metrics import registry as _reg
+                _reg.counter("leaf_offgrid").increment()
             # col_dense is grid-independent (counted cells finite; pads are
             # excluded via PAD_TS), so a non-shared grid with finite values
             # keeps the cheap slot-boundary rate path
@@ -914,7 +945,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         shared_ts_row=shared_ts_row, dense=dense,
                         cache_token=(shard.keys_serial, shard.keys_epoch,
                                      sel.pids_key),
-                        route_host=route_host), stats
+                        route_host=route_host, phase=phase), stats
 
 
 class SelectPersistedSegmentsExec(MultiSchemaPartitionsExec):

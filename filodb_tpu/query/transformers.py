@@ -93,7 +93,7 @@ class PeriodicSamplesMapper(RangeVectorTransformer):
         # window bounds come from row 0 and every gather takes the
         # column fast path).  Halves the general path's HBM timestamp
         # traffic and skips the S-fold ts transfer entirely.
-        shared = data.shared_ts_row is not None
+        shared = data.shared_grid      # rows with phases ship their own
         ts_in = data.ts_off[:1] if shared else data.ts_off
         if vals.ndim == 3:
             S, T, B = vals.shape

@@ -67,13 +67,14 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
-def _compile_run(one_chip, S, G, fn, ragged=False, panels=1):
+def _compile_run(one_chip, S, G, fn, ragged=False, panels=1, phased=False):
     """Lower + compile pallas_fused._run exactly as a FusedDispatch calls
     it (interpret=False).  `S` and `G` may be tuples: one working set
-    each, all in the one program."""
+    each, all in the one program.  `phased`: working sets on a phase
+    grid, each with its [Sp, 1] phase column, over the plan's 16 rows."""
     plan = _plan()
     # precorrected (no drop correction in the kernel), as the mirror serves
-    flags = pf._flavor(fn, True, False, ragged)
+    flags = pf._flavor(fn, True, False, ragged, phased)
     Ss = S if isinstance(S, tuple) else (S,)
     Gs = G if isinstance(G, tuple) else (G,) * len(Ss)
     with_ts = ragged and flags.kind == "rate_family"
@@ -81,11 +82,14 @@ def _compile_run(one_chip, S, G, fn, ragged=False, panels=1):
         (_sds((pf.pad_series_count(s), plan.Tp), jnp.float32, one_chip),
          _sds((pf.pad_series_count(s), 1), jnp.float32, one_chip),
          (_sds((pf.pad_series_count(s), 1), jnp.int32, one_chip),) * panels)
+        + ((_sds((pf.pad_series_count(s), 1), jnp.float32, one_chip),)
+           if phased else ())
         for s in Ss)
     args = [sets,
             _sds((panels * len(Ss),), jnp.int32, one_chip) if panels > 1
             else None,
-            _sds(plan.rows.shape, jnp.float32, one_chip),
+            _sds((plan.prows if phased else plan.rows).shape, jnp.float32,
+                 one_chip),
             _sds(plan.tsrow.shape, jnp.float32, one_chip) if with_ts
             else None]
     return pf._run.lower(
@@ -137,6 +141,31 @@ def test_fused_kernel_compiles_for_v5e(one_chip, chip_runtime,
         # the two [Sp, 1] column operands (vbase_p, gids_p) tile to 1 KiB
         # per row: recorded, not repaired here (ISSUE 24)
         assert ma.temp_size_in_bytes >= pf.pad_series_count(S) * 1024
+
+
+@pytest.mark.parametrize("S,G,fn,ragged,panels", [
+    # promscrape-counters-262k.open's one program a request: four working
+    # sets, each row at the base row plus its target's scrape offset
+    ((79_042, 78_244, 52_281, 52_577), (10, 10, 1, 20), "rate", False, 1),
+    (S_SHARD, 1000, "rate", True, 1),
+    (S_SHARD, 1000, "sum_over_time", False, 1),
+    (S_SHARD, 1000, "avg_over_time", True, 1),
+    (S_SHARD, 1000, "count_over_time", False, 3),
+    (S_SHARD, 1000, "last_over_time", False, 1),
+    (S_SHARD, 1000, "last_over_time", True, 1),
+], ids=["rate-4sets", "rate-ragged", "sum_ot", "avg_ot-ragged",
+        "count_ot-3panels", "last_ot", "last_ot-ragged"])
+def test_phased_kernel_compiles_for_v5e(one_chip, chip_runtime,
+                                        S, G, fn, ragged, panels):
+    """The phased variant (rows on a phase grid: a slot a row, by one
+    compare of its phase with the window's slack): the gathers take an
+    index a (row, window), which Mosaic must lower as it lowers the shared
+    index; sums and present counts come back as a pair."""
+    compiled = _compile_run(one_chip, S, G, fn, ragged, panels, phased=True)
+    _check(compiled, pallas=True)
+    n_sets = len(S) if isinstance(S, tuple) else 1
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == n_sets
 
 
 def test_histogram_gather_and_flatten_compile_for_v5e(one_chip,

@@ -131,6 +131,9 @@ STORES = {
     "ragged counts": lambda: store_of([720, 1, 300, 0, 719, 2] * 16),
     "ragged starts": lambda: store_of(
         [100] * 64, START + np.arange(64) * 7 * STEP),
+    # one scrape grid, each row behind it by its target's offset
+    "scrape offsets": lambda: store_of(
+        [720] * 96, START + (np.arange(96) * 7919) % STEP),
     "empty rows": lambda: store_of([0] * 32),
     "no rows": lambda: store_of([]),
     "one sample a row": lambda: store_of([1] * 40),
@@ -171,8 +174,11 @@ def test_the_estimate_from_facts_is_estimate_scans(shape, span):
         # scalar form: one row's estimate times the rows, where
         # _estimate_scan sums S equal floats: the integer within 1
         assert abs(got - want) <= 1
+    # rows of one count and one extent answer from four scalars wherever
+    # the span clips them alike (ISSUE 37: rows that differ by a phase)
     assert (facts.uniform is not None) == (
-        shape in ("uniform", "empty rows", "one sample a row"))
+        shape in ("uniform", "empty rows", "one sample a row",
+                  "ragged starts", "scrape offsets"))
     # the two decisions made from it, away from the last unit
     for cap in (0, 1, want // 2, 2 * want + 2, 2_000_000):
         assert leaf_route(got, 1, cap) == leaf_route(want, 1, cap)

@@ -11,15 +11,15 @@ CONFIG, CELL = "ts128-counters-262k-32sh", "ts128-counters-262k-32sh.open"
 SERIES, SAMPLES = 2048, 240
 
 
-def small_config(**over):
-    return dict(bench_json("configs", CONFIG), series=SERIES,
+def small_config(config=CONFIG, **over):
+    return dict(bench_json("configs", config), series=SERIES,
                 samples=SAMPLES, **over)
 
 
-def small_plan(cfg, seed):
+def small_plan(cfg, seed, cell=CELL):
     """The cell's six panels on a grid that 240 samples hold: 20 minutes a
     request, two phases."""
-    tp = dict(bench_json("workloads", CELL)["traffic"], span_s=1200,
+    tp = dict(bench_json("workloads", cell)["traffic"], span_s=1200,
               phases=2, warmup_opens=1)
     return bench_module("traffic", tp["kind"]).Plan(cfg, tp, seed)
 
@@ -28,12 +28,15 @@ class Ts128Rig(HistRig):
     """One `FiloServer` on port 0 holding the small deployment, loaded by
     the configuration's loader, with the reference's tables in the client's `Tables`;
     `get`, `ask`, `open`, `counters` and `close` are `HistRig`'s.
-    `FILODB_TPU_FUSED_INTERPRET=1` is the caller's to set."""
+    `FILODB_TPU_FUSED_INTERPRET=1` is the caller's to set.  A subclass
+    names another counters configuration and cell (`CONFIG`, `CELL`)."""
+    CONFIG, CELL = CONFIG, CELL
 
     def __init__(self, seed, control=None):
         from filodb_tpu.standalone import DatasetConfig, FiloServer
-        self.cfg = small_config()
-        self.plan = small_plan(self.cfg, seed)
+        self.seed = seed
+        self.cfg = small_config(self.CONFIG)
+        self.plan = small_plan(self.cfg, seed, self.CELL)
         self.srv = FiloServer(
             [DatasetConfig(self.cfg["dataset"], self.cfg["shards"])],
             http_host="127.0.0.1", http_port=0)
