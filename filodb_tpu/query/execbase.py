@@ -463,6 +463,37 @@ def _block_empty(wends: np.ndarray) -> ResultBlock:
 
 
 
+def _present_comp(op: str, comp: np.ndarray) -> np.ndarray:
+    """Merged components [G, W, C] -> final [G, W], NaN where no series
+    was present: ops/agg.present in NumPy, on the host, where the merged
+    partial already is.  (On the device the same [G, W, C] cost an
+    upload, a jit call and a blocking readback behind other requests'
+    kernels, for under a microsecond of work, and took the merged f64
+    sums through f32.  parallel/mesh.py presents on the device what is
+    already there, with agg_ops.present.)"""
+    if op == "group":
+        v = comp[..., 0]
+        return np.where(np.isinf(v), np.nan, v)
+    c = comp[..., -1]
+    if op == "count":
+        out = c
+    elif op in ("sum", "min", "max"):
+        out = comp[..., 0]
+    elif op == "avg":
+        out = comp[..., 0] / np.maximum(c, 1.0)
+    elif op in ("stddev", "stdvar"):
+        cs = np.maximum(c, 1.0)
+        # inf - inf where a group's samples overflow: NaN on the device too
+        with np.errstate(invalid="ignore", over="ignore"):
+            out = np.maximum(comp[..., 1] / cs - (comp[..., 0] / cs) ** 2,
+                             0.0)
+            if op == "stddev":
+                out = np.sqrt(out)
+    else:
+        raise ValueError(op)
+    return np.where(c > 0, out, np.nan)
+
+
 def present_partial(p: AggPartial) -> Optional[ResultBlock]:
     """Finish an AggPartial into a ResultBlock."""
     if p.sketch is not None:
@@ -479,8 +510,8 @@ def present_partial(p: AggPartial) -> Optional[ResultBlock]:
             out = np.where(present_cnt[..., None] > 0, buckets, np.nan)
             return ResultBlock(p.group_keys, p.wends, out, p.bucket_les,
                                cache_token=p.cache_token)
-        out = np.asarray(agg_ops.present(p.op, jnp.asarray(p.comp)))
-        return ResultBlock(p.group_keys, p.wends, out,
+        return ResultBlock(p.group_keys, p.wends,
+                           _present_comp(p.op, p.comp),
                            cache_token=p.cache_token)
     # candidate form
     if p.op in ("topk", "bottomk"):
@@ -595,16 +626,60 @@ def reduce_partials(parts: List[AggPartial],
     return _reduce_aligned(parts, compress)
 
 
+# Merge layouts: what _reduce_aligned derives from its children's group
+# keys alone (the merged key list, and for every child row its row of the
+# merged block), by _reduced_token(parts).  A child's token names its
+# working set (keys epoch, row set) and grouping, so a new epoch or row
+# set is another key and a stale entry is never asked for again; the
+# oldest entries leave beyond _REDUCE_LAYOUTS_MAX (an entry is a tuple of
+# keys the partials hold anyway and 8 bytes a child row: 20 KB at 30
+# shards of 80 groups).
+_REDUCE_LAYOUTS: Dict[Tuple, Tuple] = {}
+_REDUCE_LAYOUTS_MAX = 256
+_REDUCE_LAYOUT_LOCK = threading.Lock()
+
+
+def _merge_layout(parts: List[AggPartial], token: Optional[Tuple]
+                  ) -> Tuple[List[RangeVectorKey], np.ndarray]:
+    """(merged group keys in first-seen order, int64 [sum of the parts'
+    groups]: the merged row of every part's every group, parts in child
+    order).  Remembered by `token` where there is one; the lock is held
+    around the dict's pop and reinsert only, and of two threads that
+    miss together the first insert stays."""
+    from filodb_tpu.utils.metrics import registry
+    held = None
+    if token is not None:
+        with _REDUCE_LAYOUT_LOCK:
+            held = _lru_touch(_REDUCE_LAYOUTS, token)
+        registry.counter("reduce_layout",
+                         result="miss" if held is None else "hit").increment()
+    if held is None:
+        gmap: Dict[RangeVectorKey, int] = {}
+        index = np.asarray([gmap.setdefault(k, len(gmap))
+                            for p in parts for k in p.group_keys], np.int64)
+        index.setflags(write=False)
+        held = (tuple(gmap), index)
+        if token is not None:
+            with _REDUCE_LAYOUT_LOCK:
+                held = _REDUCE_LAYOUTS.setdefault(token, held)
+                while len(_REDUCE_LAYOUTS) > _REDUCE_LAYOUTS_MAX:
+                    del _REDUCE_LAYOUTS[next(iter(_REDUCE_LAYOUTS))]
+    return list(held[0]), held[1]
+
+
+def _part_rows(parts: List[AggPartial], index: np.ndarray):
+    """(part, the merged rows of its groups), parts in child order."""
+    lo = 0
+    for p in parts:
+        yield p, index[lo:lo + len(p.group_keys)]
+        lo += len(p.group_keys)
+
+
 def _reduce_aligned(parts: List[AggPartial],
                     compress: bool) -> AggPartial:
     op = parts[0].op
-    gmap: Dict[RangeVectorKey, int] = {}
-    gkeys: List[RangeVectorKey] = []
-    for p in parts:
-        for k in p.group_keys:
-            if k not in gmap:
-                gmap[k] = len(gkeys)
-                gkeys.append(k)
+    token = _reduced_token(parts)
+    gkeys, index = _merge_layout(parts, token)
     wends = parts[0].wends
     if parts[0].sketch is not None:
         # quantile sketches: concat centroid axis per group (zero-weight
@@ -616,46 +691,65 @@ def _reduce_aligned(parts: List[AggPartial],
         cat = np.zeros((G, W, M, 2))
         cat[..., 0] = np.nan
         off = 0
-        for p in parts:
-            idx = np.asarray([gmap[k] for k in p.group_keys], dtype=np.int64)
+        for p, idx in _part_rows(parts, index):
             m = p.sketch.shape[2]
             cat[idx, :, off:off + m] = p.sketch
             off += m
         return AggPartial(op, gkeys, wends,
                           sketch=(sketch_ops.merge_sketches(cat)
                                   if compress else cat),
-                          params=parts[0].params,
-                          cache_token=_reduced_token(parts))
+                          params=parts[0].params, cache_token=token)
     if parts[0].comp is not None:
+        from filodb_tpu.utils.metrics import registry
         C = parts[0].comp.shape[-1]
         W = parts[0].comp.shape[1]
         combs = agg_ops.combiners_for(op, C)
         init = {"sum": 0.0, "min": np.inf, "max": -np.inf}
         ufuncs = {"sum": np.add, "min": np.minimum, "max": np.maximum}
-        # one combiner for every component (all but min/max): one call a
-        # partial.  A hist_sum partial has a component a bucket, and each
-        # NumPy call is a chance to lose the interpreter lock to another
-        # request for a switch interval
-        whole = len(set(combs)) == 1
-        out = np.empty((len(gkeys), W, C))
-        for i, comb in enumerate(combs):
-            out[..., i] = init[comb]
-        for p in parts:
-            idx = np.asarray([gmap[k] for k in p.group_keys], dtype=np.int64)
-            if whole:
-                ufuncs[combs[0]].at(out, idx, p.comp)
-                continue
+        if op == "hist_sum":
+            # Left as PR 31 wrote it, its fill a component included, ON
+            # PURPOSE.  Merged as below, the third of
+            # histdev-64b-4k.quantiles' requests that group by _ns_ answers
+            # 26 ms sooner, the cell serves 8% more requests and its p95
+            # falls 28%; but six requests share one interpreter lock, the
+            # ungrouped two thirds lose the turns that the grouped ones
+            # gave up at every call here, and the cell's MEDIAN, which is
+            # theirs, rises from 33 to 41 ms against a bound of 6%
+            # (PERF.md section 6, PR 39; section 7 for what would free it)
+            out = np.empty((len(gkeys), W, C))
             for i, comb in enumerate(combs):
-                ufuncs[comb].at(out[..., i], idx, p.comp[..., i])
+                out[..., i] = init[comb]
+            for p, idx in _part_rows(parts, index):
+                np.add.at(out, idx, p.comp)
+            calls = len(parts)
+        else:
+            # every part's rows in child order, merged in ONE call where
+            # one combiner serves every component (all ops but min):
+            # ufunc.at applies its indices in order, so a cell's f64 sum
+            # adds its shards in child order whatever their rows' order in
+            # the block they are views of (pf.FusedDispatch sorts its sets
+            # by shape).  The calls a request are a constant, not two a
+            # shard, and none of them hashes a key on a layout hit
+            stacked = np.concatenate([p.comp for p in parts],
+                                     dtype=np.float64)
+            if len(set(combs)) == 1:
+                out = np.full((len(gkeys), W, C), init[combs[0]])
+                ufuncs[combs[0]].at(out, index, stacked)
+                calls = 1
+            else:
+                out = np.empty((len(gkeys), W, C))
+                for i, comb in enumerate(combs):
+                    out[..., i] = init[comb]
+                    ufuncs[comb].at(out[..., i], index, stacked[..., i])
+                calls = C
+        registry.counter("reduce_merge_calls").increment(calls)
         return AggPartial(op, gkeys, wends, comp=out, params=parts[0].params,
-                          bucket_les=parts[0].bucket_les,
-                          cache_token=_reduced_token(parts))
+                          bucket_les=parts[0].bucket_les, cache_token=token)
     # candidate form: concat and remap groups
     ck: List[RangeVectorKey] = []
     cv: List[np.ndarray] = []
     cg: List[np.ndarray] = []
-    for p in parts:
-        idx = np.asarray([gmap[k] for k in p.group_keys], dtype=np.int64)
+    for p, idx in _part_rows(parts, index):
         ck.extend(p.cand_keys)
         cv.append(p.cand_vals)
         cg.append(idx[p.cand_groups])
@@ -952,9 +1046,15 @@ class NonLeafExecPlan(ExecPlan):
         those would silently drop a shard-key combo's data."""
         if not self.dedup_shard_children:
             return {}
+        shards = [getattr(c, "shard", None) for c in self._children]
+        listed = [s for s in shards if s is not None]
+        if len(set(listed)) == len(listed):
+            # no shard twice, so no key twice: the common case pays no
+            # identity string a child (32 of them on every request of a
+            # chip's share of a 128-shard layout)
+            return {}
         by_key: Dict[Tuple, List[int]] = {}
-        for i, c in enumerate(self._children):
-            shard = getattr(c, "shard", None)
+        for i, (c, shard) in enumerate(zip(self._children, shards)):
             if shard is None:
                 continue
             key = (type(c).__name__, getattr(c, "dataset", None), shard,

@@ -45,7 +45,7 @@ class Ts128Rig(HistRig):
         spans = dict.fromkeys(("keys_and_routing", "generate", "reference",
                                "ingest_columns"), 0.0)
         self.ref, self.per_shard = bench_module(
-            "loaders", self.cfg["loader"]).load(
+            "loaders", self.cfg.get("loader", "grid")).load(
             self.srv, self.cfg, self.plan, seed, control, spans,
             bench_module)
         self.tables = bench_module("", "client").Tables({
