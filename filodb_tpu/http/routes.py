@@ -754,6 +754,10 @@ class PromHttpApi:
         from filodb_tpu.utils.metrics import registry
         import time as _time
         now_ms = int(_time.time() * 1000)
+        # the standard family, read at scrape: user + system CPU seconds
+        # of every thread of this process (its rate is cores in use;
+        # about 1.0 is one saturated interpreter lock)
+        registry.counter("process_cpu_seconds").value = _time.process_time()
         for dataset, eng in self.engines.items():
             source = getattr(eng, "source", None)
             mapper = self.shard_mappers.get(dataset)

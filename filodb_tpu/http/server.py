@@ -3,19 +3,48 @@
 ref: http/.../FiloHttpServer.scala:85 — binds the route tree, started by the
 standalone FiloServer.  Python stdlib ThreadingHTTPServer is the transport;
 all route logic lives in routes.py.
+
+The stdlib server answers HTTP/1.0: a connection a request, a thread a
+connection.  The whole life of a connection lies under spans, so that what
+a client waits for outside the door `http.request` has a name:
+
+    acceptor thread   conn.accept        accept()'s return -> the end of
+                                         process_request: the handler
+                                         thread's creation and start, and
+                                         the acceptor's wait to run again
+    handler thread    conn.serve         the thread's outermost span; its
+                                         self time: the socket's files, the
+                                         handler object, the glue
+                        conn.read_request  the request line, the headers
+                        http.request       the door, as ever (traced routes)
+                        conn.close         the files' flush and close, the
+                                           socket's shutdown: after the
+                                           answer, so outside the client's
+                                           latency, inside the lock
+
+The two threads overlap (the handler runs before `Thread.start()` has
+returned to the acceptor), so what a CLIENT waits for between them is
+booked apart, one stage after the other by construction:
+`conn_handover_seconds_total`, accept()'s return to the handler thread's
+first act.  `conn.*` counts every connection, the operator's untraced
+routes (`/metrics`, `/admin/*`) too.  A thread's outermost span books its
+CPU time (utils/metrics.span): `span_conn_serve_cpu_seconds_total /
+span_conn_serve_calls_total` is how much of the interpreter lock a request
+takes.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from filodb_tpu.http.routes import PromHttpApi
 from filodb_tpu.utils.metrics import (mint_trace_id, parse_traceparent,
-                                      span, trace_context)
+                                      registry, span, trace_context)
 
 # the data-plane doors: a request to one of these opens (or continues, from
 # a W3C `traceparent` header) a trace whose root span is `http.request`.
@@ -30,6 +59,46 @@ def _no_span(name):
     return contextlib.nullcontext()
 
 
+class _Server(ThreadingHTTPServer):
+    """ThreadingHTTPServer with the connection's hand-over under spans."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self._accepted = {}     # socket -> accept()'s return, ns
+        self._handover = registry.counter("conn_handover_seconds")
+
+    def get_request(self):
+        got = super().get_request()
+        # closed by process_request, the next thing the acceptor does
+        # (verify_request, between the two, admits everything)
+        self._accepting = span("conn.accept").__enter__()
+        self._accepted[got[0]] = time.perf_counter_ns()
+        return got
+
+    def process_request(self, request, client_address):
+        try:
+            super().process_request(request, client_address)
+        except BaseException:
+            self._accepted.pop(request, None)   # no thread took it over
+            raise
+        finally:
+            self._accepting.__exit__(None, None, None)
+
+    def process_request_thread(self, request, client_address):
+        """ThreadingMixIn's, under `conn.serve`; the handler's finish()
+        has shut the socket down unless setup() raised."""
+        waited = time.perf_counter_ns() - self._accepted.pop(request)
+        with span("conn.serve"):
+            try:
+                self.finish_request(request, client_address)
+            except Exception:  # noqa: BLE001 — as the stdlib's
+                self.handle_error(request, client_address)
+            finally:
+                if request.fileno() != -1:
+                    self.shutdown_request(request)
+        self._handover.increment(waited * 1e-9)
+
+
 class FiloHttpServer:
 
     def __init__(self, api: PromHttpApi, host: str = "127.0.0.1",
@@ -38,6 +107,32 @@ class FiloHttpServer:
         api_ref = api
 
         class _Handler(BaseHTTPRequestHandler):
+            def handle_one_request(self):
+                """The request line's read and the headers' parse under
+                `conn.read_request`: closed by parse_request's end, or
+                here where the line never came."""
+                self._reading = span("conn.read_request").__enter__()
+                try:
+                    super().handle_one_request()
+                finally:
+                    self._end_read()
+
+            def parse_request(self):
+                try:
+                    return super().parse_request()
+                finally:
+                    self._end_read()
+
+            def _end_read(self):
+                reading, self._reading = self._reading, None
+                if reading is not None:
+                    reading.__exit__(None, None, None)
+
+            def finish(self):
+                with span("conn.close"):
+                    super().finish()
+                    self.server.shutdown_request(self.request)
+
             def _serve(self, method: str):
                 parsed = urllib.parse.urlsplit(self.path)
                 if not parsed.path.startswith(_TRACED_PREFIXES):
@@ -140,7 +235,7 @@ class FiloHttpServer:
             def log_message(self, fmt, *args):
                 pass                 # quiet; observability goes via metrics
 
-        self._httpd = ThreadingHTTPServer((host, port), _Handler)
+        self._httpd = _Server((host, port), _Handler)
         self._thread: Optional[threading.Thread] = None
 
     @property

@@ -710,20 +710,23 @@ class _SpanSite:
     `span_<name>_calls_total`, dots written `_`) and its profiler label.
     One family per span and no `span` label, so that a scrape summed per
     family still tells the spans apart.  Only `_book` writes these
-    counters, under its one lock.
+    counters, under its one lock.  A span that exits as its thread's
+    outermost gets a fourth, `span_<name>_cpu_seconds_total`, at its first
+    such exit (`cpu`): a name that is never a root has no such family.
 
     A site opened with `hist=True` is one of the older spans whose
     `span_<name>_seconds` histogram (tagged, with trace exemplars) is
     documented: it keeps the histogram, whose `_sum` is its seconds, in
     place of the `_seconds_total` counter.  A name is one site: its
     first opening decides."""
-    __slots__ = ("label", "self_c", "calls_c", "dur_c", "hists")
+    __slots__ = ("label", "self_c", "calls_c", "dur_c", "hists", "cpu_c")
 
     def __init__(self, name: str, hist: bool):
         flat = name.replace(".", "_")
         self.label = "filodb:" + name
         self.self_c = registry.counter(f"span_{flat}_self_seconds")
         self.calls_c = registry.counter(f"span_{flat}_calls")
+        self.cpu_c = None
         if hist:
             self.dur_c = None
             self.hists = (f"span_{name}_seconds", {})   # tags -> Histogram
@@ -738,6 +741,14 @@ class _SpanSite:
         if h is None:
             h = by_tags[key] = registry.histogram(family, **tags)
         return h
+
+    def cpu(self) -> Counter:
+        """`span_<name>_cpu_seconds_total`, made at a root's first exit."""
+        c = self.cpu_c
+        if c is None:
+            flat = self.label[len("filodb:"):].replace(".", "_")
+            c = self.cpu_c = registry.counter(f"span_{flat}_cpu_seconds")
+        return c
 
 
 _span_sites: Dict[str, _SpanSite] = {}
@@ -760,10 +771,25 @@ def _find_trace_annotation():
     return _trace_annotation
 
 
+def enter_annotation(name: str):
+    """Inside a profiler session an entered `TraceAnnotation`
+    `filodb:<name>`, for its caller to exit; else None.  For what is timed
+    outside the span tree and must still show on its thread's line (the
+    collector's hook, utils/heap.py: it may not open a span, which is not
+    safe to re-enter from an arbitrary bytecode boundary)."""
+    ann = _trace_annotation or _find_trace_annotation()
+    if not SPANS_ENABLED or ann is None or not ann.is_enabled():
+        return None
+    ann = ann("filodb:" + name)
+    ann.__enter__()
+    return ann
+
+
 def _book(done: list) -> None:
     """Book a thread's exited spans, in exit order (children before their
     parents): each adds its duration to its parent's children time, then
-    `duration - children` to its site's self seconds.  Run when the
+    `duration - children` to its site's self seconds; the thread's
+    outermost span also books the CPU time it read.  Run when the
     thread's outermost span exits, after the work it timed (behind the
     HTTP door: after the reply is written), in one warm loop; an exit
     itself touches no counter and takes no lock."""
@@ -774,6 +800,8 @@ def _book(done: list) -> None:
                 parent = sp._parent
                 if parent is not None:
                     parent._children_ns += dur
+                else:
+                    site.cpu().value += sp.cpu_ns * 1e-9
                 site.self_c.value += (dur - sp._children_ns) * 1e-9
                 site.calls_c.value += 1
                 if site.dur_c is not None:
@@ -826,13 +854,30 @@ class span:
     plane, on the clock of the device's operations; the outermost span
     checks the recorder's flag, and the spans under it do as it did.
 
+    A thread's OUTERMOST span also reads the thread's CPU clock
+    (`time.thread_time_ns()`, CLOCK_THREAD_CPUTIME_ID: a request's
+    `conn.serve`, an accept, a flush pass; two reads each) and books
+    `span_<name>_cpu_seconds_total`: a thread that waits for the
+    interpreter lock, the device or a socket is off the CPU, and one that
+    runs Python holds the lock, so a root's CPU time is the program's
+    measure of the lock-HELD time behind its wall time.  It is an upper
+    bound where C code computes, or the kernel works, with the lock
+    released (large NumPy loops, system calls), hence `cpu`, not `held`.
+    The spans under a root do not read the clock: where it is a system
+    call of its own (15 us a read and ticks of 10 ms under the sandbox of
+    the benchmark's machine, PERF.md section 6, PR 40) a request's 300
+    spans would pay a third of its throughput and measure mostly the
+    reads.
+
     `hist=True` keeps the span's documented `span_<name>_seconds`
     histogram (see _SpanSite).  After exit `dur_ns` holds the duration,
     for callers that report it elsewhere (one clock, not a second pair
-    around the same call)."""
+    around the same call), and `cpu_ns` a root's CPU time (None on a span
+    under one)."""
 
-    __slots__ = ("name", "tags", "dur_ns", "_site", "_t0", "_children_ns",
-                 "_id", "_parent", "_path_root", "_ann", "_tid")
+    __slots__ = ("name", "tags", "dur_ns", "cpu_ns", "_site", "_t0", "_c0",
+                 "_children_ns", "_id", "_parent", "_path_root", "_ann",
+                 "_tid")
 
     def __init__(self, name: str, hist: bool = False, **tags: str):
         self.name = name
@@ -842,6 +887,7 @@ class span:
             site = _span_sites[name] = _SpanSite(name, hist)
         self._site = site
         self.dur_ns = 0
+        self.cpu_ns = None
         self._t0 = None
 
     @property
@@ -868,6 +914,7 @@ class span:
             ann = _trace_annotation or _find_trace_annotation()
             annotate = ann is not None and ann.is_enabled()
         self._path_root = len(stack) <= local.get("path_base", 0)
+        self._tid = local.get("trace_id")
         self._children_ns = 0
         self._id = next(_span_ids)      # below 2**64 for any process life
         stack.append(self)
@@ -876,6 +923,8 @@ class span:
             ann.__enter__()
         else:
             self._ann = None
+        if self._parent is None:
+            self._c0 = time.thread_time_ns()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -886,13 +935,15 @@ class span:
         self.dur_ns = time.perf_counter_ns() - t0
         if self._site is None:
             return False
+        if self._parent is None:
+            self.cpu_ns = time.thread_time_ns() - self._c0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
         local = _active.__dict__
         stack = local["stack"]
         stack.pop()
-        tid = self._tid = local.get("trace_id")
+        tid = self._tid
         if tid:
             evs = local.get("events")
             if evs is not None and len(evs) < collector.max_events:
@@ -911,8 +962,13 @@ class span:
             else self._parent._path + "." + self.name
 
     def event(self) -> dict:
+        # a parent outside the trace (the connection's spans above
+        # `http.request`) is no parent in it
+        parent = self._parent
+        if parent is not None and parent._tid != self._tid:
+            parent = None
         return _event(self._path, self.name, self._tid, self._id,
-                      self._parent, self._t0, self.dur_ns, self.tags)
+                      parent, self._t0, self.dur_ns, self.tags)
 
 
 class span_part:

@@ -261,3 +261,243 @@ def test_idle_by_span_on_the_recorded_trace():
               if any(e[1] <= r[1] for e in enq)
               and any(f[1] + f[2] >= r[1] + r[2] for f in fet)]
     assert len(inside) >= want["launches_between_enqueue_and_fetch"]
+
+
+# ------------------------------------- the CPU readers and the rest (PR 40)
+
+span_cpu_ms = load("readers", "span_cpu_ms")
+span_sum = load("readers", "span_sum")
+ROOT = os.path.dirname(BENCH)
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+FIVE = [w["name"] for w in BENCHMARK["workloads"]]
+# metric -> (reader, the cells that list it)
+NEW = {
+    "request_cpu_ms": ("span_cpu_ms", FIVE),
+    "conn_before_door_ms": ("span_sum", FIVE),
+    "conn_close_ms": ("span_self_ms", FIVE),
+    "door_unseen_share": ("span_sum", FIVE),
+    "gc_full_pass_s": ("counter_delta", FIVE),
+    "idle_under_collector_share": ("idle_by_span", FIVE),
+}
+SPAN_KEYS = ("spans", "self_of", "whole_of", "less")
+
+
+def layer_metric(name):
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
+        return json.load(f)
+
+
+def cpu_snapshot(rows):
+    """{family: value} from {span: (self wall, wall, cpu or None, calls)}:
+    a span under a root has no CPU family."""
+    out = {}
+    for name, (self_s, dur, cpu, calls) in rows.items():
+        out[fam(name, "_self_seconds_total")] = self_s
+        out[fam(name, "_seconds_total")] = dur
+        out[fam(name, "_calls_total")] = calls
+        if cpu is not None:
+            out[fam(name, "_cpu_seconds_total")] = cpu
+    return out
+
+
+CPU_BEFORE = cpu_snapshot({
+    "conn.serve": (1.0, 9.0, 2.0, 100), "conn.accept": (0.5, 0.5, 0.05, 100),
+    "flush.pass": (0.0, 0.0, 0.0, 0),
+    "exec.ReduceAggregateExec": (3.0, 4.0, None, 100),
+    "leaf.index_lookup": (0.4, 0.4, None, 400)})
+CPU_AFTER = cpu_snapshot({
+    "conn.serve": (1.3, 11.0, 2.6, 110),            # cpu +0.6 s
+    "conn.accept": (0.6, 0.6, 0.06, 110),           # cpu +0.01 s
+    "flush.pass": (0.2, 0.7, 0.3, 1),               # cpu +0.3 s
+    "exec.ReduceAggregateExec": (3.9, 5.0, None, 110),
+    "leaf.index_lookup": (0.8, 0.8, None, 440)})
+CPU_CTX = {"counters": {"window": (CPU_BEFORE, CPU_AFTER)},
+           "results": RESULTS}
+
+
+def test_span_cpu_ms_takes_the_whole_cpu_of_the_roots():
+    # conn.serve +0.6 s and conn.accept +0.01 s over ten requests
+    assert span_cpu_ms.read(CPU_CTX, whole_of=["conn.serve", "conn.accept"]) \
+        == pytest.approx(61.0, rel=1e-9)
+    assert span_cpu_ms.read(CPU_CTX, whole_of=["conn.serve"]) == \
+        pytest.approx(60.0, rel=1e-9)
+
+
+def test_span_cpu_ms_on_a_program_without_the_cpu_families():
+    """The parent commit books the wall families alone; so does this one
+    for a span that was never a thread's outermost."""
+    ctx = {"counters": {"window": (BEFORE, AFTER)}, "results": RESULTS}
+    assert span_cpu_ms.read(ctx, whole_of=["conn.serve", "conn.accept"],
+                            table=True) is None
+    assert span_cpu_ms.read(CPU_CTX, whole_of=["leaf.index_lookup"]) is None
+    assert span_cpu_ms.read(dict(CPU_CTX, results=[]),
+                            whole_of=["conn.serve"]) is None
+
+
+def test_the_span_table_goes_to_stderr_once(capsys):
+    got = span_cpu_ms.read(CPU_CTX, whole_of=["conn.serve", "conn.accept"],
+                           table=True)
+    assert got == pytest.approx(61.0)
+    out, err = capsys.readouterr()
+    assert out == ""
+    lines = err.splitlines()
+    assert lines[0].startswith("span table:") and "10 requests" in lines[0]
+    rows = {ln.split()[0]: ln.split()[1:] for ln in lines[2:-2]}
+    assert len(rows) == 5
+    # calls, self wall, whole wall and (a root's) CPU ms a request, the
+    # roots first by CPU (names as /metrics flattens them)
+    assert [ln.split()[0] for ln in lines[2:5]] == \
+        ["conn_serve", "flush_pass", "conn_accept"]
+    assert rows["conn_serve"] == ["1.000", "30.0000", "200.0000", "60.0000"]
+    assert rows["leaf_index_lookup"] == ["4.000", "40.0000", "40.0000", "-"]
+    assert lines[-2].split() == ["sum", "190.0000", "91.0000"]
+    # 0.91 s of CPU over the 9.1 s from the first send to the last answer
+    sent = max(r["done"] for r in RESULTS) - min(r["send"] for r in RESULTS)
+    assert lines[-1].endswith(f"= {0.91 / sent:.3f} cores")
+
+
+def conn_ctx(handover=True):
+    snap = {fam("conn.serve", "_seconds_total"): 5.0,
+            fam("conn.serve", "_self_seconds_total"): 1.0,
+            fam("conn.read_request", "_self_seconds_total"): 2.0,
+            fam("conn.close", "_seconds_total"): 0.5,
+            "conn_handover_seconds_total": 1.0}
+    later = {fam("conn.serve", "_seconds_total"): 5.8,          # +800 ms
+             fam("conn.serve", "_self_seconds_total"): 1.02,    # +20 ms
+             fam("conn.read_request", "_self_seconds_total"): 2.03,  # +30
+             fam("conn.close", "_seconds_total"): 0.6,          # +100 ms
+             "conn_handover_seconds_total": 1.05}               # +50 ms
+    if not handover:
+        del later["conn_handover_seconds_total"]
+    return {"counters": {"window": (snap, later)}, "results": RESULTS}
+
+
+UNSEEN = dict(whole_of=["conn.serve"], less=["conn.close"],
+              counters=["conn_handover_seconds_total"],
+              share_of_latency="unseen")
+BEFORE_DOOR = dict(self_of=["conn.serve", "conn.read_request"],
+                   counters=["conn_handover_seconds_total"])
+
+
+def test_span_sum_worked_by_hand():
+    ctx = conn_ctx()
+    # (50 + 800 - 100) ms over 10 requests of 100 ms: 75 seen, 25% unseen
+    assert span_sum.read(ctx, **UNSEEN) == pytest.approx(25.0, rel=1e-9)
+    assert span_sum.read(ctx, whole_of=["conn.serve"],
+                         share_of_latency="unseen") == \
+        pytest.approx(20.0, rel=1e-9)
+    # (50 + 20 + 30) ms over 10 requests
+    assert span_sum.read(ctx, **BEFORE_DOOR) == pytest.approx(10.0, rel=1e-9)
+
+
+@pytest.mark.parametrize("args", [UNSEEN, BEFORE_DOOR])
+def test_span_sum_on_a_program_without_a_family(args):
+    """The parent has no such span; a tree without the hand-over's counter
+    reads nothing either (the stages would not be one after the other)."""
+    old = {"counters": {"window": (BEFORE, AFTER)}, "results": RESULTS}
+    assert span_sum.read(old, **args) is None
+    assert span_sum.read(conn_ctx(handover=False), **args) is None
+    assert span_sum.read(dict(conn_ctx(), results=[]), **args) is None
+
+
+def test_the_door_metrics_close():
+    """door_outside_share = door_unseen_share + conn_before_door_ms's
+    share of the latency, by the files' own lists."""
+    ctx = conn_ctx()
+    snap, later = ctx["counters"]["window"]
+    # http.request is what conn.serve holds beside its self time, the
+    # request's read and the close
+    snap[fam("http.request", "_seconds_total")] = 0.0
+    later[fam("http.request", "_seconds_total")] = 0.8 - 0.02 - 0.03 - 0.1
+    outside = span_self_ms.read(
+        ctx, **layer_metric("door_outside_share")["args"])
+    unseen = span_sum.read(ctx, **layer_metric("door_unseen_share")["args"])
+    before_ms = span_sum.read(
+        ctx, **layer_metric("conn_before_door_ms")["args"])
+    mean_ms = 100.0
+    assert outside == pytest.approx(unseen + 100.0 * before_ms / mean_ms,
+                                    rel=1e-9)
+
+
+def opened_spans():
+    """Every span name the program's code opens: string literals passed
+    to `span(` / `span_part(` / `enter_annotation(`, and the
+    `exec.<PlanClass>` pattern."""
+    import re
+    names = set()
+    pkg = os.path.join(ROOT, "filodb_tpu")
+    for dirpath, _, files in os.walk(pkg):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(dirpath, fn)) as f:
+                    names |= set(re.findall(
+                        r'\b(?:span|span_part|enter_annotation)'
+                        r'\(\s*f?"([^"{]+)', f.read()))
+    return names
+
+
+def counters_booked():
+    """Every counter name the program's code books by a string literal."""
+    import re
+    names = set()
+    for dirpath, _, files in os.walk(os.path.join(ROOT, "filodb_tpu")):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(dirpath, fn)) as f:
+                    names |= set(re.findall(
+                        r'registry\.counter\(\s*"([^"]+)"', f.read()))
+    return names
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_a_new_layer_metric_is_listed_and_names_spans_that_exist(name):
+    reader, cells = NEW[name]
+    spec = layer_metric(name)
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert spec["name"] == name and spec["reader"] == reader
+    assert os.path.exists(os.path.join(BENCH, "readers", reader + ".py"))
+    assert (entry["unit"], entry["layer"], entry["moves"]) == \
+        (spec["unit"], spec["layer"], spec["moves"])
+    assert entry["workloads"] == cells
+    assert entry["layer"] in {m["layer"] for m in BENCHMARK["per_layer"]
+                              if m["name"] not in NEW}
+    args = spec["args"]
+    named = [s for key in SPAN_KEYS for s in args.get(key, ())]
+    named += [args["duration_of"]] if "duration_of" in args else []
+    plain = [c for c in args.get("counters", ()) if not c.startswith("span_")]
+    named += [c[len("span_"):-len("_seconds_total")]
+              for c in args.get("counters", ()) if c.startswith("span_")]
+    # (a counter's name is the span's with its dots flattened)
+    opened = {s.replace(".", "_") for s in opened_spans()}
+    for s in named:
+        assert s.replace(".", "_") in opened, s
+    for c in plain:
+        assert c[:-len("_total")] in counters_booked(), c
+    assert named, "every new metric reads some span"
+
+
+def test_the_new_spans_are_read_by_a_metric():
+    """Every span this PR opens is named by some new metric's file, and
+    each of its counters too."""
+    read, counters = set(), set()
+    for name in NEW:
+        args = layer_metric(name)["args"]
+        for key in SPAN_KEYS:
+            read |= set(args.get(key, ()))
+        read |= {args.get("duration_of")}
+        counters |= set(args.get("counters", ()))
+    assert {"conn.accept", "conn.serve", "conn.read_request", "conn.close",
+            "gc.full_pass"} <= read
+    assert {"gc_full_pass_seconds_total", "conn_handover_seconds_total",
+            "span_flush_heap_settle_seconds_total"} <= counters
+
+
+def test_every_metric_of_the_benchmark_has_its_file_and_its_reader():
+    listed = {m["name"] for m in BENCHMARK["per_layer"]}
+    files = {fn[:-len(".json")]
+             for fn in os.listdir(os.path.join(BENCH, "layer_metrics"))}
+    assert listed <= files
+    for name in files:
+        assert os.path.exists(os.path.join(
+            BENCH, "readers", layer_metric(name)["reader"] + ".py")), name
