@@ -168,7 +168,8 @@ class FusedPlan(NamedTuple):
     """Host-built query plan: the shared window rows, packed for upload.
     The selection / band matrices the matmul kinds read are a pure
     function of `idx1`, `idx2` and `n1 >= 1` and are built on the device,
-    inside the jitted call (kernel_operands)."""
+    inside the jitted call (kernel_operands).  Built by `build_plan`
+    alone: it gives every plan its own `resident` dict."""
     rows: np.ndarray     # [8, Wp] f32  the eight rows below, in this order
     t1: np.ndarray       # [1, Wp] f32   ts at first[w]
     t2: np.ndarray       # [1, Wp] f32   ts at last[w]
@@ -196,7 +197,15 @@ class FusedPlan(NamedTuple):
     # that depends on the window alone: first = idx1 - (phase > ws -
     # ts_row[idx1 - 1]), last = idx2 - (phase > we - ts_row[idx2]).  What
     # a dispatch of phased working sets uploads in place of `rows`.
-    prows: Optional[np.ndarray] = None
+    prows: np.ndarray
+    # the call's host operands as they lie on a device, put at the first
+    # enqueue that takes them there (enqueue_operands): (device, "rows" |
+    # "prows" | "tsrow") -> the device array.  Nothing else refers to
+    # them, so they are freed with the plan.
+    resident: dict
+    # what the plan weighs in a cache: its host arrays, and those three
+    # again for every local device, the most `resident` can come to hold
+    nbytes: int
 
 
 def build_plan(ts_row: np.ndarray, wends: np.ndarray,
@@ -241,9 +250,13 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
     prows[_PT1M, :W], prows[_PT2M, :W] = ts_row[fm], ts_row[lm]
     prows[_PS1, :W] = np.where(first >= 1, wstart - 1 - ts_row[fm], _NO_SLOT)
     prows[_PS2, :W] = np.where(last >= 0, wend - ts_row[la], _NO_SLOT)
+    wvalid, wvalid1 = n >= 2, n >= 1
+    operands = rows.nbytes + prows.nbytes + tsr.nbytes
     return FusedPlan(rows, *(rows[i:i + 1] for i in range(8)), tsrow=tsr,
-                     wvalid=(n >= 2), wvalid1=(n >= 1), W=W, Tp=Tp,
-                     prows=prows)
+                     wvalid=wvalid, wvalid1=wvalid1, W=W, Tp=Tp,
+                     prows=prows, resident={},
+                     nbytes=operands * (1 + jax.local_device_count())
+                     + wvalid.nbytes + wvalid1.nbytes)
 
 
 def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False):
@@ -322,21 +335,41 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
                      offsets=None, sets: int = 1,
                      phased: bool = False) -> tuple:
     """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
-    takes from the host, put explicitly (so the call itself transfers
-    nothing) and counted, uploads and working sets beside enqueues, on
-    /metrics.  `tsrow` rides only where the kernel reads it (the ragged
-    rate family), `offsets` only where some set has several panels; the
-    others are None.  `sets`: the working sets the call carries.
-    `phased`: the rows are the plan's [16, Wp] `prows`."""
+    takes from the host, as device arrays (so the call itself transfers
+    nothing).  The plan's own operands are put on a device once and stay
+    with the plan (`plan.resident`): the panels of an open and the leaves
+    of a request share the plan object, so only the first enqueue of a
+    (plan, device, operand) uploads.  Enqueues that miss together each
+    put, and all go on with the array stored first.  `offsets` belongs to
+    the call and is put every time.  Counted on /metrics: enqueues,
+    working sets, and the puts really made (uploads over enqueues is the
+    plan's miss share).  `tsrow` rides only where the kernel reads it
+    (the ragged rate family), `offsets` only where some set has several
+    panels; the others are None.  `sets`: the working sets the call
+    carries.  `phased`: the rows are the plan's [16, Wp] `prows`."""
     from filodb_tpu.utils.metrics import registry
-    host = (plan.prows if phased else plan.rows,
-            plan.tsrow if ragged and kind == "rate_family" else None,
-            None if offsets is None else np.asarray(offsets, np.int32))
     registry.counter("fused_enqueues").increment()
     registry.counter("fused_enqueue_sets").increment(sets)
-    registry.counter("fused_enqueue_uploads").increment(
-        sum(x is not None for x in host))
-    return jax.device_put(host, device)     # None is an empty pytree
+    held, uploads = plan.resident, 0
+
+    def resident(which):
+        nonlocal uploads
+        arr = held.get((device, which))
+        if arr is None:
+            uploads += 1
+            arr = held.setdefault(
+                (device, which),
+                jax.device_put(getattr(plan, which), device))
+        return arr
+
+    rows = resident("prows" if phased else "rows")
+    tsrow = resident("tsrow") if ragged and kind == "rate_family" else None
+    if offsets is not None:
+        uploads += 1
+        offsets = jax.device_put(np.asarray(offsets, np.int32), device)
+    if uploads:
+        registry.counter("fused_enqueue_uploads").increment(uploads)
+    return rows, tsrow, offsets
 
 
 def _shift_r(x, k: int, fill):
@@ -1317,7 +1350,8 @@ class FusedDispatch:
         self.key = (fn_name, precorrected, interpret, ragged, phased)
         self.device = device
         self.flags = _flavor(*self.key)
-        self._sets: list = []       # (values, [(groups, G, op)], offsets)
+        # per set: (values, [(groups, G, op)], offsets, the panels' G summed)
+        self._sets: list = []
         self._res = None
         self._counts = None         # dense rows: [rows, W] f64
         self._lo: list = []         # per set: its panels' first rows
@@ -1329,9 +1363,14 @@ class FusedDispatch:
     def add(self, values: PaddedValues, panels) -> int:
         """Queue one working set's group-mode panels
         [(PaddedGroups, num_groups, agg_op)]; -> its index for `comps`."""
-        counts = [int(G) for _, G, _ in panels]
-        self._sets.append((values, list(panels),
-                           np.cumsum([0] + counts[:-1])))
+        panels = list(panels)
+        # each panel's first group within the set ([0] for the one panel
+        # a request's leaf brings), and the groups of all its panels
+        offs, total = [], 0
+        for _, G, _ in panels:
+            offs.append(total)
+            total += int(G)
+        self._sets.append((values, panels, offs, total))
         return len(self._sets) - 1
 
     def enqueue(self) -> None:
@@ -1340,39 +1379,40 @@ class FusedDispatch:
         call."""
         if not self._sets or self._res is not None:
             return
-        totals = [int(offs[-1]) + int(panels[-1][1])
-                  for _, panels, offs in self._sets]
-        gps = [pad_group_count(G) for G in totals]
+        gps = [pad_group_count(total) for *_, total in self._sets]
         order = sorted(range(len(self._sets)), key=lambda k: (
             self._sets[k][0].vals_p.shape[0], gps[k],
             len(self._sets[k][1])))
-        sets, pieces, offsets = [], [], []
+        dense = not (self.flags.ragged or self.flags.phased)
+        # the groups' sizes down the output's rows (a set's panels, then
+        # its pad rows at 0): the counts of dense rows are made from it
+        gsize = np.zeros(sum(gps)) if dense else None
+        sets, offsets = [], []
         self._lo = [None] * len(order)
         base = 0
         for k in order:
-            values, panels, offs = self._sets[k]
+            values, panels, offs, _ = self._sets[k]
             sets.append(_kernel_set(
                 values, tuple(g.gids_p for g, _, _ in panels)))
-            offsets.append(offs)
-            self._lo[k] = base + offs
-            # the set's rows of the output: its panels' groups, then pad
-            pieces += [g.gsize for g, _, _ in panels]
-            pieces.append(np.zeros(gps[k] - totals[k], np.int64))
+            offsets += offs
+            self._lo[k] = lo = [base + o for o in offs]
+            if dense:
+                for (g, G, _), at in zip(panels, lo):
+                    gsize[at:at + G] = g.gsize
             base += gps[k]
-        multi = any(len(o) > 1 for o in offsets)
+        multi = len(offsets) > len(sets)
         self._res, _ = _enqueue_run(
-            self.plan, self.device, tuple(sets),
-            np.concatenate(offsets) if multi else None,
+            self.plan, self.device, tuple(sets), offsets if multi else None,
             tuple(gps[k] for k in order), **self.flags._asdict())
-        if not (self.flags.ragged or self.flags.phased):
+        if dense:
             # dense rows on one timestamp row: the counts are |group| x
             # the shared window validity, nothing of the result: made
             # while the device works
             plan = self.plan
             wvalid = (plan.wvalid1 if self.flags.kind in OVER_TIME_FNS
                       else plan.wvalid)
-            self._counts = np.concatenate(pieces).astype(np.float64)[
-                :, None] * wvalid[None, :].astype(np.float64)
+            self._counts = gsize[:, None] * wvalid[None, :].astype(
+                np.float64)
 
     def fetch(self) -> None:
         """The one synchronizing readback, and the panels' presentation

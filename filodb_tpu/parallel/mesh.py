@@ -1111,8 +1111,9 @@ class MeshExecutor:
                 return self._finish_count_panels(packed, wends_p, W,
                                                  range_ms, kpanels, out,
                                                  minsamp)
-            # plan cache: per-time-slice plans; each dispatch puts its
-            # plan's [8, Wlp] rows on its device (pf.enqueue_operands)
+            # plan cache: per-time-slice plans; a plan's [8, Wlp] rows go
+            # to a device at its first dispatch there and stay with the
+            # plan (pf.enqueue_operands)
             plan_key = (packed.shared_ts_row.tobytes(), wends_p.tobytes(),
                         range_ms)
             from filodb_tpu.query.exec import _lru_touch

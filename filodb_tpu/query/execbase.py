@@ -255,8 +255,12 @@ class _FusedCache(dict):
 
 
 def _plan_nbytes(plan) -> int:
-    return int(plan.prows.nbytes + plan.tsrow.nbytes + plan.wvalid.nbytes
-               + plan.wvalid1.nbytes)
+    """A plan's weight, taken at insert and so before any enqueue: its
+    host arrays and the copies of the call's operands that
+    `pf.enqueue_operands` may leave on every local device for as long as
+    the plan lives (`pf.FusedPlan.nbytes`), so the cache's budget bounds
+    those too."""
+    return plan.nbytes
 
 
 def _vals_nbytes(v) -> int:
