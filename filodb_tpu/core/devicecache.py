@@ -79,6 +79,28 @@ class _MirrorSnapshot:
     phase_dev: object = None
     # per column: no NaN anywhere in the counted region
     col_finite: Dict[str, bool] = dataclasses.field(default_factory=dict)
+    # a PLACED snapshot (_place_on_grid): the rows hold other counts of
+    # samples than one another (targets that come and go, scrapes that
+    # failed), so column k of ts_off and of every value column is not a
+    # row's k-th sample but SLOT k of the scrape grid, ts_row0[k] =
+    # ts_row0[0] + k x interval; a row's sample lies in the slot of its
+    # timestamp less the row's phase, the slots nobody filled hold NaN
+    # (col_finite is false) and ts_off holds every slot's own time, filled
+    # or not.  `counts` stays the rows' true sample counts.  0: a column
+    # is a sample position, as the store has it.
+    interval: int = 0
+    placed_rows: int = 0                       # rows that do not fill the grid
+    # the store generation whose SAMPLES the device arrays hold.  `gen`
+    # moves on with bookkeeping that touches none of them (a paging attempt
+    # that found nothing on disk writes paged_floor:
+    # DenseSeriesStore.set_paged) and the arrays are handed on as they are;
+    # `data_gen` then stays, and with it what was made from the arrays (the
+    # fused leaf's padded working sets key on it)
+    data_gen: int = -1
+
+    def __post_init__(self):
+        if self.data_gen < 0:
+            object.__setattr__(self, "data_gen", self.gen)
 
     @property
     def uniform_grid(self) -> bool:
@@ -181,8 +203,98 @@ def _detect_phase_grid(ts_off: np.ndarray, counts: np.ndarray,
     return base, phase.astype(np.int32), 0
 
 
+def _slots_fit(n_slots: int, t_used: int, interval: int) -> bool:
+    """Whether a grid of `n_slots` slots may hold rows of at most `t_used`
+    samples: not over twice as many slots as the longest row has samples
+    (a store whose rows are mostly holes keeps the store's layout), and
+    every slot's time exact in f32 beside a phase."""
+    return n_slots <= 2 * max(t_used, 1) + 64 \
+        and (n_slots + 1) * interval < _F32_EXACT_MS
+
+
+def _place_on_grid(ts_off: np.ndarray, counts: np.ndarray, base_ms: int = 0):
+    """Slots for a store whose rows hold other counts of samples than one
+    another: (ts_row0 [n_slots] int32, phase [S] int32, need [R] the rows
+    that do not fill every slot, slot [R, T] int32 each one's samples'
+    slots (-1 beyond its count), interval); or the count of rows off the
+    grid (at least 1) where some sample fits no slot.
+
+    The interval is the least gap between two samples of a row, the one
+    most rows agree on (one late scrape is then one row off the grid, not
+    another interval); a row's phase its first sample's time since the epoch
+    modulo the interval, the grid's slot 0 the earliest whole multiple of
+    the interval that some row's first sample, less its phase, lies on:
+    every shard of one deployment so finds one base row (as
+    _detect_phase_grid's does).  A sample fits its slot exactly or not at
+    all: a scrape that came late, a second interval, or a store too sparse
+    for a grid (_slots_fit) leave the store off the grid.  A row without
+    samples has phase 0 and fills no slot.  Row blocks bound the
+    temporaries."""
+    s, t = ts_off.shape
+    pos = np.arange(t)[None, :]
+    live = counts > 0
+    least = np.full(s, _F32_EXACT_MS, np.int64)     # each row's least gap
+    for lo in range(0, s, _PHASE_BLOCK):
+        blk = slice(lo, lo + _PHASE_BLOCK)
+        least[blk] = np.where(pos[:, 1:] < counts[blk, None],
+                              np.diff(ts_off[blk], axis=1),
+                              _F32_EXACT_MS).min(axis=1,
+                                                 initial=_F32_EXACT_MS)
+    gaps, rows_of = np.unique(least[least < _F32_EXACT_MS],
+                              return_counts=True)
+    if not gaps.size:
+        return int(live.sum()) or 1      # no row shows an interval
+    # (a tie goes to the longer gap: a scrape off its schedule shortens a
+    # row's least gap, never lengthens it)
+    interval = int(gaps[rows_of == rows_of.max()].max())
+    first = base_ms + ts_off[:, 0].astype(np.int64)
+    phase = np.where(live, first % interval, 0)
+    row0 = int((first - phase)[live].min()) - base_ms    # slot 0's offset
+    last = np.full(s, -1, np.int64)      # each row's last slot
+    on = np.ones(s, bool)
+    for lo in range(0, s, _PHASE_BLOCK):
+        blk = slice(lo, lo + _PHASE_BLOCK)
+        q, rem = np.divmod(ts_off[blk].astype(np.int64)
+                           - phase[blk, None] - row0, interval)
+        held = pos < counts[blk, None]
+        on[blk] = ~((rem != 0) & held).any(axis=1)
+        last[blk] = np.where(held, q, -1).max(axis=1, initial=-1)
+    n_slots = int(last.max()) + 1
+    if not on.all() or not _slots_fit(n_slots, t, interval):
+        return max(int(s - on.sum()), 1)
+    need = np.flatnonzero(counts != n_slots)
+    slot = np.empty((need.size, t), np.int32)
+    for lo in range(0, need.size, _PHASE_BLOCK):
+        rows = need[lo:lo + _PHASE_BLOCK]
+        q = (ts_off[rows].astype(np.int64) - phase[rows, None] - row0) \
+            // interval
+        slot[lo:lo + rows.size] = np.where(pos < counts[rows, None], q, -1)
+    ts_row0 = (row0 + np.arange(n_slots, dtype=np.int64) * interval) \
+        .astype(np.int32)
+    return ts_row0, phase.astype(np.int32), need, slot, interval
+
+
+def _placed(x: np.ndarray, rows: int, need: np.ndarray, slot: np.ndarray,
+            n_slots: int) -> np.ndarray:
+    """`x` [S, T] (NaN beyond a row's count) as a placed snapshot holds
+    it: [rows, n_slots], the samples of each row of `need` moved to their
+    slots (_place_on_grid), NaN in every slot that nobody filled and in
+    the rows past the store's.  The rows not in `need` fill every slot
+    already."""
+    out = np.full((rows, n_slots), np.nan, x.dtype)
+    out[:x.shape[0], :x.shape[1]] = x
+    for lo in range(0, need.size, _PHASE_BLOCK):
+        r = need[lo:lo + _PHASE_BLOCK]
+        at = slot[lo:lo + r.size]
+        i, j = np.nonzero(at >= 0)
+        vals = x[r[i], j]
+        out[r] = np.nan
+        out[r[i], at[i, j]] = vals
+    return out
+
+
 def _note_phase_grid(shard_num: Optional[int], phase: Optional[np.ndarray],
-                     offgrid_rows: int) -> int:
+                     offgrid_rows: int, placed_rows: int = 0) -> int:
     """Book a build's grid by shard; -> the rows with a phase."""
     from filodb_tpu.utils.metrics import registry
     phase_rows = int(np.count_nonzero(phase)) if phase is not None else 0
@@ -190,6 +302,8 @@ def _note_phase_grid(shard_num: Optional[int], phase: Optional[np.ndarray],
     registry.gauge("device_mirror_phase_rows", shard=shard).update(phase_rows)
     registry.gauge("device_mirror_offgrid_rows",
                    shard=shard).update(offgrid_rows)
+    registry.gauge("device_mirror_placed_rows",
+                   shard=shard).update(placed_rows)
     return phase_rows
 
 
@@ -657,8 +771,29 @@ class DeviceMirror:
         with span("mirror.phase_detect"):
             ts_row0, phase, offgrid = _detect_phase_grid(ts_off, counts,
                                                          base_ms)
-        phase_rows = _note_phase_grid(self.shard_num, phase, offgrid)
         dev_rows = _mirror_rows(s)
+        # the cheap cases came first, at what they cost; rows that hold
+        # other counts of samples (or lie a whole interval apart) are placed
+        # on the slots of their scrape grid, if every sample fits one
+        # (scalar columns: a histogram's buckets stay as the store has them)
+        need = slot = None
+        interval = 0
+        if offgrid and all(a is None or a.ndim == 2
+                           for a in store.cols.values()):
+            with span("mirror.place_slots"):
+                got = _place_on_grid(ts_off, counts, base_ms)
+                if isinstance(got, tuple):
+                    ts_row0, phase, need, slot, interval = got
+                    offgrid = 0
+                    nbytes = nbytes // t * len(ts_row0)
+                    ts_off = ts_row0[None, :] + phase[:, None]
+                    metrics_registry.counter(
+                        "device_mirror_rows_placed").increment(need.size)
+                else:
+                    offgrid = got
+        placed_rows = 0 if need is None else int(need.size)
+        phase_rows = _note_phase_grid(self.shard_num, phase, offgrid,
+                                      placed_rows)
         for name, arr in store.cols.items():
             if arr is not None:
                 # counter columns are reset-corrected in f64 BEFORE rebasing
@@ -667,7 +802,15 @@ class DeviceMirror:
                 is_counter = name in counter_cols
                 rebased, vb, corrected = rebase_values(
                     arr[:s, :t], is_counter, return_corrected=True)
-                cols[name] = dput(_pad_rows(rebased, dev_rows, np.nan))
+                if interval:
+                    # corrected and rebased over the samples that exist, in
+                    # their order (a reset across a hole is a reset; the base
+                    # is a row's first sample), THEN placed
+                    with span("mirror.place_slots"):
+                        cols[name] = dput(_placed(rebased, dev_rows, need,
+                                                  slot, len(ts_row0)))
+                else:
+                    cols[name] = dput(_pad_rows(rebased, dev_rows, np.nan))
                 vbases[name] = dput(_pad_rows(np.asarray(vb), dev_rows, 0))
                 host_vbases[name] = np.asarray(vb, np.float64)
                 fin = np.isfinite(corrected)
@@ -675,7 +818,7 @@ class DeviceMirror:
                 # counted region fully finite (padding beyond counts is NaN
                 # by construction and doesn't disqualify)
                 pos_ok = pos >= counts[:, None]
-                col_finite[name] = bool(
+                col_finite[name] = not interval and bool(
                     (fin | pos_ok[..., None] if fin.ndim == 3
                      else fin | pos_ok).all())
                 if is_counter:
@@ -684,7 +827,8 @@ class DeviceMirror:
                     last_raw[name] = lr
                     cum_drop[name] = cd
         # single publication point (GIL-atomic): see _MirrorSnapshot
-        self._snap = _MirrorSnapshot(gen0, base_ms, t,
+        self._snap = _MirrorSnapshot(gen0, base_ms,
+                                     len(ts_row0) if interval else t,
                                      dput(_pad_rows(ts_off, dev_rows,
                                                     PAD_TS)),
                                      cols, vbases,
@@ -697,7 +841,9 @@ class DeviceMirror:
                                      phase_rows=phase_rows,
                                      phase_dev=self._phase_dev(
                                          phase, phase_rows, dev_rows, dput),
-                                     col_finite=col_finite)
+                                     col_finite=col_finite,
+                                     interval=interval,
+                                     placed_rows=placed_rows)
         # the histogram records the WHOLE refresh wall (host prep +
         # uploads: the operational "how long did the rebuild take");
         # the per-query tally gets only the device-dispatch share
@@ -849,7 +995,11 @@ class DeviceMirror:
         s_old = snap.counts.shape[0]
         s_new = store.num_series
         t_new = max(store.time_used, 1)
-        if s_new < s_old or t_new < snap.t_used:
+        # a placed snapshot's columns are slots: it may hold more of them
+        # than any row has samples, and an appended sample goes to the slot
+        # of its timestamp, never to the row's count
+        placed = snap.interval > 0
+        if s_new < s_old or (t_new < snap.t_used and not placed):
             return False
         if set(n for n, a in store.cols.items() if a is not None) \
                 != set(snap.cols):
@@ -873,9 +1023,13 @@ class DeviceMirror:
         if (delta < 0).any():
             return False
         total_new = int(delta.sum())
-        if total_new == 0 and s_new == s_old and t_new == snap.t_used:
+        if total_new == 0 and s_new == s_old and (
+                placed or t_new == snap.t_used):
+            # bookkeeping-only generation bump: `data_gen` stays
             self._snap = dataclasses.replace(snap, gen=gen0)
-            return True                  # bookkeeping-only generation bump
+            return True
+        if total_new == 0 and placed:
+            return False                 # empty new rows: the full build's
         if total_new == 0:
             # series/time grew but no new cells (e.g. new rows whose batch
             # was dropped as out-of-order): pad-only, no scatter to build
@@ -894,6 +1048,28 @@ class DeviceMirror:
         off = new_ts - snap.base_ms
         if off.size and (off.min() <= -(1 << 30) or off.max() >= (1 << 30)):
             return False                 # out of int32 offset range: re-base
+        phase = ts_row0 = None
+        if placed:
+            # a row's phase is its first sample's: a row new since the
+            # snapshot (or empty in it) brings its own
+            phase = np.zeros(s_new, np.int64)
+            phase[:s_old] = snap.phase
+            empty = counts_old[rows] == 0
+            fresh = rows[empty]
+            row0 = int(snap.ts_row0[0])
+            at0 = np.cumsum(n_new) - n_new       # each row's first new cell
+            phase[fresh] = (off[at0[empty]] - row0) % snap.interval
+            idx_p, rem = np.divmod(off - phase[idx_r] - row0, snap.interval)
+            t_new = max(snap.t_used, int(idx_p.max()) + 1)
+            if rem.any() or idx_p.min() < 0 \
+                    or not _slots_fit(t_new, int(counts_new.max()),
+                                      snap.interval):
+                # a sample that fits no slot (a late scrape, a row that
+                # starts before the grid does): which rows are off the grid
+                # is the full build's to say
+                return False
+            ts_row0 = (row0 + np.arange(t_new, dtype=np.int64)
+                       * snap.interval).astype(np.int32)
 
         # device-dispatch share of the refresh (scatter/pad/upload ops);
         # host math (counter correction, vbase bookkeeping) stays out so
@@ -909,15 +1085,25 @@ class DeviceMirror:
         if dR or dT:
             ts_dev = jnp.pad(ts_dev, ((0, dR), (0, dT)),
                              constant_values=PAD_TS)
-        ts_dev = ts_dev.at[idx_r, idx_p].set(off.astype(np.int32))
+        if placed:
+            # every slot's own time, filled or not: the new slots of every
+            # row, and every slot of the rows that brought a phase
+            if dT:
+                ts_dev = ts_dev.at[:s_new, snap.t_used:].set(
+                    (ts_row0[None, snap.t_used:]
+                     + phase[:, None]).astype(np.int32))
+            if fresh.size:
+                ts_dev = ts_dev.at[fresh].set(
+                    (ts_row0[None, :] + phase[fresh, None]).astype(np.int32))
+        else:
+            ts_dev = ts_dev.at[idx_r, idx_p].set(off.astype(np.int32))
         xfer_s += _time.perf_counter() - _td
 
         # phase-grid preservation: every row appended as many samples, each
         # the base row's new offsets plus its own phase, and the base row's
         # least gap still exceeds every phase
-        ts_row0 = None
         whole = s_new == s_old and rows.size == s_new
-        if snap.ts_row0 is not None and whole \
+        if snap.ts_row0 is not None and whole and not placed \
                 and bool((delta == delta[0]).all()):
             off2 = off.reshape(s_new, -1) - snap.phase[:, None]
             start0, k = int(counts_old[0]), off2.shape[1]
@@ -930,6 +1116,21 @@ class DeviceMirror:
                 ts_row0[:snap.t_used] = snap.ts_row0
                 ts_row0[start0:start0 + k] = off2[0].astype(np.int32)
         kept = ts_row0 is not None
+        phase_dev, phase_rows = snap.phase_dev, snap.phase_rows
+        placed_rows = 0
+        if placed:
+            phase = phase.astype(np.int32)
+            placed_rows = int((counts_new != t_new).sum())
+            phase_rows = _note_phase_grid(self.shard_num, phase, 0,
+                                          placed_rows)
+            if fresh.size or dR:
+                _td = _time.perf_counter()
+                phase_dev = self._phase_dev(
+                    phase, phase_rows, dev_rows,
+                    lambda x: jax.device_put(x, self.device))
+                xfer_s += _time.perf_counter() - _td
+        elif kept:
+            phase = snap.phase
         if snap.ts_row0 is not None and not kept:
             # the grid is lost; which rows left it is the next full
             # build's to say: here, the rows that appended otherwise than
@@ -1024,9 +1225,10 @@ class DeviceMirror:
             tail_cum_drop=cum_drop, vbase_valid=vbase_valid,
             ts_row0=ts_row0, col_finite=col_finite,
             # the phases are the rows' own: they stand while the grid does
-            phase=snap.phase if kept else None,
-            phase_rows=snap.phase_rows if kept else 0,
-            phase_dev=snap.phase_dev if kept else None)
+            phase=phase if kept else None,
+            phase_rows=phase_rows if kept else 0,
+            phase_dev=phase_dev if kept else None,
+            interval=snap.interval, placed_rows=placed_rows)
         # appended-tail transfer size: int32 ts offsets + each column's
         # per-cell bytes over the new cells only
         per_cell = 4 + sum(

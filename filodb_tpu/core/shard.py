@@ -32,6 +32,7 @@ NO_HORIZON_MS = -(1 << 62)
 NO_ROWS_HORIZON_MS = 1 << 62
 _KEY_RESOLVE_CACHE_MAX = 4               # live key tables per shard (schemas)
 _LOOKUP_CACHE_MAX = 32                   # memoized lookup_partitions results
+_SELECTION_PARTS_MAX = 16                # ... and masks kept of each of them
 
 import numpy as np
 
@@ -110,7 +111,7 @@ class SelectionFacts:
     inside `store.mutation()`, so facts whose `generation` is the
     store's are the store's own.  Arrays are frozen."""
     __slots__ = ("generation", "counts", "first", "last", "samples",
-                 "uniform", "covered_max", "ceil_min")
+                 "uniform", "covered_max", "ceil_min", "_estimated")
 
     def __init__(self, store: DenseSeriesStore, rows: np.ndarray):
         self.generation = store.generation
@@ -119,6 +120,7 @@ class SelectionFacts:
             arr.setflags(write=False)
         self.counts, self.first, self.last = cnt, first, last
         self.samples = int(cnt.sum())
+        self._estimated = None          # estimate(): (start, end, answer)
         # every row with the same count and the same extent from its
         # first to its last timestamp (one scrape grid, each row on it or
         # behind it by its own phase): the estimate is then arithmetic on
@@ -156,8 +158,13 @@ class SelectionFacts:
             alike = first_hi == first or (start_ms >= first_hi
                                           and end_ms <= last)
         if not alike:
-            return estimate_samples(self.counts, self.first, self.last,
-                                    start_ms, end_ms)
+            # the array formula is 2.2 ms at 73,000 rows, and the panels of
+            # a dashboard's open ask for one range: the last answer stands
+            held = self._estimated
+            if held is None or held[:2] != (start_ms, end_ms):
+                held = self._estimated = (start_ms, end_ms, estimate_samples(
+                    self.counts, self.first, self.last, start_ms, end_ms))
+            return held[2]
         lo, hi = max(first, start_ms), min(last, end_ms)
         if cnt <= 0 or hi < lo:
             return 0
@@ -176,13 +183,15 @@ class SchemaSelection:
     RawBlock.cache_token) hit by identity instead of copying and
     comparing the arrays; `facts` holds the newest SelectionFacts
     (TimeSeriesShard.selection_facts)."""
-    __slots__ = ("pids", "rows", "pids_key", "rows_key", "facts")
+    __slots__ = ("pids", "rows", "pids_key", "rows_key", "facts", "member")
 
     def __init__(self, pids: np.ndarray, rows: np.ndarray):
         rows.setflags(write=False)
         self.pids, self.rows = pids, rows
         self.pids_key, self.rows_key = pids.tobytes(), rows.tobytes()
         self.facts: Optional[SelectionFacts] = None
+        # PartLookupResult.among: where these series stand in `within`'s
+        self.member: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -193,13 +202,16 @@ class PartLookupResult:
     shard's pid->row / pid->key tables; parts_by_schema materializes
     PartitionInfo lists lazily for metadata/maintenance consumers.
     `shared`: the lookup memo hands this object to every request whose
-    range holds every selected series' life."""
+    range holds every selected series' life.  `within`: the memo entry's whole
+    answer, where this one is the part of it that a range keeps (the same
+    series in the same order, less those whose life the range misses)."""
     shard: int
     part_ids: np.ndarray
     pids_by_schema: Dict[str, np.ndarray]
     first_schema: Optional[str]
     shard_obj: Optional["TimeSeriesShard"] = None
     shared: bool = False
+    within: Optional["PartLookupResult"] = None
     _selections: Dict[str, SchemaSelection] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
 
@@ -217,14 +229,32 @@ class PartLookupResult:
                 pids, self.shard_obj.rows_for(pids))
         return sel
 
+    def among(self, schema_name: str
+              ) -> Optional[Tuple[SchemaSelection, np.ndarray]]:
+        """Where this answer is part of a whole one: the whole's selection
+        on one schema, and where this part's series stand in it (ascending:
+        a part keeps the whole's order).  The fused leaf pads ONE working
+        set a shard, the whole's, and drops the rows a range leaves out by
+        their group (MultiSchemaPartitionsExec._build_fused)."""
+        if self.within is None:
+            return None
+        sel, whole = self.selection(schema_name), \
+            self.within.selection(schema_name)
+        if sel.member is None:
+            sel.member = np.flatnonzero(np.isin(whole.pids, sel.pids))
+            sel.member.setflags(write=False)
+        return whole, sel.member
+
 
 class _Selection:
     """A lookup memo entry: the series (filters, limit) select on this
     shard whatever the range, in part_ids_from_filters' end-time order,
     with their lives; valid while `stamp` is (index.mutations,
-    keys_epoch).  `whole` answers every range that holds all the lives."""
+    keys_epoch).  `whole` answers every range that holds all the lives;
+    `parts` holds the answers of the others by the mask a range lays over
+    `ids` (its bytes -> PartLookupResult, least recently used first)."""
     __slots__ = ("stamp", "ids", "start", "end", "max_start", "min_end",
-                 "whole")
+                 "whole", "parts")
 
 
 class TimeSeriesShard:
@@ -1124,9 +1154,9 @@ class TimeSeriesShard:
         index.mutations, so a dashboard whose `end` moves one step an
         open keeps hitting.  A range that holds every selected series'
         life takes the entry's frozen answer as it is; any other masks
-        the entry's arrays, and a subsequence of a stably sorted sequence
-        is the stably sorted subsequence: part_ids_from_filters' own
-        answer either way."""
+        the entry's arrays (_part_of: one answer a mask), and a
+        subsequence of a stably sorted sequence is the stably sorted
+        subsequence: part_ids_from_filters' own answer either way."""
         try:
             ck = (tuple(filters), limit)
             hash(ck)                  # filters with unhashable fields
@@ -1157,15 +1187,39 @@ class TimeSeriesShard:
             res = ent.whole
         else:
             mask = (ent.start <= end_time_ms) & (ent.end >= start_time_ms)
-            res = self._lookup_result(ent.ids[mask][:limit])
+            res = self._part_of(ent, mask, limit)
         if self._traced_pids and res.part_ids.size:
             self._trace_touch("query_lookup", res.part_ids)
+        return res
+
+    def _part_of(self, ent: _Selection, mask: np.ndarray,
+                 limit: Optional[int]) -> PartLookupResult:
+        """The entry's series whose life meets a range, by the range's mask
+        over them.  Ranges differ with every open; masks move only where a
+        range's end passes a birth or its start a death (a dozen an hour
+        in a fleet that replaces its targets every ten minutes), so the
+        answer of a mask is kept as `whole` is: one object, its selection,
+        cache keys and facts, to every request that lays the same mask."""
+        mk = np.packbits(mask).tobytes()
+        with self._lookup_lock:
+            res = ent.parts.pop(mk, None)
+            if res is not None:
+                ent.parts[mk] = res
+        if res is None:
+            res = self._lookup_result(ent.ids[mask][:limit])
+            if limit is None:
+                res.within = ent.whole
+            with self._lookup_lock:
+                ent.parts[mk] = res
+                while len(ent.parts) > _SELECTION_PARTS_MAX:
+                    ent.parts.pop(next(iter(ent.parts)))
         return res
 
     def _fill_selection(self, filters: Sequence[ColumnFilter],
                         limit: Optional[int], stamp: tuple) -> _Selection:
         ent = _Selection()
         ent.stamp = stamp
+        ent.parts = {}
         ent.ids = self.index.part_ids_from_filters(filters, -_NEVER_MS,
                                                    _NEVER_MS)
         ent.start, ent.end = self.index.lives_of(ent.ids)
@@ -1424,6 +1478,7 @@ class TimeSeriesShard:
         # whose below-floor range needs the column store, instead of a
         # round trip per partition (the netstore win; free locally).
         prefetch: Dict[int, list] = {}
+        nothing_down_to: Dict[int, int] = {}    # pid -> asked, nothing there
         if not isinstance(self.column_store, NullColumnStore):
             reqs, req_pids = [], []
             for info in parts:
@@ -1443,11 +1498,27 @@ class TimeSeriesShard:
                 reqs.append((info.part_key, start_time_ms, hi))
                 req_pids.append(info.part_id)
             if reqs:
-                for pid, chunks in zip(req_pids,
-                                       self.column_store.read_chunks_multi(
-                                           self.dataset, self.shard_num,
-                                           reqs)):
-                    prefetch[pid] = chunks
+                got = list(self.column_store.read_chunks_multi(
+                    self.dataset, self.shard_num, reqs))
+                prefetch.update(zip(req_pids, got))
+                # rows with nothing there (a series born inside the range:
+                # a fleet that replaces its targets has hundreds a shard)
+                # are asked about once more in one more batch, as far below
+                # again as was asked or as the query is long: what holds
+                # nothing there either is not asked about again by every
+                # query that starts a step earlier
+                none = [i for i, chunks in enumerate(got) if not chunks]
+                wider = [(reqs[i][0], max(start_time_ms - max(
+                    reqs[i][2] - start_time_ms + 1,
+                    end_time_ms - start_time_ms), 0), start_time_ms - 1)
+                    for i in none]
+                for i, (_, lo, hi), chunks in zip(
+                        none, wider, self.column_store.read_chunks_multi(
+                            self.dataset, self.shard_num, wider)
+                        if wider else ()):
+                    if not chunks and not self.resident.read(req_pids[i],
+                                                             lo, hi):
+                        nothing_down_to[req_pids[i]] = lo
         paged = 0
         parts_paged = 0
         for info in parts:
@@ -1503,7 +1574,9 @@ class TimeSeriesShard:
                             store.set_paged(row,
                                             floor=int(store.ts[row, 0]))
                     else:
-                        store.set_paged(row, floor=start_time_ms)
+                        store.set_paged(row, floor=min(
+                            start_time_ms, nothing_down_to.get(
+                                info.part_id, start_time_ms)))
                     if cnt == 0 and store.page_only[row]:
                         store.set_paged(row, ceil=max(
                             int(store.paged_ceil[row]), hi))

@@ -338,6 +338,39 @@ _FUSED_GROUP_CACHE = _FusedCache("groups", _groups_nbytes,
 _FUSED_CACHE_LOCK = threading.Lock()
 
 
+_FUSED_VALS_BUILDERS: Dict[Tuple, threading.Lock] = {}   # key -> its lock
+
+
+def fused_values(key, build):
+    """The padded working set under `key`, built by ONE of the leaves that
+    miss it together: a new snapshot makes every request in flight miss at
+    once, and each build takes the working set's rows out of the mirror
+    and pads them (two device arrays of its size, 440 MB at 73,000 rows;
+    six requests over four shards held 10 GB of a 16 GB chip between them
+    for one entry each: PERF.md section 6, PR 42).  The key's lock is held
+    around `build()` alone; the others wait there and take what it made."""
+    with _FUSED_CACHE_LOCK:
+        held = _FUSED_VALS_CACHE.get(key)
+        if held is not None:
+            return held
+        lock = _FUSED_VALS_BUILDERS.setdefault(key, threading.Lock())
+    with lock:
+        with _FUSED_CACHE_LOCK:
+            held = _FUSED_VALS_CACHE.get(key)
+        if held is not None:
+            return held
+        try:
+            held = build()
+            # a new snapshot generation obsoletes this mirror's older
+            # entries — the insert drops them NOW, not at LRU eviction:
+            # each pins a full padded copy of the working set in HBM
+            with _FUSED_CACHE_LOCK:
+                return _FUSED_VALS_CACHE.insert(key, held)
+        finally:
+            with _FUSED_CACHE_LOCK:
+                _FUSED_VALS_BUILDERS.pop(key, None)
+
+
 class GroupCardinalityError(ValueError):
     """group-by cardinality limit exceeded — a real query error that must
     surface even from the fused fast path (everything else falls back)."""

@@ -35,10 +35,11 @@ from filodb_tpu.query.execbase import (
     QueryError, QueryResultLike, RawBlock, ScalarResult,
     _FUSED_CACHE_LOCK, _FUSED_MINMAX_PAD_CACHE, _FUSED_PLAN_CACHE,
     _FUSED_VALS_CACHE, _block_empty, _group_cache_insert,
-    _group_cache_lookup, _lru_touch, _note_mirror_limit, agg_token)
+    _group_cache_lookup, _lru_touch, _note_mirror_limit, agg_token,
+    fused_values)
 from filodb_tpu.query.transformers import (
     AggregateMapReduce, PeriodicSamplesMapper, RangeVectorTransformer,
-    _group_ids, _group_ids_cached)
+    _group_ids, _group_ids_cached, _group_ids_of_part)
 from filodb_tpu.query.fusedbatch import FusedCall, finish_fused_calls
 from filodb_tpu.utils.metrics import span
 
@@ -78,7 +79,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 fused = self._finish_or_degrade(fused)
         else:
             self._transformer_overrides = {}
-            self._fused_cache_key = None
+            self._fused_cache_key = self._fused_whole = None
             data, stats = self._do_execute(source)
             try:
                 fused = self._try_fused(data, stats)
@@ -117,7 +118,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
 
     def _prepare_fused(self, source):
         self._transformer_overrides = {}
-        self._fused_cache_key = None
+        self._fused_cache_key = self._fused_whole = None
         data, stats = self._do_execute(source)
         try:
             pre = self._try_fused(data, stats, defer=True)
@@ -263,7 +264,21 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # plan + prepared-input caches: a repeat query over an unchanged
         # snapshot (the dashboard-poll pattern) skips the selection-matrix
         # rebuild AND the full padded device copy (PreparedInputs contract)
-        key = self._fused_cache_key
+        key = vkey = self._fused_cache_key
+        # a range that leaves some of the selector's series out (their life
+        # lies past it: targets that come and go) reads the working set of
+        # ALL of them, the one every other range of the shard reads, and
+        # drops the rows it leaves out by their group: `whole` is that
+        # set (_WholeSet).  The padded values key on the set, the groups on
+        # the set and the part.  (min and max ride the per-series run,
+        # which takes a set's rows for its series: the part's own set)
+        whole, rows = None, shape[0]
+        if key is not None and self._fused_whole is not None \
+                and not is_hist and t1.op in ("sum", "avg", "count"):
+            whole = self._fused_whole
+            rows = whole.rows
+            vkey = key[:3] + (whole.rows_key,)
+            key = vkey + (key[3],)
         plan = padded_vals = groups = gkeys = None
         # a plan reads differences of timestamps only: built from the row
         # moved to start at 0 (the window ends with it), two shards whose
@@ -281,7 +296,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         data.base_ms + plan_shift)
             with _FUSED_CACHE_LOCK:
                 plan = _FUSED_PLAN_CACHE.lookup(plan_key)
-                padded_vals = _FUSED_VALS_CACHE.lookup(key)
+                padded_vals = _FUSED_VALS_CACHE.lookup(vkey)
             groups, gkeys = _group_cache_lookup(key, t1.by, t1.without)
             if padded_vals is not None:
                 registry.counter("leaf_fused_prep_hits").increment()
@@ -296,8 +311,11 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                     plan = _FUSED_PLAN_CACHE.insert(plan_key, plan)
         if gkeys is None:
             with span("leaf.group_ids"):
-                gids, gkeys = _group_ids_cached(data.cache_token, data.keys,
-                                                t1.by, t1.without)
+                if whole is None:
+                    gids, gkeys = _group_ids_cached(
+                        data.cache_token, data.keys, t1.by, t1.without)
+                else:
+                    gids, gkeys = whole.group_ids(t1.by, t1.without)
         self._check_group_limit(gkeys)
         B = shape[2] if is_hist else 1
         num_slots = len(gkeys) * B      # hist: one kernel group per (g, b)
@@ -309,42 +327,39 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                          not dense, phased=phased) is None:
             return None
         if padded_vals is None:
-            if is_hist:
-                vals, vbase = data.values, data.vbase
-                # [S, T, B] -> [S*B, T] rows (bucket-major within a series,
-                # same layout PeriodicSamplesMapper flattens to)
-                with span("leaf.hist_flatten"):
-                    flat = jnp.moveaxis(jnp.asarray(vals), 2, 1) \
-                        .reshape(shape[0] * B, shape[1])
-                    vb_flat = (np.zeros(flat.shape[0], np.float32)
-                               if vbase is None
-                               else jnp.asarray(vbase,
-                                                jnp.float32).reshape(-1))
-                with span("leaf.pad_values"):
-                    padded_vals = pf.pad_values(flat, vb_flat, plan)
-            else:
+            def pad():
+                if is_hist:
+                    vals, vbase = data.values, data.vbase
+                    # [S, T, B] -> [S*B, T] rows (bucket-major within a
+                    # series, same layout PeriodicSamplesMapper flattens to)
+                    with span("leaf.hist_flatten"):
+                        flat = jnp.moveaxis(jnp.asarray(vals), 2, 1) \
+                            .reshape(shape[0] * B, shape[1])
+                        vb_flat = (np.zeros(flat.shape[0], np.float32)
+                                   if vbase is None
+                                   else jnp.asarray(vbase,
+                                                    jnp.float32).reshape(-1))
+                    with span("leaf.pad_values"):
+                        return pf.pad_values(flat, vb_flat, plan)
                 # rows out of the mirror come padded to the ladder's rung
                 # already: the take, the pad and the kernel then compile
                 # once a rung, not once a row count
-                Sp = pf.pad_series_count(shape[0])
-                vals = data.rows_padded("values", Sp)
-                vbase = data.rows_padded("vbase", Sp)
+                src = data if whole is None else whole
+                Sp = pf.pad_series_count(rows)
+                vals = src.rows_padded("values", Sp)
+                vbase = src.rows_padded("vbase", Sp)
                 if vbase is None:
                     vbase = np.zeros(vals.shape[0], np.float32)
                 # the kernel variant follows from the data: the phased one
                 # where some row of THIS working set has a phase
                 phase = None
-                if phased and data.phase.any():
-                    phase = data.rows_padded("phase", Sp)
+                if phased and src.phase.any():
+                    phase = src.rows_padded("phase", Sp)
                 with span("leaf.pad_values"):
-                    padded_vals = pf.pad_values(vals, vbase, plan,
-                                                phase=phase)
-            if key is not None:
-                # a new snapshot generation obsoletes this mirror's older
-                # entries — the insert drops them NOW, not at LRU eviction:
-                # each pins a full padded copy of the working set in HBM
-                with _FUSED_CACHE_LOCK:
-                    _FUSED_VALS_CACHE.insert(key, padded_vals)
+                    return pf.pad_values(vals, vbase, plan, phase=phase)
+            # one builder a working set: the other leaves that missed with
+            # this one wait for its padded values and share them
+            padded_vals = pad() if vkey is None else fused_values(vkey, pad)
         if groups is None:
             with span("leaf.pad_groups"):
                 if is_hist:
@@ -352,12 +367,25 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                                  + np.arange(B)[None, :]).reshape(-1)
                     groups = pf.pad_groups(gids_flat, shape[0] * B,
                                            num_slots)
-                else:
+                elif whole is None:
                     groups = pf.pad_groups(gids, shape[0], len(gkeys))
+                else:
+                    # pad_groups' column with the part's rows where they
+                    # stand in the set: the rows this range leaves out
+                    # belong to no group, as the rows that pad the set to
+                    # its rung do
+                    col = np.full((pf.pad_series_count(rows), 1), -1,
+                                  np.int32)
+                    col[whole.member, 0] = gids
+                    groups = pf.PaddedGroups(
+                        jnp.asarray(col), np.bincount(
+                            gids, minlength=len(gkeys))[:len(gkeys)])
             _group_cache_insert(key, t1.by, t1.without, groups, gkeys)
         registry.counter("leaf_fused_kernel").increment()
         if padded_vals.phase_p is not None:
             registry.counter("leaf_phase_fused").increment()
+        if not dense:
+            registry.counter("leaf_ragged_fused").increment()
         if not is_hist:
             # broadened matmul path: any fusable (fn, agg) combination,
             # ragged (validity-weighted) when the working set has NaN
@@ -371,7 +399,7 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 plan=plan, values=padded_vals, groups=groups, gkeys=gkeys,
                 wends=wends, fn=fn, op=t1.op,
                 precorrected=data.precorrected, interpret=interpret,
-                ragged=not dense, num_series=shape[0], cache_key=ck,
+                ragged=not dense, num_series=rows, cache_key=ck,
                 cache_token=agg_token(t1.op, t1.by, t1.without,
                                       data.cache_token))
             return fc
@@ -730,10 +758,17 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         counter_col = col_def is not None and (col_def.detect_drops
                                                or col_def.counter)
         fn_is_counter = False
-        for t in self.transformers:
+        # whether the leaf's range function counts a NaN as an ABSENT
+        # sample wherever it runs, fused or general: what may read a placed
+        # mirror snapshot (_PLACED_FNS)
+        nan_is_absent = False
+        for i, t in enumerate(self.transformers):
             if isinstance(t, PeriodicSamplesMapper):
                 spec = RANGE_FUNCTIONS.get(t.function or "")
                 fn_is_counter = spec.is_counter if spec else False
+                t = self._transformer_overrides.get(i, t)
+                nan_is_absent = t.window_ms is not None \
+                    and t.function in _PLACED_FNS
                 break
         # device-resident fast path: gather rows from the HBM mirror instead
         # of re-shipping the matrix every query (ref: block-memory working
@@ -830,10 +865,15 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # pairing a newer snapshot's grid with an older one's values
                 # would feed the kernel zero-padded phantom columns
                 snap = mirror.snapshot()
+            if ok and (not snap.interval or nan_is_absent):
                 # no device work yet: the rows of an array leave the mirror
                 # when the block's field is first read (MirrorGather books
                 # the span and the dispatch of each take where it runs)
                 mirrored = mirror.gather_cached(rows, snap)
+            # (a placed snapshot holds NaN where a row has no sample: a
+            # function that takes a NaN for a sample, the staleness rule of
+            # last_over_time, count_over_time's slots, raw samples going
+            # out, reads the store's own rows below instead)
         # value column selection: histograms gather [S, T, B]
         shared_ts_row = phase = None
         dense = True
@@ -852,9 +892,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             if shared_ts_row is not None:
                 phase = mirrored.deferred("phase")
             elif snap.counts.size:
-                # the store's rows fit no phase grid (a hole, a late
-                # scrape, targets that come and go): the leaf's
-                # transformers run on the general path
+                # some sample of the store fits no slot of a scrape
+                # grid (a scrape later than the tolerance, a second
+                # interval): the leaf's transformers run on the general
+                # path
                 from filodb_tpu.utils.metrics import registry as _reg
                 _reg.counter("leaf_offgrid").increment()
             # col_dense is grid-independent (counted cells finite; pads are
@@ -866,8 +907,13 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # (mirror.serial, not id(): ids are reused after GC; raw
                 # rows bytes, not their hash: a collision would silently
                 # serve another row-set's values)
-                self._fused_cache_key = (mirror.serial, snap.gen, col_name,
-                                         sel.rows_key)
+                self._fused_cache_key = (mirror.serial, snap.data_gen,
+                                         col_name, sel.rows_key)
+                among = lookup.among(schema_name)
+                if among is not None and among[1].size == rows.size:
+                    self._fused_whole = _WholeSet(
+                        mirror.gather_cached(among[0].rows, snap),
+                        col_name, shard, *among)
         else:
             from filodb_tpu.utils.metrics import registry as _reg
             _reg.counter("leaf_host_gather").increment()
@@ -946,6 +992,59 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         cache_token=(shard.keys_serial, shard.keys_epoch,
                                      sel.pids_key),
                         route_host=route_host, phase=phase), stats
+
+
+class _WholeSet:
+    """The fused working set of ALL the series a selector has on a shard,
+    for a leaf whose range leaves some of them out (their life lies past
+    it: shard.PartLookupResult.among): its rows still in the mirror, read
+    as a block's are (`rows_padded`, `phase`), and `member`, where the
+    leaf's own rows stand among them.  Every range reads this one set, so
+    a fleet whose targets come and go pads one working set a shard and not
+    one for every birth a range's end lies before."""
+    __slots__ = ("rows_key", "member", "_held", "_keys", "_token")
+
+    def __init__(self, gather, col_name: str, shard, sel,
+                 member: np.ndarray):
+        self.rows_key, self.member = sel.rows_key, member
+        self._held = {"values": gather.deferred("values", col_name),
+                      "vbase": gather.deferred("vbase", col_name),
+                      "phase": gather.deferred("phase")}
+        # the set's series as a block of them carries them (RawBlock.keys,
+        # cache_token): their group ids are the ones its own leaves made
+        self._keys = LazyKeys(shard, sel.pids)
+        self._token = (shard.keys_serial, shard.keys_epoch, sel.pids_key)
+
+    def group_ids(self, by, without):
+        """The leaf's own series' group ids and keys, from the set's: the
+        label loop over 73,000 keys (0.47 s) ran when a range that holds
+        every life first asked, and a part only renumbers."""
+        return _group_ids_of_part(
+            *_group_ids_cached(self._token, self._keys, by, without),
+            self.member)
+
+    @property
+    def rows(self) -> int:
+        return self._held["values"].shape[0]
+
+    @property
+    def phase(self) -> Optional[np.ndarray]:
+        held = self._held["phase"]
+        return None if held is None else held.host()
+
+    def rows_padded(self, field: str, rows_to: int):
+        held = self._held[field]
+        return None if held is None else held.resolve(rows_to)
+
+
+# Range functions that count a NaN as an absent sample on every path they
+# can take: the fused kernel's ragged variants (valid boundaries for the rate
+# family, validity-weighted sums, presence by valid count), the reduce_window
+# min/max and the general XLA path (ops/rangefns: _valid_bounds,
+# _valid_count).  Only these read a PLACED mirror snapshot
+# (core/devicecache._MirrorSnapshot.interval), whose empty slots are NaN.
+_PLACED_FNS = frozenset(("rate", "increase", "delta", "sum_over_time",
+                         "avg_over_time", "min_over_time", "max_over_time"))
 
 
 class SelectPersistedSegmentsExec(MultiSchemaPartitionsExec):

@@ -327,6 +327,23 @@ def _group_ids(keys: Sequence[RangeVectorKey], by: Tuple[str, ...],
     return gids, gkeys
 
 
+def _group_ids_of_part(gids: np.ndarray, gkeys: List[RangeVectorKey],
+                       member: np.ndarray
+                       ) -> Tuple[np.ndarray, List[RangeVectorKey]]:
+    """_group_ids' answer for the keys at positions `member` (ascending) of
+    a key sequence whose own answer is (gids, gkeys): the groups that still
+    hold a series, numbered by first appearance, in array operations in
+    place of a Python loop over the keys (0.47 s at 73,000 of them)."""
+    g = gids[member]
+    first = np.full(len(gkeys), g.size, np.int64)
+    first[g[::-1]] = np.arange(g.size - 1, -1, -1)     # the last write wins
+    kept = np.flatnonzero(first < g.size)
+    kept = kept[np.argsort(first[kept], kind="stable")]
+    rank = np.empty(len(gkeys), np.int32)
+    rank[kept] = np.arange(kept.size, dtype=np.int32)
+    return rank[g], [gkeys[k] for k in kept.tolist()]
+
+
 _CANDIDATE_OPS = {"topk", "bottomk", "count_values"}
 
 # host group-id cache: (cache_token, by, without) -> (gids, gkeys).
@@ -370,7 +387,10 @@ def _group_ids_cached(token, keys, by, without):
                    for o in _HOST_GROUP_CACHE):
                 return gids, gkeys
         _HOST_GROUP_CACHE[k] = (gids, gkeys)
-        while len(_HOST_GROUP_CACHE) > 8:
+        # a chip's four shards times the four groupings of a dashboard,
+        # twice: the fused leaf takes the ids of a range's part from the
+        # whole selection's (leafexec._WholeSet.group_ids), so those stay
+        while len(_HOST_GROUP_CACHE) > 32:
             _HOST_GROUP_CACHE.pop(next(iter(_HOST_GROUP_CACHE)))
     return gids, gkeys
 

@@ -153,8 +153,14 @@ def test_fused_kernel_compiles_for_v5e(one_chip, chip_runtime,
     (S_SHARD, 1000, "count_over_time", False, 3),
     (S_SHARD, 1000, "last_over_time", False, 1),
     (S_SHARD, 1000, "last_over_time", True, 1),
+    # promchurn-counters-262k.open's one program a request (ISSUE 42): the
+    # four shards' placed working sets (290,975 rows: the series that came
+    # and went beside the live ones), NaN where a row holds no sample
+    ((87_700, 86_800, 58_100, 58_400), (10, 10, 1, 20), "rate", True, 1),
+    ((87_700, 86_800, 58_100, 58_400), (10, 10, 1, 20), "increase", True, 1),
 ], ids=["rate-4sets", "rate-ragged", "sum_ot", "avg_ot-ragged",
-        "count_ot-3panels", "last_ot", "last_ot-ragged"])
+        "count_ot-3panels", "last_ot", "last_ot-ragged",
+        "rate-ragged-4sets", "increase-ragged-4sets"])
 def test_phased_kernel_compiles_for_v5e(one_chip, chip_runtime,
                                         S, G, fn, ragged, panels):
     """The phased variant (rows on a phase grid: a slot a row, by one

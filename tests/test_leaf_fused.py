@@ -55,10 +55,13 @@ def test_fused_leaf_matches_general_path(fused_env):
 
 
 def test_fused_skipped_on_ragged_grid(fused_env):
-    """Series with different sample grids must take the general path."""
+    """Series with different sample grids must take the general path: a
+    second scrape interval fits no slot of the first's grid.  (Rows that
+    merely start late or hold fewer samples are placed on the grid's slots
+    and fuse: ISSUE 42, tests/test_slot_placement.py.)"""
     full = counter_batch(20, T, start_ms=START_MS)
     ragged = counter_batch(10, T // 2, start_ms=START_MS + 5_000,
-                           metric="other_total", seed=5)
+                           step_ms=15_000, metric="other_total", seed=5)
     engine = _mk_engine([full, ragged])
     before = _fused_count()
     a = _query(engine, 'sum(rate(request_total{_ws_="demo"}[5m])) by (_ns_)')
@@ -449,3 +452,89 @@ def test_lazykeys_defers_materialization_on_fused_path():
     assert len(lk) == 3 and bool(lk)
     assert lk._keys is None                     # len/bool didn't materialize
     assert lk[0] is not None and lk._keys is not None
+
+
+@pytest.mark.parametrize("promql,by_set", [
+    ('sum(rate(request_total{_ws_="demo"}[5m])) by (_ns_)', True),
+    ('avg(increase(request_total{_ws_="demo"}[5m]))', True),
+    ('count(rate(request_total{_ws_="demo"}[5m])) by (dc)', True),
+    ('sum(increase(request_total{_ws_="demo"}[5m])) by (_ns_, dc)', True),
+    # min and max ride the per-series run over the part's own rows
+    ('max(rate(request_total{_ws_="demo"}[5m])) by (_ns_)', False),
+])
+def test_a_series_the_range_leaves_out_is_dropped_from_the_whole_set(
+        fused_env, promql, by_set):
+    """A range that misses some series' lives (here: three whose index
+    entry ended before it, their samples still in the store) is answered
+    from the working set of ALL the selector's series, already padded for
+    the ranges that hold every life, with the rows left out in no group
+    (ISSUE 42): no take, no pad, and the answer of a store that never held
+    the three."""
+    def ask(eng, start_s):
+        res = eng.query_range(promql, start_s, 60, END_S)
+        assert res.error is None, res.error
+        return {tuple(sorted(k.labels_dict.items())): np.asarray(v)
+                for k, _, v in res.series()}
+
+    full = counter_batch(20, T, start_ms=START_MS, resets=True)
+    engine = _mk_engine([full])
+    shard = engine.source.get_shard("prometheus", 0)
+    gone = (4, 9, 17)
+    for pid in gone:
+        shard.index.update_end_time(pid, START_MS + 10 * 10_000)
+    # a range that holds every life pads the set (and builds the mirror)
+    ask(engine, START_S + 60)
+    pads = registry.counter("span_leaf_pad_values_calls")
+
+    def takes():
+        return sum(v for n, _, v in registry.snapshot_samples()
+                   if n == "mirror_gather_takes_total")
+    before, padded, taken = _fused_count(), pads.value, takes()
+    got = ask(engine, START_S + 600)        # past the three's lives
+    assert _fused_count() > before, "fused path did not engage"
+    if by_set:
+        assert pads.value == padded and takes() == taken
+    # the oracle: a store that holds the other seventeen alone
+    keep = np.isin(full.part_idx, gone, invert=True)
+    idx = np.cumsum(np.isin(np.arange(20), gone, invert=True)) - 1
+    rest = RecordBatch(
+        full.schema, [k for i, k in enumerate(full.part_keys)
+                      if i not in gone],
+        idx[full.part_idx[keep]], full.timestamps[keep],
+        {k: v[keep] for k, v in full.columns.items()}, full.bucket_les)
+    want = ask(_mk_engine([rest]), START_S + 600)
+    assert set(got) == set(want)
+    for k in want:
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-5, atol=1e-4,
+                                   equal_nan=True)
+    # ... and with the three in it where the range holds them
+    both = ask(engine, START_S + 60)
+    assert any(not np.allclose(both[k][-len(got[k]):], got[k],
+                               rtol=1e-3, equal_nan=True) for k in got)
+
+
+@pytest.mark.parametrize("by,without", [(("_ns_",), ()), ((), ()),
+                                        (("_ns_", "dc"), ()),
+                                        ((), ("instance",))])
+def test_a_parts_group_ids_are_the_loops_own(by, without):
+    """`_group_ids_of_part` renumbers the whole sequence's ids for a
+    subsequence of its keys: the ids and the keys that `_group_ids` gives
+    for that subsequence itself, groups that lost every series gone."""
+    from filodb_tpu.query.rangevector import RangeVectorKey
+    from filodb_tpu.query.transformers import (_group_ids,
+                                               _group_ids_of_part)
+    rng = np.random.default_rng(7)
+    keys = [RangeVectorKey.make({"_ns_": f"App-{rng.integers(9)}",
+                                 "dc": f"dc-{rng.integers(3)}",
+                                 "instance": f"i{i}",
+                                 "_metric_": "request_total"})
+            for i in range(400)]
+    gids, gkeys = _group_ids(keys, by, without)
+    for member in (np.arange(400), np.arange(5, 400, 7),
+                   np.flatnonzero(rng.random(400) < 0.02), np.array([399]),
+                   np.array([], np.int64)):
+        want_ids, want_keys = _group_ids([keys[i] for i in member], by,
+                                         without)
+        got_ids, got_keys = _group_ids_of_part(gids, gkeys, member)
+        np.testing.assert_array_equal(got_ids, want_ids)
+        assert got_keys == want_keys

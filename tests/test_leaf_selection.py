@@ -284,6 +284,59 @@ def test_an_ended_series_leaves(live):
     assert fills_since(before)
 
 
+def test_a_series_that_ended_was_evicted_and_came_back_has_its_history():
+    """Eviction takes an ended series out of memory and out of the index;
+    its flushed chunks stay in the column store under its key.  When the
+    key is scraped again ingest creates a new row, whose history lies on
+    disk: a query that starts below the row's first sample pages it in
+    (the floor of a row that ingest creates is unknown, not 0)."""
+    from filodb_tpu.core.store import InMemoryColumnStore, InMemoryMetaStore
+    ms = TimeSeriesMemStore(column_store=InMemoryColumnStore(),
+                            meta_store=InMemoryMetaStore())
+    shard = ms.setup("prometheus", 0)
+    eng = engine_over(ms)
+    shard.ingest(gauge_batch(4, 60, start_ms=START), offset=1)
+    shard.flush_all_groups()
+    q = "sum(count_over_time(heap_usage[5m]))"
+    at = START + 59 * STEP
+    assert count_at(eng, at, q)[0] == 4 * 30
+    back = START + 200 * STEP
+    for pid in range(4):
+        shard.index.update_end_time(pid, at)
+    assert shard.evict_ended_partitions(back) == 4
+    assert count_at(eng, at, q)[0] is None
+    shard.ingest(gauge_batch(4, 10, start_ms=back), offset=2)
+    store = shard.stores["gauge"]
+    rows = shard.rows_for(shard.lookup_partitions([], 0, 1 << 62).part_ids)
+    assert (store.paged_floor[rows] == np.iinfo(np.int64).max).all()
+    # (a query that ends before the new life begins is not the row's: its
+    # index entry starts at `back`)
+    assert count_at(eng, at, q)[0] is None
+    res = eng.query_range(q, at // 1000, 60, (back + 9 * STEP) // 1000)
+    assert res.error is None, res.error
+    assert float(np.asarray(res.blocks[0].values)[0][0]) == 4 * 30
+    assert res.stats.samples_paged == 4 * 31     # from the range's start
+    # ... and a series that has no history (born inside the range) is
+    # asked about once, as far below again as the query reached, and not by
+    # every query that starts a step earlier
+    shard.ingest(gauge_batch(6, 10, start_ms=back), offset=3)
+    fresh = np.setdiff1d(shard.rows_for(shard.lookup_partitions(
+        [], 0, 1 << 62).part_ids), rows)
+    assert fresh.size == 2
+    start = back - 30 * STEP
+    assert shard.ensure_paged_pids(
+        "gauge", shard.lookup_partitions([], 0, 1 << 62).part_ids,
+        start, back + 9 * STEP) == 0
+    # (30 steps were asked about, the query is 39 long)
+    assert (store.paged_floor[fresh] == start - 39 * STEP).all()
+    gen = store.generation
+    lk = shard.lookup_partitions([], 0, 1 << 62)
+    _, facts = shard.selection_facts(lk, "gauge")
+    assert not facts.may_need_paging(start - 39 * STEP, back + 9 * STEP)
+    assert facts.may_need_paging(start - 40 * STEP, back + 9 * STEP)
+    assert store.generation == gen
+
+
 def cold_shard(tmp_path, series=4, samples=60):
     """A shard flushed to disk and recovered into a fresh memstore: every
     row is page-only and empty until a query pages it in."""

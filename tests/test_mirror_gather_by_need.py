@@ -253,8 +253,10 @@ def test_general_path_takes_what_it_reads_and_answers_as_before(
     else:
         engine = _mk_engine([
             gauge_batch(20, T, start_ms=START_MS),
+            # (a second scrape interval: rows that merely start late or
+            # hold fewer samples are placed on the grid's slots, ISSUE 42)
             gauge_batch(10, T // 2, start_ms=START_MS + 5_000,
-                        metric="other_gauge", seed=5)])
+                        step_ms=15_000, metric="other_gauge", seed=5)])
         promql = 'sum(sum_over_time(other_gauge{_ws_="demo"}[5m])) by (dc)'
     general = registry.counter("leaf_general_path")
     engine.query_range(promql, *ARGS)                   # builds the mirror

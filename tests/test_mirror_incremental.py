@@ -28,8 +28,22 @@ def _slices(batch, bounds):
 def _mirror_state(mirror, store):
     snap = mirror._snap
     out = {"ts": np.asarray(snap.ts_off)}
+    packed = None
+    if snap.interval:
+        # a PLACED snapshot (columns are the scrape grid's slots, NaN where
+        # a row holds no sample: ISSUE 42) holds the samples an unplaced one
+        # holds: each row's, packed to the front, compare as before
+        from filodb_tpu.ops.timewindow import PAD_TS
+        held = np.isfinite(np.asarray(next(iter(snap.cols.values()))))
+        packed = np.argsort(~held, axis=1, kind="stable")
+        width = max(store.time_used, 1)
+        out["ts"] = np.take_along_axis(
+            np.where(held, out["ts"], PAD_TS), packed, axis=1)[:, :width]
     for n, a in snap.cols.items():
         out[f"col_{n}"] = np.asarray(a)
+        if packed is not None:
+            out[f"col_{n}"] = np.take_along_axis(
+                out[f"col_{n}"], packed, axis=1)[:, :width]
         # reconstruct ABSOLUTE values: rebased + vbase (bases may differ
         # between incremental and full paths for fresh rows; absolutes
         # must not)

@@ -246,9 +246,11 @@ OFFSETS = [4_000, 0, STEP - 1, 1, 4_000]
     ("one timestamp row", [0] * 5, {}, True, 0),
     ("the earliest row is not row 0", [7, 9_000, 3, 3, 8], {}, True, 0),
     ("a late sample", OFFSETS, {"late": (2, 5, 7)}, False, 1),
+    # rows that hold other counts of samples, or lie a whole interval
+    # apart, are placed on the grid's slots (ISSUE 42: test_slot_placement)
     ("a hole: one row a sample short", OFFSETS,
-     {"counts": [8, 8, 7, 8, 8]}, False, 1),
-    ("a row a whole interval behind", [0, 5, STEP, 9], {}, False, 1),
+     {"counts": [8, 8, 7, 8, 8]}, "placed", 0),
+    ("a row a whole interval behind", [0, 5, STEP, 9], {}, "placed", 0),
     # ... and one that starts in the next interval since the epoch, but
     # less than an interval behind the earliest: a phase off THAT row
     ("no whole interval holds every first sample", [6_000, 15_000, 7_000],
@@ -262,9 +264,19 @@ def test_the_mirror_finds_the_phase_grid_or_counts_the_rows_off_it(
     assert mirror.ensure_fresh(store)
     snap = mirror.snapshot()
     got = mirror.fused_eligible("value", snap)
-    assert (got is not None) == on_grid, case
+    assert (got is not None) == (on_grid is True), case
     assert registry.gauge("device_mirror_offgrid_rows",
                           shard="977").value == offgrid
+    if on_grid == "placed":
+        # fusable by the ragged variants alone: the empty slots are NaN
+        assert snap.interval == STEP and snap.placed_rows
+        assert mirror.fused_eligible("value", snap,
+                                     allow_ragged=True) is not None
+        assert registry.gauge("device_mirror_placed_rows",
+                              shard="977").value == snap.placed_rows
+        return
+    assert snap.interval == 0 and registry.gauge(
+        "device_mirror_placed_rows", shard="977").value == 0
     if not on_grid:
         assert snap.phase is None and not snap.uniform_grid
         assert mirror.gather_cached(rows, snap).deferred("phase") is None
