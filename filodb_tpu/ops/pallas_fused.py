@@ -198,6 +198,10 @@ class FusedPlan(NamedTuple):
     # ts_row[idx1 - 1]), last = idx2 - (phase > we - ts_row[idx2]).  What
     # a dispatch of phased working sets uploads in place of `rows`.
     prows: np.ndarray
+    # the widest non-empty window, in slots of the shared row (0: every
+    # window is empty): how far the ragged rate family's boundary fills
+    # have to carry a sample (scan_steps)
+    span: int
     # the call's host operands as they lie on a device, put at the first
     # enqueue that takes them there (enqueue_operands): (device, "rows" |
     # "prows" | "tsrow") -> the device array.  Nothing else refers to
@@ -254,12 +258,35 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
     operands = rows.nbytes + prows.nbytes + tsr.nbytes
     return FusedPlan(rows, *(rows[i:i + 1] for i in range(8)), tsrow=tsr,
                      wvalid=wvalid, wvalid1=wvalid1, W=W, Tp=Tp,
-                     prows=prows, resident={},
+                     prows=prows, span=int(n.max()) if W else 0, resident={},
                      nbytes=operands * (1 + jax.local_device_count())
                      + wvalid.nbytes + wvalid1.nbytes)
 
 
-def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False):
+def _row_steps(Tp: int) -> int:
+    """The doubling steps a fill takes to cross a row of Tp slots."""
+    return max(Tp - 1, 1).bit_length()
+
+
+def scan_steps(plan: FusedPlan, kind: str, ragged: bool,
+               phased: bool = False) -> int:
+    """The doubling steps the ragged rate family's boundary fills take
+    (`_fill_scan2`): the least j whose reach of 2**j - 1 slots crosses the
+    plan's widest window (one slot wider on a phase grid, where a row may
+    take the slot before the shared first), and never more than cross the
+    row, ceil(log2(Tp)).  Only samples inside a window count (fewer than
+    two mask the cell), so a carry that travels a window's width selects
+    what one that travels the row's selects.  `[5m]` over a 10 s grid: 5,
+    `[1h]`: 9, a range past 512 slots: 10 at Tp 768.  Every other flavor
+    runs no fill and gets 0, so none of them compiles anew."""
+    if not (ragged and kind == "rate_family"):
+        return 0
+    span = plan.span + (1 if phased else 0)
+    return min(max(span - 1, 0).bit_length(), _row_steps(plan.Tp))
+
+
+def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
+                    ragged: bool = False):
     """The 12 operands `_kernel` reads after (vals, vbase, gids), from a
     plan's uploaded rows; `phased` (rows is the plan's [16, Wp] `prows`):
     13, the last being the rows themselves for the kernel's slacks, with
@@ -273,25 +300,39 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False):
     o[t, w] = 1{t == idx[w]} and l[t, w] = 1{t <= idx[w]} over the
     non-empty windows (n1 >= 1), 0 elsewhere.  The gather kinds
     (_selects_by_gather) read none of them and get [8, 128] stand-ins,
-    which frees their ~1.5 MB of VMEM for larger series blocks.  `n`
+    which frees their ~1.5 MB of VMEM for larger series blocks; the
+    `ragged` rate family alone reads one, the [Tp, Wp] band, first of
+    the four.  `n`
     resolves to the true counts for the over_time kinds; `tsrow` None
     (every kind but the ragged rate family leaves it unread) becomes
     zeros."""
     def row(i):
         return rows[i:i + 1]
 
-    if _selects_by_gather(kind):
-        sel = (jnp.zeros((8, _LANE), jnp.float32),) * 4
+    stand_in = jnp.zeros((8, _LANE), jnp.float32)
+    band_only = ragged and kind == "rate_family"
+    if _selects_by_gather(kind) and not band_only:
+        sel = (stand_in,) * 4
     else:
         t = jax.lax.broadcasted_iota(jnp.int32, (Tp, rows.shape[1]), 0)
         valid = row(_N1) >= 1.0
 
-        def mat(i, leq):
-            iw = jnp.where(valid, row(i).astype(jnp.int32), -1)
-            return ((t <= iw) if leq else (t == iw)).astype(jnp.float32)
+        def slot(i):
+            return jnp.where(valid, row(i).astype(jnp.int32), -1)
 
-        sel = (mat(_I1, False), mat(_I2, False), mat(_I1, True),
-               mat(_I2, True))
+        def mat(i, leq):
+            return ((t <= slot(i)) if leq else (t == slot(i))).astype(
+                jnp.float32)
+
+        if band_only:
+            # a row's valid samples a window are one product with the
+            # band the over_time kinds make in the kernel (l2 - l1 + o1):
+            # it rides in o1's place
+            band = (t >= slot(_I1)) & (t <= slot(_I2))
+            sel = (band.astype(jnp.float32),) + (stand_in,) * 3
+        else:
+            sel = (mat(_I1, False), mat(_I2, False), mat(_I1, True),
+                   mat(_I2, True))
     if tsrow is None:
         tsrow = jnp.zeros((1, Tp), jnp.float32)
     if phased:
@@ -346,10 +387,17 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
     plan's miss share).  `tsrow` rides only where the kernel reads it
     (the ragged rate family), `offsets` only where some set has several
     panels; the others are None.  `sets`: the working sets the call
-    carries.  `phased`: the rows are the plan's [16, Wp] `prows`."""
+    carries.  `phased`: the rows are the plan's [16, Wp] `prows`.
+    A ragged rate-family call also books its scan_steps on
+    `fused_ragged_scan_steps_total` (5 a launch where the windows are
+    `[5m]` of a 10 s grid, ceil(log2(Tp)) where they span the row: whether
+    a deployment's windows let the fills stop short)."""
     from filodb_tpu.utils.metrics import registry
     registry.counter("fused_enqueues").increment()
     registry.counter("fused_enqueue_sets").increment(sets)
+    steps = scan_steps(plan, kind, ragged, phased)
+    if steps:
+        registry.counter("fused_ragged_scan_steps").increment(steps)
     held, uploads = plan.resident, 0
 
     def resident(which):
@@ -406,24 +454,23 @@ def _fill_scan(x, ok, left: bool):
     return x, okf
 
 
-def _fill_scan2(x, y, ok, left: bool):
-    """_fill_scan over two carriers sharing ONE validity evolution — the
-    ragged rate path fills values and timestamps against the same mask,
-    and sharing the okf carry halves the live [bs, Tp] scan temporaries
-    (the footprint that forces the series-block shrink)."""
+def _fill_scan2(x, y, steps: int, left: bool):
+    """Forward (left=False) / backward (left=True) fill of two carriers
+    over `steps` doubling steps: a slot ends holding the nearest sample
+    within 2**steps - 1 slots on the fill side (scan_steps: as far as the
+    widest window is wide).  The ragged rate path fills values `x` and
+    timestamps `y` together, and validity rides in `y` itself, NaN where a
+    slot holds no sample (`x` holds 0 there): no third carrier to shift, so
+    a step is two shifts, one compare and two selects, all f32 (Mosaic
+    rejects i1 vreg shifts: _fill_scan).  A slot no sample reaches reads 0
+    in `x` and NaN in `y`; the caller zeroes the few it gathers."""
     shift = _shift_l if left else _shift_r
-    okf = ok.astype(jnp.float32)
-    k = 1
-    while k < x.shape[1]:
-        xs = shift(x, k, 0.0)
-        ys = shift(y, k, 0.0)
-        oks = shift(okf, k, 0.0)
-        keep = okf > 0
-        x = jnp.where(keep, x, xs)
-        y = jnp.where(keep, y, ys)
-        okf = jnp.maximum(okf, oks)
-        k *= 2
-    return x, y, okf
+    for j in range(steps):
+        k = 1 << j
+        keep = y == y
+        x = jnp.where(keep, x, shift(x, k, 0.0))
+        y = jnp.where(keep, y, shift(y, k, jnp.nan))
+    return x, y
 
 
 def _cumsum_lanes(x):
@@ -466,7 +513,7 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             num_groups: int, is_counter: bool, is_rate: bool,
             with_drops: bool, kind: str = "rate_family",
             ragged: bool = False, per_series: bool = False,
-            phased: bool = False):
+            phased: bool = False, steps: int):
     v = vals_ref[:]                                   # [BS, Tp]
     if phased:
         # rows of a phase grid (FusedPlan.prows): after the 12 operands
@@ -484,6 +531,23 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         idx1 = i1_ref[:].astype(jnp.int32) - early1.astype(jnp.int32)
         idx2 = i2_ref[:].astype(jnp.int32) - early2.astype(jnp.int32)
         slots = n_ref[:] + e1 - e2                    # [BS, Wp] own count
+    if phased and (not _selects_by_gather(kind)
+                   or (ragged and kind == "rate_family")):
+        # a product with the base row's band (the over_time kinds' sums
+        # and counts, the ragged rate family's counts) is a row's own by
+        # two gathered corrections: a row that starts a slot early adds
+        # the sample before the band, which is its own first, one that
+        # ends a slot early takes the band's last, the slot after its own,
+        # away (an empty band leaves the one sample a row may hold, at
+        # last == first - 1, to them alone).  Both slots are gathered by
+        # a row's own [BS, Wp] index: Mosaic lowers no shared [1, Wp] one
+        # past 128 windows, and folds `idx2 + early2` back into one
+        after = jnp.where(early2, idx2 + 1, idx2)
+
+        def corrected(total, x):
+            return total \
+                + jnp.where(early1, _gather_cols(x, idx1), 0.0) \
+                - jnp.where(early2, _gather_cols(x, after), 0.0)
     if kind == "last_over_time":
         # instant-vector selector (`sum by (x) (metric)` with staleness
         # lookback): the last sample in each window, gathered at last[w];
@@ -516,19 +580,6 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # holes, take per-(series, window) counts from a second matmul of
         # the validity mask against the same band (VERDICT r2 item 2).
         band = l2_ref[:] - l1_ref[:] + o1_ref[:]
-        if phased:
-            # the band is the base row's; a row that starts a slot early
-            # adds the sample before it, one that ends a slot early takes
-            # the band's last away: two gathered corrections to the one
-            # band product (an empty band leaves the one sample a row may
-            # hold, at last == first - 1, to them alone)
-            before = jnp.maximum(i1_ref[:].astype(jnp.int32) - 1, 0)
-            at_end = i2_ref[:].astype(jnp.int32)
-
-            def corrected(total, x):
-                return total \
-                    + jnp.where(early1, _gather_cols(x, before), 0.0) \
-                    - jnp.where(early2, _gather_cols(x, at_end), 0.0)
         if ragged:
             validf = (v == v).astype(jnp.float32)     # NaN-aware
             s = _dot_hi(jnp.where(v == v, v, 0.0), band)
@@ -580,34 +631,35 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             # previous RAW value (prev + vbase), cumulative across the row
             d = jnp.where(m & (pok > 0) & (vz < prev),
                           prev + vbase_ref[:], 0.0)
-            c = vz + _cumsum_lanes(d)
+            c = vz + jnp.where(m, _cumsum_lanes(d), 0.0)
         else:
             c = vz
         tsb = jnp.where(m, ts_ref[:] + ph if phased
-                        else jnp.broadcast_to(ts_ref[:], v.shape), 0.0)
-        f_c, f_t, _ = _fill_scan2(c, tsb, m, left=False)
-        b_c, b_t, _ = _fill_scan2(c, tsb, m, left=True)
-        # exact selections at the first/last window slots, and the
-        # validity count as a cumsum difference — all integer-in-f32.
-        # Empty windows gather slot 0: nv <= 1 there, so presence masks
-        # them (a phased row without a slot has last == first - 1: nv 0).
+                        else jnp.broadcast_to(ts_ref[:], v.shape), jnp.nan)
+        f_c, f_t = _fill_scan2(c, tsb, steps, left=False)
+        b_c, b_t = _fill_scan2(c, tsb, steps, left=True)
+        # exact selections at the first/last window slots, and the count
+        # of valid samples between them as one product with the 0/1 band
+        # (kernel_operands puts it in o1's place) on the MXU this branch
+        # leaves idle: integers under 2^24 in f32 accumulation, exact.
+        # Empty windows gather slot 0 and count 0, so presence masks them.
         if not phased:
             idx1 = i1_ref[:].astype(jnp.int32)
             idx2 = i2_ref[:].astype(jnp.int32)
         mf = m.astype(jnp.float32)
-        cs_m = _cumsum_lanes(mf)
-        nv = _gather_cols(cs_m, idx2) - _gather_cols(cs_m, idx1) \
-            + _gather_cols(mf, idx1)
+        nv = _dot_1p(mf, o1_ref[:])
+        if phased:
+            nv = corrected(nv, mf)
         v1 = _gather_cols(b_c, idx1)
         v2 = _gather_cols(f_c, idx2)
         t1 = _gather_cols(b_t, idx1)
         t2 = _gather_cols(f_t, idx2)
+        # a slot no sample reached reads 0, as its value does: such a cell
+        # holds fewer than two samples and is masked, and no NaN goes on
+        t1 = jnp.where(t1 == t1, t1, 0.0)
+        t2 = jnp.where(t2 == t2, t2, 0.0)
         n = jnp.maximum(nv, 2.0)                      # math-safe; masked
         pres = (nv >= 2.0).astype(jnp.float32)
-        if phased:
-            # a first slot one past the row gathers nothing, not the count
-            # there: a row holds no more samples than slots
-            pres = pres * (slots >= 2.0).astype(jnp.float32)
     else:
         if not phased:
             idx1 = i1_ref[:].astype(jnp.int32)
@@ -656,9 +708,9 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
     if is_rate:
         out = out / jnp.maximum(we - ws, 1.0) * 1000.0
     if pres is not None:
-        # no NaN into the MXU (a phased row's masked cell divides by the
-        # floor of `sampled`: an inf there must not meet the 0)
-        out = jnp.where(pres > 0, out, 0.0) if phased else out * pres
+        # no NaN into the MXU (a masked cell divides by the floor of
+        # `sampled`: an inf there must not meet the 0)
+        out = jnp.where(pres > 0, out, 0.0)
 
     _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
 
@@ -698,7 +750,7 @@ def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
 
 
 def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
-                   phased: bool = False) -> str:
+                   phased: bool = False, steps: int = 0) -> str:
     """The compile-cache shape signature recorded with jit compile
     events (utils/devicetelem): the padded dims + static flags that key
     the trace cache, so a recompile storm names the shape that drove it.
@@ -707,17 +759,18 @@ def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
     Sp = sum(st[0].shape[0] for st in sets)
     return (f"S{Sp}xT{plan.Tp}xW{plan.t1.shape[1]}xG{sum(num_groups)}:{kind}"
             + (":ragged" if ragged else "") + (":phased" if phased else "")
+            + (f":{steps}steps" if steps else "")
             + (f":{len(sets)}sets" if len(sets) > 1 else ""))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
-    "kind", "ragged", "per_series", "phased"))
+    "kind", "ragged", "per_series", "phased", "steps"))
 def _run(sets, offsets, rows, tsrow, *,
          num_groups: Tuple[int, ...], is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
          ragged: bool = False, per_series: bool = False,
-         phased: bool = False):
+         phased: bool = False, steps: int):
     """One fused dispatch, whole: the plan's kernel operands
     (kernel_operands), built once, then for every working set of `sets`
     its group merge (merge_gid_cols) and its own Pallas call, in one
@@ -735,7 +788,7 @@ def _run(sets, offsets, rows, tsrow, *,
     sum(num_groups[:i]).  A set's block is what a call of that set alone
     returns, bit for bit: the sets meet only in the concatenation."""
     Tp = sets[0][0].shape[1]
-    operands = kernel_operands(rows, tsrow, Tp, kind, phased)
+    operands = kernel_operands(rows, tsrow, Tp, kind, phased, ragged)
     outs, p0 = [], 0
     for st, Gp in zip(sets, num_groups):
         vals_p, vbase_p, gids = st[:3]
@@ -748,7 +801,8 @@ def _run(sets, offsets, rows, tsrow, *,
             rows.shape[1], Gp, st[3] if phased else None,
             is_counter=is_counter, is_rate=is_rate,
             with_drops=with_drops, interpret=interpret, kind=kind,
-            ragged=ragged, per_series=per_series, phased=phased))
+            ragged=ragged, per_series=per_series, phased=phased,
+            steps=steps))
     paired = ragged or phased
     if len(outs) == 1:
         return tuple(outs[0]) if paired else outs[0]
@@ -760,12 +814,12 @@ def _run(sets, offsets, rows, tsrow, *,
 
 @functools.partial(jax.jit, static_argnames=(
     "Wp", "Gp", "is_counter", "is_rate", "with_drops", "interpret", "kind",
-    "ragged", "per_series", "phased"))
+    "ragged", "per_series", "phased", "steps"))
 def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
              phase_p=None, *,
              is_counter: bool, is_rate: bool, with_drops: bool,
              interpret: bool, kind: str, ragged: bool, per_series: bool,
-             phased: bool = False):
+             phased: bool = False, steps: int):
     """One working set's Pallas call inside `_run`'s trace: its own
     series block, grid and group count over the shared plan operands.
     Called from `_run` and nowhere else.  It is a jit only so that sets
@@ -806,7 +860,7 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     kern = functools.partial(_kernel, num_groups=Gp, is_counter=is_counter,
                              is_rate=is_rate, with_drops=with_drops,
                              kind=kind, ragged=ragged, per_series=per_series,
-                             phased=phased)
+                             phased=phased, steps=steps)
     with_counts = ragged or phased       # presence rides a second output
     if per_series:
         out_spec = pl.BlockSpec((bs, Wp), lambda i: (i, 0), **space)
@@ -843,12 +897,23 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
                   panels: int = 1, phased: bool = False) -> int:
     """Rough resident-bytes model for one grid step: the band kinds' 4
     selection matrices and band temporary (the gather kinds ship 4 KB
-    stand-ins), the double-buffered values block, the group one-hot +
+    stand-ins; the ragged rate family reads ONE [Tp, Wp] band, held in
+    the pipeline's two buffers: 8 * Tp * Wp bytes, 12 MiB at 1536 x 1024,
+    so a long range of many windows diverts here and does not fail at
+    lowering), the double-buffered values block, the group one-hot +
     accumulator, and [bs, Wp] f32 temporaries.  The ragged rate family's
-    fill/prefix scans keep ~19 [bs, Tp] temporaries live (calibrated against the
-    Mosaic scoped-vmem allocation report on a real v5e: 21.36 MiB at
-    bs=256, Tp=768, Wp=128, Gp=1000 — the first on-chip ragged compile
-    OOM'd scoped vmem where the old 8-temporary model predicted 13 MiB).
+    19 [bs, Tp] temporaries are an upper bound kept on purpose.  Mosaic's
+    scoped allocation for the kernel's temporaries at Tp=768, Wp=128 on a
+    phase grid (the compiler's own report for a described v5e, re-read by
+    tests/test_chip_compile.py) was 14.56 MiB at bs=256 and 6.80 at 128
+    with three fill carriers over ten steps, which is the 19; it is 10.55
+    and 5.16 MiB (8.03 and 3.91 on one shared row) since the fills carry
+    two over the steps a window needs, under 14 of them.  So 256 rows
+    would fit.  They are not taken: on the chip the larger block
+    measured 14.28 against 14.52 ms a launch of
+    promchurn-counters-262k.open's four sets (PERF.md section 6, PR 43),
+    and a block of other rows regroups the epilogue's f32 sums, so the
+    answers would stop being the smaller block's bit for bit.
     Callers divert to the general XLA path when this exceeds VMEM_BUDGET
     instead of failing at kernel lowering; _run shrinks its series block
     (pick_block) before giving up, so the gate must test the SMALLEST
@@ -857,9 +922,7 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
         5 * Tp * Wp * 4
     vals = 2 * bs * Tp * 4
     if ragged and kind == "rate_family":
-        # 19 was calibrated BEFORE _fill_scan2 halved the scan carries;
-        # kept until the next on-chip window re-measures it (conservative
-        # = smaller blocks than strictly needed, never an OOM)
+        sel += 2 * Tp * Wp * 4
         vals += 19 * bs * Tp * 4
     # multi-panel epilogue (merge_gid_cols): each extra grouping column
     # builds another [Gp, bs] one-hot compare temporary feeding the
@@ -879,7 +942,7 @@ def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     (None when even _MIN_BS doesn't — the caller must divert to the
     general path).  The ragged rate family's scan temporaries scale with
     bs*Tp, so long rows fuse fine at a smaller block: at Tp=768 the
-    dense kernel keeps bs=256 while ragged rate drops to 64 instead of
+    dense kernel keeps bs=256 while ragged rate drops to 128 instead of
     falling off the fused path entirely."""
     bs = _BS
     while bs >= _MIN_BS:
@@ -935,10 +998,19 @@ def can_fuse(fn_name: str, agg_op: str, shared_grid: bool,
 # traceable entry for callers composing the kernel inside shard_map (the
 # mesh executor); the jit wrapper inlines under an enclosing trace.
 # One working set; gids_p is one [Sp, P] matrix, merged by the caller.
+# `steps` is the plan's scan_steps; a caller without the plan at hand
+# leaves it out and the ragged rate family's fills cross the whole row,
+# which selects the same samples (never 0 there: a fill of no steps
+# reads a hole at a window's edge as a sample).
 def run_kernel(vals_p, vbase_p, gids_p, rows, tsrow=None, *,
-               num_groups: int, **kw):
+               num_groups: int, kind: str = "rate_family",
+               ragged: bool = False, steps: Optional[int] = None, **kw):
+    if steps is None:
+        steps = _row_steps(vals_p.shape[1]) \
+            if ragged and kind == "rate_family" else 0
     return _run(((vals_p, vbase_p, (gids_p,)),), None, rows, tsrow,
-                num_groups=(num_groups,), **kw)
+                num_groups=(num_groups,), kind=kind, ragged=ragged,
+                steps=steps, **kw)
 
 
 class PreparedInputs(NamedTuple):
@@ -1060,7 +1132,8 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
     res, _ = _enqueue_run(
         plan, device, (_kernel_set(prepared, (prepared.gids_p,)),), None,
         (Gp,),
-        **_flavor(fn_name, precorrected, interpret, ragged, phased)._asdict())
+        **_flavor(plan, fn_name, precorrected, interpret, ragged,
+                  phased)._asdict())
     if ragged or phased:
         sums, cnts = res
         counts = np.asarray(cnts, np.float64)[:num_groups, :plan.W]
@@ -1291,7 +1364,8 @@ def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
     with span_part("leaf.enqueue_jit"):
         res = watched_call(
             "fused_run", _run,
-            _run_shape_sig(sets, plan, num_groups, kind, ragged, phased),
+            _run_shape_sig(sets, plan, num_groups, kind, ragged, phased,
+                           flags["steps"]),
             lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
                          **flags),
             device=device)
@@ -1306,17 +1380,21 @@ class _FlavorFlags(NamedTuple):
     kind: str
     ragged: bool
     phased: bool
+    steps: int
 
 
-def _flavor(fn_name: str, precorrected: bool, interpret: bool,
-            ragged: bool, phased: bool = False) -> _FlavorFlags:
+def _flavor(plan: FusedPlan, fn_name: str, precorrected: bool,
+            interpret: bool, ragged: bool,
+            phased: bool = False) -> _FlavorFlags:
     """`_run`'s static flags of one (function, precorrected, interpret,
-    ragged, phased) flavor."""
+    ragged, phased) flavor over `plan`, whose windows say how far the
+    ragged rate family's fills reach (scan_steps: 0 for the others)."""
     is_counter = fn_name in ("rate", "increase")
+    kind = fn_name if fn_name in OVER_TIME_FNS else "rate_family"
     return _FlavorFlags(
         is_counter, fn_name == "rate", is_counter and not precorrected,
-        interpret, fn_name if fn_name in OVER_TIME_FNS else "rate_family",
-        ragged, phased)
+        interpret, kind, ragged, phased,
+        scan_steps(plan, kind, ragged, phased))
 
 
 def _kernel_set(values, gid_cols: tuple) -> tuple:
@@ -1349,7 +1427,7 @@ class FusedDispatch:
         self.plan = plan
         self.key = (fn_name, precorrected, interpret, ragged, phased)
         self.device = device
-        self.flags = _flavor(*self.key)
+        self.flags = _flavor(plan, *self.key)
         # per set: (values, [(groups, G, op)], offsets, the panels' G summed)
         self._sets: list = []
         self._res = None

@@ -301,11 +301,12 @@ def device_put_packed(packed: PackedShards, mesh: Mesh) -> PackedShards:
 
 @functools.partial(jax.jit, static_argnames=(
     "G", "S", "T", "Tp", "is_counter", "is_rate", "interpret", "kind",
-    "ragged"))
+    "ragged", "steps"))
 def _device_fused_call(values, group_ids, vbase, rows, tsrow, *, G: int,
                        S: int, T: int, Tp: int, is_counter: bool,
                        is_rate: bool, interpret: bool,
-                       kind: str = "rate_family", ragged: bool = False):
+                       kind: str = "rate_family", ragged: bool = False,
+                       steps: Optional[int] = None):
     """One device's share of the multi-chip fused scan: pad this device's
     [1, S, T] values + [1, S, P] grouping (P > 1: run_agg_batch panels
     over disjoint group-id ranges, multi-hot kernel epilogue) to kernel
@@ -319,8 +320,10 @@ def _device_fused_call(values, group_ids, vbase, rows, tsrow, *, G: int,
     zeroed they contribute nothing (pack pad rows carry gid 0 but add +0
     to its sums).  Ragged packs keep their NaNs — the kernel's fill
     scans treat them as absent samples; pad rows become all-NaN rows
-    whose presence is 0.  with_drops is always False here: counter
-    functions require a precorrected pack."""
+    whose presence is 0.  `steps` is the time slice's plan's
+    pf.scan_steps (left out: the fills cross the row, run_kernel).
+    with_drops is always False here: counter functions require a
+    precorrected pack."""
     from filodb_tpu.ops import pallas_fused as pf
     Gp = pf.pad_group_count(G)
     Sp = pf.pad_series_count(S)
@@ -335,7 +338,7 @@ def _device_fused_call(values, group_ids, vbase, rows, tsrow, *, G: int,
     res = pf.run_kernel(v, vb, g, rows, tsrow, num_groups=Gp,
                         is_counter=is_counter, is_rate=is_rate,
                         with_drops=False, interpret=interpret, kind=kind,
-                        ragged=ragged)
+                        ragged=ragged, steps=steps)
     if ragged:
         return res[0][:G], res[1][:G]
     return res[:G]
@@ -1205,18 +1208,20 @@ class MeshExecutor:
             for si in range(D):
                 for ti in range(n_time):
                     dev = grid[si, ti]
+                    steps = pf.scan_steps(plans[ti], kind_k, ragged)
+                    sig_t = sig + (f":{steps}steps" if steps else "")
                     rows_d, ts_d, _ = pf.enqueue_operands(
                         plans[ti], dev, kind_k, ragged)
                     _d0 = _time.perf_counter()
                     res = watched_call(
-                        "mesh_fused", _device_fused_call, sig,
+                        "mesh_fused", _device_fused_call, sig_t,
                         lambda: _device_fused_call(
                             vblocks[dev], gblocks[dev], vbblocks[dev],
                             rows_d, ts_d, G=Gtot, S=S, T=T, Tp=Tp,
                             is_counter=is_counter,
                             is_rate=(fn_name == "rate"),
                             interpret=interpret,
-                            kind=kind_k, ragged=ragged),
+                            kind=kind_k, ragged=ragged, steps=steps),
                         device=dev)
                     # per-chip ledger entry per dispatch: the seconds here
                     # are issue wall only (the chips compute concurrently;
@@ -1224,7 +1229,7 @@ class MeshExecutor:
                     # the COUNTS reconcile 1:1 with
                     # mesh_fused_perdevice_dispatches
                     telem.record_dispatch(
-                        "mesh_fused", device=dev, shape=sig,
+                        "mesh_fused", device=dev, shape=sig_t,
                         seconds=_time.perf_counter() - _d0,
                         bytes_in=int(getattr(vblocks[dev], "nbytes", 0)))
                     if ragged:

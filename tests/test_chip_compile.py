@@ -57,26 +57,32 @@ def chip_runtime():
     cc.reset_cache()
 
 
-def _plan():
-    ts_row = np.arange(T, dtype=np.int64) * STEP_MS
-    wends = ts_row[-1] - np.arange(W, dtype=np.int64)[::-1] * 60_000
-    return pf.build_plan(ts_row, wends, RANGE_MS)
+def _plan(range_ms=RANGE_MS, windows=W, samples=T):
+    ts_row = np.arange(samples, dtype=np.int64) * STEP_MS
+    # a window a minute (a slot where a minute apart would leave the row)
+    every = 60_000 if windows * 6 <= samples else STEP_MS
+    wends = ts_row[-1] - np.arange(windows, dtype=np.int64)[::-1] * every
+    return pf.build_plan(ts_row, wends, range_ms)
 
 
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
-def _compile_run(one_chip, S, G, fn, ragged=False, panels=1, phased=False):
+def _compile_run(one_chip, S, G, fn, ragged=False, panels=1, phased=False,
+                 range_ms=RANGE_MS, steps=None, windows=W, samples=T):
     """Lower + compile pallas_fused._run exactly as a FusedDispatch calls
     it (interpret=False).  `S` and `G` may be tuples: one working set
     each, all in the one program.  `phased`: working sets on a phase
-    grid, each with its [Sp, 1] phase column, over the plan's 16 rows."""
-    plan = _plan()
+    grid, each with its [Sp, 1] phase column, over the plan's 16 rows.
+    `range_ms`: the windows' width, from which the ragged rate family's
+    fills take their reach, which must come out as `steps`."""
+    plan = _plan(range_ms, windows, samples)
     # precorrected (no drop correction in the kernel), as the mirror serves
-    flags = pf._flavor(fn, True, False, ragged, phased)
+    flags = pf._flavor(plan, fn, True, False, ragged, phased)
     Ss = S if isinstance(S, tuple) else (S,)
     Gs = G if isinstance(G, tuple) else (G,) * len(Ss)
+    assert steps is None or flags.steps == steps
     with_ts = ragged and flags.kind == "rate_family"
     sets = tuple(
         (_sds((pf.pad_series_count(s), plan.Tp), jnp.float32, one_chip),
@@ -172,6 +178,125 @@ def test_phased_kernel_compiles_for_v5e(one_chip, chip_runtime,
     n_sets = len(S) if isinstance(S, tuple) else 1
     assert compiled.as_text().count(
         "custom_call_target=\"tpu_custom_call\"") == n_sets
+
+
+CHURN_ROWS = (72_744,) * 4      # 4 sets of 73,728 x 768 on the row ladder
+
+
+@pytest.mark.parametrize("steps,range_ms", [(5, RANGE_MS), (10, 7_000_000)],
+                         ids=["5steps", "10steps"])
+@pytest.mark.parametrize("S,G,fn,phased", [
+    (S_SHARD, 1000, "rate", False),
+    (S_SHARD, 1000, "delta", False),
+    (CHURN_ROWS, (10, 10, 1, 20), "rate", True),
+], ids=["rate-ragged", "delta-ragged", "rate-ragged-phased-4sets"])
+def test_ragged_rate_fills_compile_at_a_windows_reach_and_the_rows(
+        one_chip, chip_runtime, S, G, fn, phased, steps, range_ms):
+    """The ragged rate family's fills at the reach of `[5m]` windows
+    (5 doubling steps, what promchurn-counters-262k.open runs) and at the
+    reach of the row (10: a range past 512 slots), whose f32 NaN-carrying
+    shifts and selects and the one band product Mosaic must lower
+    (interpret mode has hidden a lowering failure of this branch before)."""
+    compiled = _compile_run(one_chip, S, G, fn, True, phased=phased,
+                            range_ms=range_ms, steps=steps)
+    _check(compiled, pallas=True)
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") \
+        == (len(S) if isinstance(S, tuple) else 1)
+
+
+@pytest.mark.parametrize("phased,Gp", [(True, 24), (False, 24),
+                                       (True, 1000), (False, 1000)])
+def test_the_vmem_estimate_covers_mosaics_scoped_allocation(
+        one_chip, chip_runtime, monkeypatch, phased, Gp):
+    """`vmem_estimate`'s bound for the ragged rate family against what
+    Mosaic really takes at the block `pick_block` gives: a limit far too
+    small makes the compiler name the kernel's scoped allocation (its
+    temporaries; the pipeline's double-buffered blocks it allocates
+    apart), and the estimate must hold that plus the two buffers each of
+    the values and of the band, and stay inside the budget.  Printed
+    (-s): 5.2 MiB phased, 3.9 on one shared row at 128 rows, where three
+    carriers over ten steps took 6.8 and 5.9."""
+    import re
+
+    from jax.experimental.pallas import tpu as pltpu
+    plan = _plan()
+    Wp = plan.t1.shape[1]
+    bs = pf.pick_block(plan.Tp, Wp, pf.pad_group_count(Gp), "rate_family",
+                       True, phased=phased)
+    # 128 rows at the cell's few groups; 1,000 groups on a phase grid
+    # leave room for 64 beside the band's two buffers
+    assert bs == (64 if phased and Gp == 1000 else 128)
+    jax.clear_caches()      # a trace kept from another test holds no limit
+    real = pf.pl.pallas_call
+    monkeypatch.setattr(
+        pf.pl, "pallas_call", lambda *a, **k: real(
+            *a, compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=2 << 20), **k))
+    with pytest.raises(Exception) as err:       # noqa: PT011 — XLA's own
+        _compile_run(one_chip, 72_744, Gp, "rate", True, phased=phased)
+    jax.clear_caches()      # ... and this one's must not outlive it
+    said = re.search(r"Scoped allocation with size ([0-9.]+)([MK])",
+                     str(err.value))
+    if said is None:
+        pytest.skip("the compiler did not name its scoped allocation")
+    scoped = float(said.group(1)) * (1 << 20 if said.group(2) == "M"
+                                     else 1 << 10)
+    values = 2 * bs * plan.Tp * 4
+    band = 2 * plan.Tp * Wp * 4
+    estimate = pf.vmem_estimate(plan.Tp, Wp, pf.pad_group_count(Gp),
+                                "rate_family", True, bs=bs, phased=phased)
+    print(f"bs={bs} scoped={scoped / 2 ** 20:.2f}M values={values / 2 ** 20:.2f}M "
+          f"band={band / 2 ** 20:.2f}M estimate={estimate / 2 ** 20:.2f}M")
+    assert scoped + values + band <= estimate <= pf.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("fn,ragged", [
+    ("rate", True), ("sum_over_time", True), ("avg_over_time", False),
+    ("count_over_time", True)])
+def test_phased_band_corrections_compile_past_128_windows(
+        one_chip, chip_runtime, fn, ragged):
+    """200 windows (Wp 256): the two gathered corrections of a band
+    product take a row's own [BS, Wp] slots.  By the shared [1, Wp] row
+    Mosaic refused the phased over_time kinds there ("Invalid input
+    layout": a [1, 128] i32 slice at a lane offset broadcast down the
+    sublanes), as it still refuses the UNPHASED gather kinds'
+    `_gather_cols` (PERF.md section 7)."""
+    compiled = _compile_run(one_chip, 8_192, 16, fn, ragged, phased=True,
+                            windows=200)
+    _check(compiled, pallas=True)
+
+
+@pytest.mark.parametrize("samples,windows,range_ms,Gp,fits", [
+    (1_500, 500, RANGE_MS, 16, True),       # Tp 1536, Wp 512: 32 rows
+    (1_500, 500, 20_000_000, 16, True),     # ... fills across the row, 11
+    (720, 1_000, RANGE_MS, 16, True),       # Tp 768, Wp 1024
+    (1_200, 600, RANGE_MS, 16, True),       # Tp 1280, Wp 640
+    (1_500, 500, RANGE_MS, 1_000, False),   # ... and 1,000 groups: no
+    (1_500, 1_000, RANGE_MS, 16, False),    # Tp 1536, Wp 1024: 12 MiB of band
+    (2_000, 1_000, RANGE_MS, 16, False),    # Tp 2048, Wp 1024: 16 MiB
+], ids=["1536x512", "1536x512-11steps", "768x1024", "1280x640",
+        "1536x512-G1000", "1536x1024", "2048x1024"])
+def test_a_long_range_of_many_windows_compiles_or_diverts(
+        one_chip, chip_runtime, samples, windows, range_ms, Gp, fits):
+    """The ragged rate family's [Tp, Wp] band lies in VMEM twice: hours
+    of samples under a Grafana panel's 500 to 1,000 windows (phased rows,
+    the served flavor; the unphased gather kinds lower no more than 128
+    windows) either get a block from `pick_block` and then compile under
+    the chip's scoped limit, or get None, which is where leafexec and the
+    mesh executor divert to the general path and `_run` refuses by name."""
+    plan = _plan(range_ms, windows, samples)
+    bs = pf.pick_block(plan.Tp, plan.t1.shape[1], pf.pad_group_count(Gp),
+                       "rate_family", True, phased=True)
+    assert (bs is not None) == fits
+    run = lambda: _compile_run(                              # noqa: E731
+        one_chip, 8_192, Gp, "rate", True, phased=True, range_ms=range_ms,
+        windows=windows, samples=samples)
+    if fits:
+        _check(run(), pallas=True)
+    else:
+        with pytest.raises(ValueError, match="exceeds VMEM budget"):
+            run()
 
 
 def test_histogram_gather_and_flatten_compile_for_v5e(one_chip,

@@ -572,6 +572,14 @@ def test_pick_block_adaptive():
                             bs=256) > pf.VMEM_BUDGET
     # tiny shapes keep the full block (interpret-mode tests stay fast)
     assert pf.pick_block(256, 128, 8, "rate_family", True) == pf._BS
+    # ISSUE 43 re-read Mosaic's report (tests/test_chip_compile.py): two
+    # carriers over a window's steps take 8.77 MiB at 256 rows, so they
+    # would fit; the block stays (1.7% on the chip, and other rows a
+    # block regroup the f32 group sums), as promchurn-counters-262k.open's
+    # ragged phased sets show
+    for Gp in (16, 24):
+        assert pf.pick_block(768, 128, Gp, "rate_family", True,
+                             phased=True) == 128
 
 
 def test_fused_ragged_rate_long_rows():
@@ -1037,7 +1045,7 @@ def test_the_one_presentation_is_the_per_panel_formula(fn, ragged):
     plan, sets, kw = _sets_case(fn, ragged, [(96, ["sum", "avg"], 0),
                                              (300, ["sum"], 4)])
     got, _ = _dispatch_sets(plan, sets, kw)
-    flags = pf._flavor(kw["fn_name"], kw["precorrected"], True, ragged)
+    flags = pf._flavor(plan, kw["fn_name"], kw["precorrected"], True, ragged)
     wvalid = plan.wvalid1 if flags.kind in pf.OVER_TIME_FNS else plan.wvalid
     for (values, panels), g_set in zip(sets, got):
         for (groups, G, _), g in zip(panels, g_set):
@@ -1055,3 +1063,310 @@ def test_the_one_presentation_is_the_per_panel_formula(fn, ragged):
             want = np.stack([sums * (counts > 0), counts], axis=-1)
             assert g.dtype == want.dtype and g.shape == want.shape
             assert g.tobytes() == want.tobytes()
+
+
+# ------- ISSUE 43: the ragged rate family's fills reach a window's width
+
+REACH_SPAN, REACH_T = 30, 120          # [5m] of a 10 s grid; Tp 128
+
+
+def _reach_rows(kind, span=REACH_SPAN, T=REACH_T, S=8, seed=3):
+    """[S, T] monotone counters (precorrected: no reset), each row holed
+    as `kind` says, at a place of its own."""
+    rng = np.random.default_rng(seed)
+    raw = 1e5 + np.cumsum(rng.integers(1, 50, (S, T)).astype(float), axis=1)
+    for s in range(S):
+        a = int(rng.integers(span, T - span))
+        if kind.startswith("hole-"):
+            raw[s, a:a + int(kind[5:])] = np.nan
+        elif kind == "born-late":       # after the first window has closed
+            raw[s, :a + 5] = np.nan
+        elif kind == "ended-early":     # before the last window opens
+            raw[s, a - 5:] = np.nan
+        elif kind == "one-sample":
+            raw[s, :a], raw[s, a + 1:] = np.nan, np.nan
+        elif kind == "none":
+            raw[s] = np.nan
+        elif kind == "random":
+            raw[s, rng.random(T) < 0.1 * (s + 1)] = np.nan
+    return raw
+
+
+def _reach_plan(range_ms=REACH_SPAN * START_STEP, T=REACH_T, stride=3):
+    ts_row = np.arange(T, dtype=np.int64) * START_STEP
+    # ends off the slots, from before the row's first window to past its last
+    wends = np.arange(2 * START_STEP + 4_000, (T + 6) * START_STEP,
+                      stride * START_STEP)
+    return ts_row, wends, build_plan(ts_row, wends, range_ms)
+
+
+def _reach_phase(S, phased, seed=5):
+    if not phased:
+        return None
+    phase = np.random.default_rng(seed).integers(1, START_STEP, S)
+    phase[0], phase[1] = 0, START_STEP - 1
+    return phase
+
+
+def _ragged_launch(plan, raw, phase, fn="rate", steps=None, gids=None):
+    """One ragged rate-family launch (a group a row unless `gids` says
+    otherwise) at `steps` doubling steps, None being the plan's own:
+    -> (sums, presence) [G, W] f32, as the device returned them."""
+    from filodb_tpu.ops import pallas_fused as pf
+    S = raw.shape[0]
+    gids = np.arange(S) if gids is None else gids
+    G = int(gids.max()) + 1
+    vbase = np.where(np.isnan(raw), np.inf, raw).min(axis=1)
+    vbase = np.where(np.isfinite(vbase), vbase, 0.0)
+    prepared = pad_inputs((raw - vbase[:, None]).astype(np.float32),
+                          vbase.astype(np.float32), gids.astype(np.int32),
+                          plan, G, phase=phase)
+    flags = pf._flavor(plan, fn, True, True, True, phase is not None)
+    if steps is not None:
+        flags = flags._replace(steps=steps)
+    res, _ = pf._enqueue_run(
+        plan, None, (pf._kernel_set(prepared, (prepared.gids_p,)),), None,
+        (pf.pad_group_count(G),), **flags._asdict())
+    return tuple(np.asarray(r)[:G, :plan.W] for r in res)
+
+
+def _full_reach(plan):
+    return (plan.Tp - 1).bit_length()
+
+
+_PHASED = pytest.mark.parametrize("phased", [False, True],
+                                  ids=["shared-row", "phase-grid"])
+
+
+@_PHASED
+@pytest.mark.parametrize("kind", [
+    "hole-1", "hole-6", f"hole-{REACH_SPAN - 1}", "born-late", "ended-early",
+    "one-sample", "none", "random"])
+def test_fills_as_far_as_a_window_equal_fills_across_the_row(kind, phased):
+    """(a) A launch whose fills take the plan's steps (5: a `[5m]` window
+    is 30 slots, 31 on a phase grid) returns what the same launch returns
+    at the reach of the whole row (7 at 128 columns), bit for bit."""
+    from filodb_tpu.ops import pallas_fused as pf
+    _, _, plan = _reach_plan()
+    raw = _reach_rows(kind)
+    phase = _reach_phase(len(raw), phased)
+    assert pf.scan_steps(plan, "rate_family", True, phased) == 5
+    assert _full_reach(plan) == 7
+    got = _ragged_launch(plan, raw, phase)
+    want = _ragged_launch(plan, raw, phase, steps=_full_reach(plan))
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+        assert not np.isnan(g).any()
+    if kind in ("one-sample", "none"):
+        assert not got[1].any()         # a rate takes two samples
+    else:
+        assert got[1].any()
+
+
+@_PHASED
+@pytest.mark.parametrize("fn", ["rate", "delta"])
+def test_a_hole_longer_than_the_window_leaves_it_absent(fn, phased):
+    """(b) A hole of 36 slots under windows of 30: the windows inside it
+    are absent, every other is the oracle's and the full reach's."""
+    import oracle
+    ts_row, wends, plan = _reach_plan(stride=1)
+    raw = _reach_rows(f"hole-{REACH_SPAN + 6}")
+    phase = _reach_phase(len(raw), phased)
+    got = _ragged_launch(plan, raw, phase, fn)
+    want = _ragged_launch(plan, raw, phase, fn, steps=_full_reach(plan))
+    ph = np.zeros(len(raw), int) if phase is None else phase
+    ref = np.stack([oracle.eval_series(ts_row + ph[s], raw[s], wends,
+                                       REACH_SPAN * START_STEP, fn)
+                    for s in range(len(raw))])
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    sums, pres = got
+    np.testing.assert_array_equal(pres, ~np.isnan(ref))
+    for s in range(len(raw)):
+        hole = np.flatnonzero(np.isnan(raw[s]))
+        first = np.searchsorted(ts_row + ph[s], wends - REACH_SPAN
+                                * START_STEP + 1, side="left")
+        last = np.searchsorted(ts_row + ph[s], wends, side="right") - 1
+        inside = (first >= hole[0]) & (last <= hole[-1]) & (last >= first)
+        assert inside.sum() >= 5 and not pres[s, inside].any()
+        # ... and its neighbours on either side are there
+        w0, w1 = np.flatnonzero(inside)[[0, -1]]
+        assert pres[s, w0 - 3] and pres[s, w1 + 3]
+    np.testing.assert_allclose(np.where(pres > 0, sums, np.nan), ref,
+                               rtol=2e-5, atol=1e-6, equal_nan=True)
+
+
+@_PHASED
+@pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
+def test_an_all_nan_row_adds_nothing_and_no_output_is_nan(fn, phased):
+    """(c) Rows without a sample (a mesh pack's pad rows, a target that
+    never answered) ride in a group beside live rows and add 0 to its sum
+    and 0 to its presence: the groups read what they read with those rows
+    in a group of their own, and no cell of either output is NaN."""
+    _, _, plan = _reach_plan()
+    raw = _reach_rows("random", S=12)
+    raw[[2, 5, 6, 11]] = np.nan
+    phase = _reach_phase(len(raw), phased)
+    among = np.arange(len(raw)) % 2
+    apart = np.where(np.isnan(raw).all(axis=1), 2, among)
+    got = _ragged_launch(plan, raw, phase, fn, gids=among)
+    want = _ragged_launch(plan, raw, phase, fn, gids=apart)
+    for g, w in zip(got, want):
+        assert not np.isnan(g).any() and not np.isnan(w).any()
+        assert np.array_equal(g, w[:2])
+        assert not w[2].any()
+    assert 0 < got[1].max() <= (among == 0).sum() - 2
+
+
+@pytest.mark.parametrize("T,range_ms,phased,want", [
+    (720, 300_000, False, 5),       # [5m] of a 10 s grid: 30 slots
+    (720, 300_000, True, 5),        # ... 31 with the early slot
+    (720, 320_000, False, 5),       # 32 slots: 2**5 - 1 still crosses
+    (720, 320_000, True, 6),        # 33 do not
+    (720, 3_600_000, False, 9),     # [1h]: 360
+    (720, 3_600_000, True, 9),
+    (720, 7_000_000, False, 10),    # 700 slots, past 512
+    (720, 9_000_000, True, 10),     # a range past the row: the row's reach
+    (120, 9_000_000, False, 7),
+    (120, 10_000, True, 1),         # one slot a window, two with the early
+    (120, 10_000, False, 0),
+], ids=lambda v: str(v))
+def test_scan_steps_follow_the_plans_widest_window(T, range_ms, phased, want):
+    """(d) The least j with 2**j - 1 >= span - 1, capped at what crosses
+    the row; the launch's signature names it."""
+    from filodb_tpu.ops import pallas_fused as pf
+    ts_row = np.arange(T, dtype=np.int64) * START_STEP
+    wends = np.arange(4_000, (T + 6) * START_STEP, 6 * START_STEP)
+    plan = build_plan(ts_row, wends, range_ms)
+    assert plan.span == pf.window_counts(ts_row, wends, range_ms).max()
+    flags = pf._flavor(plan, "rate", True, True, True, phased)
+    assert flags.steps == want == pf.scan_steps(plan, "rate_family", True,
+                                                phased)
+    sets = ((np.zeros((256, plan.Tp)),),)
+    sig = pf._run_shape_sig(sets, plan, (8,), flags.kind, True, phased,
+                            flags.steps)
+    assert (f":{want}steps" in sig) == (want > 0)
+
+
+@pytest.mark.parametrize("fn,ragged,phased", [
+    ("rate", False, False), ("rate", False, True), ("delta", False, False),
+    ("sum_over_time", False, False), ("sum_over_time", True, True),
+    ("avg_over_time", True, False), ("count_over_time", True, True),
+    ("last_over_time", True, False), ("last_over_time", False, True)])
+def test_no_other_flavor_gets_a_compile_key_of_the_windows(fn, ragged,
+                                                           phased):
+    """(d) Only the ragged rate family reads `steps`: every other flavor
+    passes 0 whatever the plan's windows, so a new range compiles nothing
+    there, and its signature does not name the steps."""
+    from filodb_tpu.ops import pallas_fused as pf
+    sigs = set()
+    for range_ms in (300_000, 3_600_000):
+        _, _, plan = _reach_plan(range_ms)
+        flags = pf._flavor(plan, fn, True, True, ragged, phased)
+        assert flags.steps == 0
+        sigs.add(pf._run_shape_sig(((np.zeros((256, plan.Tp)),),), plan,
+                                   (8,), flags.kind, ragged, phased,
+                                   flags.steps))
+    assert len(sigs) == 1 and "steps" not in sigs.pop()
+
+
+@_PHASED
+def test_a_launch_books_its_scan_steps(phased):
+    """`fused_ragged_scan_steps_total` moves by the launch's steps, once a
+    launch, beside `fused_enqueues_total`; a dense launch moves it by 0."""
+    from filodb_tpu.utils.metrics import registry
+
+    def booked():
+        return registry.counter("fused_ragged_scan_steps").value
+
+    for range_ms, want in ((300_000, 5), (900_000, 7)):
+        _, _, plan = _reach_plan(range_ms)
+        raw = _reach_rows("hole-6")
+        s0, e0 = booked(), _enqueue_counts()[0]
+        _ragged_launch(plan, raw, _reach_phase(len(raw), phased))
+        assert booked() - s0 == want
+        assert _enqueue_counts()[0] - e0 == 1
+    ts_row, raw, gids = _mk(S=16, T=REACH_T, resets=False)
+    s0 = booked()
+    reb, vbase = rebase_values(raw, True)
+    fused_rate_groupsum(reb.astype(np.float32), vbase.astype(np.float32),
+                        gids, plan, 5, interpret=True, precorrected=True)
+    assert booked() == s0
+
+
+@pytest.mark.parametrize("kind", ["hole-1", "hole-6", "random", "none"])
+def test_a_caller_without_the_plans_steps_fills_across_the_row(kind):
+    """`run_kernel` without `steps` (a caller that composes the kernel
+    and has no plan at hand: __graft_entry__'s dry run before it passed
+    them) fills across the whole row and returns what the plan's steps
+    return, bit for bit; never a fill of no steps, which reads a hole at
+    a window's edge as a sample."""
+    from filodb_tpu.ops import pallas_fused as pf
+    _, _, plan = _reach_plan()
+    raw = _reach_rows(kind)
+    S = len(raw)
+    vbase = np.where(np.isnan(raw), np.inf, raw).min(axis=1)
+    vbase = np.where(np.isfinite(vbase), vbase, 0.0)
+    prepared = pad_inputs((raw - vbase[:, None]).astype(np.float32),
+                          vbase.astype(np.float32),
+                          np.arange(S, dtype=np.int32), plan, S)
+    kw = dict(num_groups=pf.pad_group_count(S), is_counter=True,
+              is_rate=True, with_drops=False, interpret=True, ragged=True)
+    got = pf.run_kernel(prepared.vals_p, prepared.vbase_p, prepared.gids_p,
+                        plan.rows, plan.tsrow, **kw)
+    want = _ragged_launch(plan, raw, None)
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g)[:S, :plan.W], w)
+    # ... and so does the mesh executor's per-device call, with and without
+    from filodb_tpu.parallel.mesh import _device_fused_call
+    for steps in ({}, {"steps": pf.scan_steps(plan, "rate_family", True)}):
+        dev = _device_fused_call(
+            (raw - vbase[:, None]).astype(np.float32)[None],
+            np.arange(S, dtype=np.int32)[None, :, None],
+            vbase.astype(np.float32)[None], plan.rows, plan.tsrow, G=S, S=S,
+            T=raw.shape[1], Tp=plan.Tp, is_counter=True, is_rate=True,
+            interpret=True, ragged=True, **steps)
+        for g, w in zip(dev, want):
+            assert np.array_equal(np.asarray(g)[:, :plan.W], w)
+    if kind != "none":
+        # ... where no fill at all answers otherwise
+        none = pf.run_kernel(prepared.vals_p, prepared.vbase_p,
+                             prepared.gids_p, plan.rows, plan.tsrow,
+                             steps=0, **kw)
+        assert not np.array_equal(np.asarray(none[0])[:S, :plan.W], want[0])
+
+
+def test_the_jitted_entries_take_no_default_for_the_steps():
+    """`_run` (and `_run_set`, `_kernel` under it) name `steps` or fail:
+    a default would be a reach that some caller's windows outgrow."""
+    from filodb_tpu.ops import pallas_fused as pf
+    _, _, plan = _reach_plan()
+    flags = pf._flavor(plan, "rate", True, True, True)._asdict()
+    del flags["steps"]
+    vals = jnp.zeros((256, plan.Tp), jnp.float32)
+    st = (vals, vals[:, :1], (jnp.zeros((256, 1), jnp.int32),))
+    with pytest.raises(TypeError, match="steps"):
+        pf._run((st,), None, plan.rows, plan.tsrow, num_groups=(8,), **flags)
+
+
+@pytest.mark.parametrize("phased", [False, True])
+@pytest.mark.parametrize("Tp,Wp", [(768, 128), (768, 1024), (1536, 512),
+                                   (2048, 1024)])
+def test_the_vmem_estimate_holds_the_ragged_rate_familys_band(Tp, Wp, phased):
+    """The one [Tp, Wp] f32 band this flavor reads lies in VMEM twice
+    (the pipeline's two buffers): the estimate holds both beside what the
+    dense twin's holds, so a long range of many windows diverts
+    (`pick_block` None) and does not fail at lowering; the cell's shape
+    keeps its 128 rows."""
+    from filodb_tpu.ops import pallas_fused as pf
+    for bs in (32, 128):
+        ragged = pf.vmem_estimate(Tp, Wp, 128, "rate_family", True, bs=bs,
+                                  phased=phased)
+        dense = pf.vmem_estimate(Tp, Wp, 128, "rate_family", False, bs=bs,
+                                 phased=phased)
+        assert ragged - dense == 8 * Tp * Wp + 19 * bs * Tp * 4
+    bs = pf.pick_block(Tp, Wp, 128, "rate_family", True, phased=phased)
+    if (Tp, Wp) == (768, 128):
+        assert bs == 128
+    if 8 * Tp * Wp >= pf.VMEM_BUDGET:
+        assert bs is None

@@ -215,6 +215,73 @@ def test_a_row_takes_the_earlier_slot_exactly_past_the_windows_slack():
             row[last[has]])
 
 
+@pytest.mark.parametrize("fn", ["rate", "delta"])
+@pytest.mark.parametrize("holes", ["every-third", "pairs", "random", "head",
+                                   "tail", "all-but-two"])
+def test_the_valid_count_is_a_rows_own_on_either_side_of_the_slack(
+        holes, fn):
+    """ISSUE 43: the ragged rate family counts a row's valid samples a
+    window by one product with the base row's band and the two gathered
+    corrections, where it took a running count at the row's own slots.
+    The rows of the test above, holed: phases at, before and past each
+    window's slack, windows whose base band is empty included; presence
+    is `count >= 2` by a count on the row itself, the values the
+    oracle's (which divide by the count), and both are what fills across
+    the whole row give."""
+    ts_row = 5_000 + np.arange(30) * STEP
+    wends = np.arange(ts_row[0] - 20_000, ts_row[-1] + 70_000, 3_333)
+    plan = pf.build_plan(ts_row, wends, RANGE)
+    slack = np.concatenate([plan.prows[pf._PS1, :len(wends)],
+                            plan.prows[pf._PS2, :len(wends)]])
+    phase = np.unique([0, 1, 1_667, 3_333, 4_999, 5_000, 5_001, STEP - 1]
+                      + [int(x) for x in np.concatenate([slack, slack + 1])
+                         if 0 <= x < STEP])
+    S, T = len(phase), len(ts_row)
+    rng = np.random.default_rng(43)
+    vals = 1e4 + np.cumsum(rng.integers(1, 30, (S, T)).astype(float), axis=1)
+    m = {"every-third": np.arange(T)[None, :] % 3 == np.arange(S)[:, None] % 3,
+         "pairs": (np.arange(T)[None, :] + np.arange(S)[:, None]) % 5 < 2,
+         "random": rng.random((S, T)) < 0.4,
+         "head": np.arange(T)[None, :] < 3 + np.arange(S)[:, None] % 9,
+         "tail": np.arange(T)[None, :] > 18 + np.arange(S)[:, None] % 9,
+         "all-but-two": ~np.isin(
+             np.arange(T)[None, :] - np.arange(S)[:, None] % 20, (4, 6)),
+         }[holes]
+    vals[np.broadcast_to(m, vals.shape)] = np.nan
+    vbase = np.where(np.isnan(vals), np.inf, vals).min(axis=1)
+    args = ((vals - vbase[:, None]).astype(np.float32),
+            vbase.astype(np.float32), np.arange(S), plan, S, fn)
+    sums, counts = pf.fused_rate_groupsum(
+        *args, precorrected=True, interpret=True, ragged=True, phase=phase)
+    held = np.zeros((S, len(wends)), int)
+    want = np.full((S, len(wends)), np.nan)
+    for s in range(S):
+        row = ts_row + phase[s]
+        first = np.searchsorted(row, wends - RANGE + 1, side="left")
+        last = np.searchsorted(row, wends, side="right") - 1
+        ok = ~np.isnan(vals[s])
+        held[s] = [ok[a:b + 1].sum() for a, b in zip(first, last)]
+        want[s] = oracle.eval_series(row, vals[s], wends, RANGE, fn)
+    base_empty = plan.rows[pf._N1, :len(wends)] == 0
+    assert base_empty.any() and (held[:, ~base_empty] >= 2).any()
+    assert (held[:, base_empty] <= 1).all()
+    np.testing.assert_array_equal(counts, held >= 2)
+    np.testing.assert_allclose(pf.present_sum(sums, counts), want,
+                               rtol=2e-5, atol=1e-6, equal_nan=True)
+    # ... and at the reach of the whole row
+    prepared = pf.pad_inputs(*args[:5], phase=phase)
+    flags = pf._flavor(plan, fn, True, True, True, True)
+    assert flags.steps == 3 < (plan.Tp - 1).bit_length()
+    full, _ = pf._enqueue_run(
+        plan, None, (pf._kernel_set(prepared, (prepared.gids_p,)),), None,
+        (pf.pad_group_count(S),),
+        **flags._replace(steps=(plan.Tp - 1).bit_length())._asdict())
+    np.testing.assert_array_equal(np.asarray(full[0])[:S, :len(wends)],
+                                  np.asarray(sums))
+    np.testing.assert_array_equal(np.asarray(full[1])[:S, :len(wends)],
+                                  counts)
+
+
 # --------------------------------------------------------------- (d) mirror
 
 def _store(offsets, n=8, late=None, counts=None):
