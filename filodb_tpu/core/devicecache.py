@@ -169,7 +169,11 @@ def _detect_phase_grid(ts_off: np.ndarray, counts: np.ndarray,
     first, and their leaves share a plan (offsets may start below 0).
     Else (None, None, n): n rows hold another count of samples than row
     0, or do not lie on the earliest row shifted, or lie a whole gap or
-    more behind it."""
+    more behind it; or every row, where some row has a phase and the base
+    row's last slot plus a phase is no whole millisecond in f32
+    (_slot_times_exact: 1,677 slots at 10 s; booked by name on
+    `device_mirror_inexact_grids_total`).  One shared row has no phase to
+    add: its times are the plan's to check (FusedPlan.exact)."""
     s = ts_off.shape[0]
     if s == 0:
         return None, None, 0
@@ -196,11 +200,27 @@ def _detect_phase_grid(ts_off: np.ndarray, counts: np.ndarray,
     off = int(s - on.sum())
     if off:
         return None, None, off
+    if phase.any() and not _slot_times_exact(
+            int(base[c - 1]) - int(base[0]) + 2 * gap if c > 1 else 0):
+        return None, None, s
     back = (base_ms + int(base[0])) % gap if c > 1 else 0
     if back and int(phase.max()) + back < min(gap, _F32_EXACT_MS):
         base[:c] -= back
         phase += back
     return base, phase.astype(np.int32), 0
+
+
+def _slot_times_exact(reach_ms: int) -> bool:
+    """Whether a grid whose last slot lies `reach_ms` past its first,
+    counting an interval for a row's phase and one for the base row's move
+    back, keeps every time a whole millisecond in f32.  A grid that does
+    not is booked by name: the kernel would answer from times a
+    millisecond off."""
+    ok = reach_ms < _F32_EXACT_MS
+    if not ok:
+        from filodb_tpu.utils.metrics import registry
+        registry.counter("device_mirror_inexact_grids").increment()
+    return ok
 
 
 def _slots_fit(n_slots: int, t_used: int, interval: int) -> bool:
@@ -209,7 +229,7 @@ def _slots_fit(n_slots: int, t_used: int, interval: int) -> bool:
     (a store whose rows are mostly holes keeps the store's layout), and
     every slot's time exact in f32 beside a phase."""
     return n_slots <= 2 * max(t_used, 1) + 64 \
-        and (n_slots + 1) * interval < _F32_EXACT_MS
+        and _slot_times_exact((n_slots + 1) * interval)
 
 
 def _place_on_grid(ts_off: np.ndarray, counts: np.ndarray, base_ms: int = 0):
@@ -1109,9 +1129,12 @@ class DeviceMirror:
             start0, k = int(counts_old[0]), off2.shape[1]
             grown = np.concatenate([snap.ts_row0[max(start0 - 1, 0):start0],
                                     off2[0]])
+            gap = int(np.diff(grown).min()) if grown.size > 1 else 0
             if bool((off2 == off2[0:1]).all()) and (
                     not snap.phase_rows or grown.size < 2
-                    or int(np.diff(grown).min()) > int(snap.phase.max())):
+                    or (gap > int(snap.phase.max())
+                        and _slot_times_exact(int(grown[-1]) + 2 * gap
+                                              - int(snap.ts_row0[0])))):
                 ts_row0 = np.full(t_new, PAD_TS, np.int32)
                 ts_row0[:snap.t_used] = snap.ts_row0
                 ts_row0[start0:start0 + k] = off2[0].astype(np.int32)

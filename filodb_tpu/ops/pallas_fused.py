@@ -210,6 +210,17 @@ class FusedPlan(NamedTuple):
     # what the plan weighs in a cache: its host arrays, and those three
     # again for every local device, the most `resident` can come to hold
     nbytes: int
+    # the row tiles one gather of one working set visits, summed over the
+    # window tiles (_tile_ranges): (on one shared row, on a phase grid).
+    # Wp / 128 x Tp / 128 when every tile is visited, as an unrolled
+    # gather does (gather_loops)
+    tile_visits: Tuple[int, int]
+    # whether every time the kernel reads (the boundary timestamps, the
+    # window edges, the shared row, the phased rows' slacks) is the same
+    # number in f32: whole milliseconds are exact below 2^24 (4.66 h from
+    # the row's first sample), multiples of 8 ms below 2^27.  A leaf
+    # declines a plan that is not (leafexec: `leaf_inexact_times_total`)
+    exact: bool
 
 
 def build_plan(ts_row: np.ndarray, wends: np.ndarray,
@@ -256,11 +267,36 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
     prows[_PS2, :W] = np.where(last >= 0, wend - ts_row[la], _NO_SLOT)
     wvalid, wvalid1 = n >= 2, n >= 1
     operands = rows.nbytes + prows.nbytes + tsr.nbytes
+    exact = _f32_holds(ts_row, wstart - 1, wend)
+    visits = tuple(
+        int(np.maximum(t[1] - t[0] + 1, 0).sum()) for t in (
+            _tile_ranges(np, rows, Tp, False),
+            _tile_ranges(np, prows, Tp, True))) if gather_loops(Tp, Wp) \
+        else ((Tp // _LANE) * (Wp // _LANE),) * 2
     return FusedPlan(rows, *(rows[i:i + 1] for i in range(8)), tsrow=tsr,
                      wvalid=wvalid, wvalid1=wvalid1, W=W, Tp=Tp,
                      prows=prows, span=int(n.max()) if W else 0, resident={},
                      nbytes=operands * (1 + jax.local_device_count())
-                     + wvalid.nbytes + wvalid1.nbytes)
+                     + wvalid.nbytes + wvalid1.nbytes,
+                     tile_visits=visits, exact=exact)
+
+
+def _f32_holds(*times) -> bool:
+    """Whether f32 holds every one of these whole numbers of milliseconds,
+    and with them every difference of two (a plan's slacks), exactly: all
+    are multiples of 2^k (k the fewest trailing zero bits among them) and
+    under 2^(24 + k) in size.  Sufficient, and two reductions an array:
+    a plan is built beside five other threads, and every NumPy call over
+    an array of 500 elements or more lets the interpreter lock go."""
+    low, high = 0, 0
+    for t in times:
+        if t.size:
+            a = np.abs(t)
+            low |= int(np.bitwise_or.reduce(a))
+            high = max(high, int(a.max()))
+    if not low:
+        return True
+    return high < (1 << 24) * (low & -low)
 
 
 def _row_steps(Tp: int) -> int:
@@ -283,6 +319,31 @@ def scan_steps(plan: FusedPlan, kind: str, ragged: bool,
         return 0
     span = plan.span + (1 if phased else 0)
     return min(max(span - 1, 0).bit_length(), _row_steps(plan.Tp))
+
+
+def _tile_ranges(xp, rows, Tp: int, phased: bool):
+    """-> [2, Wp / 128] i32: the first and last 128-slot tile of the row
+    that each tile of 128 windows can read, from a plan's `rows` (or its
+    `prows` where `phased`): what `_gather_cols` visits.  `xp` is numpy on
+    the host (build_plan: what a launch will visit, for the counter) and
+    jax.numpy inside `_run`'s trace (kernel_operands: the kernel's scalar
+    operand, made from the rows an enqueue has uploaded anyway, so it is
+    no upload and no compile key).  A tile's windows read from the least
+    first slot to the greatest last one of its NON-EMPTY windows (an empty
+    or padded window's slot is the 0 sentinel and its cell is masked: it
+    reads 0 unvisited); on a phase grid from one slot before the first
+    (a row may take the slot before the shared one) over every real
+    window (a row's own window may hold a sample where the base row's
+    holds none).  A tile of no such window visits nothing: (Tp / 128, -1)."""
+    if phased:
+        live, lo, hi = rows[_N] >= 2.0, rows[_PI1] - 1.0, rows[_PI2]
+    else:
+        live, lo, hi = rows[_N1] >= 1.0, rows[_I1], rows[_I2]
+    lo = xp.where(live, lo, float(Tp)).reshape(-1, _LANE).min(axis=1)
+    hi = xp.where(live, hi, -1.0).reshape(-1, _LANE).max(axis=1)
+    return xp.stack([xp.floor(xp.clip(lo, 0, Tp) / _LANE),
+                     xp.floor(xp.clip(hi, -1, Tp - 1) / _LANE)]
+                    ).astype(xp.int32)
 
 
 def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
@@ -335,12 +396,13 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
                    mat(_I2, True))
     if tsrow is None:
         tsrow = jnp.zeros((1, Tp), jnp.float32)
+    tiles = _tile_ranges(jnp, rows, Tp, phased)
     if phased:
         return sel + (row(_PT1), row(_PT2), row(_N1), row(_WS), row(_WE),
-                      tsrow, row(_PI1), row(_PI2), rows)
+                      tsrow, row(_PI1), row(_PI2), tiles, rows)
     return sel + (row(_T1), row(_T2),
                   row(_N1 if kind in OVER_TIME_FNS else _N),
-                  row(_WS), row(_WE), tsrow, row(_I1), row(_I2))
+                  row(_WS), row(_WE), tsrow, row(_I1), row(_I2), tiles)
 
 
 def merge_gid_cols(gids, offsets):
@@ -372,6 +434,19 @@ def _committed_device(arr):
     return None
 
 
+def gathers(kind: str, ragged: bool, phased: bool) -> int:
+    """The `_gather_cols` calls one working set's kernel makes: the rate
+    family's two boundaries (values and, on ragged rows, timestamps),
+    last_over_time's one (and its validity on ragged rows), and on a phase
+    grid the two corrections of every band product."""
+    band = 2 if phased else 0           # one `corrected` product
+    if kind == "rate_family":
+        return 4 + band if ragged else 2
+    if kind == "last_over_time":
+        return 2 if ragged else 1
+    return 2 * band if ragged else band
+
+
 def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
                      offsets=None, sets: int = 1,
                      phased: bool = False) -> tuple:
@@ -391,10 +466,19 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
     A ragged rate-family call also books its scan_steps on
     `fused_ragged_scan_steps_total` (5 a launch where the windows are
     `[5m]` of a 10 s grid, ceil(log2(Tp)) where they span the row: whether
-    a deployment's windows let the fills stop short)."""
+    a deployment's windows let the fills stop short).  Every call books
+    its real window count on `fused_windows_total` (over the enqueues: the
+    windows a launch, 61 for an hour at 60 s, 721 for six hours at 30 s)
+    and the row tiles its gathers visit on `fused_gather_tile_visits_total`
+    (working sets x gathers x the plan's tile ranges: Wp / 128 x Tp / 128 a
+    gather when the narrowed gather does not engage)."""
     from filodb_tpu.utils.metrics import registry
     registry.counter("fused_enqueues").increment()
     registry.counter("fused_enqueue_sets").increment(sets)
+    registry.counter("fused_windows").increment(plan.W)
+    visits = sets * gathers(kind, ragged, phased) * plan.tile_visits[phased]
+    if visits:
+        registry.counter("fused_gather_tile_visits").increment(visits)
     steps = scan_steps(plan, kind, ragged, phased)
     if steps:
         registry.counter("fused_ragged_scan_steps").increment(steps)
@@ -482,39 +566,121 @@ def _cumsum_lanes(x):
     return x
 
 
-def _gather_cols(x, idx):
-    """out[s, w] = x[s, idx[0, w]], or x[s, idx[s, w]] where `idx` has a
-    row a series (the phased variant: a row's slot is the shared one or
+_UNROLLED_VISITS = 8
+
+
+def gather_loops(Tp: int, Wp: int) -> bool:
+    """Whether `_gather_cols` walks each window tile's OWN row tiles in a
+    loop (`_tile_ranges`) or visits every (window tile, row tile) pair
+    unrolled: the pairs decide.  Up to 8 pairs (an hour of a 10 s scrape
+    under one tile of windows: 1 x 6) the unrolled body is short and
+    measured faster on the chip, 1.60 against 1.92 ms a launch of four
+    sets at 262,144 x 768 (the loop reads a tile at an address only the
+    launch knows, and one visit cannot overlap the next); past that the
+    loop wins by what it skips, 4.35 against 18.04 ms at 81,920 x 2,304
+    under 768 windows, 22 of 108 pairs visited (PERF.md section 6,
+    PR 44)."""
+    return (Tp // _LANE) * (Wp // _LANE) > _UNROLLED_VISITS
+
+
+def _gather_cols(src, idx, tiles):
+    """out[s, w] = src[s, idx[0, w]], or src[s, idx[s, w]] where `idx` has
+    a row a series (the phased variant: a row's slot is the shared one or
     the one before it) — the one-hot selection matmul as pure
     data movement.  Mosaic lowers take_along_axis to tpu.dynamic_gather
     only within one 128-lane vreg (the cross-vreg form fails to compile),
     so the row is gathered per 128-lane tile and
     the right tile selected per window.  Exact: no arithmetic touches
-    the values."""
-    bs, Tp = x.shape
+    the values.
+
+    `idx` is the plan's shared row as its REF ([1, Wp] f32: a tile of it
+    is loaded at its lane offset, which Mosaic lowers at any Wp; a slice
+    of the loaded VALUE at lane offset 128, broadcast down the sublanes,
+    it refuses: "Invalid input layout") or a [bs, Wp] i32 value.
+
+    `tiles` None (few pairs, `gather_loops`): `src` is a [bs, Tp] value
+    and every row tile is visited for every window tile, unrolled.  Else
+    `tiles` is [2, Wp / 128] i32 in scalar memory (`_tile_ranges`): window
+    tile j's slots lie in row tiles tiles[0, j] .. tiles[1, j], the loop
+    visits those alone (a tile of 128 windows at a 30 s step over a 10 s
+    grid reads 388 slots: 4 or 5 of a 2,304-slot row's 18), and `src` is a
+    REF, the values block itself or a scratch block a computed array was
+    parked in (`_kernel`'s `park`), because a tile of it is read at a lane
+    offset that only the launch knows.  There a window outside its tile's
+    range (an empty or padded one, whose slot is the 0 sentinel) reads 0,
+    not slot 0: its cell is masked either way."""
+    bs, Tp = src.shape
     Wp = idx.shape[1]
     chunks = []
-    for wc in range(0, Wp, _LANE):
-        ic = jnp.broadcast_to(idx[:, wc:wc + _LANE], (bs, _LANE))
-        acc = jnp.zeros((bs, _LANE), x.dtype)
-        for k in range(0, Tp, _LANE):
-            tile = x[:, k:k + _LANE]
-            local = jnp.clip(ic - k, 0, _LANE - 1)
-            g = jnp.take_along_axis(tile, local, axis=1,
-                                    mode="promise_in_bounds")
-            acc = jnp.where((ic >= k) & (ic < k + _LANE), g, acc)
+    for j, wc in enumerate(range(0, Wp, _LANE)):
+        ic = jnp.broadcast_to(idx[:, wc:wc + _LANE].astype(jnp.int32),
+                              (bs, _LANE))
+
+        # (both are traced before `ic` moves on to the next window tile)
+        def visit(k, tile, acc):
+            g = jnp.take_along_axis(tile, jnp.clip(ic - k, 0, _LANE - 1),
+                                    axis=1, mode="promise_in_bounds")
+            return jnp.where((ic >= k) & (ic < k + _LANE), g, acc)
+
+        def visit_at(kt, acc):
+            k = pl.multiple_of(kt * _LANE, _LANE)
+            return visit(k, src[:, pl.ds(k, _LANE)], acc)
+
+        acc = jnp.zeros((bs, _LANE), jnp.float32)
+        if tiles is None:
+            for k in range(0, Tp, _LANE):
+                acc = visit(k, src[:, k:k + _LANE], acc)
+        else:
+            acc = jax.lax.fori_loop(tiles[0, j], tiles[1, j] + 1, visit_at,
+                                    acc)
         chunks.append(acc)
     return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
 
 
+def parked(kind: str, ragged: bool, phased: bool, with_drops: bool,
+           looped: bool = True) -> int:
+    """The [bs, Tp] scratch blocks one grid step of `_kernel` parks
+    computed arrays in for a `_gather_cols` that loops (`gather_loops`;
+    the values block itself is gathered in place; an unrolled gather reads
+    values and parks nothing): the reset-corrected values; on ragged rows
+    the filled values and timestamps of either side (and the validity a
+    phase grid's count correction reads), last_over_time's zeroed values
+    and validity, a phase grid's two band corrections' sources."""
+    if not looped:
+        return 0
+    if kind == "rate_family":
+        return 4 + phased if ragged else int(with_drops)
+    if kind == "last_over_time" or phased:
+        return 2 if ragged else 0
+    return 0
+
+
 def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             t1_ref, t2_ref, n_ref, ws_ref, we_ref, ts_ref, i1_ref, i2_ref,
-            *out_refs,
+            tiles_ref, *out_refs,
             num_groups: int, is_counter: bool, is_rate: bool,
             with_drops: bool, kind: str = "rate_family",
             ragged: bool = False, per_series: bool = False,
             phased: bool = False, steps: int):
     v = vals_ref[:]                                   # [BS, Tp]
+    looped = gather_loops(v.shape[1], i1_ref.shape[1])
+    gather = functools.partial(_gather_cols,
+                               tiles=tiles_ref if looped else None)
+    # what a gather reads the values block as: in place where it loops
+    vsrc = vals_ref if looped else v
+    # after the outputs come the scratch blocks (`parked` of them): where
+    # the gather loops, a computed [BS, Tp] array is stored in one to be
+    # gathered from
+    free = list(out_refs[len(out_refs) - parked(kind, ragged, phased,
+                                                with_drops, looped):])
+    out_refs = out_refs[:len(out_refs) - len(free)]
+
+    def park(x):
+        if not looped:
+            return x
+        ref = free.pop()
+        ref[...] = x
+        return ref
     if phased:
         # rows of a phase grid (FusedPlan.prows): after the 12 operands
         # come the plan's [16, Wp] rows and the working set's [BS, 1] phase
@@ -544,10 +710,10 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # past 128 windows, and folds `idx2 + early2` back into one
         after = jnp.where(early2, idx2 + 1, idx2)
 
-        def corrected(total, x):
+        def corrected(total, src):
             return total \
-                + jnp.where(early1, _gather_cols(x, idx1), 0.0) \
-                - jnp.where(early2, _gather_cols(x, after), 0.0)
+                + jnp.where(early1, gather(src, idx1), 0.0) \
+                - jnp.where(early2, gather(src, after), 0.0)
     if kind == "last_over_time":
         # instant-vector selector (`sum by (x) (metric)` with staleness
         # lookback): the last sample in each window, gathered at last[w];
@@ -556,18 +722,18 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # slot is a staleness marker that makes the series absent, not a
         # hole to skip (unlike the rate family's range-vector filtering)
         if not phased:
-            idx2, slots = i2_ref[:].astype(jnp.int32), n_ref[:]
+            idx2, slots = i2_ref, n_ref[:]
         if ragged:
             m = v == v
-            sel = _gather_cols(jnp.where(m, v, 0.0), idx2)
+            sel = gather(park(jnp.where(m, v, 0.0)), idx2)
             # empty windows gather column idx 0 (a plan sentinel): the
             # true-count mask zeroes their presence
-            pres = _gather_cols(m.astype(jnp.float32), idx2) \
+            pres = gather(park(m.astype(jnp.float32)), idx2) \
                 * jnp.minimum(slots, 1.0)
             out = (sel + vbase_ref[:]) * pres
             _epilogue(gids_ref, out, pres, out_refs, num_groups, per_series)
             return
-        sel = _gather_cols(v, idx2) * jnp.minimum(slots, 1.0)
+        sel = gather(vsrc, idx2) * jnp.minimum(slots, 1.0)
         out = sel + vbase_ref[:] * jnp.minimum(slots, 1.0)
         _epilogue(gids_ref, out, jnp.minimum(slots, 1.0) if phased else None,
                   out_refs, num_groups, per_series)
@@ -582,14 +748,15 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         band = l2_ref[:] - l1_ref[:] + o1_ref[:]
         if ragged:
             validf = (v == v).astype(jnp.float32)     # NaN-aware
-            s = _dot_hi(jnp.where(v == v, v, 0.0), band)
+            vz = jnp.where(v == v, v, 0.0)
+            s = _dot_hi(vz, band)
             n = _dot_1p(validf, band)                  # [BS, Wp] valid counts
             if phased:
-                s = corrected(s, jnp.where(v == v, v, 0.0))
-                n = corrected(n, validf)
+                s = corrected(s, park(vz))
+                n = corrected(n, park(validf))
             pres = (n > 0).astype(jnp.float32)
         elif phased:
-            s = corrected(_dot_hi(v, band), v)
+            s = corrected(_dot_hi(v, band), vsrc)
             n = slots
             pres = (n > 0).astype(jnp.float32)
         else:
@@ -644,16 +811,15 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # leaves idle: integers under 2^24 in f32 accumulation, exact.
         # Empty windows gather slot 0 and count 0, so presence masks them.
         if not phased:
-            idx1 = i1_ref[:].astype(jnp.int32)
-            idx2 = i2_ref[:].astype(jnp.int32)
+            idx1, idx2 = i1_ref, i2_ref
         mf = m.astype(jnp.float32)
         nv = _dot_1p(mf, o1_ref[:])
         if phased:
-            nv = corrected(nv, mf)
-        v1 = _gather_cols(b_c, idx1)
-        v2 = _gather_cols(f_c, idx2)
-        t1 = _gather_cols(b_t, idx1)
-        t2 = _gather_cols(f_t, idx2)
+            nv = corrected(nv, park(mf))
+        v1 = gather(park(b_c), idx1)
+        v2 = gather(park(f_c), idx2)
+        t1 = gather(park(b_t), idx1)
+        t2 = gather(park(f_t), idx2)
         # a slot no sample reached reads 0, as its value does: such a cell
         # holds fewer than two samples and is masked, and no NaN goes on
         t1 = jnp.where(t1 == t1, t1, 0.0)
@@ -662,8 +828,7 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         pres = (nv >= 2.0).astype(jnp.float32)
     else:
         if not phased:
-            idx1 = i1_ref[:].astype(jnp.int32)
-            idx2 = i2_ref[:].astype(jnp.int32)
+            idx1, idx2 = i1_ref, i2_ref
         if with_drops:
             # the first column has no predecessor.  A reset adds the FULL
             # previous RAW value = prev + vbase (rebased rows; ref:
@@ -673,11 +838,11 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             d = jnp.where(v < prev, prev + vbase_ref[:], 0.0)
             col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
             d = jnp.where(col == 0, 0.0, d)
-            c = v + _cumsum_lanes(d)
+            c = park(v + _cumsum_lanes(d))
         else:
-            c = v
-        v1 = _gather_cols(c, idx1)                     # [BS, Wp]
-        v2 = _gather_cols(c, idx2)
+            c = vsrc
+        v1 = gather(c, idx1)                           # [BS, Wp]
+        v2 = gather(c, idx2)
         if phased:
             # a row's boundary timestamps: the base row's at its own slot,
             # plus its phase (exact in f32: offsets stay under 2^24 ms)
@@ -831,14 +996,14 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     from jax.experimental.pallas import tpu as pltpu
 
     Sp, Tp = vals_p.shape
-    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2 = operands[:12]
+    o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2, tiles = operands[:13]
     # adaptive series block: the ragged rate family's scan temporaries
     # scale with bs*Tp, so long rows shrink the block instead of OOMing
     # scoped vmem (or being rejected by the eligibility gate).  All
     # shapes here are static at trace time; Sp is padded to _BS, which
     # every smaller power-of-two block divides.
     bs = pick_block(Tp, Wp, Gp, kind, ragged, panels=gids_p.shape[1],
-                    phased=phased)
+                    phased=phased, with_drops=with_drops)
     if bs is None:
         if interpret:
             bs = _MIN_BS            # no scoped-vmem limit off-chip
@@ -857,6 +1022,9 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     # gids may carry P grouping columns (multi-panel batch)
     gid_spec = pl.BlockSpec((bs, gids_p.shape[1]), lambda i: (i, 0), **space)
     fix = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0), **space)  # noqa: E731
+    # the gathers' tile ranges are scalars the kernel branches on
+    tile_spec = fix(tiles.shape) if interpret else pl.BlockSpec(
+        memory_space=pltpu.SMEM)
     kern = functools.partial(_kernel, num_groups=Gp, is_counter=is_counter,
                              is_rate=is_rate, with_drops=with_drops,
                              kind=kind, ragged=ragged, per_series=per_series,
@@ -879,14 +1047,17 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
                   fix(o1.shape), fix(o2.shape), fix(l1.shape),
                   fix(l2.shape),
                   fix((1, Wp)), fix((1, Wp)), fix((1, Wp)), fix((1, Wp)),
-                  fix((1, Wp)), fix((1, Tp)), fix((1, Wp)), fix((1, Wp))]
+                  fix((1, Wp)), fix((1, Tp)), fix((1, Wp)), fix((1, Wp)),
+                  tile_spec]
         # the phased variant's two: the plan's rows, the set's phases
-        + ([fix(operands[12].shape), col_spec] if phased else []),
+        + ([fix(operands[13].shape), col_spec] if phased else []),
         out_specs=out_specs,
         out_shape=out_shapes,
+        scratch_shapes=[pltpu.VMEM((bs, Tp), jnp.float32)] * parked(
+            kind, ragged, phased, with_drops, gather_loops(Tp, Wp)),
         interpret=interpret,
     )(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
-      idx1, idx2, *((operands[12], phase_p) if phased else ()))
+      idx1, idx2, tiles, *((operands[13], phase_p) if phased else ()))
 
 
 VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
@@ -894,7 +1065,8 @@ VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
 
 def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
                   ragged: bool = False, bs: int = _BS,
-                  panels: int = 1, phased: bool = False) -> int:
+                  panels: int = 1, phased: bool = False,
+                  with_drops: bool = False) -> int:
     """Rough resident-bytes model for one grid step: the band kinds' 4
     selection matrices and band temporary (the gather kinds ship 4 KB
     stand-ins; the ragged rate family reads ONE [Tp, Wp] band, held in
@@ -914,6 +1086,14 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     promchurn-counters-262k.open's four sets (PERF.md section 6, PR 43),
     and a block of other rows regroups the epilogue's f32 sums, so the
     answers would stop being the smaller block's bit for bit.
+    Fitted at Tp=768, Wp=128 and, since PR 44, held against the same
+    report at a Grafana dashboard's shape, Tp=2304 x Wp=768 (six hours of
+    a 10 s scrape under 721 windows): the dense rate family takes 3.09 MiB
+    of temporaries beside 2.25 of block buffers at 128 rows (256 rows are
+    over the budget), the dense phased one 5.31 + 2.25, against estimates
+    of 6.9 and 9.3 MiB.  The band kinds hold five [Tp, Wp] matrices, 35 MB
+    there: they divert by this estimate, as the ragged rate family on one
+    shared row does past Wp=256 at Tp=2304 (its band twice: 14 MB).
     Callers divert to the general XLA path when this exceeds VMEM_BUDGET
     instead of failing at kernel lowering; _run shrinks its series block
     (pick_block) before giving up, so the gate must test the SMALLEST
@@ -924,6 +1104,12 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     if ragged and kind == "rate_family":
         sel += 2 * Tp * Wp * 4
         vals += 19 * bs * Tp * 4
+    else:
+        # the scratch blocks the gathers read computed arrays from (the
+        # ragged rate family's five lie inside its 19; a dense rate kernel
+        # parks one only where it corrects resets itself, `with_drops`)
+        vals += parked(kind, ragged, phased, with_drops,
+                       gather_loops(Tp, Wp)) * bs * Tp * 4
     # multi-panel epilogue (merge_gid_cols): each extra grouping column
     # builds another [Gp, bs] one-hot compare temporary feeding the
     # accumulated multi-hot — a large merged batch that fit the P=1
@@ -932,12 +1118,21 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     # the phased variant keeps a row's own slots, counts and presence
     # beside them: eight more [bs, Wp] temporaries, and the second output
     inter = (20 if phased else 12) * bs * Wp * 4
+    if _selects_by_gather(kind) and not (ragged and kind == "rate_family"):
+        # the gather kinds but for the ragged rate family, as Mosaic
+        # reports them at Tp 768 and 2304 x Wp 256 and 768
+        # (tests/test_chip_compile.py): beside the block's two buffers two
+        # [bs, Tp] temporaries more, and under 3 [bs, Wp] ones (under 8.5
+        # on a phase grid), counted 6 (12)
+        vals += 2 * bs * Tp * 4
+        inter = (12 if phased else 6) * bs * Wp * 4
     return sel + vals + group + inter + (Gp * Wp * 8 if phased else 0)
 
 
 def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
                ragged: bool = False, panels: int = 1,
-               phased: bool = False) -> Optional[int]:
+               phased: bool = False,
+               with_drops: bool = False) -> Optional[int]:
     """Largest series-block size whose vmem_estimate fits VMEM_BUDGET
     (None when even _MIN_BS doesn't — the caller must divert to the
     general path).  The ragged rate family's scan temporaries scale with
@@ -946,8 +1141,9 @@ def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     falling off the fused path entirely."""
     bs = _BS
     while bs >= _MIN_BS:
-        if vmem_estimate(Tp, Wp, Gp, kind, ragged, bs=bs,
-                         panels=panels, phased=phased) <= VMEM_BUDGET:
+        if vmem_estimate(Tp, Wp, Gp, kind, ragged, bs=bs, panels=panels,
+                         phased=phased,
+                         with_drops=with_drops) <= VMEM_BUDGET:
             return bs
         bs //= 2
     return None

@@ -367,14 +367,22 @@ class QueryEngine:
             # serialization share of the fixed per-query floor
             secs = (np.asarray(b.wends, np.int64) / 1000.0).tolist()
             present = ~np.isnan(vals)
+            no_inf = ~np.isinf(vals).any(axis=1)
             for i, key in enumerate(b.keys):
-                idx = np.flatnonzero(present[i]).tolist()
-                if not idx:
+                idx = np.flatnonzero(present[i])
+                if not idx.size:
                     continue
-                row = vals[i]
+                # a row's points leave NumPy in one call (a NumPy scalar a
+                # point costs more than its formatting: 20 series x 721
+                # windows a response at a dashboard's own resolution), and
+                # a row without an infinity formats without asking a point
+                whole = idx.size == vals.shape[1]
+                pts = (vals[i] if whole else vals[i][idx]).tolist()
+                ts = secs if whole else [secs[j] for j in idx.tolist()]
+                fmt = _FMT_FINITE if no_inf[i] else _fmt
                 out.append({"metric": _prom_labels(key.labels_dict),
-                            "values": [[secs[j], _fmt(row[j])]
-                                       for j in idx]})
+                            "values": [[t, fmt(v)]
+                                       for t, v in zip(ts, pts)]})
         payload = {"status": "success",
                    "data": {"resultType": "matrix", "result": out}}
         return _attach_partial_fields(payload, result.partial,
@@ -449,6 +457,9 @@ def _prom_labels(labels: Dict[str, str]) -> Dict[str, str]:
     if metric:
         out["__name__"] = metric
     return out
+
+
+_FMT_FINITE = "{:.17g}".format      # `_fmt` of a number that is not +-Inf or NaN
 
 
 def _fmt(v: float) -> str:

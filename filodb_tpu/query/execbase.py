@@ -339,6 +339,7 @@ _FUSED_CACHE_LOCK = threading.Lock()
 
 
 _FUSED_VALS_BUILDERS: Dict[Tuple, threading.Lock] = {}   # key -> its lock
+_FUSED_PLAN_BUILDERS: Dict[Tuple, threading.Lock] = {}
 
 
 def fused_values(key, build):
@@ -349,26 +350,41 @@ def fused_values(key, build):
     six requests over four shards held 10 GB of a 16 GB chip between them
     for one entry each: PERF.md section 6, PR 42).  The key's lock is held
     around `build()` alone; the others wait there and take what it made."""
+    return _built_once(_FUSED_VALS_CACHE, _FUSED_VALS_BUILDERS, key, build)
+
+
+def fused_plan(key, build):
+    """The plan under `key`, built by ONE of the leaves that miss it
+    together: the six panels of an open arrive at once, all on a grid no
+    plan has been built for, and a plan of 721 windows over 2,304 slots is
+    a hundred NumPy calls on arrays long enough to let the interpreter lock
+    go at each (44 ms a build beside five other threads, 0.86 builds a
+    request: PERF.md section 6, PR 44).  The others wait off the lock."""
+    return _built_once(_FUSED_PLAN_CACHE, _FUSED_PLAN_BUILDERS, key, build)
+
+
+def _built_once(cache: "_FusedCache", builders: dict, key, build):
     with _FUSED_CACHE_LOCK:
-        held = _FUSED_VALS_CACHE.get(key)
+        held = cache.get(key)
         if held is not None:
             return held
-        lock = _FUSED_VALS_BUILDERS.setdefault(key, threading.Lock())
+        lock = builders.setdefault(key, threading.Lock())
     with lock:
         with _FUSED_CACHE_LOCK:
-            held = _FUSED_VALS_CACHE.get(key)
+            held = cache.get(key)
         if held is not None:
             return held
         try:
             held = build()
-            # a new snapshot generation obsoletes this mirror's older
-            # entries — the insert drops them NOW, not at LRU eviction:
-            # each pins a full padded copy of the working set in HBM
+            # (values: a new snapshot generation obsoletes this mirror's
+            # older entries, and the insert drops them NOW, not at LRU
+            # eviction: each pins a full padded copy of the working set in
+            # HBM)
             with _FUSED_CACHE_LOCK:
-                return _FUSED_VALS_CACHE.insert(key, held)
+                return cache.insert(key, held)
         finally:
             with _FUSED_CACHE_LOCK:
-                _FUSED_VALS_BUILDERS.pop(key, None)
+                builders.pop(key, None)
 
 
 class GroupCardinalityError(ValueError):

@@ -36,7 +36,7 @@ from filodb_tpu.query.execbase import (
     _FUSED_CACHE_LOCK, _FUSED_MINMAX_PAD_CACHE, _FUSED_PLAN_CACHE,
     _FUSED_VALS_CACHE, _block_empty, _group_cache_insert,
     _group_cache_lookup, _lru_touch, _note_mirror_limit, agg_token,
-    fused_values)
+    fused_plan, fused_values)
 from filodb_tpu.query.transformers import (
     AggregateMapReduce, PeriodicSamplesMapper, RangeVectorTransformer,
     _group_ids, _group_ids_cached, _group_ids_of_part)
@@ -301,14 +301,22 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             if padded_vals is not None:
                 registry.counter("leaf_fused_prep_hits").increment()
         if plan is None:
-            with span("leaf.build_plan"):
-                plan = pf.build_plan(plan_row.astype(np.int64),
-                                     eval_wends - plan_shift, t0.window_ms)
-            if key is not None:
-                with _FUSED_CACHE_LOCK:
-                    # the first build of a grid stays: a request whose
-                    # leaves held two equal plans would be two device calls
-                    plan = _FUSED_PLAN_CACHE.insert(plan_key, plan)
+            def build():
+                with span("leaf.build_plan"):
+                    return pf.build_plan(plan_row.astype(np.int64),
+                                         eval_wends - plan_shift,
+                                         t0.window_ms)
+            # one builder a grid: the panels of an open miss together, and
+            # a request whose leaves held two equal plans would be two
+            # device calls
+            plan = build() if key is None else fused_plan(plan_key, build)
+        if not plan.exact:
+            # the kernel's times are f32 milliseconds off the row's first
+            # sample: some boundary of this grid is not one (odd
+            # milliseconds past 4.66 h).  Declined by name, not answered
+            # from times a millisecond off
+            registry.counter("leaf_inexact_times").increment()
+            return None
         if gkeys is None:
             with span("leaf.group_ids"):
                 if whole is None:

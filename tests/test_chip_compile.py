@@ -19,8 +19,14 @@ import jax.numpy as jnp
 from filodb_tpu.ops import pallas_fused as pf
 
 HBM_BYTES = 16 * 1000 ** 3          # one v5e chip
+# the shape of the six hour-long cells (720 samples a series; chip_smoke.py's
+# 110 windows, the cells ask 61: one tile of 128 either way) ...
 T, W, STEP_MS, RANGE_MS = 720, 110, 10_000, 300_000
+# ... and of promperf6h-counters-82k.open (ISSUE 44): six hours at a panel's
+# own resolution, 2,304 samples a series under 721 windows of `[40s]`
+T_6H, W_6H, RANGE_6H_MS = 2_304, 721, 40_000
 S_FLAGSHIP, S_SHARD = 1_048_576, 262_144
+S_6H = (24_600, 24_400, 16_400, 16_500)     # 81,920 series on four shards
 
 
 @pytest.fixture(scope="module")
@@ -260,13 +266,95 @@ def test_phased_band_corrections_compile_past_128_windows(
     product take a row's own [BS, Wp] slots.  By the shared [1, Wp] row
     Mosaic refused the phased over_time kinds there ("Invalid input
     layout": a [1, 128] i32 slice at a lane offset broadcast down the
-    sublanes), as it still refuses the UNPHASED gather kinds'
-    `_gather_cols` (PERF.md section 7)."""
+    sublanes), as it refused the UNPHASED gather kinds' `_gather_cols`
+    until ISSUE 44 (which loads the tile off the ref)."""
     compiled = _compile_run(one_chip, 8_192, 16, fn, ragged, phased=True,
                             windows=200)
     _check(compiled, pallas=True)
 
 
+def _scoped_bytes(one_chip, monkeypatch, limit, **run):
+    """What Mosaic names when a kernel's scoped VMEM passes `limit`: the
+    first of its allocations that does (the pipeline's block buffers, then
+    the kernel's temporaries and scratch); 0 when it compiles under it."""
+    import re
+
+    from jax.experimental.pallas import tpu as pltpu
+    real = pf.pl.pallas_call
+    jax.clear_caches()      # a trace kept from another test holds no limit
+    with monkeypatch.context() as m:
+        m.setattr(pf.pl, "pallas_call", lambda *a, **k: real(
+            *a, compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=int(limit)), **k))
+        try:
+            _compile_run(one_chip, **run)
+            return 0
+        except Exception as err:        # noqa: BLE001 - XLA's own
+            said = re.search(r"Scoped allocation with size ([0-9.]+)([MK])",
+                             str(err))
+            if said is None:
+                pytest.skip("the compiler did not name its scoped "
+                            f"allocation: {str(err)[:200]}")
+            return float(said.group(1)) * (1 << 20 if said.group(2) == "M"
+                                           else 1 << 10)
+        finally:
+            jax.clear_caches()      # ... and this one's must not outlive it
+
+
+WIDE_KINDS = [("rate", False, False), ("increase", False, False),
+              ("delta", False, False), ("last_over_time", False, False),
+              ("rate", True, False), ("rate", False, True)]
+
+
+@pytest.mark.parametrize("samples,windows", [
+    (T, 200), (T, W_6H), (T_6H, 200), (T_6H, W_6H)],
+    ids=["Tp768-Wp256", "Tp768-Wp768", "Tp2304-Wp256", "Tp2304-Wp768"])
+@pytest.mark.parametrize("fn,ragged,phased", WIDE_KINDS, ids=[
+    "rate", "increase", "delta", "last_ot", "rate-ragged", "rate-phased"])
+def test_gather_kinds_compile_past_one_window_tile_inside_the_estimate(
+        one_chip, chip_runtime, monkeypatch, fn, ragged, phased, samples,
+        windows):
+    """Every kind whose boundaries `_gather_cols` selects, past 128 windows
+    (where the shared `[1, Wp]` index did not lower before ISSUE 44) and at
+    a Grafana dashboard's shape, Tp 2,304 x Wp 768: the program of a
+    request's four working sets compiles for the described v5e, and
+    Mosaic's own scoped allocations (the block buffers, then the
+    temporaries with the gathers' scratch) lie under `vmem_estimate` at
+    the block `pick_block` gives, inside the budget.  Where no block fits
+    (the ragged rate family's `[Tp, Wp]` band lies in VMEM twice: 14 MB at
+    2,304 x 768) `_run` declines by name before lowering, which is where
+    leafexec's guard diverts."""
+    plan = _plan(RANGE_6H_MS, windows, samples)
+    Wp, Gp = plan.t1.shape[1], pf.pad_group_count(20)
+    kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
+    bs = pf.pick_block(plan.Tp, Wp, Gp, kind, ragged, phased=phased)
+    run = dict(S=S_6H, G=(10, 10, 1, 20), fn=fn, ragged=ragged,
+               phased=phased, range_ms=RANGE_6H_MS, windows=windows,
+               samples=samples)
+    if bs is None:
+        assert ragged and plan.Tp * Wp * 8 > pf.VMEM_BUDGET // 2
+        with pytest.raises(ValueError, match="exceeds VMEM budget"):
+            _compile_run(one_chip, **run)
+        return
+    compiled = _compile_run(one_chip, **run)
+    _check(compiled, pallas=True)
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == len(S_6H)
+    buffers = _scoped_bytes(one_chip, monkeypatch, 256 << 10, **run)
+    temps = _scoped_bytes(one_chip, monkeypatch, buffers + (128 << 10),
+                          **run)
+    estimate = pf.vmem_estimate(plan.Tp, Wp, Gp, kind, ragged, bs=bs,
+                                phased=phased)
+    print(f"bs={bs} buffers={buffers / 2 ** 20:.2f}M "
+          f"temps={temps / 2 ** 20:.2f}M estimate={estimate / 2 ** 20:.2f}M")
+    assert buffers >= 2 * bs * plan.Tp * 4
+    assert buffers + temps <= estimate <= pf.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("fn,ragged,phased", [
+    ("rate", True, True), ("rate", False, False), ("delta", False, False),
+    ("last_over_time", False, False), ("rate", True, False)],
+    ids=["rate-ragged-phased", "rate", "delta", "last_ot", "rate-ragged"])
 @pytest.mark.parametrize("samples,windows,range_ms,Gp,fits", [
     (1_500, 500, RANGE_MS, 16, True),       # Tp 1536, Wp 512: 32 rows
     (1_500, 500, 20_000_000, 16, True),     # ... fills across the row, 11
@@ -278,19 +366,24 @@ def test_phased_band_corrections_compile_past_128_windows(
 ], ids=["1536x512", "1536x512-11steps", "768x1024", "1280x640",
         "1536x512-G1000", "1536x1024", "2048x1024"])
 def test_a_long_range_of_many_windows_compiles_or_diverts(
-        one_chip, chip_runtime, samples, windows, range_ms, Gp, fits):
+        one_chip, chip_runtime, samples, windows, range_ms, Gp, fits,
+        fn, ragged, phased):
     """The ragged rate family's [Tp, Wp] band lies in VMEM twice: hours
-    of samples under a Grafana panel's 500 to 1,000 windows (phased rows,
-    the served flavor; the unphased gather kinds lower no more than 128
-    windows) either get a block from `pick_block` and then compile under
-    the chip's scoped limit, or get None, which is where leafexec and the
-    mesh executor divert to the general path and `_run` refuses by name."""
+    of samples under a Grafana panel's 500 to 1,000 windows (ragged phased
+    rows, promchurn's flavor, and ragged rows on one shared row) either get
+    a block from `pick_block` and then compile under the chip's scoped
+    limit, or get None, which is where leafexec and the mesh executor
+    divert to the general path and `_run` refuses by name.  The dense
+    unphased gather kinds (which lowered no more than 128 windows before
+    ISSUE 44) hold no band: they fit at every one of these shapes."""
     plan = _plan(range_ms, windows, samples)
+    kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
+    fits = fits or not ragged
     bs = pf.pick_block(plan.Tp, plan.t1.shape[1], pf.pad_group_count(Gp),
-                       "rate_family", True, phased=True)
+                       kind, ragged, phased=phased)
     assert (bs is not None) == fits
     run = lambda: _compile_run(                              # noqa: E731
-        one_chip, 8_192, Gp, "rate", True, phased=True, range_ms=range_ms,
+        one_chip, 8_192, Gp, fn, ragged, phased=phased, range_ms=range_ms,
         windows=windows, samples=samples)
     if fits:
         _check(run(), pallas=True)

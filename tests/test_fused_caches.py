@@ -181,20 +181,23 @@ def test_the_leaves_of_a_request_share_one_plan_build(rig):
         assert all(k[0] == "plan" for k in execbase._FUSED_PLAN_CACHE)
 
 
-def test_two_requests_that_build_one_grids_plan_at_once_are_a_call_each(
+def test_two_requests_that_miss_one_grids_plan_at_once_build_it_once(
         rig, monkeypatch):
     """The six panels of an open share a grid and are in flight together:
-    when two of them miss the plan cache at once, both build, the first
-    insert stays, and every leaf of BOTH requests holds that one object:
-    each request is still one device call of 30 working sets."""
+    when two of them miss the plan cache at once, ONE builds
+    (`execbase.fused_plan`: the other waits for it off the interpreter
+    lock; since ISSUE 44, where a plan of 721 windows costs a build a
+    hundred hand-offs of that lock), and every leaf of BOTH requests holds
+    that one object: each request is still one device call of 30 working
+    sets."""
     import threading
     from filodb_tpu.ops import pallas_fused as pf
     with _FUSED_CACHE_LOCK:
         execbase._FUSED_PLAN_CACHE.clear()
-    real, gate = pf.build_plan, threading.Barrier(2, timeout=60)
+    real = pf.build_plan
 
     def build_plan(*a, **kw):
-        gate.wait()                  # both requests are past their miss
+        time.sleep(1.0)             # the other request reaches its miss
         return real(*a, **kw)
 
     monkeypatch.setattr(pf, "build_plan", build_plan)
@@ -212,7 +215,7 @@ def test_two_requests_that_build_one_grids_plan_at_once_are_a_call_each(
     assert len(out) == 2 and all(why is None and err <= TOL
                                  for (err, why), _ in out)
     time.sleep(0.3)
-    assert registry.counter("span_leaf_build_plan_calls").value - built == 2
+    assert registry.counter("span_leaf_build_plan_calls").value - built == 1
     assert registry.counter("fused_enqueues").value - enq == 2
     assert registry.counter("fused_enqueue_sets").value - sets \
         == 2 * rig.populated

@@ -16,11 +16,11 @@ def small_config(config=CONFIG, **over):
                 samples=SAMPLES, **over)
 
 
-def small_plan(cfg, seed, cell=CELL):
+def small_plan(cfg, seed, cell=CELL, **traffic):
     """The cell's six panels on a grid that 240 samples hold: 20 minutes a
-    request, two phases."""
-    tp = dict(bench_json("workloads", cell)["traffic"], span_s=1200,
-              phases=2, warmup_opens=1)
+    request, two phases (`traffic`: a subclass's own cut, `Ts128Rig.TRAFFIC`)."""
+    tp = dict(bench_json("workloads", cell)["traffic"],
+              **(traffic or Ts128Rig.TRAFFIC))
     return bench_module("traffic", tp["kind"]).Plan(cfg, tp, seed)
 
 
@@ -29,14 +29,17 @@ class Ts128Rig(HistRig):
     the configuration's loader, with the reference's tables in the client's `Tables`;
     `get`, `ask`, `open`, `counters` and `close` are `HistRig`'s.
     `FILODB_TPU_FUSED_INTERPRET=1` is the caller's to set.  A subclass
-    names another counters configuration and cell (`CONFIG`, `CELL`)."""
+    names another counters configuration and cell (`CONFIG`, `CELL`), and
+    where it must another size and cut of the traffic (`SIZE`, `TRAFFIC`)."""
     CONFIG, CELL = CONFIG, CELL
+    SIZE = {}                   # over small_config's 2,048 x 240
+    TRAFFIC = dict(span_s=1200, phases=2, warmup_opens=1)
 
     def __init__(self, seed, control=None):
         from filodb_tpu.standalone import DatasetConfig, FiloServer
         self.seed = seed
-        self.cfg = small_config(self.CONFIG)
-        self.plan = small_plan(self.cfg, seed, self.CELL)
+        self.cfg = dict(small_config(self.CONFIG), **self.SIZE)
+        self.plan = small_plan(self.cfg, seed, self.CELL, **self.TRAFFIC)
         self.srv = FiloServer(
             [DatasetConfig(self.cfg["dataset"], self.cfg["shards"])],
             http_host="127.0.0.1", http_port=0)
