@@ -22,9 +22,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 import re
 
 from filodb_tpu.promql.lexer import ParseError, duration_to_ms
-from filodb_tpu.query.engine import QueryEngine, _prom_error_payload
+from filodb_tpu.query.engine import (QueryEngine, _prom_error_payload,
+                                     rows_parsed)
 from filodb_tpu.query.rangevector import PlannerParams
-from filodb_tpu.utils.metrics import span
+from filodb_tpu.utils.metrics import registry, span
 
 
 class PromHttpApi:
@@ -107,6 +108,20 @@ class PromHttpApi:
                multi_params: Optional[Dict[str, List[str]]] = None,
                headers: Optional[Dict[str, str]] = None
                ) -> Tuple[int, object]:
+        """`route` as a caller in the process reads it: a range query's
+        rows parsed into `data.result` (the HTTP server asks `route` and
+        sends their text as it is)."""
+        status, payload = self.route(method, path, params, body,
+                                     multi_params, headers)
+        if isinstance(payload, dict):
+            rows_parsed(payload)
+        return status, payload
+
+    def route(self, method: str, path: str, params: Dict[str, str],
+              body: bytes = b"",
+              multi_params: Optional[Dict[str, List[str]]] = None,
+              headers: Optional[Dict[str, str]] = None
+              ) -> Tuple[int, object]:
         parts = [p for p in path.split("/") if p]
         multi = multi_params or {k: [v] for k, v in params.items()}
         try:
@@ -206,8 +221,7 @@ class PromHttpApi:
                 return self._explain(eng, q, start, step, end)
             res = self.frontends[dataset].query_range(
                 q, start, step, end, planner_params)
-            with span("http.present"):
-                payload = QueryEngine.to_prom_matrix(res)
+            payload = _present_matrix(res)
             if res.trace_id:
                 payload["traceID"] = res.trace_id
             if _want_stats(params):
@@ -252,8 +266,7 @@ class PromHttpApi:
             want_stats = _want_stats(params) or req.get("stats") in (
                 True, "true", "1", "all")
             for res in results:
-                with span("http.present"):
-                    p = QueryEngine.to_prom_matrix(res)
+                p = rows_parsed(_present_matrix(res))
                 if res.trace_id:
                     p["traceID"] = res.trace_id
                 if want_stats:
@@ -1492,6 +1505,23 @@ def _want_stats(params: Dict[str, str]) -> bool:
 
 def _err(msg: str) -> Dict[str, str]:
     return {"status": "error", "errorType": "bad_data", "error": msg}
+
+
+def _present_matrix(res) -> Dict:
+    """A range query's envelope, its rows rendered once as the response's
+    own JSON text beside it (`QueryEngine.render_prom_matrix`; the server
+    splices that text and does not walk it again).  Books the points
+    written, and those a row with an infinity sent down the per-point path,
+    once a response."""
+    with span("http.present"):
+        payload = QueryEngine.render_prom_matrix(res)
+        rendered = payload.get("_rendered")
+        if rendered is not None:
+            registry.counter("http_present_points").increment(
+                rendered.points)
+            registry.counter("http_present_point_fallbacks").increment(
+                rendered.fallbacks)
+    return payload
 
 
 def _throttled_status(res, payload) -> Optional[int]:

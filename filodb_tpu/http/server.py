@@ -54,6 +54,20 @@ from filodb_tpu.utils.metrics import (mint_trace_id, parse_traceparent,
 _TRACED_PREFIXES = ("/api/", "/promql/", "/influx/")
 
 
+def _encode_json(payload) -> bytes:
+    """A JSON route's body.  `json.dumps` walks the payload; the rows a
+    range query's envelope carries beside it (`_rendered`: already their
+    JSON text) close its `data` object, unwalked."""
+    rendered = payload.pop("_rendered", None) \
+        if isinstance(payload, dict) else None
+    if rendered is None:
+        return json.dumps(payload).encode()
+    data = json.dumps(payload.pop("data"))
+    envelope = json.dumps(payload)
+    return (f'{envelope[:-1]}, "data": {data[:-1]}, "result": '
+            f'{rendered.text}}}}}').encode()
+
+
 def _no_span(name):
     """In `span`'s place for a request that is not traced."""
     return contextlib.nullcontext()
@@ -185,7 +199,7 @@ class FiloHttpServer:
                 # concurrency semaphore and device time
                 from filodb_tpu.query.activequeries import bind_client_conn
                 with bind_client_conn(self.connection), sp("http.route"):
-                    status, payload = api_ref.handle(
+                    status, payload = api_ref.route(
                         method, parsed.path, params, body,
                         multi_params=multi, headers=dict(self.headers))
                 extra_headers = {}
@@ -207,7 +221,7 @@ class FiloHttpServer:
                         blob = b""
                     else:
                         with sp("http.encode"):
-                            blob = json.dumps(payload).encode()
+                            blob = _encode_json(payload)
                     ctype = "application/json"
                 try:
                     with sp("http.write"):

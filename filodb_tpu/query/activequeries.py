@@ -539,7 +539,7 @@ _conn_local = threading.local()
 class bind_client_conn:
     """Bind the serving thread's client socket for the duration of a
     request so `register()` can attach it to the entry (the disconnect
-    watcher's handle).  The HTTP shell wraps `api.handle` in this."""
+    watcher's handle).  The HTTP shell wraps `api.route` in this."""
 
     def __init__(self, sock):
         self.sock = sock

@@ -6,9 +6,11 @@ prometheus/.../query/PrometheusModel.scala (result JSON conversion).
 """
 from __future__ import annotations
 
+import json
 import math
 import time as _time
 import uuid
+from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -352,41 +354,25 @@ class QueryEngine:
     # ------------------------------------------------- Prometheus JSON model
 
     @staticmethod
-    def to_prom_matrix(result: QueryResult) -> Dict:
-        """ref: PrometheusModel.toPromSuccessResponse (matrix result)."""
+    def render_prom_matrix(result: QueryResult) -> Dict:
+        """ref: PrometheusModel.toPromSuccessResponse (matrix result).  The
+        envelope is a small dict WITHOUT `data.result`; the rows ride beside
+        it under `_rendered`, already the response's JSON text (a
+        `RenderedRows`), for the HTTP server to splice in unwalked."""
         err = _prom_error_payload(result)
         if err is not None:
             return err
-        out = []
-        for b in result.blocks:
-            vals = np.asarray(b.values)
-            if vals.ndim != 2:      # histogram series -> skip buckets here
-                continue
-            # block-level assembly: one seconds conversion + one NaN mask
-            # per block instead of per-sample Python math — the result-
-            # serialization share of the fixed per-query floor
-            secs = (np.asarray(b.wends, np.int64) / 1000.0).tolist()
-            present = ~np.isnan(vals)
-            no_inf = ~np.isinf(vals).any(axis=1)
-            for i, key in enumerate(b.keys):
-                idx = np.flatnonzero(present[i])
-                if not idx.size:
-                    continue
-                # a row's points leave NumPy in one call (a NumPy scalar a
-                # point costs more than its formatting: 20 series x 721
-                # windows a response at a dashboard's own resolution), and
-                # a row without an infinity formats without asking a point
-                whole = idx.size == vals.shape[1]
-                pts = (vals[i] if whole else vals[i][idx]).tolist()
-                ts = secs if whole else [secs[j] for j in idx.tolist()]
-                fmt = _FMT_FINITE if no_inf[i] else _fmt
-                out.append({"metric": _prom_labels(key.labels_dict),
-                            "values": [[t, fmt(v)]
-                                       for t, v in zip(ts, pts)]})
-        payload = {"status": "success",
-                   "data": {"resultType": "matrix", "result": out}}
+        payload = {"status": "success", "data": {"resultType": "matrix"},
+                   "_rendered": _render_matrix_rows(result.blocks)}
         return _attach_partial_fields(payload, result.partial,
                                       result.stats.warnings)
+
+    @staticmethod
+    def to_prom_matrix(result: QueryResult) -> Dict:
+        """`render_prom_matrix` with the rows parsed into `data.result`:
+        the same text read again, so there is one set of formatting
+        rules."""
+        return rows_parsed(QueryEngine.render_prom_matrix(result))
 
     @staticmethod
     def to_prom_vector(result: QueryResult) -> Dict:
@@ -459,7 +445,72 @@ def _prom_labels(labels: Dict[str, str]) -> Dict[str, str]:
     return out
 
 
-_FMT_FINITE = "{:.17g}".format      # `_fmt` of a number that is not +-Inf or NaN
+@dataclass(frozen=True)
+class RenderedRows:
+    """The `result` array of a matrix response as its JSON text, the points
+    written, and those of them that took the per-point path.  (`json.dumps`
+    refuses it: an envelope that still carries one is not a body yet.)"""
+    text: str
+    points: int
+    fallbacks: int
+
+
+def rows_parsed(payload: Dict) -> Dict:
+    """A matrix envelope as Prometheus shapes it: the rows that ride beside
+    it as text (`render_prom_matrix`) parsed into `data.result`."""
+    rendered = payload.pop("_rendered", None)
+    if rendered is not None:
+        payload["data"]["result"] = json.loads(rendered.text)
+    return payload
+
+
+_compact_json = json.JSONEncoder(separators=(",", ":")).encode
+
+
+def _render_matrix_rows(blocks) -> RenderedRows:
+    """Every rule of a matrix's rows, once: histogram blocks and rows
+    without a point are skipped, `_metric_` leaves as `__name__`, a NaN
+    point is left out, a value is its `.17g` text (`+Inf` / `-Inf`), a
+    timestamp what `json.dumps` writes for that float."""
+    rows: List[str] = []
+    points = fallbacks = 0
+    for b in blocks:
+        vals = np.asarray(b.values)
+        if vals.ndim != 2 or not vals.shape[1]:
+            continue    # histogram series (no buckets here), or no window
+        # one template a block, the JSON text of a point with its timestamp
+        # written and its value still to come: a row that is finite
+        # throughout is then ONE formatting call, with no list, float or
+        # string object a point (20 series x 721 windows a response at a
+        # dashboard's own resolution).  Two NumPy calls a block say which
+        # rows those are; a call over 500 elements lets the interpreter
+        # lock go, and a request waits up to 5 ms to have it back
+        stamps = [repr(t / 1000.0)
+                  for t in np.asarray(b.wends, np.int64).tolist()]
+        pieces = [f'[{t},"%.17g"]' for t in stamps]
+        whole = "[" + ",".join(pieces) + "]"
+        finite = np.isfinite(vals).all(axis=1).tolist()
+        for i, key in enumerate(b.keys):
+            if finite[i]:
+                text = whole % tuple(vals[i].tolist())
+                points += len(stamps)
+            else:
+                idx = np.flatnonzero(~np.isnan(vals[i])).tolist()
+                if not idx:
+                    continue
+                pts = vals[i][idx].tolist()
+                if math.inf in pts or -math.inf in pts:
+                    text = "[" + ",".join(
+                        [f'[{stamps[j]},"{_fmt(v)}"]'
+                         for j, v in zip(idx, pts)]) + "]"
+                    fallbacks += len(idx)
+                else:
+                    text = ("[" + ",".join([pieces[j] for j in idx])
+                            + "]") % tuple(pts)
+                points += len(idx)
+            labels = _compact_json(_prom_labels(key.labels_dict))
+            rows.append(f'{{"metric":{labels},"values":{text}}}')
+    return RenderedRows("[" + ",".join(rows) + "]", points, fallbacks)
 
 
 def _fmt(v: float) -> str:
