@@ -221,6 +221,17 @@ class FusedPlan(NamedTuple):
     # the row's first sample), multiples of 8 ms below 2^27.  A leaf
     # declines a plan that is not (leafexec: `leaf_inexact_times_total`)
     exact: bool
+    # the columns of a row that one kernel instance computes over
+    # (`_col_reach`): the slots the plan's windows reach, rounded up to
+    # whole 128-lane tiles; Tp where they reach the row (or all but its
+    # last tile).  Static: `_run`'s compile key where Tp was.  Under Tp
+    # an instance LOADS one tile more (`_load_cols`) and turns it
+    Tq: int
+    # the first of those columns as a launch derives it from the rows it
+    # has uploaded (`_col_offset`): (on one shared row, on a phase grid).
+    # Any column, not a tile's first.  Never a compile key: the device
+    # computes its own
+    c0: Tuple[int, int]
 
 
 def build_plan(ts_row: np.ndarray, wends: np.ndarray,
@@ -268,17 +279,79 @@ def build_plan(ts_row: np.ndarray, wends: np.ndarray,
     wvalid, wvalid1 = n >= 2, n >= 1
     operands = rows.nbytes + prows.nbytes + tsr.nbytes
     exact = _f32_holds(ts_row, wstart - 1, wend)
+    Tq = _col_reach(first, la, T, Tp)
+    c0 = tuple(int(_col_offset(np, r, Tp, Tq, phased))
+               for phased, r in ((False, rows), (True, prows)))
     visits = tuple(
         int(np.maximum(t[1] - t[0] + 1, 0).sum()) for t in (
-            _tile_ranges(np, rows, Tp, False),
-            _tile_ranges(np, prows, Tp, True))) if gather_loops(Tp, Wp) \
-        else ((Tp // _LANE) * (Wp // _LANE),) * 2
+            _tile_ranges(np, rows, Tq, False, c0[0]),
+            _tile_ranges(np, prows, Tq, True, c0[1]))) \
+        if gather_loops(Tq, Wp) else ((Tq // _LANE) * (Wp // _LANE),) * 2
     return FusedPlan(rows, *(rows[i:i + 1] for i in range(8)), tsrow=tsr,
                      wvalid=wvalid, wvalid1=wvalid1, W=W, Tp=Tp,
                      prows=prows, span=int(n.max()) if W else 0, resident={},
                      nbytes=operands * (1 + jax.local_device_count())
                      + wvalid.nbytes + wvalid1.nbytes,
-                     tile_visits=visits, exact=exact)
+                     tile_visits=visits, exact=exact, Tq=Tq, c0=c0)
+
+
+def _col_reach(first, la, T: int, Tp: int) -> int:
+    """-> Tq: how many columns of a row hold every slot the plan's windows
+    read, in whole 128-lane tiles.  The slots run from the one BEFORE the
+    earliest window's first (a row of a phase grid may take it: `fm`, the
+    rate family's and a band correction's) to the latest window's last,
+    over every real window, empty ones too (a row's own window may hold a
+    sample where the base row's holds none).  Tq depends on how MANY slots
+    the windows reach and not on where they lie: the block starts AT the
+    first slot read (`_col_offset`), wherever in a tile that is, so a
+    dashboard whose `end` moves with the newest sample keeps ONE program
+    (a width that followed the first slot's place in its tile would flip
+    between two, a tile apart, every time it crossed a tile edge: the
+    churned cell's opens walk it through every offset).  The price is one
+    tile more to load (`_load_cols`) and one turn of the loaded block, so
+    a plan is trimmed only where that still leaves a tile out: an hour of
+    `[5m]` at 60 s over a 10 s grid reaches 391 slots, 512 columns of a
+    768-slot row (640 loaded); six hours of `[40s]` at 30 s reach 2,165
+    of 2,304: the row."""
+    if not len(first):
+        return Tp
+    lo = max(int(np.clip(first, 0, T).min()) - 1, 0)
+    Tq = _pad_to(max(int(la.max()) - lo + 1, 1), _LANE)
+    return Tq if _load_cols(Tq, Tp) < Tp else Tp
+
+
+def _load_cols(Tq: int, Tp: int) -> int:
+    """The columns of a row a kernel instance loads to compute over Tq of
+    them: the row where Tq is the row; else one tile more than Tq, since
+    the loaded block starts on a tile edge (Mosaic places a block at
+    multiples of 128 lanes) up to a tile before the first column read."""
+    return Tp if Tq == Tp else Tq + _LANE
+
+
+def _live_firsts(rows, phased: bool):
+    """(live, first): the windows whose slots a launch reads, as
+    `_tile_ranges` takes them, and the row of their first slots, from a
+    plan's `rows` (or its `prows` where `phased`).  On one shared row the
+    non-empty windows (an empty or padded one's slot is the 0 sentinel
+    and its cell is masked); on a phase grid every real window (a row's
+    own window may hold a sample where the base row's holds none)."""
+    if phased:
+        return rows[_N] >= 2.0, rows[_PI1]
+    return rows[_N1] >= 1.0, rows[_I1]
+
+
+def _col_offset(xp, rows, Tp: int, Tq: int, phased: bool):
+    """-> i32 scalar c0: the first of the Tq columns a launch computes
+    over, from a plan's `rows` (or its `prows` where `phased`): the slot
+    before the earliest first that a live window reads (as `_tile_ranges`
+    takes them), held inside the row.  `xp` is numpy on the host
+    (build_plan: the plan's `c0`, its tile ranges) and jax.numpy inside
+    `_run`'s trace (kernel_operands: made from the rows an enqueue has
+    uploaded anyway, so it is no upload and, being data, no compile key).
+    With Tq from `_col_reach`, [c0, c0 + Tq) holds every slot read."""
+    live, lo = _live_firsts(rows, phased)
+    lo = xp.where(live, lo - 1.0, float(Tp)).min()
+    return xp.clip(lo, 0, Tp - Tq).astype(xp.int32)
 
 
 def _f32_holds(*times) -> bool:
@@ -310,21 +383,23 @@ def scan_steps(plan: FusedPlan, kind: str, ragged: bool,
     (`_fill_scan2`): the least j whose reach of 2**j - 1 slots crosses the
     plan's widest window (one slot wider on a phase grid, where a row may
     take the slot before the shared first), and never more than cross the
-    row, ceil(log2(Tp)).  Only samples inside a window count (fewer than
+    row block, ceil(log2(Tq)).  Only samples inside a window count (fewer than
     two mask the cell), so a carry that travels a window's width selects
     what one that travels the row's selects.  `[5m]` over a 10 s grid: 5,
-    `[1h]`: 9, a range past 512 slots: 10 at Tp 768.  Every other flavor
+    `[1h]`: 9, a range past 512 slots: 10 at Tq 768.  Every other flavor
     runs no fill and gets 0, so none of them compiles anew."""
     if not (ragged and kind == "rate_family"):
         return 0
     span = plan.span + (1 if phased else 0)
-    return min(max(span - 1, 0).bit_length(), _row_steps(plan.Tp))
+    return min(max(span - 1, 0).bit_length(), _row_steps(plan.Tq))
 
 
-def _tile_ranges(xp, rows, Tp: int, phased: bool):
+def _tile_ranges(xp, rows, Tp: int, phased: bool, c0=None):
     """-> [2, Wp / 128] i32: the first and last 128-slot tile of the row
-    that each tile of 128 windows can read, from a plan's `rows` (or its
-    `prows` where `phased`): what `_gather_cols` visits.  `xp` is numpy on
+    block (`Tp` columns from column `c0`: the row itself, or a plan's
+    `Tq` from its `_col_offset`) that each tile of 128 windows can read,
+    from a plan's `rows` (or its `prows` where `phased`): what
+    `_gather_cols` visits.  `xp` is numpy on
     the host (build_plan: what a launch will visit, for the counter) and
     jax.numpy inside `_run`'s trace (kernel_operands: the kernel's scalar
     operand, made from the rows an enqueue has uploaded anyway, so it is
@@ -339,6 +414,8 @@ def _tile_ranges(xp, rows, Tp: int, phased: bool):
         live, lo, hi = rows[_N] >= 2.0, rows[_PI1] - 1.0, rows[_PI2]
     else:
         live, lo, hi = rows[_N1] >= 1.0, rows[_I1], rows[_I2]
+    if c0 is not None:
+        lo, hi = lo - c0, hi - c0
     lo = xp.where(live, lo, float(Tp)).reshape(-1, _LANE).min(axis=1)
     hi = xp.where(live, hi, -1.0).reshape(-1, _LANE).max(axis=1)
     return xp.stack([xp.floor(xp.clip(lo, 0, Tp) / _LANE),
@@ -347,12 +424,20 @@ def _tile_ranges(xp, rows, Tp: int, phased: bool):
 
 
 def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
-                    ragged: bool = False):
+                    ragged: bool = False, Tq: Optional[int] = None):
     """The 12 operands `_kernel` reads after (vals, vbase, gids), from a
     plan's uploaded rows; `phased` (rows is the plan's [16, Wp] `prows`):
     13, the last being the rows themselves for the kernel's slacks, with
     the boundary slots and timestamps those of the phased rows and `n` the
-    base row's true count for every kind.  Traceable: `_run` calls it
+    base row's true count for every kind.  `Tq` under Tp (a plan whose
+    windows reach fewer columns than the row has, `_col_reach`): the
+    kernel computes over the Tq columns from column c0 (`_col_offset`,
+    computed here from the rows), so the slots, the tile ranges, the
+    bands and `tsrow` are made relative to c0, and one operand more comes
+    last, [2] i32, which `_run_set` prefetches into scalar memory: the
+    tile the loaded block starts at (for its index map) and how many
+    columns below c0 that is (for the kernel's turn of the block).  `Tq`
+    None or Tp: the row, and nothing of this.  Traceable: `_run` calls it
     inside its jit (and with it every caller that composes `run_kernel`
     under its own trace, parallel/mesh.py), so an enqueue ships the
     [8, Wp] rows and nothing else of the plan.
@@ -370,16 +455,28 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
     def row(i):
         return rows[i:i + 1]
 
+    trimmed = Tq is not None and Tq != Tp
+    if trimmed:
+        c0 = _col_offset(jnp, rows, Tp, Tq, phased)
+        # (the windows `_col_offset` took c0 from; another's slot is the
+        # 0 sentinel and stays 0: its cell is masked)
+        live = _live_firsts(rows, phased)[0][None, :]
+
+        def rel(i):
+            return jnp.where(live, row(i) - c0.astype(jnp.float32), 0.0)
+    else:
+        Tq, c0, rel = Tp, None, row
+
     stand_in = jnp.zeros((8, _LANE), jnp.float32)
     band_only = ragged and kind == "rate_family"
     if _selects_by_gather(kind) and not band_only:
         sel = (stand_in,) * 4
     else:
-        t = jax.lax.broadcasted_iota(jnp.int32, (Tp, rows.shape[1]), 0)
+        t = jax.lax.broadcasted_iota(jnp.int32, (Tq, rows.shape[1]), 0)
         valid = row(_N1) >= 1.0
 
         def slot(i):
-            return jnp.where(valid, row(i).astype(jnp.int32), -1)
+            return jnp.where(valid, rel(i).astype(jnp.int32), -1)
 
         def mat(i, leq):
             return ((t <= slot(i)) if leq else (t == slot(i))).astype(
@@ -395,14 +492,22 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
             sel = (mat(_I1, False), mat(_I2, False), mat(_I1, True),
                    mat(_I2, True))
     if tsrow is None:
-        tsrow = jnp.zeros((1, Tp), jnp.float32)
-    tiles = _tile_ranges(jnp, rows, Tp, phased)
+        tsrow = jnp.zeros((1, Tq), jnp.float32)
+    elif trimmed:
+        tsrow = jax.lax.dynamic_slice_in_dim(tsrow, c0, Tq, axis=1)
+    tiles = _tile_ranges(jnp, rows, Tq, phased, c0)
+    at = ()
+    if trimmed:
+        # the loaded block: whole tiles from the tile c0 lies in, held
+        # inside the row (then c0 lies a tile further in)
+        tile = jnp.minimum(c0 // _LANE, (Tp - _load_cols(Tq, Tp)) // _LANE)
+        at = (jnp.stack([tile, c0 - tile * _LANE]),)
     if phased:
         return sel + (row(_PT1), row(_PT2), row(_N1), row(_WS), row(_WE),
-                      tsrow, row(_PI1), row(_PI2), tiles, rows)
+                      tsrow, rel(_PI1), rel(_PI2), tiles, rows) + at
     return sel + (row(_T1), row(_T2),
                   row(_N1 if kind in OVER_TIME_FNS else _N),
-                  row(_WS), row(_WE), tsrow, row(_I1), row(_I2), tiles)
+                  row(_WS), row(_WE), tsrow, rel(_I1), rel(_I2), tiles) + at
 
 
 def merge_gid_cols(gids, offsets):
@@ -449,7 +554,8 @@ def gathers(kind: str, ragged: bool, phased: bool) -> int:
 
 def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
                      offsets=None, sets: int = 1,
-                     phased: bool = False) -> tuple:
+                     phased: bool = False,
+                     cols: Optional[int] = None) -> tuple:
     """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
     takes from the host, as device arrays (so the call itself transfers
     nothing).  The plan's own operands are put on a device once and stay
@@ -470,12 +576,19 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
     its real window count on `fused_windows_total` (over the enqueues: the
     windows a launch, 61 for an hour at 60 s, 721 for six hours at 30 s)
     and the row tiles its gathers visit on `fused_gather_tile_visits_total`
-    (working sets x gathers x the plan's tile ranges: Wp / 128 x Tp / 128 a
-    gather when the narrowed gather does not engage)."""
+    (working sets x gathers x the plan's tile ranges: Wp / 128 x Tq / 128 a
+    gather when the narrowed gather does not engage), and the columns of a
+    row each of its kernel instances loads on `fused_columns_read_total`
+    (`_load_cols` of `cols`, the launch's Tq: the plan's unless the flavor
+    takes the row; over the enqueues: 640 a launch, of which 512 are
+    computed over, for an hour of `[5m]` at 60 s over a 768-slot row; 768
+    before a block followed the windows' reach)."""
     from filodb_tpu.utils.metrics import registry
     registry.counter("fused_enqueues").increment()
     registry.counter("fused_enqueue_sets").increment(sets)
     registry.counter("fused_windows").increment(plan.W)
+    registry.counter("fused_columns_read").increment(
+        _load_cols(cols or plan.Tq, plan.Tp))
     visits = sets * gathers(kind, ragged, phased) * plan.tile_visits[phased]
     if visits:
         registry.counter("fused_gather_tile_visits").increment(visits)
@@ -637,21 +750,38 @@ def _gather_cols(src, idx, tiles):
     return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
 
 
+def _gathers_values(kind: str, ragged: bool, phased: bool,
+                    with_drops: bool) -> bool:
+    """Whether some `_gather_cols` of the flavor reads the values block
+    itself and not an array computed from it: dense rows' boundaries
+    (unless the kernel corrects resets first), last_over_time's, and a
+    phase grid's band corrections."""
+    if ragged:
+        return False
+    if kind == "rate_family":
+        return not with_drops
+    return kind == "last_over_time" or phased
+
+
 def parked(kind: str, ragged: bool, phased: bool, with_drops: bool,
-           looped: bool = True) -> int:
+           looped: bool = True, turned: bool = False) -> int:
     """The [bs, Tp] scratch blocks one grid step of `_kernel` parks
     computed arrays in for a `_gather_cols` that loops (`gather_loops`;
     the values block itself is gathered in place; an unrolled gather reads
     values and parks nothing): the reset-corrected values; on ragged rows
     the filled values and timestamps of either side (and the validity a
     phase grid's count correction reads), last_over_time's zeroed values
-    and validity, a phase grid's two band corrections' sources."""
+    and validity, a phase grid's two band corrections' sources.  `turned`
+    (a trimmed plan's block, `_load_cols`): the values a gather reads in
+    place are no longer the loaded block but its turn, a computed array
+    like the others: one block more wherever they are (`_gathers_values`)."""
     if not looped:
         return 0
+    turn = int(turned and _gathers_values(kind, ragged, phased, with_drops))
     if kind == "rate_family":
-        return 4 + phased if ragged else int(with_drops)
+        return (4 + phased if ragged else int(with_drops)) + turn
     if kind == "last_over_time" or phased:
-        return 2 if ragged else 0
+        return (2 if ragged else 0) + turn
     return 0
 
 
@@ -661,18 +791,28 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             num_groups: int, is_counter: bool, is_rate: bool,
             with_drops: bool, kind: str = "rate_family",
             ragged: bool = False, per_series: bool = False,
-            phased: bool = False, steps: int):
+            phased: bool = False, steps: int, at_ref=None):
     v = vals_ref[:]                                   # [BS, Tp]
+    turned = at_ref is not None
+    if turned:
+        # a trimmed plan's block (`_load_cols`): one tile wider than the
+        # Tq columns the plan computes over and begun on a tile edge,
+        # at_ref[1] columns below the first of them.  One turn of the
+        # lanes (the XLU's, by an amount only the launch knows) brings
+        # that column to lane 0; the tile that falls off is read by no
+        # window.  Pure data movement: the values are the row's
+        from jax.experimental.pallas import tpu as pltpu
+        load, below = v.shape[1], at_ref[1]
+        v = pltpu.roll(v, jnp.where(below > 0, load - below, 0),
+                       1)[:, :load - _LANE]
     looped = gather_loops(v.shape[1], i1_ref.shape[1])
     gather = functools.partial(_gather_cols,
                                tiles=tiles_ref if looped else None)
-    # what a gather reads the values block as: in place where it loops
-    vsrc = vals_ref if looped else v
     # after the outputs come the scratch blocks (`parked` of them): where
     # the gather loops, a computed [BS, Tp] array is stored in one to be
     # gathered from
-    free = list(out_refs[len(out_refs) - parked(kind, ragged, phased,
-                                                with_drops, looped):])
+    free = list(out_refs[len(out_refs) - parked(
+        kind, ragged, phased, with_drops, looped, turned):])
     out_refs = out_refs[:len(out_refs) - len(free)]
 
     def park(x):
@@ -681,6 +821,11 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         ref = free.pop()
         ref[...] = x
         return ref
+    # what a gather reads the values block as: in place where it loops
+    # (the turned block is no operand: it is parked where it is read)
+    vsrc = vals_ref if looped and not turned else v
+    if turned and _gathers_values(kind, ragged, phased, with_drops):
+        vsrc = park(v)
     if phased:
         # rows of a phase grid (FusedPlan.prows): after the 12 operands
         # come the plan's [16, Wp] rows and the working set's [BS, 1] phase
@@ -915,14 +1060,17 @@ def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
 
 
 def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
-                   phased: bool = False, steps: int = 0) -> str:
+                   phased: bool = False, steps: int = 0,
+                   Tq: Optional[int] = None) -> str:
     """The compile-cache shape signature recorded with jit compile
     events (utils/devicetelem): the padded dims + static flags that key
     the trace cache, so a recompile storm names the shape that drove it.
     A call of several sets names their summed rows and groups and how
-    many they were."""
+    many they were.  `T` is the row block's width, the launch's `Tq`
+    (the row's Tp where it is left out)."""
     Sp = sum(st[0].shape[0] for st in sets)
-    return (f"S{Sp}xT{plan.Tp}xW{plan.t1.shape[1]}xG{sum(num_groups)}:{kind}"
+    return (f"S{Sp}xT{Tq or plan.Tp}xW{plan.t1.shape[1]}"
+            f"xG{sum(num_groups)}:{kind}"
             + (":ragged" if ragged else "") + (":phased" if phased else "")
             + (f":{steps}steps" if steps else "")
             + (f":{len(sets)}sets" if len(sets) > 1 else ""))
@@ -930,12 +1078,12 @@ def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
-    "kind", "ragged", "per_series", "phased", "steps"))
+    "kind", "ragged", "per_series", "phased", "steps", "Tq"))
 def _run(sets, offsets, rows, tsrow, *,
          num_groups: Tuple[int, ...], is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
          ragged: bool = False, per_series: bool = False,
-         phased: bool = False, steps: int):
+         phased: bool = False, steps: int, Tq: Optional[int] = None):
     """One fused dispatch, whole: the plan's kernel operands
     (kernel_operands), built once, then for every working set of `sets`
     its group merge (merge_gid_cols) and its own Pallas call, in one
@@ -951,9 +1099,13 @@ def _run(sets, offsets, rows, tsrow, *,
     concatenated on the group axis (a pair of them, sums and present
     counts, when `ragged` or `phased`): set i's rows start at
     sum(num_groups[:i]).  A set's block is what a call of that set alone
-    returns, bit for bit: the sets meet only in the concatenation."""
+    returns, bit for bit: the sets meet only in the concatenation.
+    `Tq` (the flavor's, `_flavor`; None: the row's Tp): the columns of a
+    row each kernel instance computes over, from the column the rows say
+    (`_col_offset`: data, so a dashboard that moves along the row with
+    the newest sample runs one program)."""
     Tp = sets[0][0].shape[1]
-    operands = kernel_operands(rows, tsrow, Tp, kind, phased, ragged)
+    operands = kernel_operands(rows, tsrow, Tp, kind, phased, ragged, Tq)
     outs, p0 = [], 0
     for st, Gp in zip(sets, num_groups):
         vals_p, vbase_p, gids = st[:3]
@@ -995,14 +1147,22 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     is still `_run`'s alone."""
     from jax.experimental.pallas import tpu as pltpu
 
-    Sp, Tp = vals_p.shape
+    Sp = vals_p.shape[0]
     o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2, tiles = operands[:13]
+    # the columns computed over: the plan's Tq (kernel_operands made `ts`
+    # that wide), the row's own Tp where the windows reach the row; the
+    # columns loaded: a tile more, or the row
+    Tq = ts.shape[1]
+    trimmed = Tq != vals_p.shape[1]
+    load = _load_cols(Tq, vals_p.shape[1])
     # adaptive series block: the ragged rate family's scan temporaries
     # scale with bs*Tp, so long rows shrink the block instead of OOMing
     # scoped vmem (or being rejected by the eligibility gate).  All
     # shapes here are static at trace time; Sp is padded to _BS, which
     # every smaller power-of-two block divides.
-    bs = pick_block(Tp, Wp, Gp, kind, ragged, panels=gids_p.shape[1],
+    # (sized by the columns LOADED: the block's two buffers are that wide,
+    # and a bound on them bounds the narrower arrays computed from them)
+    bs = pick_block(load, Wp, Gp, kind, ragged, panels=gids_p.shape[1],
                     phased=phased, with_drops=with_drops)
     if bs is None:
         if interpret:
@@ -1013,15 +1173,28 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
             # never reach this, but direct fused_rate_groupsum users can
             raise ValueError(
                 f"fused kernel shape exceeds VMEM budget at every block "
-                f"size (Tp={Tp}, Wp={Wp}, Gp={Gp}, kind={kind}, "
+                f"size (Tp={load}, Wp={Wp}, Gp={Gp}, kind={kind}, "
                 f"ragged={ragged}); use the general path")
     grid = Sp // bs
     space = {} if interpret else {"memory_space": pltpu.VMEM}
-    row_spec = pl.BlockSpec((bs, Tp), lambda i: (i, 0), **space)
-    col_spec = pl.BlockSpec((bs, 1), lambda i: (i, 0), **space)
+    # (an index map also gets the prefetched scalar where there is one)
+    if trimmed:
+        # `load` columns of the row from a tile only the launch knows: the
+        # last operand (kernel_operands: that tile, and c0's place past
+        # it) is prefetched into scalar memory and the block is placed by
+        # ELEMENT offsets (a blocked index counts in blocks, and the tile
+        # is no multiple of the block), a multiple of 128 lanes by
+        # construction
+        row_spec = pl.BlockSpec(
+            (pl.Element(bs), pl.Element(load)),
+            lambda i, at: (i * bs, at[0] * _LANE), **space)
+    else:
+        row_spec = pl.BlockSpec((bs, load), lambda i, *_: (i, 0), **space)
+    col_spec = pl.BlockSpec((bs, 1), lambda i, *_: (i, 0), **space)
     # gids may carry P grouping columns (multi-panel batch)
-    gid_spec = pl.BlockSpec((bs, gids_p.shape[1]), lambda i: (i, 0), **space)
-    fix = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0), **space)  # noqa: E731
+    gid_spec = pl.BlockSpec((bs, gids_p.shape[1]), lambda i, *_: (i, 0),
+                            **space)
+    fix = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0, 0), **space)  # noqa: E731
     # the gathers' tile ranges are scalars the kernel branches on
     tile_spec = fix(tiles.shape) if interpret else pl.BlockSpec(
         memory_space=pltpu.SMEM)
@@ -1031,7 +1204,7 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
                              phased=phased, steps=steps)
     with_counts = ragged or phased       # presence rides a second output
     if per_series:
-        out_spec = pl.BlockSpec((bs, Wp), lambda i: (i, 0), **space)
+        out_spec = pl.BlockSpec((bs, Wp), lambda i, *_: (i, 0), **space)
         out_shape = jax.ShapeDtypeStruct((Sp, Wp), jnp.float32)
     else:
         out_spec = fix((Gp, Wp))
@@ -1040,24 +1213,31 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     out_shapes = [out_shape, out_shape] if with_counts else out_shape
     # selection-matrix specs follow the operands' actual shapes: the
     # gather kinds get tiny stand-ins for the unread o1/o2/l1/l2
-    return pl.pallas_call(
-        kern,
+    call = dict(
         grid=(grid,),
         in_specs=[row_spec, col_spec, gid_spec,
                   fix(o1.shape), fix(o2.shape), fix(l1.shape),
                   fix(l2.shape),
                   fix((1, Wp)), fix((1, Wp)), fix((1, Wp)), fix((1, Wp)),
-                  fix((1, Wp)), fix((1, Tp)), fix((1, Wp)), fix((1, Wp)),
+                  fix((1, Wp)), fix((1, Tq)), fix((1, Wp)), fix((1, Wp)),
                   tile_spec]
         # the phased variant's two: the plan's rows, the set's phases
         + ([fix(operands[13].shape), col_spec] if phased else []),
         out_specs=out_specs,
-        out_shape=out_shapes,
-        scratch_shapes=[pltpu.VMEM((bs, Tp), jnp.float32)] * parked(
-            kind, ragged, phased, with_drops, gather_loops(Tp, Wp)),
-        interpret=interpret,
-    )(vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
-      idx1, idx2, tiles, *((operands[13], phase_p) if phased else ()))
+        scratch_shapes=[pltpu.VMEM((bs, Tq), jnp.float32)] * parked(
+            kind, ragged, phased, with_drops, gather_loops(Tq, Wp),
+            trimmed))
+    args = (vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
+            idx1, idx2, tiles, *((operands[13], phase_p) if phased else ()))
+    if trimmed:
+        # the kernel body is the row's own but for the turn of the block
+        return pl.pallas_call(
+            lambda at_ref, *refs: kern(*refs, at_ref=at_ref),
+            grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
+                                                   **call),
+            out_shape=out_shapes, interpret=interpret)(operands[-1], *args)
+    return pl.pallas_call(kern, out_shape=out_shapes, interpret=interpret,
+                          **call)(*args)
 
 
 VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
@@ -1094,6 +1274,10 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     of 6.9 and 9.3 MiB.  The band kinds hold five [Tp, Wp] matrices, 35 MB
     there: they divert by this estimate, as the ragged rate family on one
     shared row does past Wp=256 at Tp=2304 (its band twice: 14 MB).
+    A trimmed plan's kernel (`_col_reach`) is estimated at the columns it
+    LOADS (`_load_cols`: `_run_set` picks its block by them), a tile more
+    than it computes over: at 640 of 768 Mosaic takes 0.62 MiB of buffers
+    and 3.40 of temporaries on a phase grid, the turned block among them.
     Callers divert to the general XLA path when this exceeds VMEM_BUDGET
     instead of failing at kernel lowering; _run shrinks its series block
     (pick_block) before giving up, so the gate must test the SMALLEST
@@ -1556,12 +1740,12 @@ def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
     with span_part("leaf.enqueue_pack"):
         rows, tsrow, offs = enqueue_operands(plan, device, kind, ragged,
                                              offsets, sets=len(sets),
-                                             phased=phased)
+                                             phased=phased, cols=flags["Tq"])
     with span_part("leaf.enqueue_jit"):
         res = watched_call(
             "fused_run", _run,
             _run_shape_sig(sets, plan, num_groups, kind, ragged, phased,
-                           flags["steps"]),
+                           flags["steps"], flags["Tq"]),
             lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
                          **flags),
             device=device)
@@ -1577,6 +1761,7 @@ class _FlavorFlags(NamedTuple):
     ragged: bool
     phased: bool
     steps: int
+    Tq: int
 
 
 def _flavor(plan: FusedPlan, fn_name: str, precorrected: bool,
@@ -1584,13 +1769,29 @@ def _flavor(plan: FusedPlan, fn_name: str, precorrected: bool,
             phased: bool = False) -> _FlavorFlags:
     """`_run`'s static flags of one (function, precorrected, interpret,
     ragged, phased) flavor over `plan`, whose windows say how far the
-    ragged rate family's fills reach (scan_steps: 0 for the others)."""
+    ragged rate family's fills reach (scan_steps: 0 for the others) and
+    how many columns of a row they reach at all (`Tq`).  Two flavors take
+    the row's Tp whatever the windows reach.  A kernel that corrects
+    resets itself: the corrections up to a slot are the ROW'S prefix sum,
+    which no block that starts later holds, and a prefix started later
+    rounds otherwise.  And the dense gather kinds on one shared row
+    (`rate` / `increase` / `delta`, `last_over_time`, the histogram rows):
+    one or two gathers over the values are all their kernel does along
+    the row, and turning the block costs them more than two tiles of
+    six give: 1.685 against 1.625 ms a launch at the hour-long cells'
+    shape, 2.30 against 2.19 over 64-bucket rows, 1.44 against 1.42 for
+    last_over_time, where the phased, ragged and band kinds gain 10 to
+    29% (PERF.md section 6, PR 46)."""
     is_counter = fn_name in ("rate", "increase")
     kind = fn_name if fn_name in OVER_TIME_FNS else "rate_family"
+    with_drops = is_counter and not precorrected
+    whole_row = with_drops or (_selects_by_gather(kind) and not ragged
+                               and not phased)
     return _FlavorFlags(
-        is_counter, fn_name == "rate", is_counter and not precorrected,
+        is_counter, fn_name == "rate", with_drops,
         interpret, kind, ragged, phased,
-        scan_steps(plan, kind, ragged, phased))
+        scan_steps(plan, kind, ragged, phased),
+        plan.Tp if whole_row else plan.Tq)
 
 
 def _kernel_set(values, gid_cols: tuple) -> tuple:

@@ -63,10 +63,13 @@ def chip_runtime():
     cc.reset_cache()
 
 
-def _plan(range_ms=RANGE_MS, windows=W, samples=T):
+def _plan(range_ms=RANGE_MS, windows=W, samples=T, every_ms=None):
     ts_row = np.arange(samples, dtype=np.int64) * STEP_MS
-    # a window a minute (a slot where a minute apart would leave the row)
-    every = 60_000 if windows * 6 <= samples else STEP_MS
+    # the windows spread over the row (a minute apart at the hour-long
+    # cells' 720 x 110, 30 s at the six-hour cell's 2,304 x 721), so that
+    # they reach it and the row block is the row (plan.Tq == plan.Tp);
+    # `every_ms`: a request's own step, which may reach less
+    every = every_ms or max(samples // windows, 1) * STEP_MS
     wends = ts_row[-1] - np.arange(windows, dtype=np.int64)[::-1] * every
     return pf.build_plan(ts_row, wends, range_ms)
 
@@ -76,14 +79,15 @@ def _sds(shape, dtype, sharding):
 
 
 def _compile_run(one_chip, S, G, fn, ragged=False, panels=1, phased=False,
-                 range_ms=RANGE_MS, steps=None, windows=W, samples=T):
+                 range_ms=RANGE_MS, steps=None, windows=W, samples=T,
+                 every_ms=None):
     """Lower + compile pallas_fused._run exactly as a FusedDispatch calls
     it (interpret=False).  `S` and `G` may be tuples: one working set
     each, all in the one program.  `phased`: working sets on a phase
     grid, each with its [Sp, 1] phase column, over the plan's 16 rows.
     `range_ms`: the windows' width, from which the ragged rate family's
     fills take their reach, which must come out as `steps`."""
-    plan = _plan(range_ms, windows, samples)
+    plan = _plan(range_ms, windows, samples, every_ms)
     # precorrected (no drop correction in the kernel), as the mirror serves
     flags = pf._flavor(plan, fn, True, False, ragged, phased)
     Ss = S if isinstance(S, tuple) else (S,)
@@ -255,6 +259,118 @@ def test_the_vmem_estimate_covers_mosaics_scoped_allocation(
     print(f"bs={bs} scoped={scoped / 2 ** 20:.2f}M values={values / 2 ** 20:.2f}M "
           f"band={band / 2 ** 20:.2f}M estimate={estimate / 2 ** 20:.2f}M")
     assert scoped + values + band <= estimate <= pf.VMEM_BUDGET
+
+
+W_OPEN = 61         # the cells' own request: an hour at a window a minute
+
+
+@pytest.mark.parametrize("S,G,fn,ragged,phased,samples,windows,range_ms,Tq", [
+    # promchurn-counters-262k.open's one program a request since ISSUE 46:
+    # a kernel instance computes over the 512 columns an hour of `[5m]`
+    # reaches (391 slots) from a column the launch computes, out of 640
+    # loaded from the tile edge below it
+    (CHURN_ROWS, (10, 10, 1, 20), "rate", True, True, T, W_OPEN, RANGE_MS,
+     512),
+    (CHURN_ROWS, (10, 10, 1, 20), "increase", True, True, T, W_OPEN,
+     RANGE_MS, 512),
+    # ... its dense twin's, the unphased cells', the gauges' band kinds,
+    # the histogram cell's largest shard, ragged rows on one shared row
+    ((79_042, 78_244, 52_281, 52_577), (10, 10, 1, 20), "rate", False, True,
+     T, W_OPEN, RANGE_MS, 512),
+    ((79_042, 78_244, 52_281, 52_577), (10, 10, 1, 20), "rate", False, False,
+     T, W_OPEN, RANGE_MS, 512),
+    ((79_042, 78_244, 52_281, 52_577), (10, 10, 1, 20), "sum_over_time",
+     False, False, T, W_OPEN, RANGE_MS, 512),
+    (S_SHARD, 1000, "avg_over_time", True, True, T, W_OPEN, RANGE_MS, 512),
+    (1_235 * 64, 10 * 64, "rate", False, False, T, W_OPEN, RANGE_MS, 512),
+    (S_SHARD, 1000, "rate", True, False, T, W_OPEN, RANGE_MS, 512),
+    (S_SHARD, 1000, "last_over_time", True, True, T, W_OPEN, RANGE_MS, 512),
+    (S_SHARD, 1000, "last_over_time", False, False, T, W_OPEN, RANGE_MS,
+     512),
+    # a shorter dashboard: 30 minutes reach 211 slots, two tiles
+    (CHURN_ROWS, (10, 10, 1, 20), "rate", True, True, T, 31, RANGE_MS, 256),
+    # the six-hour dashboard reaches 2,165 of 2,304 columns: the row, the
+    # program it had (promperf6h-counters-82k.open)
+    (S_6H, (10, 10, 1, 20), "rate", False, False, T_6H, W_6H, RANGE_6H_MS,
+     T_6H),
+    # ... and two hours of it at the same resolution 768 columns, under
+    # two window tiles: the looped gather over the TURNED block, parked
+    # (dense rows) or computed from (ragged ones)
+    (S_6H, (10, 10, 1, 20), "rate", False, False, T_6H, 241, RANGE_6H_MS,
+     768),
+    (S_6H, (10, 10, 1, 20), "rate", False, True, T_6H, 241, RANGE_6H_MS,
+     768),
+    (S_6H, (10, 10, 1, 20), "last_over_time", False, False, T_6H, 241,
+     RANGE_6H_MS, 768),
+    (S_6H, (10, 10, 1, 20), "rate", True, True, T_6H, 241, RANGE_6H_MS, 768),
+], ids=["churn-rate", "churn-increase", "scrape-rate", "rate-4sets", "sum_ot",
+        "avg_ot-ragged-phased", "rate-hist64", "rate-ragged",
+        "last_ot-ragged-phased", "last_ot", "churn-rate-30min", "rate-6h",
+        "rate-6h-2h", "rate-6h-2h-phased", "last_ot-6h-2h",
+        "rate-6h-2h-ragged-phased"])
+def test_a_row_block_at_the_windows_reach_compiles_for_v5e(
+        one_chip, chip_runtime, S, G, fn, ragged, phased, samples, windows,
+        range_ms, Tq):
+    """A launch whose kernel instances load a block of a row from a tile
+    only the launch knows (ISSUE 46: placed by element offsets off a
+    prefetched scalar) and turn it by an amount only the launch knows (a
+    dynamic lane rotate, then a static slice): Mosaic must take both, at
+    every flavor the cells run; a block no wider than the row's gets no
+    more series than the row's did at the cells' shape (more rows would
+    regroup the f32 group sums)."""
+    # the requests' own steps: a minute over the hour-long rows, 30 s over
+    # the six-hour ones
+    every = 30_000 if samples == T_6H else 60_000
+    plan = _plan(range_ms, windows, samples, every)
+    assert (plan.Tq, plan.Tp) == (Tq, pf._pad_to(samples, 128))
+    kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
+    Gp = pf.pad_group_count(max(G) if isinstance(G, tuple) else G)
+    Wp = plan.t1.shape[1]
+    rows = [pf.pick_block(t, Wp, Gp, kind, ragged, phased=phased)
+            for t in (pf._load_cols(Tq, plan.Tp), plan.Tp)]
+    # at the cells' 640 of 768 columns loaded no flavor's block grows; half
+    # the row's columns and fewer leave the ragged rate family room for 256
+    assert rows[0] == rows[1] or (Tq * 2 <= plan.Tp and rows[0] > rows[1])
+    compiled = _compile_run(one_chip, S, G, fn, ragged, phased=phased,
+                            range_ms=range_ms, windows=windows,
+                            samples=samples, every_ms=every)
+    _check(compiled, pallas=True)
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") \
+        == (len(S) if isinstance(S, tuple) else 1)
+
+
+@pytest.mark.parametrize("fn,ragged,phased,windows", [
+    ("rate", True, True, W_OPEN), ("rate", True, False, W_OPEN),
+    ("increase", True, True, W_OPEN), ("rate", True, True, 31)],
+    ids=["churn", "rate-ragged", "churn-increase", "churn-30min"])
+def test_the_vmem_estimate_covers_mosaic_at_the_windows_reach(
+        one_chip, chip_runtime, monkeypatch, fn, ragged, phased, windows):
+    """`vmem_estimate` at the columns a trimmed program LOADS (`_run_set`
+    sizes its block by them) against what Mosaic takes for that program
+    of the ragged rate family, the flavor the estimate is fitted to (the
+    pipeline's block buffers, then the kernel's temporaries): it still
+    covers both, inside the budget, at the block `pick_block` gives: 128
+    rows for the churned cell's flavor at 640 columns loaded as at 768
+    (256 rows at 640 are estimated at 16.3 MiB)."""
+    plan = _plan(RANGE_MS, windows, T, 60_000)
+    load = pf._load_cols(plan.Tq, plan.Tp)
+    Wp, Gp = plan.t1.shape[1], pf.pad_group_count(20)
+    kind = fn if fn in pf.OVER_TIME_FNS else "rate_family"
+    bs = pf.pick_block(load, Wp, Gp, kind, ragged, phased=phased)
+    if windows == W_OPEN:
+        assert (plan.Tq, load, bs) == (512, 640, 128)
+    run = dict(S=CHURN_ROWS, G=(10, 10, 1, 20), fn=fn, ragged=ragged,
+               phased=phased, windows=windows, every_ms=60_000)
+    buffers = _scoped_bytes(one_chip, monkeypatch, 256 << 10, **run)
+    temps = _scoped_bytes(one_chip, monkeypatch, buffers + (128 << 10),
+                          **run)
+    estimate = pf.vmem_estimate(load, Wp, Gp, kind, ragged, bs=bs,
+                                phased=phased)
+    print(f"Tq={plan.Tq} load={load} bs={bs} buffers={buffers / 2 ** 20:.2f}M "
+          f"temps={temps / 2 ** 20:.2f}M estimate={estimate / 2 ** 20:.2f}M")
+    assert buffers >= 2 * bs * load * 4
+    assert buffers + temps <= estimate <= pf.VMEM_BUDGET
 
 
 @pytest.mark.parametrize("fn,ragged", [

@@ -300,7 +300,7 @@ def _launch(plan, vals, gids, fn, wide, phase=None, ragged=False):
     them, or (`wide`) opened to every tile of the row."""
     real = pf._tile_ranges
 
-    def every_tile(xp, rows, Tp, phased):
+    def every_tile(xp, rows, Tp, phased, c0=0):
         n = rows.shape[1] // 128
         return xp.stack([xp.zeros(n), xp.full(n, Tp // 128 - 1)]
                         ).astype(xp.int32)
@@ -375,13 +375,22 @@ def test_tile_ranges_on_the_host_are_the_kernels_on_the_device():
     # one window tile over six row tiles: few enough pairs to visit them
     # all unrolled (`gather_loops`), whatever the ranges say (tiles 2 to 5)
     assert not pf.gather_loops(hour.Tp, 128) and pf.gather_loops(2304, 768)
-    assert hour.tile_visits == (6, 6)
+    # (the kernel computes over the 512 columns the windows reach, from
+    # column 256: four tiles a gather, where the row has six)
+    assert (hour.Tq, hour.c0) == (512, (256, 256))
+    assert hour.tile_visits == (4, 4)
     assert pf._tile_ranges(np, hour.rows, hour.Tp, False).tolist() \
         == [[2], [5]]
+    assert pf._tile_ranges(np, hour.rows, hour.Tq, False, 256).tolist() \
+        == [[0], [3]]
     empty = pf.build_plan(ts[:720], -np.arange(1, 200)[::-1] * 60_000, 40_000)
     assert pf._tile_ranges(np, empty.rows, empty.Tp, False).tolist() \
         == [[6, 6], [-1, -1]]
-    assert empty.tile_visits[0] == 0
+    # (windows that reach one slot get a row block of one tile: two pairs,
+    # few enough to visit unrolled, where the row's six tiles were looped
+    # over and none visited)
+    assert empty.Tq == 128 and not pf.gather_loops(empty.Tq, 256)
+    assert empty.tile_visits[0] == 2
 
 
 def test_a_launch_books_its_windows_and_the_tiles_its_gathers_visit():
