@@ -352,70 +352,6 @@ def bench_query_under_ingest(quick: bool):
           quiesced_qps=round(1 / per_quiesced, 1))
 
 
-def bench_query_1m(quick: bool):
-    """North-star end-to-end: memstore ingest -> index lookup -> dense
-    gather -> mesh pack (cached group ids) -> kernel, at 1M series
-    (BASELINE.md config 3; VERDICT r1 item 4).  Runs the full host path
-    the flagship query takes, so host-side per-series Python would show
-    up here immediately."""
-    import jax
-    from filodb_tpu.core.memstore import TimeSeriesMemStore
-    from filodb_tpu.ingest.generator import counter_batch
-    from filodb_tpu.parallel.mesh import MeshExecutor, make_mesh
-    from filodb_tpu.ops.timewindow import make_window_ends
-    from filodb_tpu.core.index import Equals
-    # T=60 in both modes: the 5m-rate window grid needs >= 300s of data
-    # or make_window_ends returns an empty grid and p50 measures nothing
-    S, T = (50_000, 60) if quick else (1_000_000, 60)
-    ms = TimeSeriesMemStore()
-    sh = ms.setup("prometheus", 0)
-    t0 = time.perf_counter()
-    # ingest in slices to bound the peak batch footprint
-    step = 250_000
-    from filodb_tpu.core.partkey import PartKey
-    from filodb_tpu.core.records import RecordBatch
-    for lo in range(0, S, step):
-        n = min(step, S - lo)
-        b = counter_batch(n, T, start_ms=START, num_apps=100)
-        if lo:
-            # re-key the slice so series identities stay distinct
-            keys = [PartKey.make(pk.metric,
-                                 {**dict(pk.tags),
-                                  "instance": f"I{lo}-{i}"})
-                    for i, pk in enumerate(b.part_keys)]
-            b = RecordBatch(b.schema, keys, b.part_idx, b.timestamps,
-                            b.columns, b.bucket_les)
-        sh.ingest(b)
-    ingest_s = time.perf_counter() - t0
-    _emit("query_1m", "ingest_samples_per_sec", S * T / ingest_s,
-          "samples/s", series=S)
-    mesh = make_mesh(1, 1, devices=jax.devices()[:1])
-    ex = MeshExecutor(ms, "prometheus", mesh)
-    filters = [Equals("_metric_", "request_total")]
-    end_ms = START + (T - 1) * 10_000
-    wends = make_window_ends(START + 300_000, end_ms, 60_000)
-
-    def run():
-        packed = ex.lookup_and_pack(filters, START, end_ms, by=("_ns_",),
-                                    fn_name="rate")
-        out, labels = ex.run_agg(packed, wends, range_ms=300_000,
-                                 fn_name="rate", agg_op="sum")
-        return np.asarray(out)
-
-    t1 = time.perf_counter()
-    run()                      # cold: compile + group cache + pack upload
-    cold_s = time.perf_counter() - t1
-    lat = []
-    for _ in range(2 if quick else 5):
-        t1 = time.perf_counter()
-        run()
-        lat.append(time.perf_counter() - t1)
-    p50 = float(np.median(lat))
-    _emit("query_1m", "sum_by_rate_p50_latency", p50 * 1000, "ms",
-          series=S, samples_scanned_per_sec=round(S * T / p50, 1),
-          cold_first_query_s=round(cold_s, 3))
-
-
 # -------------------------------------------------------------- histogram
 
 
@@ -757,7 +693,6 @@ BENCHES: Dict[str, Callable[[bool], None]] = {
     "query": bench_query,
     "query_hicard": bench_query_hicard,
     "dashboard_batch": bench_dashboard_batch,
-    "query_1m": bench_query_1m,
     "query_odp": bench_query_odp,
     "partition_list": bench_partition_list,
     "query_under_ingest": bench_query_under_ingest,

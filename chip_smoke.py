@@ -271,11 +271,7 @@ class Client:
 ROUTE_COUNTERS = ("leaf_fused_kernel_total", "leaf_host_routed_total",
                   "leaf_fused_errors_total", "warmup_compile_errors_total",
                   "device_mirror_query_fallbacks_total",
-                  "device_mirror_refreshes_total",
-                  "mesh_fused_kernel_total", "mesh_fused_host_total",
-                  "mesh_fused_errors_total",
-                  "mesh_partials_collective_merge_total",
-                  "mesh_partials_host_merge_total")
+                  "device_mirror_refreshes_total")
 
 
 def route_delta(before, after):
@@ -671,10 +667,10 @@ def run_one_chip(server, cli, S, T, S_gauge, n_write):
 
 
 def run_four_chips(server, cli, S, T, S_gauge):
-    """What exists only across chips: one mirror per device, per-device
-    fused dispatch behind the served queries, and the mesh executor's
-    collective merge — with what they are compared with, and nothing
-    else."""
+    """What exists only across chips, on the path that serves: one mirror
+    per device and a fused call on every device behind the served queries,
+    their partials merged on the host, each answer against the f64
+    reference, and nothing else."""
     end_ms = START_MS + (T - 1) * STEP_MS
     end_s = end_ms // 1000
     grid_a = window_grid(end_ms, N_WINDOWS + REPEATS)
@@ -704,41 +700,9 @@ def run_four_chips(server, cli, S, T, S_gauge):
         f"mirror bytes + fused dispatches on {len(used)} devices, " \
         f"want {ARGS.chips}: {sorted(used)}"
 
-    # the cross-device merge: MeshExecutor over the same store (the gauge
-    # metric: the pack is a second device copy, kept small).  On a TPU the
-    # [G, W] partials of the four per-device kernel runs merge as one psum
-    # over the interconnect; on the CPU (rehearsal) has_ici() is false and
-    # the host merge runs instead
-    from filodb_tpu.core.index import Equals
-    from filodb_tpu.parallel.mesh import MeshExecutor, make_mesh
-    mesh = make_mesh(ARGS.chips, 1)
-    ex = MeshExecutor(server.memstore, DATASET, mesh)
-    wends = grid_a[-N_WINDOWS:]
-    before = cli.counters()
-    t0 = time.perf_counter()
-    packed = ex.lookup_and_pack([Equals("_metric_", "heap_usage")],
-                                int(wends[0] - RANGE_MS), int(end_ms),
-                                by=("_ns_",), fn_name="sum_over_time")
-    out, labels = ex.run_agg(packed, wends, range_ms=RANGE_MS,
-                             fn_name="sum_over_time", agg_op="sum")
-    secs = time.perf_counter() - t0
-    d = route_delta(before, cli.counters())
-    got = {lab["_ns_"]: {int(w // 1000): float(out[g, i])
-                         for i, w in enumerate(wends)}
-           for g, lab in enumerate(labels)}
-    worst = compare(got, subset(g_sot.by_ns(), int(wends[0] // 1000), end_s),
-                    "other", "mesh")
-    assert d.get("mesh_fused_kernel_total"), \
-        ("the mesh executor did not take its per-device kernel route", d)
-    merged = ("collective" if d.get("mesh_partials_collective_merge_total")
-              else "host")
-    if DEVICE["platform"] == "tpu":
-        assert merged == "collective", d
-    emit("mesh", checked_vs_f64_reference=True, max_rel_err=worst,
-         seconds=round(secs, 3), partial_merge=merged, route=d)
     m1 = cli.counters()
     for bad in ("leaf_fused_errors_total", "warmup_compile_errors_total",
-                "leaf_host_routed_total", "mesh_fused_errors_total"):
+                "leaf_host_routed_total"):
         assert m1.get(bad, 0) == m0.get(bad, 0), bad
 
 

@@ -48,13 +48,12 @@ whenever the VMEM estimate demands it."""
 
 
 def kernel_mode() -> Optional[bool]:
-    """How the Pallas kernel may run in this process — the one gate the
-    leaf (query/leafexec.py) and the mesh executor (parallel/mesh.py)
-    share.  False: compiled for the chip, which is what a `tpu` backend
-    always gets (FILODB_TPU_FUSED_INTERPRET has no effect there).  True:
-    interpret mode, only on a backend without an MXU and only under the
-    test-only switch FILODB_TPU_FUSED_INTERPRET.  None: not at all — the
-    caller takes its general or host path."""
+    """How the Pallas kernel may run in this process — the fused leaf's
+    gate (query/leafexec.py).  False: compiled for the chip, which is
+    what a `tpu` backend always gets (FILODB_TPU_FUSED_INTERPRET has no
+    effect there).  True: interpret mode, only on a backend without an
+    MXU and only under the test-only switch FILODB_TPU_FUSED_INTERPRET.
+    None: not at all — the caller takes its general or host path."""
     if jax.default_backend() == "tpu":
         return False
     return True if os.environ.get("FILODB_TPU_FUSED_INTERPRET") else None
@@ -439,8 +438,8 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
     columns below c0 that is (for the kernel's turn of the block).  `Tq`
     None or Tp: the row, and nothing of this.  Traceable: `_run` calls it
     inside its jit (and with it every caller that composes `run_kernel`
-    under its own trace, parallel/mesh.py), so an enqueue ships the
-    [8, Wp] rows and nothing else of the plan.
+    under its own trace, parallel/mesh._device_fused_call), so an enqueue
+    ships the [8, Wp] rows and nothing else of the plan.
 
     The band kinds' selection matrices are built here, on the device:
     o[t, w] = 1{t == idx[w]} and l[t, w] = 1{t <= idx[w]} over the
@@ -516,7 +515,7 @@ def merge_gid_cols(gids, offsets):
     sum of the earlier panels' group counts; -1 pad rows stay -1).  The
     kernel epilogue turns the columns into one multi-hot matrix, so P
     groupings cost ONE dispatch.  One operand passes through unchanged:
-    a single panel's offset is 0, and the mesh merges on the host."""
+    a single panel's offset is 0."""
     if len(gids) == 1:
         return gids[0]
     return jnp.concatenate(
@@ -1169,8 +1168,8 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
             bs = _MIN_BS            # no scoped-vmem limit off-chip
         else:
             # fail loudly here rather than with an opaque Mosaic
-            # scoped-vmem OOM at lowering: gated callers (leafexec, mesh)
-            # never reach this, but direct fused_rate_groupsum users can
+            # scoped-vmem OOM at lowering: the gated caller (leafexec)
+            # never reaches this, but direct fused_rate_groupsum users can
             raise ValueError(
                 f"fused kernel shape exceeds VMEM budget at every block "
                 f"size (Tp={load}, Wp={Wp}, Gp={Gp}, kind={kind}, "
@@ -1375,8 +1374,8 @@ def can_fuse(fn_name: str, agg_op: str, shared_grid: bool,
             and fn_name in FUSABLE_FNS)
 
 
-# traceable entry for callers composing the kernel inside shard_map (the
-# mesh executor); the jit wrapper inlines under an enclosing trace.
+# traceable entry for callers composing the kernel under a trace of their
+# own (parallel/mesh._device_fused_call); the jit wrapper inlines there.
 # One working set; gids_p is one [Sp, P] matrix, merged by the caller.
 # `steps` is the plan's scan_steps; a caller without the plan at hand
 # leaves it out and the ragged rate family's fills cross the whole row,
@@ -1495,9 +1494,8 @@ def fused_rate_groupsum(vals, vbase, gids, plan: FusedPlan,
     come back from the kernel then too.
 
     `device` pins every operand (values, plan rows) to that chip so the
-    jit executes THERE — the per-device unit of the multi-chip dispatch
-    path (parallel/mesh.py), which runs this exact function once per
-    device and merges the [G, W] partials it returns.
+    jit executes THERE: a leaf over a sharded mirror runs on its
+    mirror's chip (doc/multichip.md).
     """
     over_time = fn_name in OVER_TIME_FNS
     if prepared is None:

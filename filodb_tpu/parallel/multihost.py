@@ -11,8 +11,8 @@ SCOPE — read this before wiring a pod:
 This module provides the verified building blocks (runtime join, global
 mesh construction, per-host global-array assembly).  They degrade exactly
 to the single-host path under one process, which is what CI exercises.
-Driving `MeshExecutor` across processes additionally requires invariants
-the CALLER must establish (single-host runs get them for free):
+Driving `mesh.distributed_window_agg` across processes additionally requires
+invariants the CALLER must establish (single-host runs get them for free):
 
 1. **Globally consistent group slots.**  `pack_shards` assigns
    aggregation-group slots from a local registry; every process must pack

@@ -522,8 +522,7 @@ def _present_comp(op: str, comp: np.ndarray) -> np.ndarray:
     partial already is.  (On the device the same [G, W, C] cost an
     upload, a jit call and a blocking readback behind other requests'
     kernels, for under a microsecond of work, and took the merged f64
-    sums through f32.  parallel/mesh.py presents on the device what is
-    already there, with agg_ops.present.)"""
+    sums through f32.)"""
     if op == "group":
         v = comp[..., 0]
         return np.where(np.isinf(v), np.nan, v)

@@ -9,7 +9,7 @@ module is the device-side twin of the PR 3 query-attribution layer:
   - **kernel dispatch ledger** — every fused/general device call records
     a bounded ring entry {kernel, shape signature, device, wall seconds,
     bytes in/out, origin trace id} plus per-device cumulative counters.
-    Call sites (query/fusedbatch.py, query/leafexec.py, parallel/mesh.py,
+    Call sites (query/fusedbatch.py, query/leafexec.py,
     core/devicecache.py) report through `record_dispatch`, which ALSO
     feeds the per-thread exec tally — so QueryStats.device_seconds and
     the ledger's per-query sum reconcile by construction (the parity

@@ -1058,7 +1058,7 @@ _degrade_last: Dict[str, float] = {}
 
 def log_fused_degradation(where: str, exc: BaseException,
                           min_interval_s: float = 60.0) -> None:
-    """The fused fast paths (query/exec.py leaf, parallel/mesh.py) degrade
+    """The fused fast path (query/leafexec.py) degrades
     silently to the general path on any error; without the exception text
     the operator only sees an error counter climb with nothing to
     diagnose.  Rate-limited so a hot query loop can't flood the log."""

@@ -50,11 +50,11 @@ def test_ledger_feeds_exec_tally_in_lockstep():
         telem.record_dispatch("fused_run", device="chipA",
                               shape="S4xT8", seconds=0.5)
         telem.record_dispatch("fused_run", device="chipA", seconds=0.25)
-        telem.record_dispatch("mesh_fused", device="chipB", seconds=0.125)
+        telem.record_dispatch("mirror_gather", device="chipB", seconds=0.125)
         assert exec_tally.device_s == pytest.approx(0.875)
         assert exec_tally.device_calls == {
             ("chipA", "fused_run"): [0.75, 2],
-            ("chipB", "mesh_fused"): [0.125, 1]}
+            ("chipB", "mirror_gather"): [0.125, 1]}
         split = sum(c[0] for c in exec_tally.device_calls.values())
         assert split == pytest.approx(exec_tally.device_s)
         # transfers/compiles never feed the tally (note_transfer and the
@@ -76,11 +76,11 @@ def test_stats_device_calls_merge_and_wire_parity():
                     device_calls={"chipA|fused_run": [0.5, 2]})
     s2 = QueryStats(device_seconds=0.25,
                     device_calls={"chipA|fused_run": [0.125, 1],
-                                  "chipB|mesh_fused": [0.125, 1]})
+                                  "chipB|mirror_gather": [0.125, 1]})
     s1.merge(s2)
     assert s1.device_seconds == pytest.approx(0.75)
     assert s1.device_calls == {"chipA|fused_run": [0.625, 3],
-                               "chipB|mesh_fused": [0.125, 1]}
+                               "chipB|mirror_gather": [0.125, 1]}
     assert sum(c[0] for c in s1.device_calls.values()) \
         == pytest.approx(s1.device_seconds)
     # over the wire: the generic dataclass codec ships the new field
@@ -90,7 +90,7 @@ def test_stats_device_calls_merge_and_wire_parity():
     # ?stats=true shape: device -> kernel -> {seconds, dispatches}
     d = s1.to_dict()["devices"]
     assert d["chipA"]["fused_run"] == {"seconds": 0.625, "dispatches": 3}
-    assert d["chipB"]["mesh_fused"]["dispatches"] == 1
+    assert d["chipB"]["mirror_gather"]["dispatches"] == 1
 
 
 def test_kill_switch_skips_ledger_but_never_stats():

@@ -362,51 +362,3 @@ def test_cold_leaf_serialize_roundtrip(cold_cluster):
     back = serialize.loads(blob)
     assert isinstance(back, SelectPersistedSegmentsExec)
     assert back.tier is tier
-
-
-# ------------------------------------------------- mesh-wide dispatch
-
-def test_mesh_binop_agg_matches_engine():
-    """parallel/mesh.run_binop_agg: the mesh-wide sum/count ratio equals
-    the single-process engine's binary-join result — only [G, W]
-    partials cross devices, the label match and gather+binop run once."""
-    import jax
-
-    from filodb_tpu.core.index import Equals
-    from filodb_tpu.ops.timewindow import make_window_ends
-    from filodb_tpu.parallel.mesh import MeshExecutor, make_mesh
-    from filodb_tpu.parallel.shardmapper import SpreadProvider
-    from filodb_tpu.query.engine import QueryEngine
-
-    from test_mesh import _mk_store
-
-    ms, mapper = _mk_store(num_shards=4)
-    mesh = make_mesh(4, 2, devices=jax.devices("cpu")[:8])
-    range_ms = 300_000
-    qstart_s = START_S + 600
-    qend_s = START_S + 3600
-    eng = QueryEngine("prometheus", ms, mapper,
-                      SpreadProvider(default_spread=2))
-    res = eng.query_range(
-        'sum by (_ns_)(rate(request_total{_ws_="demo"}[5m]))'
-        ' / on (_ns_) count by (_ns_)(rate(request_total{_ws_="demo"}[5m]))',
-        qstart_s, 60, qend_s)
-    want = {k.labels_dict["_ns_"]: np.asarray(v)
-            for k, _, v in res.series()}
-    assert res.error is None and want
-
-    ex = MeshExecutor(ms, "prometheus", mesh)
-    wends = make_window_ends(qstart_s * 1000, qend_s * 1000, 60_000)
-    filters = [Equals("_metric_", "request_total"), Equals("_ws_", "demo")]
-    out, labels = ex.run_binop_agg(
-        filters, filters, qstart_s * 1000 - range_ms, qend_s * 1000,
-        wends, range_ms=range_ms, fn_name="rate", op="/",
-        agg_op_l="sum", agg_op_r="count", by=("_ns_",))
-    got = {d["_ns_"]: out[i] for i, d in enumerate(labels)}
-    assert set(got) == set(want)
-    for ns in want:
-        w = want[ns]
-        valid = ~np.isnan(w)
-        np.testing.assert_allclose(got[ns][valid], w[valid], rtol=1e-6,
-                                   err_msg=ns)
-        assert np.isnan(got[ns][~valid]).all(), ns

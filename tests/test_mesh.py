@@ -9,19 +9,23 @@ import pytest
 
 import jax
 
+from jax.sharding import NamedSharding, PartitionSpec as P
+
 from filodb_tpu.core.memstore import TimeSeriesMemStore
 from filodb_tpu.core.records import RecordBatch
 from filodb_tpu.core.index import Equals
-from filodb_tpu.ingest.generator import counter_batch, gauge_batch
-from filodb_tpu.ops.timewindow import make_window_ends
-from filodb_tpu.parallel.mesh import (MeshExecutor, make_mesh, pack_shards,
+from filodb_tpu.ingest.generator import counter_batch
+from filodb_tpu.ops import agg as agg_ops
+from filodb_tpu.ops.counter import rebase_values
+from filodb_tpu.ops.timewindow import PAD_TS, make_window_ends, to_offsets
+from filodb_tpu.parallel.mesh import (make_mesh, pack_shards,
                                       device_put_packed,
                                       distributed_window_agg,
                                       distributed_window_raw)
 from filodb_tpu.parallel.shardmapper import ShardEvent, ShardMapper, SpreadProvider
 from filodb_tpu.query.engine import QueryEngine
 
-from test_query_engine import _mk_engine, START_MS, START_S, NUM_SAMPLES
+from test_query_engine import START_MS, START_S, NUM_SAMPLES
 
 QEND_S = START_S + 3600
 STEP_S = 60
@@ -66,18 +70,63 @@ def _engine_result(ms, mapper, promql):
     return res
 
 
+def shard_blocks(ms, filters, start_ms, end_ms, counter=False):
+    """A block a shard out of the store, in the form `pack_shards` takes
+    and the driver's dry run and tests/mh_worker.py make theirs: (offsets
+    from start_ms, rebased f64 values, the rows' labels, value bases); a
+    shard that selects nothing gives the one all-pad row without labels.
+    `counter`: reset-correct on the host (a pack to be `precorrected`)."""
+    blocks = []
+    for shard in ms.shards_for("prometheus"):
+        lookup = shard.lookup_partitions(filters, start_ms, end_ms)
+        schema = lookup.first_schema
+        pids = lookup.pids_by_schema.get(schema) if schema else None
+        if pids is None or not len(pids):
+            blocks.append((np.full((1, 1), PAD_TS, np.int32),
+                           np.full((1, 1), np.nan), []))
+            continue
+        store = shard.stores[schema]
+        rows = shard.rows_for(pids)
+        ts, cols, counts = shard.snapshot_read(
+            store, lambda: store.gather_rows(rows))
+        vals, vbase = rebase_values(
+            cols[shard.schemas[schema].value_column], counter)
+        blocks.append((to_offsets(ts, counts, start_ms), vals,
+                       [key.labels for key in shard.keys_for(pids)], vbase))
+    return blocks
+
+
+def mesh_agg(mesh, packed, wends_ms, *, range_ms, fn_name, agg_op):
+    """`distributed_window_agg` over a placed pack, presented on the host:
+    window ends in absolute ms, moved onto the pack's base and padded to
+    a multiple of the time axis with ends before all data (empty windows,
+    cut off again).  -> [G, W]."""
+    wends = (np.asarray(wends_ms, np.int64) - packed.base_ms).astype(np.int32)
+    W = wends.shape[0]
+    wends = np.concatenate(
+        [wends, np.full(-W % mesh.shape["time"], -PAD_TS, np.int32)])
+    partials = distributed_window_agg(
+        mesh, packed.ts_off, packed.values, packed.group_ids,
+        jax.device_put(wends, NamedSharding(mesh, P("time"))),
+        range_ms=range_ms, fn_name=fn_name, agg_op=agg_op,
+        num_groups=packed.num_groups, base_ms=packed.base_ms,
+        vbase=packed.vbase, precorrected=packed.precorrected,
+        dense=packed.dense)
+    return np.asarray(agg_ops.present(agg_op, partials))[:, :W]
+
+
 def _mesh_result(ms, mesh, agg_op, fn_name, by=(), range_ms=300_000):
-    ex = MeshExecutor(ms, "prometheus", mesh)
-    packed = ex.lookup_and_pack(
-        [Equals("_metric_", "request_total"), Equals("_ws_", "demo"),
-         Equals("_ns_", "App-0")],
-        (START_S + 600) * 1000 - range_ms, QEND_S * 1000, by=by)
+    start_ms = (START_S + 600) * 1000 - range_ms
+    blocks = shard_blocks(
+        ms, [Equals("_metric_", "request_total"), Equals("_ws_", "demo"),
+             Equals("_ns_", "App-0")], start_ms, QEND_S * 1000)
+    packed = device_put_packed(pack_shards(blocks, by=by, base_ms=start_ms),
+                               mesh)
     wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
                              STEP_S * 1000)
-    # absolute ms: run_agg rebases onto the pack's offset base itself
-    out, labels = ex.run_agg(packed, wends, range_ms=range_ms,
-                             fn_name=fn_name, agg_op=agg_op)
-    return out, labels
+    out = mesh_agg(mesh, packed, wends, range_ms=range_ms, fn_name=fn_name,
+                   agg_op=agg_op)
+    return out, packed.group_labels
 
 
 def test_mesh_sum_rate_matches_engine(store4, mesh42):
@@ -168,205 +217,3 @@ def test_mesh_empty_shard_contributes_nothing(mesh42):
     final = np.asarray(agg_ops.present("sum", out))
     # 4 series * 10 samples/window * 1.0 each = 40
     np.testing.assert_allclose(final[0], 40.0)
-
-
-def test_mesh_fused_sum_rate_matches_general(store4, mesh42, monkeypatch):
-    """The Pallas fused mesh path (shard_map + psum around the MXU kernel)
-    must match the general distributed path and the single-process engine."""
-    from filodb_tpu.utils.metrics import registry
-    ms, mapper = store4
-    range_ms = 300_000
-
-    def run():
-        ex = MeshExecutor(ms, "prometheus", mesh42)
-        packed = ex.lookup_and_pack(
-            [Equals("_metric_", "request_total"), Equals("_ws_", "demo")],
-            (START_S + 600) * 1000 - range_ms, QEND_S * 1000,
-            by=("_ns_",), fn_name="rate")
-        wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                                 STEP_S * 1000)
-        return ex.run_agg(packed, wends, range_ms=range_ms,
-                          fn_name="rate", agg_op="sum")
-
-    out_gen, labels_gen = run()
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    before = registry.counter("mesh_fused_kernel").value
-    err_before = registry.counter("mesh_fused_errors").value
-    out_fused, labels_fused = run()
-    assert registry.counter("mesh_fused_kernel").value > before, \
-        "fused mesh path did not engage"
-    assert registry.counter("mesh_fused_errors").value == err_before
-    assert labels_fused == labels_gen
-    assert (np.isnan(out_fused) == np.isnan(out_gen)).all()
-    np.testing.assert_allclose(out_fused, out_gen, rtol=2e-5, atol=1e-4,
-                               equal_nan=True)
-
-
-def test_mesh_fused_skipped_on_ragged_pack(mesh42, monkeypatch):
-    """A pack whose shards have different grids must use the general path."""
-    from filodb_tpu.utils.metrics import registry
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    ms = TimeSeriesMemStore()
-    mapper = ShardMapper(4)
-    for s in range(4):
-        ms.setup("prometheus", s)
-        mapper.update_from_event(
-            ShardEvent("IngestionStarted", "prometheus", s, "local"))
-    # shard 0: full grid; shard 1: offset grid -> pack is not uniform
-    ms.get_shard("prometheus", 0).ingest(
-        counter_batch(8, NUM_SAMPLES, start_ms=START_MS))
-    ms.get_shard("prometheus", 1).ingest(
-        counter_batch(8, NUM_SAMPLES // 2, start_ms=START_MS + 5_000,
-                      seed=3))
-    ex = MeshExecutor(ms, "prometheus", mesh42)
-    packed = ex.lookup_and_pack([Equals("_metric_", "request_total")],
-                                START_MS, QEND_S * 1000, by=("_ns_",))
-    assert packed.shared_ts_row is None
-    wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                             STEP_S * 1000)
-    before = registry.counter("mesh_fused_kernel").value
-    out, _ = ex.run_agg(packed, wends, range_ms=300_000, fn_name="rate",
-                        agg_op="sum")
-    assert registry.counter("mesh_fused_kernel").value == before
-    assert np.isfinite(out).any()
-
-
-def test_mesh_fused_sum_over_time_matches_general(store4, mesh42,
-                                                  monkeypatch):
-    """The over_time band-matrix kernel composes on the mesh too."""
-    from filodb_tpu.utils.metrics import registry
-    ms, mapper = store4
-    range_ms = 300_000
-
-    def run():
-        ex = MeshExecutor(ms, "prometheus", mesh42)
-        packed = ex.lookup_and_pack(
-            [Equals("_metric_", "request_total"), Equals("_ws_", "demo")],
-            (START_S + 600) * 1000 - range_ms, QEND_S * 1000,
-            by=("_ns_",), fn_name="sum_over_time")
-        wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                                 STEP_S * 1000)
-        return ex.run_agg(packed, wends, range_ms=range_ms,
-                          fn_name="sum_over_time", agg_op="sum")
-
-    out_gen, labels_gen = run()
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    before = registry.counter("mesh_fused_kernel").value
-    out_fused, labels_fused = run()
-    assert registry.counter("mesh_fused_kernel").value > before
-    assert labels_fused == labels_gen
-    assert (np.isnan(out_fused) == np.isnan(out_gen)).all()
-    np.testing.assert_allclose(out_fused, out_gen, rtol=2e-4, atol=1e-3,
-                               equal_nan=True)
-
-
-@pytest.mark.parametrize("agg_op", ["sum", "avg", "count"])
-def test_mesh_fused_ragged_pack_matches_general(mesh42, monkeypatch,
-                                                agg_op):
-    """r4: a uniform-grid pack WITH NaN holes keeps shared_ts_row and runs
-    the ragged kernel variant (valid-boundary scans, presence psum'd as a
-    second output) — results match the general path's dense=False
-    semantics for sum/avg/count."""
-    from filodb_tpu.core.records import RecordBatch
-    from filodb_tpu.utils.metrics import registry
-    rng = np.random.default_rng(7)
-    ms = TimeSeriesMemStore()
-    mapper = ShardMapper(4)
-    for s in range(4):
-        sh = ms.setup("prometheus", s)
-        mapper.update_from_event(
-            ShardEvent("IngestionStarted", "prometheus", s, "local"))
-        cb = counter_batch(8, NUM_SAMPLES, start_ms=START_MS, seed=s)
-        v = cb.columns["count"].copy()
-        v[rng.random(v.shape) < 0.1] = np.nan
-        sh.ingest(RecordBatch(cb.schema, cb.part_keys, cb.part_idx,
-                              cb.timestamps, {"count": v}, cb.bucket_les))
-    ex = MeshExecutor(ms, "prometheus", mesh42)
-    packed = ex.lookup_and_pack([Equals("_metric_", "request_total")],
-                                START_MS, QEND_S * 1000,
-                                fn_name="rate")
-    assert packed.shared_ts_row is not None and not packed.dense
-    wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                             STEP_S * 1000)
-    out_gen, _ = ex.run_agg(packed, wends, range_ms=300_000,
-                            fn_name="rate", agg_op=agg_op)
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    before = registry.counter("mesh_fused_kernel").value
-    out_fused, _ = ex.run_agg(packed, wends, range_ms=300_000,
-                              fn_name="rate", agg_op=agg_op)
-    assert registry.counter("mesh_fused_kernel").value > before
-    assert (np.isnan(out_fused) == np.isnan(out_gen)).all()
-    np.testing.assert_allclose(out_fused, out_gen, rtol=2e-5, atol=1e-4,
-                               equal_nan=True)
-
-
-def test_mesh_fused_avg_divides_by_counts(store4, mesh42, monkeypatch):
-    """avg on the fused mesh path must divide group sums by present-series
-    counts (r4 regression: it silently returned raw sums)."""
-    from filodb_tpu.utils.metrics import registry
-    ms, mapper = store4
-
-    def run():
-        ex = MeshExecutor(ms, "prometheus", mesh42)
-        packed = ex.lookup_and_pack(
-            [Equals("_metric_", "request_total"), Equals("_ws_", "demo")],
-            (START_S + 600) * 1000 - 300_000, QEND_S * 1000,
-            fn_name="rate")
-        wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                                 STEP_S * 1000)
-        return ex.run_agg(packed, wends, range_ms=300_000,
-                          fn_name="rate", agg_op="avg")
-
-    out_gen, _ = run()
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    before = registry.counter("mesh_fused_kernel").value
-    out_fused, _ = run()
-    assert registry.counter("mesh_fused_kernel").value > before
-    np.testing.assert_allclose(out_fused, out_gen, rtol=2e-5, atol=1e-4,
-                               equal_nan=True)
-
-
-def test_run_agg_batch_matches_individual(store4, mesh42, monkeypatch):
-    """A dashboard's panels over ONE pack + ONE shard_map dispatch
-    (multi-hot over disjoint group-id ranges) must match per-panel
-    run_agg exactly; min/max panels fall back per panel."""
-    from filodb_tpu.utils.metrics import registry
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    ms, _ = store4
-    ex = MeshExecutor(ms, "prometheus", mesh42)
-    filters = [Equals("_metric_", "request_total"), Equals("_ws_", "demo")]
-    t0 = (START_S + 600) * 1000 - 300_000
-    t1 = QEND_S * 1000
-    wends = make_window_ends((START_S + 600) * 1000, t1, STEP_S * 1000)
-    panels = [(("_ns_",), (), "sum"),
-              (("dc",), (), "avg"),
-              (("_ns_", "dc"), (), "sum"),
-              (("dc",), (), "count"),
-              (("_ns_",), (), "max")]     # not fusable: per-panel fallback
-    want = []
-    for by, wo, op in panels:
-        pk = ex.lookup_and_pack(filters, t0, t1, by=by, without=wo,
-                                fn_name="rate")
-        want.append(ex.run_agg(pk, wends, range_ms=300_000,
-                               fn_name="rate", agg_op=op))
-    k0 = registry.counter("mesh_fused_kernel").value
-    b0 = registry.counter("mesh_fused_batch_panels").value
-    got = ex.run_agg_batch(filters, t0, t1, wends, range_ms=300_000,
-                           fn_name="rate", panels=panels)
-    assert registry.counter("mesh_fused_batch_panels").value - b0 >= 3, \
-        "fusable panels did not merge"
-    assert registry.counter("mesh_fused_kernel").value - k0 == 1, \
-        "merged panels must cost ONE kernel dispatch"
-    for (by, wo, op), (w_out, w_labels), (g_out, g_labels) in \
-            zip(panels, want, got):
-        key = (by, op)
-        assert [dict(l) for l in g_labels] == [dict(l) for l in w_labels], key
-        assert g_out.shape == w_out.shape, key
-        np.testing.assert_allclose(g_out, w_out, rtol=1e-6, atol=1e-9,
-                                   equal_nan=True, err_msg=str(key))
-    # warm repeat (the dashboard refresh loop): per-panel remaps and the
-    # merged gid upload come from _batch_gid_cache; results identical
-    again = ex.run_agg_batch(filters, t0, t1, wends, range_ms=300_000,
-                             fn_name="rate", panels=panels)
-    for (g_out, _), (a_out, _) in zip(got, again):
-        np.testing.assert_array_equal(g_out, a_out)

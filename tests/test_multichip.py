@@ -8,31 +8,23 @@ marker auto-skips below 2 local devices so tier-1 stays green on
 What it proves (doc/multichip.md):
   - the full engine with sharded DeviceMirrors + per-device fused
     dispatch returns BIT-IDENTICAL results to the unsharded engine for
-    dense, ragged and histogram `sum/max/avg by (rate())` shapes;
-  - the MeshExecutor per-device dispatch path matches the general mesh
-    path and actually fans out one kernel per device;
+    dense, ragged, gauge and histogram shapes, through the fused kernel
+    (dense, ragged, `reduce_window`) and the general path;
   - the partial-only collective merge equals the host-side
     ops/agg.reduce_phase merge;
   - a device-pinned DeviceMirror round-trips the shard partition's
-    columns bit-exactly from its assigned device;
-  - PackedShards packing is memoized per (shard-set, keys-generation):
-    a re-poll after value-only ingest hits the layout memo
-    (the ISSUE-6 acceptance gate).
+    columns bit-exactly from its assigned device.
 """
 import numpy as np
 import pytest
 
 import jax
 
-from filodb_tpu.core.index import Equals
 from filodb_tpu.core.memstore import TimeSeriesMemStore
 from filodb_tpu.core.records import RecordBatch
 from filodb_tpu.ingest.generator import (counter_batch, gauge_batch,
                                          histogram_batch)
-from filodb_tpu.ops.timewindow import make_window_ends
-from filodb_tpu.parallel.mesh import (MeshExecutor, make_mesh,
-                                      merge_device_partials)
-from filodb_tpu.parallel.shardmapper import ShardEvent, ShardMapper
+from filodb_tpu.parallel.mesh import make_mesh, merge_device_partials
 from filodb_tpu.utils.metrics import registry
 
 from test_query_engine import _mk_engine, START_MS, START_S, NUM_SAMPLES
@@ -43,9 +35,16 @@ QEND_S = START_S + 3600
 STEP_S = 60
 
 
+RAGGED_METRIC = "flaky_total"
+
+
 def _ragged_counter_batch(num_series, num_samples, seed=7):
+    """Counters with a tenth of their samples NaN, under a metric of their
+    own: under `request_total` the rows would carry the dense batch's keys
+    and timestamps, and the shard drops such a batch whole."""
     rng = np.random.default_rng(seed)
-    cb = counter_batch(num_series, num_samples, start_ms=START_MS, seed=seed)
+    cb = counter_batch(num_series, num_samples, start_ms=START_MS, seed=seed,
+                       metric=RAGGED_METRIC)
     v = cb.columns["count"].copy()
     v[rng.random(v.shape) < 0.1] = np.nan
     return RecordBatch(cb.schema, cb.part_keys, cb.part_idx, cb.timestamps,
@@ -58,57 +57,121 @@ def _series_map(res):
             for k, _, v in res.series()}
 
 
-QUERIES = [
-    'sum by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
-    'avg by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
-    'max by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
-    'sum by (instance) (increase(request_total{_ws_="demo",_ns_="App-0"}[10m]))',
-    'histogram_quantile(0.9, sum by (_ns_) (rate(http_latency{_ws_="demo"}[5m])))',
-]
+# case -> (query, the leaf counters that must move on the sharded engine
+# when the fused kernel serves: the route the case is there for)
+QUERIES = {
+    "sum-rate": (
+        'sum by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
+        ("leaf_fused_kernel",)),
+    "avg-rate": (
+        'avg by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
+        ("leaf_fused_kernel",)),
+    "max-rate": (
+        'max by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
+        ("leaf_fused_kernel",)),
+    "sum-increase-by-instance": (
+        'sum by (instance) (increase('
+        'request_total{_ws_="demo",_ns_="App-0"}[10m]))',
+        ("leaf_fused_kernel",)),
+    "hist-quantile": (
+        'histogram_quantile(0.9, sum by (_ns_) ('
+        'rate(http_latency{_ws_="demo"}[5m])))',
+        ("leaf_hist_fused",)),
+    # an *_over_time sum through the fused kernel, over a gauge column
+    "gauge-sum-over-time": (
+        'sum by (_ns_) (sum_over_time(heap_usage{_ws_="demo"}[5m]))',
+        ("leaf_fused_kernel",)),
+    # the ragged kernel's presence output; avg dividing by counts
+    "ragged-count-rate": (
+        'count by (_ns_) (rate(%s{_ws_="demo"}[5m]))' % RAGGED_METRIC,
+        ("leaf_fused_kernel", "leaf_ragged_fused")),
+    "ragged-avg-rate": (
+        'avg by (_ns_) (rate(%s{_ws_="demo"}[5m]))' % RAGGED_METRIC,
+        ("leaf_fused_kernel", "leaf_ragged_fused")),
+    # the reduce_window route
+    "gauge-min-min-over-time": (
+        'min by (_ns_) (min_over_time(heap_usage{_ws_="demo"}[5m]))',
+        ("leaf_fused_minmax",)),
+    # the general path over sharded mirrors
+    "stddev-rate": (
+        'stddev by (_ns_) (rate(request_total{_ws_="demo"}[5m]))',
+        ("leaf_general_path",)),
+}
 
 
-@pytest.mark.parametrize("fused_kernel", [False, True],
-                         ids=["general", "fused-kernel"])
-def test_engine_sharded_mirrors_bit_parity(monkeypatch, fused_kernel):
-    """The engine with per-shard device-pinned mirrors (the sharded
-    DeviceMirror mode feeding the per-device dispatch) must return
-    bit-identical results to the unsharded engine — same leaves, same
-    partial merges, only the executing device differs."""
-    def batches():
-        return [counter_batch(96, NUM_SAMPLES, start_ms=START_MS),
-                _ragged_counter_batch(32, NUM_SAMPLES, seed=11),
-                histogram_batch(24, NUM_SAMPLES, num_buckets=8,
-                                start_ms=START_MS)]
-
-    if fused_kernel:
-        monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    monkeypatch.delenv("FILODB_TPU_FORCE_SHARDED_MIRROR", raising=False)
-    eng_flat = _mk_engine(batches(), num_shards=4, spread=2)
-    flat = {q: _series_map(eng_flat.query_range(q, START_S + 600, STEP_S,
-                                                QEND_S)) for q in QUERIES}
-
-    monkeypatch.setenv("FILODB_TPU_FORCE_SHARDED_MIRROR", "1")
-    eng_shard = _mk_engine(batches(), num_shards=4, spread=2)
-    sharded = {q: _series_map(eng_shard.query_range(q, START_S + 600,
-                                                    STEP_S, QEND_S))
-               for q in QUERIES}
-
-    for q in QUERIES:
-        assert flat[q].keys() == sharded[q].keys(), q
-        for k, want in flat[q].items():
-            np.testing.assert_array_equal(sharded[q][k], want,
-                                          err_msg=f"{q} {k}")
-
-    # the mirrors really are partitioned: the shards' stores must sit on
-    # more than one device
+def _mirror_devices(eng):
     devs = set()
     for s in range(4):
-        sh = eng_shard.source.get_shard("prometheus", s)
+        sh = eng.source.get_shard("prometheus", s)
         for store in sh.stores.values():
             m = getattr(store, "device_mirror", None)
             if m is not None and m.device is not None:
                 devs.add(m.device)
+    return devs
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["general", "fused-kernel"])
+def parity_engines(request):
+    """(unsharded engine, sharded engine, fused_kernel): the two engines
+    of a mode, built once over the same batches.  A store's mirror is made
+    (and placed, or not) at its first query, so every query of the sharded
+    engine runs under FILODB_TPU_FORCE_SHARDED_MIRROR and none of the
+    other's does (`_ask`)."""
+    def batches():
+        return [counter_batch(96, NUM_SAMPLES, start_ms=START_MS),
+                _ragged_counter_batch(32, NUM_SAMPLES, seed=11),
+                gauge_batch(48, NUM_SAMPLES, start_ms=START_MS),
+                histogram_batch(24, NUM_SAMPLES, num_buckets=8,
+                                start_ms=START_MS)]
+
+    eng_flat = _mk_engine(batches(), num_shards=4, spread=2)
+    eng_shard = _mk_engine(batches(), num_shards=4, spread=2)
+    with pytest.MonkeyPatch.context() as mp:
+        _ask(mp, eng_shard, QUERIES["sum-rate"][0], True, request.param)
+    # the mirrors really are partitioned: the shards' stores must sit on
+    # more than one device
+    devs = _mirror_devices(eng_shard)
     assert len(devs) >= 2, f"mirrors not spread across devices: {devs}"
+    return eng_flat, eng_shard, request.param
+
+
+def _ask(monkeypatch, eng, query, sharded, fused_kernel):
+    if fused_kernel:
+        monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("FILODB_TPU_FUSED_INTERPRET", raising=False)
+    if sharded:
+        monkeypatch.setenv("FILODB_TPU_FORCE_SHARDED_MIRROR", "1")
+    else:
+        monkeypatch.delenv("FILODB_TPU_FORCE_SHARDED_MIRROR", raising=False)
+    return _series_map(eng.query_range(query, START_S + 600, STEP_S, QEND_S))
+
+
+@pytest.mark.parametrize("case", list(QUERIES))
+def test_engine_sharded_mirrors_bit_parity(monkeypatch, parity_engines,
+                                           case):
+    """The engine with per-shard device-pinned mirrors (the sharded
+    DeviceMirror mode feeding the per-device dispatch) must return
+    bit-identical results to the unsharded engine — same leaves, same
+    partial merges, only the executing device differs."""
+    eng_flat, eng_shard, fused_kernel = parity_engines
+    query, route = QUERIES[case]
+    flat = _ask(monkeypatch, eng_flat, query, False, fused_kernel)
+    assert not _mirror_devices(eng_flat)
+    watched = ("leaf_host_gather", "leaf_fused_errors") + route
+    before = {c: registry.counter(c).value for c in watched}
+    sharded = _ask(monkeypatch, eng_shard, query, True, fused_kernel)
+    moved = {c: registry.counter(c).value - before[c] for c in watched}
+
+    assert flat and flat.keys() == sharded.keys()
+    for k, want in flat.items():
+        assert np.isfinite(want).any(), k
+        np.testing.assert_array_equal(sharded[k], want, err_msg=str(k))
+    # every leaf read its shard's mirror
+    assert moved["leaf_host_gather"] == 0 and moved["leaf_fused_errors"] == 0
+    if fused_kernel:
+        assert all(moved[c] > 0 for c in route), moved
 
 
 def test_mirror_placer_prefers_home_and_respects_hbm_cap():
@@ -172,72 +235,6 @@ def test_mirror_shard_partition_roundtrip():
     assert placer.booked(dev) >= 0
 
 
-def _mk_store4(n_series=64, ragged=False):
-    ms = TimeSeriesMemStore()
-    mapper = ShardMapper(4)
-    for s in range(4):
-        ms.setup("prometheus", s)
-        mapper.update_from_event(
-            ShardEvent("IngestionStarted", "prometheus", s, "local"))
-    batch = (_ragged_counter_batch(n_series, NUM_SAMPLES)
-             if ragged else counter_batch(n_series, NUM_SAMPLES,
-                                          start_ms=START_MS))
-    shard_of_key = np.asarray([
-        mapper.ingestion_shard(pk.shard_key_hash(), pk.partition_hash(), 2)
-        for pk in batch.part_keys])
-    for s in range(4):
-        keep = shard_of_key[batch.part_idx] == s
-        if keep.any():
-            sub = RecordBatch(batch.schema, batch.part_keys,
-                              batch.part_idx[keep], batch.timestamps[keep],
-                              {k: v[keep] for k, v in
-                               batch.columns.items()},
-                              batch.bucket_les)
-            ms.get_shard("prometheus", s).ingest(sub)
-    return ms
-
-
-@pytest.mark.parametrize("ragged", [False, True], ids=["dense", "ragged"])
-def test_mesh_perdevice_dispatch_parity_and_fanout(monkeypatch, ragged):
-    """run_agg's fused route must dispatch the single-chip kernel once
-    per mesh device (never inside shard_map) and match the general mesh
-    path."""
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    ms = _mk_store4(ragged=ragged)
-    mesh = make_mesh(4, 2, devices=jax.devices()[:8])
-    ex = MeshExecutor(ms, "prometheus", mesh)
-    filters = [Equals("_metric_", "request_total")]
-    packed = ex.lookup_and_pack(filters, START_MS, QEND_S * 1000,
-                                by=("_ns_",), fn_name="rate")
-    assert packed.shared_ts_row is not None
-    assert packed.dense is (not ragged)
-    wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                             STEP_S * 1000)
-    k0 = registry.counter("mesh_fused_kernel").value
-    d0 = registry.counter("mesh_fused_perdevice_dispatches").value
-    fused, labels = ex.run_agg(packed, wends, range_ms=300_000,
-                               fn_name="rate", agg_op="sum")
-    assert registry.counter("mesh_fused_kernel").value == k0 + 1
-    assert registry.counter("mesh_fused_perdevice_dispatches").value \
-        == d0 + 8, "per-device dispatch must fan out over all 8 devices"
-    # general mesh path over the same pack
-    from filodb_tpu.ops import agg as agg_ops
-    from filodb_tpu.parallel.mesh import distributed_window_agg
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    wends_p, W = ex._prep_wends(packed, wends)
-    wends_dev = jax.device_put(wends_p, NamedSharding(mesh, P("time")))
-    partials = distributed_window_agg(
-        mesh, packed.ts_off, packed.values, packed.group_ids, wends_dev,
-        range_ms=300_000, fn_name="rate", agg_op="sum",
-        num_groups=packed.num_groups, base_ms=packed.base_ms,
-        vbase=packed.vbase, precorrected=packed.precorrected,
-        dense=packed.dense)
-    general = np.asarray(agg_ops.present("sum", partials))[:, :W]
-    assert (np.isnan(fused) == np.isnan(general)).all()
-    np.testing.assert_allclose(fused, general, rtol=2e-5, atol=1e-4,
-                               equal_nan=True)
-
-
 def test_merge_device_partials_collective_matches_host():
     """The partial-only psum collective and the host-side reduce_phase
     merge are the same reduce — one rides ICI, one rides host memory."""
@@ -261,40 +258,6 @@ def test_merge_device_partials_collective_matches_host():
                          for s in range(4)], axis=0) for t in range(2)],
             axis=1)
         np.testing.assert_allclose(got, want, rtol=1e-6)
-
-
-def test_pack_layout_memo_hits_on_repoll():
-    """ISSUE-6 acceptance: PackedShards repack is memoized per
-    (shard-set, keys-generation) — a re-poll after value-only ingest
-    must hit the layout memo (no per-series repack)."""
-    ms = _mk_store4()
-    mesh = make_mesh(4, 2, devices=jax.devices()[:8])
-    ex = MeshExecutor(ms, "prometheus", mesh)
-    filters = [Equals("_metric_", "request_total")]
-    t0, t1 = START_MS, QEND_S * 1000
-    h0 = registry.counter("mesh_pack_memo_hits").value
-    ex.lookup_and_pack(filters, t0, t1, by=("_ns_",), fn_name="rate")
-    # value-only ingest: same series keys, new samples -> store
-    # generations move (pack cache invalidated) but keys stay
-    batch = counter_batch(64, 4,
-                          start_ms=START_MS + NUM_SAMPLES * 10_000)
-    mapper = ShardMapper(4)
-    shard_of_key = np.asarray([
-        mapper.ingestion_shard(pk.shard_key_hash(), pk.partition_hash(), 2)
-        for pk in batch.part_keys])
-    for s in range(4):
-        keep = shard_of_key[batch.part_idx] == s
-        if keep.any():
-            sub = RecordBatch(batch.schema, batch.part_keys,
-                              batch.part_idx[keep], batch.timestamps[keep],
-                              {k: v[keep] for k, v in
-                               batch.columns.items()},
-                              batch.bucket_les)
-            ms.get_shard("prometheus", s).ingest(sub)
-    ex.lookup_and_pack(filters, t0, t1 + 40_000, by=("_ns_",),
-                       fn_name="rate")
-    assert registry.counter("mesh_pack_memo_hits").value > h0, \
-        "re-poll after value-only ingest must hit the layout memo"
 
 
 def test_make_mesh_exposes_shape_and_unused_devices():

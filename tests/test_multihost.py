@@ -7,8 +7,7 @@ import pytest
 import jax
 
 from filodb_tpu.parallel import multihost
-from filodb_tpu.parallel.mesh import (MeshExecutor, device_put_packed,
-                                      make_mesh, pack_shards)
+from filodb_tpu.parallel.mesh import device_put_packed, pack_shards
 
 
 def test_initialize_single_process_is_noop():
@@ -56,12 +55,15 @@ def test_multihost_mesh_runs_spmd_agg():
     b = counter_batch(8, 120, start_ms=START)
     ms.ingest("prometheus", 0, b, offset=1)
     mesh = multihost.global_mesh(n_shard=2, n_time=2)
-    ex = MeshExecutor(ms, "prometheus", mesh)
+    from test_mesh import mesh_agg, shard_blocks
     end = START + 119 * 10_000
-    p = ex.lookup_and_pack([Equals("_metric_", "request_total")], START, end,
-                           by=("_ns_",), fn_name="rate")
+    blocks = shard_blocks(ms, [Equals("_metric_", "request_total")], START,
+                          end, counter=True)
+    p = multihost.device_put_packed_multihost(
+        pack_shards(blocks, by=("_ns_",), base_ms=START, precorrected=True),
+        mesh)
     wends = make_window_ends(START + 400_000, end, 60_000)
-    out, labels = ex.run_agg(p, wends, range_ms=300_000, fn_name="rate",
-                             agg_op="sum")
-    assert np.isfinite(np.asarray(out)).any()
-    assert len(labels) >= 1
+    out = mesh_agg(mesh, p, wends, range_ms=300_000, fn_name="rate",
+                   agg_op="sum")
+    assert np.isfinite(out).any()
+    assert len(p.group_labels) >= 1

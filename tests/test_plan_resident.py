@@ -202,41 +202,3 @@ def test_an_evicted_plan_takes_its_device_arrays_with_it(monkeypatch):
     assert not [n for n, v in vars(pf).items()
                 if isinstance(v, dict) and any(
                     isinstance(x, jax.Array) for x in v.values())]
-
-
-@pytest.mark.multichip
-@pytest.mark.parametrize("ragged", [False, True], ids=["dense", "ragged"])
-def test_the_mesh_dispatch_puts_once_a_plan_and_device(monkeypatch, ragged):
-    """parallel/mesh.py's per-device dispatch goes through the same
-    `enqueue_operands`: device (s, t) takes time slice t's plan rows at
-    the first query of a grid, and nothing at the second."""
-    from filodb_tpu.core.index import Equals
-    from filodb_tpu.parallel.mesh import MeshExecutor, make_mesh
-    from test_multichip import (QEND_S, START_MS, START_S, STEP_S,
-                                _mk_store4)
-    monkeypatch.setenv("FILODB_TPU_FUSED_INTERPRET", "1")
-    ex = MeshExecutor(_mk_store4(ragged=ragged), "prometheus",
-                      make_mesh(4, 2, devices=jax.devices()[:8]))
-    packed = ex.lookup_and_pack([Equals("_metric_", "request_total")],
-                                START_MS, QEND_S * 1000, by=("_ns_",),
-                                fn_name="rate")
-    wends = make_window_ends((START_S + 600) * 1000, QEND_S * 1000,
-                             STEP_S * 1000)
-
-    def run():
-        e0 = registry.counter("fused_enqueues").value
-        u0 = _uploads()
-        out, _ = ex.run_agg(packed, wends, range_ms=300_000, fn_name="rate",
-                            agg_op="sum")
-        assert registry.counter("fused_enqueues").value - e0 == 8
-        return out, _uploads() - u0
-
-    first, puts = run()
-    assert puts == (16 if ragged else 8)    # + tsrow where it is read
-    (plans, _, _), = ex._fused_plan_cache.values()
-    for ti, plan in enumerate(plans):
-        assert {dev for dev, _ in plan.resident} \
-            == set(ex.mesh.devices[:, ti])
-    again, puts = run()
-    assert puts == 0
-    assert np.asarray(first).tobytes() == np.asarray(again).tobytes()
