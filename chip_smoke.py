@@ -438,6 +438,89 @@ def timed_queries(cli, name, path, promql, want, kind, end_s, expect_fused,
     return times
 
 
+# TSBS devops `double-groupby` (benchmark cell tsbscpu-gauges-40k.double-
+# groupby): rows of 13 h 9 min at a 10 s interval, the last twelve hours at
+# a one-hour step, a group a host.  A dataset of its own: rows of another
+# length in the smoke's dataset would put its mirrors on a placed grid
+TSBS_DATASET, TSBS_T = "tsbs", 4_736
+TSBS_RANGE_MS, TSBS_STEP_S, TSBS_SPAN_S = 3_600_000, 3_600, 43_200
+
+
+def double_groupby(server, cli, hosts):
+    """`avg by (hostname)(avg_over_time(cpu_usage_user[1h]))` over `hosts`
+    rows of 4,736 samples through the HTTP door, every cell against the f64
+    reference: the fused kernel must serve it, its band built in tiles (five
+    whole [4736, 128] matrices do not fit the chip's vector memory), and
+    nothing may take the general XLA path."""
+    from benchmark.generators.clamped_walk import chunk as clamped_walk
+    mapper, spread = server.mappers[TSBS_DATASET], \
+        server.spreads[TSBS_DATASET]
+    ts_row = START_MS + np.arange(TSBS_T, dtype=np.int64) * STEP_MS
+    keys = [PartKey.make("cpu_usage_user", {
+        "_ws_": "tsbs", "_ns_": f"service-{i % 20}", "hostname": f"host_{i}"})
+        for i in range(hosts)]
+    shard_of = np.fromiter(
+        (mapper.ingestion_shard(pk.shard_key_hash(), pk.partition_hash(),
+                                spread.spread_for(pk.shard_key()))
+         for pk in keys), np.int64, hosts)
+    vals = clamped_walk(np.random.default_rng([ARGS.seed, 48]),
+                        np.empty((hosts, TSBS_T)))
+    per_shard = []
+    for sh in server.memstore.shards_for(TSBS_DATASET):
+        idx = np.flatnonzero(shard_of == sh.shard_num)
+        per_shard.append(int(idx.size))
+        if idx.size:
+            got = sh.ingest_columns(
+                "gauge", [keys[i] for i in idx],
+                np.broadcast_to(ts_row, (idx.size, TSBS_T)),
+                {"value": vals[idx]}, offset=0)
+            assert got == idx.size * TSBS_T, (got, idx.size * TSBS_T)
+    csum = np.cumsum(vals, axis=1)
+    promql = "avg by (hostname)(avg_over_time(cpu_usage_user[1h]))"
+    path = f"/promql/{TSBS_DATASET}/api/v1/query_range"
+    times, worst = [], 0.0
+    # window ends whole seconds off the grid, as a query's `end` is `now`;
+    # cold, then warm at another end (the result cache must not answer)
+    for end_s in (int(ts_row[-1]) // 1000 - 17, int(ts_row[-1]) // 1000 - 4):
+        wends = end_s * 1000 - np.arange(
+            TSBS_SPAN_S // TSBS_STEP_S + 1, dtype=np.int64)[::-1] \
+            * TSBS_STEP_S * 1000
+        want = ref_sum_over_time(ts_row, csum, wends, TSBS_RANGE_MS) \
+            / (TSBS_RANGE_MS // STEP_MS)
+        before = cli.counters()
+        body, secs = cli.query(path, query=promql, start=end_s - TSBS_SPAN_S,
+                               end=end_s, step=TSBS_STEP_S)
+        after = cli.counters()
+        d = route_delta(before, after)
+        times.append(secs)
+        rows = body["data"]["result"]
+        assert len(rows) == hosts, (len(rows), hosts)
+        for row in rows:
+            i = int(row["metric"]["hostname"].split("_")[1])
+            got = np.array([float(v) for _, v in row["values"]])
+            assert [int(float(t)) for t, _ in row["values"]] \
+                == (wends // 1000).tolist(), (i, "timestamps differ")
+            err = np.abs(got - want[i])
+            assert (err <= TOL["other"]["atol"]
+                    + TOL["other"]["rtol"] * np.abs(want[i])).all(), \
+                (i, got, want[i])
+            worst = max(worst, float((err / np.maximum(np.abs(want[i]),
+                                                       1e-300)).max()))
+        tiles = int(after.get("fused_band_tiles_total", 0)
+                    - before.get("fused_band_tiles_total", 0))
+        assert d.get("leaf_fused_kernel_total", 0) >= 1 and tiles >= 1, \
+            (d, tiles)
+        for bad in ("leaf_general_path_total", "leaf_fused_errors_total",
+                    "leaf_host_gather_total", "leaf_inexact_times_total",
+                    "leaf_host_routed_total"):
+            assert after.get(bad, 0) == before.get(bad, 0), (bad, d)
+    emit("query", name="double-groupby", promql=promql, hosts=hosts,
+         samples=TSBS_T, series_per_shard=per_shard,
+         checked_vs_f64_reference=True, max_rel_err=worst,
+         first_s=round(times[0], 4), warm_s=round(times[1], 4),
+         route_warm=d, fused_kernel=True, band_tiles=tiles)
+
+
 def cache_state(path):
     n = len(os.listdir(path)) if path and os.path.isdir(path) else 0
     return {"dir": path, "entries": n}
@@ -474,7 +557,8 @@ def main():
             cache_hits["miss"] += 1
     jax.monitoring.register_event_listener(on_event)
 
-    server = FiloServer([DatasetConfig(DATASET, 4)],
+    server = FiloServer([DatasetConfig(DATASET, 4),
+                         DatasetConfig(TSBS_DATASET, 4)],
                         http_host="127.0.0.1", http_port=0)
     cache_dir = apply_jax_runtime(server.config)
     cache0 = cache_state(cache_dir)
@@ -648,6 +732,10 @@ def run_one_chip(server, cli, S, T, S_gauge, n_write):
          seconds=round(secs, 4), route=d,
          note="a raw selector under query.host_route_max_samples, gathered "
               "on the host by design: not a device check")
+
+    # --- rows of thirteen hours, a group a host: the band in tiles.  Every
+    # shard's leaf must scan more than query.host_route_max_samples
+    double_groupby(server, cli, 64 if ARGS.rehearse else 4_000)
 
     # --- the device, by the program's own telemetry
     m2 = cli.counters()

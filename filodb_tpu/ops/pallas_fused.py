@@ -13,7 +13,9 @@ whole thing in ONE read of the values, from a few host-built window rows
   selected by the same gathers (drops[s, t] = max(prev - cur, 0) is local
   once rows are dense)
 - *_over_time window sums  ->  v @ band, band[t, w] = 1{first[w] <= t <=
-  last[w]}, a 0/1 matrix built on the device
+  last[w]}, a 0/1 matrix built on the device: whole, before the kernel
+  (the resident form), or 512 columns at a time inside it (the tiled
+  form, `_band_dot`), whichever fits the larger series block (`band_form`)
 - group segment-sum  ->  onehot(gids) @ rate  on the MXU
 
 Preconditions (the caller gates, see `can_fuse`): one scrape grid across
@@ -29,6 +31,7 @@ Works on CPU via interpret=True (tests); on TPU via the MXU.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from typing import NamedTuple, Optional, Tuple
@@ -108,6 +111,22 @@ def _dot_split3(a, b):
     that f32 storage of the values already imposes on every path."""
     hi, mid, lo = _split3(b)
     return _dot_1p(a, hi) + _dot_1p(a, mid) + _dot_1p(a, lo)
+
+
+def _dot_values_split3(a, b):
+    """values x binary (the tiled band's window sums, `_band_dot`):
+    `_dot_split3` with the values on the left, each of their three parts
+    summed on its OWN: a window's hi parts (eight bits each) add up in the
+    MXU's f32 accumulator without a rounding (360 samples of size 100 need
+    22 bits), the mid and lo parts are 2^-9 and 2^-18 of them, and only the
+    three sums' addition rounds, once a cell.  `_dot_hi` splits both sides
+    (six passes, three of them against the zeros of a 0/1 operand's mid and
+    lo) into ONE accumulator, where every addition rounds at the size of
+    the running sum: over an hour of a 10 s scrape rebased by 50 the mean
+    came out 5e-5 off, which is all of an hourly mean near 1 that was read
+    as 2e-5 relative (PERF.md section 6, PR 48)."""
+    hi, mid, lo = _split3(a)
+    return _dot_1p(hi, b) + _dot_1p(mid, b) + _dot_1p(lo, b)
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -423,7 +442,8 @@ def _tile_ranges(xp, rows, Tp: int, phased: bool, c0=None):
 
 
 def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
-                    ragged: bool = False, Tq: Optional[int] = None):
+                    ragged: bool = False, Tq: Optional[int] = None,
+                    tiled: bool = False):
     """The 12 operands `_kernel` reads after (vals, vbase, gids), from a
     plan's uploaded rows; `phased` (rows is the plan's [16, Wp] `prows`):
     13, the last being the rows themselves for the kernel's slacks, with
@@ -470,7 +490,12 @@ def kernel_operands(rows, tsrow, Tp: int, kind: str, phased: bool = False,
     band_only = ragged and kind == "rate_family"
     if _selects_by_gather(kind) and not band_only:
         sel = (stand_in,) * 4
+    elif tiled and not band_only:
+        valid = row(_N1) >= 1.0
+        sel = (jnp.where(valid, rel(_I1), float(Tq)),
+               jnp.where(valid, rel(_I2), -1.0)) + (stand_in,) * 2
     else:
+        # (in this order: a resident program's text is its parent's)
         t = jax.lax.broadcasted_iota(jnp.int32, (Tq, rows.shape[1]), 0)
         valid = row(_N1) >= 1.0
 
@@ -554,7 +579,8 @@ def gathers(kind: str, ragged: bool, phased: bool) -> int:
 def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
                      offsets=None, sets: int = 1,
                      phased: bool = False,
-                     cols: Optional[int] = None) -> tuple:
+                     cols: Optional[int] = None,
+                     band_tiles: int = 0) -> tuple:
     """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
     takes from the host, as device arrays (so the call itself transfers
     nothing).  The plan's own operands are put on a device once and stay
@@ -581,7 +607,9 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
     (`_load_cols` of `cols`, the launch's Tq: the plan's unless the flavor
     takes the row; over the enqueues: 640 a launch, of which 512 are
     computed over, for an hour of `[5m]` at 60 s over a 768-slot row; 768
-    before a block followed the windows' reach)."""
+    before a block followed the windows' reach), and the tiles of band its
+    program builds (`band_tiles`, from `launch_band_tiles`) on
+    `fused_band_tiles_total`: 0 where every set holds its band resident."""
     from filodb_tpu.utils.metrics import registry
     registry.counter("fused_enqueues").increment()
     registry.counter("fused_enqueue_sets").increment(sets)
@@ -594,6 +622,8 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
     steps = scan_steps(plan, kind, ragged, phased)
     if steps:
         registry.counter("fused_ragged_scan_steps").increment(steps)
+    if band_tiles:
+        registry.counter("fused_band_tiles").increment(band_tiles)
     held, uploads = plan.resident, 0
 
     def resident(which):
@@ -749,6 +779,45 @@ def _gather_cols(src, idx, tiles):
     return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
 
 
+_BAND_COLS = 512
+"""The columns of a row one product of the tiled band covers: a [512, Wp]
+tile of 0/1 is 256 KB at one tile of windows, where the whole band of a
+13-hour row is 2.4 MB and the resident form holds five of its size."""
+
+
+def _band_dot(src, first, last, products):
+    """[dot(f(src), band) for (dot, f) in products], band[t, w] =
+    1{first[w] <= t <= last[w]}, without the band: `_BAND_COLS` columns
+    of the [bs, Tq] REF `src` at a time (each mapped by `f`, None: as they
+    are) against the tile of the band that two compares of an iota with
+    the [1, Wp] slot rows make, summed in tile order.  The whole tiles in
+    a loop (one body, so one set of temporaries: unrolled, Mosaic keeps
+    every tile's operands and the kernel's scoped memory grows with the
+    row), the columns left over after them once more.  What the resident
+    form's one product over [Tq, Wp] selects, in another order of f32
+    sums (exact where `dot` counts 0/1)."""
+    first, last = first.astype(jnp.int32), last.astype(jnp.int32)
+    (bs, Tq), Wp = src.shape, first.shape[1]
+
+    def tile(k, cols, accs):
+        t = jax.lax.broadcasted_iota(jnp.int32, (cols, Wp), 0) + k
+        band = ((t >= first) & (t <= last)).astype(jnp.float32)
+        x = src[:, pl.ds(k, cols)]
+        return tuple(acc + dot(x if f is None else f(x), band)
+                     for acc, (dot, f) in zip(accs, products))
+
+    accs = (jnp.zeros((bs, Wp), jnp.float32),) * len(products)
+    whole = Tq // _BAND_COLS
+    if whole:
+        accs = jax.lax.fori_loop(
+            0, whole, lambda i, accs: tile(
+                pl.multiple_of(i * _BAND_COLS, _BAND_COLS), _BAND_COLS,
+                accs), accs)
+    if Tq % _BAND_COLS:
+        accs = tile(whole * _BAND_COLS, Tq % _BAND_COLS, accs)
+    return accs
+
+
 def _gathers_values(kind: str, ragged: bool, phased: bool,
                     with_drops: bool) -> bool:
     """Whether some `_gather_cols` of the flavor reads the values block
@@ -763,7 +832,8 @@ def _gathers_values(kind: str, ragged: bool, phased: bool,
 
 
 def parked(kind: str, ragged: bool, phased: bool, with_drops: bool,
-           looped: bool = True, turned: bool = False) -> int:
+           looped: bool = True, turned: bool = False,
+           tiled: bool = False) -> int:
     """The [bs, Tp] scratch blocks one grid step of `_kernel` parks
     computed arrays in for a `_gather_cols` that loops (`gather_loops`;
     the values block itself is gathered in place; an unrolled gather reads
@@ -775,8 +845,12 @@ def parked(kind: str, ragged: bool, phased: bool, with_drops: bool,
     place are no longer the loaded block but its turn, a computed array
     like the others: one block more wherever they are (`_gathers_values`)."""
     if not looped:
-        return 0
-    turn = int(turned and _gathers_values(kind, ragged, phased, with_drops))
+        # (the tiled band reads its columns off a block whatever the
+        # gathers do: the turn is parked for it)
+        return int(tiled and turned)
+    # (... and where the gathers loop, unless one had it parked already)
+    turn = int(turned and (tiled or _gathers_values(
+        kind, ragged, phased, with_drops)))
     if kind == "rate_family":
         return (4 + phased if ragged else int(with_drops)) + turn
     if kind == "last_over_time" or phased:
@@ -790,9 +864,13 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
             num_groups: int, is_counter: bool, is_rate: bool,
             with_drops: bool, kind: str = "rate_family",
             ragged: bool = False, per_series: bool = False,
-            phased: bool = False, steps: int, at_ref=None):
-    v = vals_ref[:]                                   # [BS, Tp]
+            phased: bool = False, steps: int, tiled: bool = False,
+            at_ref=None):
     turned = at_ref is not None
+    # (under the tiled band the block is read a tile at a time: whole
+    # only to be turned, or for a ragged phased row's corrections)
+    v = vals_ref[:] if not tiled or turned or (ragged and phased) \
+        else None                                     # [BS, Tp]
     if turned:
         # a trimmed plan's block (`_load_cols`): one tile wider than the
         # Tq columns the plan computes over and begun on a tile edge,
@@ -804,18 +882,18 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         load, below = v.shape[1], at_ref[1]
         v = pltpu.roll(v, jnp.where(below > 0, load - below, 0),
                        1)[:, :load - _LANE]
-    looped = gather_loops(v.shape[1], i1_ref.shape[1])
+    looped = gather_loops(ts_ref.shape[1], i1_ref.shape[1])
     gather = functools.partial(_gather_cols,
                                tiles=tiles_ref if looped else None)
     # after the outputs come the scratch blocks (`parked` of them): where
     # the gather loops, a computed [BS, Tp] array is stored in one to be
     # gathered from
     free = list(out_refs[len(out_refs) - parked(
-        kind, ragged, phased, with_drops, looped, turned):])
+        kind, ragged, phased, with_drops, looped, turned, tiled):])
     out_refs = out_refs[:len(out_refs) - len(free)]
 
-    def park(x):
-        if not looped:
+    def park(x, always=False):
+        if not (looped or always):
             return x
         ref = free.pop()
         ref[...] = x
@@ -889,22 +967,44 @@ def _kernel(vals_ref, vbase_ref, gids_ref, o1_ref, o2_ref, l1_ref, l2_ref,
         # Ragged (NaN-holed) rows: validity-weighted variant — zero the
         # holes, take per-(series, window) counts from a second matmul of
         # the validity mask against the same band (VERDICT r2 item 2).
-        band = l2_ref[:] - l1_ref[:] + o1_ref[:]
+        def validity(x):
+            return (x == x).astype(jnp.float32)       # NaN-aware
+
+        def zeroed(x):
+            return jnp.where(x == x, x, 0.0)
+
+        if not tiled:
+            band = l2_ref[:] - l1_ref[:] + o1_ref[:]
+        if ragged and v is not None:
+            validf, vz = validity(v), zeroed(v)
+        if tiled:
+            # ... the same band, never whole: made a tile of columns at a
+            # time from its two slot rows, which ride in o1's and o2's
+            # place (kernel_operands), a product a tile (`_band_dot`) of
+            # the values where they lie: the block, or its turn, parked
+            vref = vals_ref
+            if turned:
+                vref = vsrc if looped and _gathers_values(
+                    kind, ragged, phased, with_drops) else park(v, True)
+            prods = _band_dot(
+                vref, o1_ref[:], o2_ref[:],
+                ((_dot_values_split3, zeroed), (_dot_1p, validity))
+                if ragged else ((_dot_values_split3, None),))
+        else:
+            prods = (_dot_hi(vz, band), _dot_1p(validf, band)) if ragged \
+                else (_dot_hi(v, band),)
+        s = prods[0]
         if ragged:
-            validf = (v == v).astype(jnp.float32)     # NaN-aware
-            vz = jnp.where(v == v, v, 0.0)
-            s = _dot_hi(vz, band)
-            n = _dot_1p(validf, band)                  # [BS, Wp] valid counts
+            n = prods[1]                               # [BS, Wp] valid counts
             if phased:
                 s = corrected(s, park(vz))
                 n = corrected(n, park(validf))
             pres = (n > 0).astype(jnp.float32)
         elif phased:
-            s = corrected(_dot_hi(v, band), vsrc)
+            s = corrected(s, vsrc)
             n = slots
             pres = (n > 0).astype(jnp.float32)
         else:
-            s = _dot_hi(v, band)
             n = n_ref[:]                              # [1, Wp] true counts
             pres = None
         if kind == "sum_over_time":
@@ -1058,6 +1158,15 @@ def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
         out_refs[1][:] += _dot_1p(onehot, pres)
 
 
+def _set_forms(sets, num_groups, load: int, Wp: int, kind: str,
+               ragged: bool, phased: bool, with_drops: bool) -> list:
+    """Whether each working set of a `_run` call takes the tiled band
+    (`band_form`, by the columns loaded): what `_run` traces its sets by
+    and an enqueue books (`launch_band_tiles`)."""
+    return [band_form(load, Wp, Gp, kind, ragged, len(st[2]), phased,
+                      with_drops)[1] for st, Gp in zip(sets, num_groups)]
+
+
 def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
                    phased: bool = False, steps: int = 0,
                    Tq: Optional[int] = None) -> str:
@@ -1104,16 +1213,22 @@ def _run(sets, offsets, rows, tsrow, *,
     (`_col_offset`: data, so a dashboard that moves along the row with
     the newest sample runs one program)."""
     Tp = sets[0][0].shape[1]
-    operands = kernel_operands(rows, tsrow, Tp, kind, phased, ragged, Tq)
+    # the band's form is a set's own (`band_form`: its group count has a
+    # say), the operands are a form's: built once for each form some set
+    # takes, and nearly always that is one
+    forms = _set_forms(sets, num_groups, _load_cols(Tq or Tp, Tp),
+                       rows.shape[1], kind, ragged, phased, with_drops)
+    operands = {tiled: kernel_operands(rows, tsrow, Tp, kind, phased, ragged,
+                                       Tq, tiled) for tiled in set(forms)}
     outs, p0 = [], 0
-    for st, Gp in zip(sets, num_groups):
+    for st, Gp, tiled in zip(sets, num_groups, forms):
         vals_p, vbase_p, gids = st[:3]
         offs = None
         if offsets is not None and len(gids) > 1:
             offs = offsets[p0:p0 + len(gids)]
         p0 += len(gids)
         outs.append(_run_set(
-            vals_p, vbase_p, merge_gid_cols(gids, offs), operands,
+            vals_p, vbase_p, merge_gid_cols(gids, offs), operands[tiled],
             rows.shape[1], Gp, st[3] if phased else None,
             is_counter=is_counter, is_rate=is_rate,
             with_drops=with_drops, interpret=interpret, kind=kind,
@@ -1161,8 +1276,9 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     # every smaller power-of-two block divides.
     # (sized by the columns LOADED: the block's two buffers are that wide,
     # and a bound on them bounds the narrower arrays computed from them)
-    bs = pick_block(load, Wp, Gp, kind, ragged, panels=gids_p.shape[1],
-                    phased=phased, with_drops=with_drops)
+    bs, tiled = band_form(load, Wp, Gp, kind, ragged,
+                          panels=gids_p.shape[1], phased=phased,
+                          with_drops=with_drops)
     if bs is None:
         if interpret:
             bs = _MIN_BS            # no scoped-vmem limit off-chip
@@ -1200,7 +1316,7 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     kern = functools.partial(_kernel, num_groups=Gp, is_counter=is_counter,
                              is_rate=is_rate, with_drops=with_drops,
                              kind=kind, ragged=ragged, per_series=per_series,
-                             phased=phased, steps=steps)
+                             phased=phased, steps=steps, tiled=tiled)
     with_counts = ragged or phased       # presence rides a second output
     if per_series:
         out_spec = pl.BlockSpec((bs, Wp), lambda i, *_: (i, 0), **space)
@@ -1225,7 +1341,7 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((bs, Tq), jnp.float32)] * parked(
             kind, ragged, phased, with_drops, gather_loops(Tq, Wp),
-            trimmed))
+            trimmed, tiled))
     args = (vals_p, vbase_p, gids_p, o1, o2, l1, l2, t1, t2, n, ws, we, ts,
             idx1, idx2, tiles, *((operands[13], phase_p) if phased else ()))
     if trimmed:
@@ -1245,7 +1361,7 @@ VMEM_BUDGET = 12 << 20          # per-core VMEM is ~16MB; leave headroom
 def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
                   ragged: bool = False, bs: int = _BS,
                   panels: int = 1, phased: bool = False,
-                  with_drops: bool = False) -> int:
+                  with_drops: bool = False, tiled: bool = False) -> int:
     """Rough resident-bytes model for one grid step: the band kinds' 4
     selection matrices and band temporary (the gather kinds ship 4 KB
     stand-ins; the ragged rate family reads ONE [Tp, Wp] band, held in
@@ -1270,9 +1386,17 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     a 10 s scrape under 721 windows): the dense rate family takes 3.09 MiB
     of temporaries beside 2.25 of block buffers at 128 rows (256 rows are
     over the budget), the dense phased one 5.31 + 2.25, against estimates
-    of 6.9 and 9.3 MiB.  The band kinds hold five [Tp, Wp] matrices, 35 MB
-    there: they divert by this estimate, as the ragged rate family on one
-    shared row does past Wp=256 at Tp=2304 (its band twice: 14 MB).
+    of 6.9 and 9.3 MiB.  The band kinds' resident form holds five [Tp, Wp]
+    matrices, 35 MB there and 12.1 MB at a 13-hour row under one tile of
+    windows (Tp=4736, Wp=128); `tiled` estimates the form that holds none
+    (`_band_dot`: one [512, Wp] tile of band at a time and what a tile's
+    product reads), and `band_form` takes whichever fits the larger block.
+    Mosaic's own need for the tiled program at Tp=4736, Wp=128, Gp=1024,
+    128 rows is between 5.7 and 6.0 MiB (the block's two buffers 4.6 of
+    it) against 8.62 estimated; tests/test_chip_compile.py compiles it,
+    and the shapes beside it, under a scoped limit SET AT the estimate.
+    The ragged rate family on one shared row still diverts past Wp=256 at
+    Tp=2304 (its band twice: 14 MB).
     A trimmed plan's kernel (`_col_reach`) is estimated at the columns it
     LOADS (`_load_cols`: `_run_set` picks its block by them), a tile more
     than it computes over: at 640 of 768 Mosaic takes 0.62 MiB of buffers
@@ -1284,6 +1408,19 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     sel = 4 * 8 * _LANE * 4 if _selects_by_gather(kind) else \
         5 * Tp * Wp * 4
     vals = 2 * bs * Tp * 4
+    if tiled and not _selects_by_gather(kind):
+        # the tiled band (`_band_dot`): of the band one tile of columns,
+        # as an iota, two compares and the 0/1 they make; of the values,
+        # beside the block's two buffers, what a tile's product reads
+        # (the arrays a ragged or phased row's band is taken over are
+        # whole: the holes zeroed, the validity, the turned block)
+        sel = 4 * min(_BAND_COLS, Tp) * Wp * 4
+        if ragged:
+            vals += 3 * bs * Tp * 4
+        elif phased:
+            vals += bs * Tp * 4
+        else:
+            vals += 3 * bs * min(_BAND_COLS, Tp) * 4
     if ragged and kind == "rate_family":
         sel += 2 * Tp * Wp * 4
         vals += 19 * bs * Tp * 4
@@ -1312,24 +1449,58 @@ def vmem_estimate(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
     return sel + vals + group + inter + (Gp * Wp * 8 if phased else 0)
 
 
+@functools.lru_cache(maxsize=1024)
+def band_form(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
+              ragged: bool = False, panels: int = 1, phased: bool = False,
+              with_drops: bool = False) -> Tuple[Optional[int], bool]:
+    """-> (bs, tiled): the largest series block whose vmem_estimate fits
+    VMEM_BUDGET (None when even _MIN_BS does not: the caller must divert
+    to the general path), and the form of the band it was sized by.  The
+    over_time kinds ("band kinds") hold the [Tp, Wp] band whole, as four
+    matrices and their difference (the resident form), or never (the
+    tiled form, `_band_dot`).  From the shape alone: the form that fits
+    the larger block, and the resident one where both fit the same, so a
+    plan the resident form took whole blocks of keeps its program and its
+    answers bit for bit (an hour of a 10 s scrape under one tile of
+    windows, Tp 768 x Wp 128: 256 rows either way).  The five matrices
+    are 12.1 MB at a 13-hour row (Tp 4,736 x Wp 128) and 35 MB at a
+    Grafana dashboard's six hours (2,304 x 768): the tiled form alone fits
+    there.  A smaller row or group count never gets a smaller block (both
+    estimates grow with either), so a gate passed at the row's Tp holds
+    at the columns a trimmed plan loads.  The other kinds have no band:
+    (their block, False)."""
+    def fit(tiled):
+        bs = _BS
+        while bs >= _MIN_BS:
+            if vmem_estimate(Tp, Wp, Gp, kind, ragged, bs=bs, panels=panels,
+                             phased=phased, with_drops=with_drops,
+                             tiled=tiled) <= VMEM_BUDGET:
+                return bs
+            bs //= 2
+        return None
+
+    whole = fit(False)
+    if _selects_by_gather(kind) or whole == _BS:
+        return whole, False
+    tiles = fit(True)
+    if (tiles or 0) > (whole or 0):
+        return tiles, True
+    return whole, False
+
+
 def pick_block(Tp: int, Wp: int, Gp: int, kind: str = "rate_family",
                ragged: bool = False, panels: int = 1,
                phased: bool = False,
                with_drops: bool = False) -> Optional[int]:
     """Largest series-block size whose vmem_estimate fits VMEM_BUDGET
-    (None when even _MIN_BS doesn't — the caller must divert to the
-    general path).  The ragged rate family's scan temporaries scale with
-    bs*Tp, so long rows fuse fine at a smaller block: at Tp=768 the
-    dense kernel keeps bs=256 while ragged rate drops to 128 instead of
-    falling off the fused path entirely."""
-    bs = _BS
-    while bs >= _MIN_BS:
-        if vmem_estimate(Tp, Wp, Gp, kind, ragged, bs=bs, panels=panels,
-                         phased=phased,
-                         with_drops=with_drops) <= VMEM_BUDGET:
-            return bs
-        bs //= 2
-    return None
+    under the band form that fits the larger one (`band_form`; None when
+    even _MIN_BS doesn't — the caller must divert to the general path).
+    The ragged rate family's scan temporaries scale with bs*Tp, so long
+    rows fuse fine at a smaller block: at Tp=768 the dense kernel keeps
+    bs=256 while ragged rate drops to 128 instead of falling off the
+    fused path entirely."""
+    return band_form(Tp, Wp, Gp, kind, ragged, panels, phased,
+                     with_drops)[0]
 
 
 def window_counts(ts_row: np.ndarray, wends: np.ndarray,
@@ -1727,23 +1898,46 @@ def _per_series_aggs(res, rows, gids, *, ops, num_groups, S: int, W: int,
                  for op, g, G in zip(ops, gids, num_groups))
 
 
+def launch_band_tiles(plan: FusedPlan, sets, num_groups, kind: str,
+                      ragged: bool, phased: bool, with_drops: bool,
+                      Tq: int) -> int:
+    """The tiles of band one `_run` call over `sets` builds: for every set
+    whose block `band_form` sizes by the tiled band (as `_run` asks it, by
+    the columns loaded), ceil(Tq / _BAND_COLS); 0 for a set that holds its
+    band resident, and for the kinds that have none."""
+    if _selects_by_gather(kind):
+        return 0
+    return -(-Tq // _BAND_COLS) * sum(_set_forms(
+        sets, num_groups, _load_cols(Tq, plan.Tp), plan.t1.shape[1], kind,
+        ragged, phased, with_drops))
+
+
 def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
                  **flags):
     """One `_run` dispatch: the small host operands put explicitly
     (enqueue_operands), then the one jit call over `sets`, compile-
-    watched.  -> (the call's lazy result, the uploaded rows)."""
+    watched.  -> (the call's lazy result, the uploaded rows).  A call
+    whose program builds its band in tiles says so on the host's line of
+    a trace (`filodb-part:leaf.band_tiled` around the jit call) and in the
+    shape a compile event names."""
     from filodb_tpu.utils.devicetelem import watched_call
     from filodb_tpu.utils.metrics import span_part
     kind, ragged, phased = flags["kind"], flags["ragged"], flags["phased"]
+    tiles = launch_band_tiles(plan, sets, num_groups, kind, ragged, phased,
+                              flags["with_drops"], flags["Tq"])
     with span_part("leaf.enqueue_pack"):
         rows, tsrow, offs = enqueue_operands(plan, device, kind, ragged,
                                              offsets, sets=len(sets),
-                                             phased=phased, cols=flags["Tq"])
-    with span_part("leaf.enqueue_jit"):
+                                             phased=phased, cols=flags["Tq"],
+                                             band_tiles=tiles)
+    with span_part("leaf.enqueue_jit"), (
+            span_part("leaf.band_tiled") if tiles
+            else contextlib.nullcontext()):
         res = watched_call(
             "fused_run", _run,
             _run_shape_sig(sets, plan, num_groups, kind, ragged, phased,
-                           flags["steps"], flags["Tq"]),
+                           flags["steps"], flags["Tq"])
+            + (f":{tiles}bandtiles" if tiles else ""),
             lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
                          **flags),
             device=device)

@@ -490,6 +490,16 @@ def _render_matrix_rows(blocks) -> RenderedRows:
         pieces = [f'[{t},"%.17g"]' for t in stamps]
         whole = "[" + ",".join(pieces) + "]"
         finite = np.isfinite(vals).all(axis=1).tolist()
+        if len(finite) > 1 and all(finite):
+            # ... and a block that is finite throughout is one call too: a
+            # row a host is 4,000 rows of 13 points a response, and a call a
+            # row is then most of the calls (a label's `%` is no directive)
+            rows.append(",".join(
+                ['{"metric":' + _labels_json(key).replace("%", "%%")
+                 + ',"values":' + whole + "}" for key in b.keys])
+                % tuple(vals.ravel().tolist()))
+            points += vals.size
+            continue
         for i, key in enumerate(b.keys):
             if finite[i]:
                 text = whole % tuple(vals[i].tolist())
@@ -508,9 +518,26 @@ def _render_matrix_rows(blocks) -> RenderedRows:
                     text = ("[" + ",".join([pieces[j] for j in idx])
                             + "]") % tuple(pts)
                 points += len(idx)
-            labels = _compact_json(_prom_labels(key.labels_dict))
-            rows.append(f'{{"metric":{labels},"values":{text}}}')
+            rows.append(f'{{"metric":{_labels_json(key)},"values":{text}}}')
     return RenderedRows("[" + ",".join(rows) + "]", points, fallbacks)
+
+
+_LABELS_JSON: Dict[RangeVectorKey, str] = {}
+_LABELS_JSON_MAX = 1 << 16
+
+
+def _labels_json(key: RangeVectorKey) -> str:
+    """A row's `metric` object as JSON text, remembered by key: the groups
+    of a dashboard or a fleet table are the same keys request after
+    request (4,000 hosts a response), and their text a pure function of
+    them.  Emptied when full: a bound, not a policy."""
+    text = _LABELS_JSON.get(key)
+    if text is None:
+        if len(_LABELS_JSON) >= _LABELS_JSON_MAX:
+            _LABELS_JSON.clear()
+        text = _LABELS_JSON[key] = _compact_json(
+            _prom_labels(key.labels_dict))
+    return text
 
 
 def _fmt(v: float) -> str:

@@ -390,6 +390,9 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                             gids, minlength=len(gkeys))[:len(gkeys)])
             _group_cache_insert(key, t1.by, t1.without, groups, gkeys)
         registry.counter("leaf_fused_kernel").increment()
+        # the groups the leaf's epilogue sums into (a histogram's slots):
+        # over the leaves, a guard that a deployment groups as it says
+        registry.counter("leaf_fused_groups").increment(num_slots)
         if padded_vals.phase_p is not None:
             registry.counter("leaf_phase_fused").increment()
         if not dense:

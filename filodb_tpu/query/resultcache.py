@@ -106,14 +106,17 @@ def _series_map(res: QueryResult, width: int) -> Optional[
     the expected window grid — a clamped/split grid must bypass, not
     crash the merge)."""
     out: Dict[RangeVectorKey, np.ndarray] = {}
+    rows = 0
     for b in res.blocks:
         vals = np.asarray(b.values, dtype=np.float64)
         if vals.ndim != 2 or vals.shape[1] != width:
             return None
-        for i, k in enumerate(b.keys):
-            if k in out:
-                return None              # ambiguous identity: don't cache
-            out[k] = vals[i]
+        # a call a block (a response of a row a host is 4,000 rows); a key
+        # met twice leaves the map short of the rows
+        out.update(zip(b.keys, vals))
+        rows += len(b.keys)
+    if len(out) != rows:
+        return None                      # ambiguous identity: don't cache
     return out
 
 

@@ -417,6 +417,60 @@ def _scoped_bytes(one_chip, monkeypatch, limit, **run):
             jax.clear_caches()      # ... and this one's must not outlive it
 
 
+# tsbscpu-gauges-40k.double-groupby (ISSUE 48): 13 h 9 min of a 10 s
+# interval under 13 windows of an hour, a shard's hosts a group each
+T_13H, W_13H, RANGE_1H_MS = 4_736, 13, 3_600_000
+S_TSBS = (1_013, 1_006, 987, 994)           # 4,000 hosts on four shards
+
+
+@pytest.mark.parametrize("S,G,fn,ragged,phased,samples,windows,range_ms", [
+    (S_TSBS, S_TSBS, "avg_over_time", False, False, T_13H, W_13H,
+     RANGE_1H_MS),
+    (4_000, 4_000, "sum_over_time", False, False, T_13H, W_13H, RANGE_1H_MS),
+    (1_000, 1_000, "avg_over_time", True, True, T_13H, W_13H, RANGE_1H_MS),
+    (S_6H, (10, 10, 1, 20), "avg_over_time", False, False, T_6H, W_6H,
+     RANGE_MS),
+], ids=["tsbs-4sets-G1000", "tsbs-G4000", "tsbs-ragged-phased",
+        "gauges-6h-Wp768"])
+def test_the_tiled_band_compiles_inside_its_estimate(
+        one_chip, chip_runtime, monkeypatch, S, G, fn, ragged, phased,
+        samples, windows, range_ms):
+    """The over_time kinds where the resident band fits no block (five
+    [4736, 128] matrices are 12.1 MB, five [2304, 768] 35 MB: `pick_block`
+    was None and the leaf took the general XLA path): `band_form` sizes the
+    block by the tiled band, the program of a request's working sets
+    compiles for the described v5e, and compiles again under a scoped limit
+    SET AT `vmem_estimate` for the largest group count, so Mosaic's own
+    need (block buffers, accumulators, the tile loop's temporaries) lies
+    under the estimate, and the estimate inside the budget.  (Mosaic's
+    need at the cell's shape, bs 128, Gp 1,024: between 5.70 and 6.0 MiB
+    against 8.62 estimated.)"""
+    every = 3_600_000 if samples == T_13H else None
+    plan = _plan(range_ms, windows, samples, every)
+    Wp = plan.t1.shape[1]
+    load = pf._load_cols(plan.Tq, plan.Tp)
+    Gp = pf.pad_group_count(max(G) if isinstance(G, tuple) else G)
+    bs, tiled = pf.band_form(load, Wp, Gp, fn, ragged, phased=phased)
+    assert tiled and bs is not None
+    assert pf.vmem_estimate(load, Wp, Gp, fn, ragged, bs=32,
+                            phased=phased) > pf.VMEM_BUDGET
+    run = dict(S=S, G=G, fn=fn, ragged=ragged, phased=phased,
+               range_ms=range_ms, windows=windows, samples=samples,
+               every_ms=every)
+    compiled = _compile_run(one_chip, **run)
+    _check(compiled, pallas=True)
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") \
+        == (len(S) if isinstance(S, tuple) else 1)
+    estimate = pf.vmem_estimate(load, Wp, Gp, fn, ragged, bs=bs,
+                                phased=phased, tiled=True)
+    assert estimate <= pf.VMEM_BUDGET
+    over = _scoped_bytes(one_chip, monkeypatch, estimate, **run)
+    print(f"Tq={plan.Tq} Wp={Wp} Gp={Gp} bs={bs} "
+          f"estimate={estimate / 2 ** 20:.2f}M over={over / 2 ** 20:.2f}M")
+    assert over == 0, f"Mosaic names {over} bytes over the estimate"
+
+
 WIDE_KINDS = [("rate", False, False), ("increase", False, False),
               ("delta", False, False), ("last_over_time", False, False),
               ("rate", True, False), ("rate", False, True)]
