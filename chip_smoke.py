@@ -521,6 +521,103 @@ def double_groupby(server, cli, hosts):
          route_warm=d, fused_kernel=True, band_tiles=tiles)
 
 
+# A fleet that churns (benchmark cell promchurn-counters-262k.open): counters
+# at a scrape offset of their own, a quarter of them short of samples
+CHURN_DATASET = "churn"
+
+
+def churned_fleet(server, cli, series, T):
+    """`sum by (_ns_)(rate(churned_total[5m]))` over counters scraped at
+    offsets of their own of which every fourth starts late, ends early or
+    misses scrapes, every cell against the f64 reference of the samples
+    that exist (the benchmark's `references/churned_scrapes.py`): the mirror
+    places the rows on the scrape grid's slots, every leaf runs the RAGGED
+    fused kernel, and its working sets are stored whole rows first: a launch
+    books the rows of its sets and those the dense body runs over, and both
+    counters must move."""
+    from benchmark.references.churned_scrapes import series_increase
+    rng = np.random.default_rng([ARGS.seed, 50])
+    mapper, spread = server.mappers[CHURN_DATASET], \
+        server.spreads[CHURN_DATASET]
+    ts_row = START_MS + np.arange(T, dtype=np.int64) * STEP_MS
+    keys = [PartKey.make("churned_total", {
+        "_ws_": "demo", "_ns_": f"App-{i % NUM_APPS}",
+        "instance": f"Instance-{i}"}) for i in range(series)]
+    shard_of = np.fromiter(
+        (mapper.ingestion_shard(pk.shard_key_hash(), pk.partition_hash(),
+                                spread.spread_for(pk.shard_key()))
+         for pk in keys), np.int64, series)
+    phase = rng.integers(0, STEP_MS, series)
+    vals = counter_chunk(rng, np.empty((series, T)))
+    exists = np.ones((series, T), bool)
+    # the short rows' lives: (first scrape, last + 1, first missed, the
+    # first after), at one of sixteen scrapes each, so that the rows of one
+    # life load as one rectangle
+    life = np.tile(np.array([0, T, T, T]), (series, 1))
+    short = np.flatnonzero(np.arange(series) % 4 == 3)
+    at = T // 8 + rng.integers(0, 16, short.size) * (3 * T // 64)
+    life[short[short % 3 == 0], 0] = at[short % 3 == 0]     # a late start
+    life[short[short % 3 == 1], 1] = at[short % 3 == 1]     # an early end
+    life[short[short % 3 == 2], 2] = at[short % 3 == 2]     # missed scrapes
+    life[short[short % 3 == 2], 3] = at[short % 3 == 2] + 3
+    for (a, b, c, d) in np.unique(life, axis=0):
+        rows = np.flatnonzero((life == (a, b, c, d)).all(axis=1))
+        exists[rows, :a] = exists[rows, b:] = exists[rows, c:d] = False
+        for lo, hi in ((a, min(b, c)), (d, b)):
+            if hi <= lo:
+                continue
+            for sh in server.memstore.shards_for(CHURN_DATASET):
+                idx = rows[shard_of[rows] == sh.shard_num]
+                if idx.size:
+                    got = sh.ingest_columns(
+                        "prom-counter", [keys[i] for i in idx],
+                        ts_row[None, lo:hi] + phase[idx, None],
+                        {"count": vals[idx, lo:hi]}, offset=0)
+                    assert got == idx.size * (hi - lo), (got, lo, hi)
+    end_s = int(ts_row[-1]) // 1000
+    promql = "sum by (_ns_)(rate(churned_total[5m]))"
+    path = f"/promql/{CHURN_DATASET}/api/v1/query_range"
+    gids = np.arange(series) % NUM_APPS
+    times, worst = [], 0.0
+    for back in (0, 1):                 # cold, then warm a step earlier
+        wends = window_grid((end_s - back * QSTEP_S) * 1000, N_WINDOWS)
+        want = GroupSums(wends)
+        want.add(series_increase(ts_row, phase, vals, exists, wends,
+                                 RANGE_MS), gids)
+        before = cli.counters()
+        body, secs = cli.query(path, query=promql,
+                               start=int(wends[0]) // 1000,
+                               end=int(wends[-1]) // 1000, step=QSTEP_S)
+        after = cli.counters()
+        times.append(secs)
+        worst = max(worst, compare(by_ns(body),
+                                   want.by_ns(1000.0 / RANGE_MS), "counter",
+                                   f"churned-fleet#{back}"))
+        moved = {k: int(after.get(k, 0) - before.get(k, 0)) for k in (
+            "leaf_ragged_fused_total", "fused_enqueues_total",
+            "fused_set_rows_total", "fused_whole_rows_total",
+            "leaf_general_path_total", "leaf_fused_errors_total",
+            "leaf_host_gather_total", "leaf_offgrid_total",
+            "leaf_host_routed_total")}
+        assert moved["leaf_ragged_fused_total"] >= 1 \
+            and moved["fused_enqueues_total"] >= 1, moved
+        # the sets hold both kinds of row: the dense body ran over the
+        # whole ones, three quarters of the fleet and their rungs' padding
+        assert 0 < moved["fused_whole_rows_total"] \
+            < moved["fused_set_rows_total"], moved
+        for bad in ("leaf_general_path_total", "leaf_fused_errors_total",
+                    "leaf_host_gather_total", "leaf_offgrid_total",
+                    "leaf_host_routed_total"):
+            assert moved[bad] == 0, (bad, moved)
+    emit("query", name="churned-fleet", promql=promql, series=series,
+         short_rows=int(short.size), checked_vs_f64_reference=True,
+         max_rel_err=worst, first_s=round(times[0], 4),
+         warm_s=round(times[1], 4), route_warm=moved,
+         ragged_fused_kernel=True,
+         whole_row_share=round(moved["fused_whole_rows_total"]
+                               / moved["fused_set_rows_total"], 4))
+
+
 def cache_state(path):
     n = len(os.listdir(path)) if path and os.path.isdir(path) else 0
     return {"dir": path, "entries": n}
@@ -558,7 +655,8 @@ def main():
     jax.monitoring.register_event_listener(on_event)
 
     server = FiloServer([DatasetConfig(DATASET, 4),
-                         DatasetConfig(TSBS_DATASET, 4)],
+                         DatasetConfig(TSBS_DATASET, 4),
+                         DatasetConfig(CHURN_DATASET, 4)],
                         http_host="127.0.0.1", http_port=0)
     cache_dir = apply_jax_runtime(server.config)
     cache0 = cache_state(cache_dir)
@@ -736,6 +834,11 @@ def run_one_chip(server, cli, S, T, S_gauge, n_write):
     # --- rows of thirteen hours, a group a host: the band in tiles.  Every
     # shard's leaf must scan more than query.host_route_max_samples
     double_groupby(server, cli, 64 if ARGS.rehearse else 4_000)
+
+    # --- a fleet that churns: the ragged kernel over a placed mirror, its
+    # working sets stored whole rows first.  Every shard's leaf must scan
+    # more than query.host_route_max_samples
+    churned_fleet(server, cli, 256 if ARGS.rehearse else 65_536, T)
 
     # --- the device, by the program's own telemetry
     m2 = cli.counters()

@@ -90,6 +90,18 @@ class _MirrorSnapshot:
     # is a sample position, as the store has it.
     interval: int = 0
     placed_rows: int = 0                       # rows that do not fill the grid
+    # a placed snapshot's fact of each row (host, bool [S]; None where the
+    # snapshot is not placed): the row is WHOLE, every slot of the grid
+    # holds its sample (its count is the grid's slot count: no late start,
+    # early end or missed scrape) and every sample of every column is
+    # finite, so the fused kernel's dense body reads no slot of it that
+    # holds none.  A fact of the row over the grid, which no range, window
+    # or `end` changes; `placed_rows` counts the rows short of slots.  A
+    # leaf takes its rows' by need (MirrorGather.whole_first), where it
+    # pads a working set; an incremental refresh keeps it (a row that
+    # misses a scrape stops being whole; one that was not is not again
+    # before the next full build)
+    whole: Optional[np.ndarray] = None
     # the store generation whose SAMPLES the device arrays hold.  `gen`
     # moves on with bookkeeping that touches none of them (a paging attempt
     # that found nothing on disk writes paged_floor:
@@ -787,6 +799,7 @@ class DeviceMirror:
         counts = store.counts[:s].copy()
         vbase_valid: Dict[str, np.ndarray] = {}
         col_finite: Dict[str, bool] = {}
+        whole = None                    # a placed build's fact of each row
         from filodb_tpu.utils.metrics import span
         with span("mirror.phase_detect"):
             ts_row0, phase, offgrid = _detect_phase_grid(ts_off, counts,
@@ -812,6 +825,8 @@ class DeviceMirror:
                 else:
                     offgrid = got
         placed_rows = 0 if need is None else int(need.size)
+        if interval:
+            whole = counts == len(ts_row0)
         phase_rows = _note_phase_grid(self.shard_num, phase, offgrid,
                                       placed_rows)
         for name, arr in store.cols.items():
@@ -838,9 +853,13 @@ class DeviceMirror:
                 # counted region fully finite (padding beyond counts is NaN
                 # by construction and doesn't disqualify)
                 pos_ok = pos >= counts[:, None]
-                col_finite[name] = not interval and bool(
-                    (fin | pos_ok[..., None] if fin.ndim == 3
-                     else fin | pos_ok).all())
+                if interval:
+                    col_finite[name] = False
+                    whole &= (fin | pos_ok).all(axis=1)
+                else:
+                    col_finite[name] = bool(
+                        (fin | pos_ok[..., None] if fin.ndim == 3
+                         else fin | pos_ok).all())
                 if is_counter:
                     raw = np.asarray(arr[:s, :t], np.float64)
                     lr, cd = _tail_state(raw, corrected)
@@ -863,7 +882,7 @@ class DeviceMirror:
                                          phase, phase_rows, dev_rows, dput),
                                      col_finite=col_finite,
                                      interval=interval,
-                                     placed_rows=placed_rows)
+                                     placed_rows=placed_rows, whole=whole)
         # the histogram records the WHOLE refresh wall (host prep +
         # uploads: the operational "how long did the rebuild take");
         # the per-query tally gets only the device-dispatch share
@@ -1171,6 +1190,13 @@ class DeviceMirror:
         cum_drop = dict(snap.tail_cum_drop)
         vbase_valid = dict(snap.vbase_valid)
         col_finite = dict(snap.col_finite)
+        whole = None
+        if placed:
+            # a row stays whole while it fills every slot the grid gains
+            # with a finite sample; a row new since the snapshot is whole
+            # if it fills the grid (all its samples are among the new)
+            whole = counts_new == t_new
+            whole[:s_old] &= snap.whole
         for name, dev in snap.cols.items():
             arr = store.cols[name]
             hist = arr.ndim == 3
@@ -1222,6 +1248,8 @@ class DeviceMirror:
             flat = rb[valid]
             col_finite[name] = bool(col_finite.get(name, False)
                                     and np.isfinite(flat).all())
+            if placed:
+                whole[rows] &= (np.isfinite(rb) | ~valid).all(axis=1)
             _td = _time.perf_counter()
             col_dev = dev
             if dR or dT:
@@ -1251,7 +1279,7 @@ class DeviceMirror:
             phase=phase if kept else None,
             phase_rows=phase_rows if kept else 0,
             phase_dev=phase_dev if kept else None,
-            interval=snap.interval, placed_rows=placed_rows)
+            interval=snap.interval, placed_rows=placed_rows, whole=whole)
         # appended-tail transfer size: int32 ts offsets + each column's
         # per-cell bytes over the new cells only
         per_cell = 4 + sum(
@@ -1396,15 +1424,29 @@ class DeferredRows:
     def ndim(self) -> int:
         return len(self.shape)
 
-    def resolve(self, rows_to: Optional[int] = None):
+    def resolve(self, rows_to: Optional[int] = None,
+                whole_first: bool = False):
         """The array; with `rows_to`, its rows followed by zero rows up to
-        that count (MirrorGather._take)."""
-        return self._gather._take(self._array, self._col, rows_to=rows_to)
+        that count; with `whole_first`, in the layout of a working set
+        stored whole rows first where the rows have one (MirrorGather
+        ._take, .whole_first)."""
+        return self._gather._take(self._array, self._col, rows_to=rows_to,
+                                  whole_first=whole_first)
 
     def host(self) -> np.ndarray:
         """The rows of the snapshot's host copy (`phase` keeps one): no
         device work."""
         return self._gather.snap.phase[self._gather.rows]
+
+    @property
+    def placed(self) -> bool:
+        """Whether the rows come out of a placed snapshot (which keeps
+        the fact of each row that `whole_first` orders a set by)."""
+        return self._gather.snap.whole is not None
+
+    def whole_first(self):
+        """MirrorGather.whole_first of the rows."""
+        return self._gather.whole_first()
 
 
 class MirrorGather:
@@ -1421,7 +1463,7 @@ class MirrorGather:
     that raised is not remembered, so the next read tries again.  The
     results live on the handle: keep it no longer than the leaf's
     execution and in no cache (it holds a whole snapshot alive)."""
-    __slots__ = ("device", "snap", "rows", "_idx", "_taken")
+    __slots__ = ("device", "snap", "rows", "_idx", "_taken", "_layout")
 
     def __init__(self, device, snap: _MirrorSnapshot, rows: np.ndarray):
         self.device = device
@@ -1429,6 +1471,7 @@ class MirrorGather:
         self.rows = rows
         self._idx: Dict[Optional[int], object] = {}   # by `rows_to`
         self._taken: Dict[Tuple, object] = {}
+        self._layout = ()               # whole_first's answer, once asked
 
     @property
     def base_ms(self) -> int:
@@ -1465,14 +1508,36 @@ class MirrorGather:
         return self._take(array, col,
                           counted="ts_off" if array == "ts_off" else "other")
 
+    def whole_first(self):
+        """-> (at, Sw) where the handle's rows, as a fused working set,
+        are stored WHOLE ROWS FIRST: row i stands at `at[i]` and the rows
+        with a hole start at row `Sw`, each part padded to a rung of the
+        series ladder of its own (pallas_fused.whole_first, from the
+        snapshot's fact of each row); None where the snapshot keeps no
+        such fact (it is not placed) or the rows are all of one kind.
+        One pass over the rows at the first call, which a leaf makes
+        where it pads a working set (a miss of its cache), and never a
+        request that finds the set."""
+        if self._layout == ():
+            from filodb_tpu.ops.pallas_fused import whole_first
+            fact = self.snap.whole
+            self._layout = None if fact is None \
+                else whole_first(fact[self.rows])
+        return None if self._layout is None else self._layout[1:]
+
     def _take(self, array: str, col: Optional[str],
-              counted: Optional[str] = None, rows_to: Optional[int] = None):
+              counted: Optional[str] = None, rows_to: Optional[int] = None,
+              whole_first: bool = False):
         """`rows_to`: take that many rows, the handle's own and then rows
         of zeros.  The fused leaf asks for its padded row count
         (pallas_fused.pad_series_count), so that the take and everything
         made from it compile once a rung of that ladder and not once a
         row count: a chip's 30 shards, or a selector's 20 row subsets, are
-        a handful of programs."""
+        a handful of programs.  `whole_first`: where the rows have that
+        layout (`whole_first`), take them in it, each part's rows and
+        then rows of zeros up to its rung, in place of `rows_to`."""
+        if whole_first and self.whole_first() is not None:
+            rows_to = "whole_first"
         got = self._taken.get((array, col, rows_to))
         if got is not None:
             return got
@@ -1485,7 +1550,11 @@ class MirrorGather:
             idx = self._idx.get(rows_to)
             if idx is None:
                 rows = self.rows.astype(np.int32)
-                if rows_to is not None:
+                if rows_to == "whole_first":
+                    index = self._layout[0]
+                    rows = np.where(index >= 0, rows[index], np.int32(
+                        np.iinfo(np.int32).max))
+                elif rows_to is not None:
                     # past every mirror's rows: such an index reads as the
                     # fill value
                     rows = np.concatenate([rows, np.full(

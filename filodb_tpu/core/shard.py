@@ -253,8 +253,8 @@ class _Selection:
     keys_epoch).  `whole` answers every range that holds all the lives;
     `parts` holds the answers of the others by the mask a range lays over
     `ids` (its bytes -> PartLookupResult, least recently used first)."""
-    __slots__ = ("stamp", "ids", "start", "end", "max_start", "min_end",
-                 "whole", "parts")
+    __slots__ = ("stamp", "ids", "start", "end", "starts", "ends",
+                 "max_start", "min_end", "whole", "parts")
 
 
 class TimeSeriesShard:
@@ -1186,26 +1186,32 @@ class TimeSeriesShard:
         if ent.max_start <= end_time_ms and ent.min_end >= start_time_ms:
             res = ent.whole
         else:
-            mask = (ent.start <= end_time_ms) & (ent.end >= start_time_ms)
-            res = self._part_of(ent, mask, limit)
+            res = self._part_of(ent, start_time_ms, end_time_ms, limit)
         if self._traced_pids and res.part_ids.size:
             self._trace_touch("query_lookup", res.part_ids)
         return res
 
-    def _part_of(self, ent: _Selection, mask: np.ndarray,
+    def _part_of(self, ent: _Selection, start_ms: int, end_ms: int,
                  limit: Optional[int]) -> PartLookupResult:
-        """The entry's series whose life meets a range, by the range's mask
-        over them.  Ranges differ with every open; masks move only where a
-        range's end passes a birth or its start a death (a dozen an hour
-        in a fleet that replaces its targets every ten minutes), so the
-        answer of a mask is kept as `whole` is: one object, its selection,
-        cache keys and facts, to every request that lays the same mask."""
-        mk = np.packbits(mask).tobytes()
+        """The entry's series whose life meets a range.  Ranges differ with
+        every open; WHICH series a range leaves out moves only where its
+        end passes a birth or its start a death (a dozen an hour in a
+        fleet that replaces its targets every ten minutes), so the answer
+        is kept as `whole` is: one object, its selection, cache keys and
+        facts, to every request that leaves the same series out.  They are
+        named by two ranks, found by two binary searches in the entry's
+        sorted lives: how many series ended before the range starts and
+        how many were born by its end (the mask over the lives, four
+        passes over 73,000 series a request and shard, is laid on a miss
+        alone)."""
+        mk = (int(np.searchsorted(ent.ends, start_ms, side="left")),
+              int(np.searchsorted(ent.starts, end_ms, side="right")))
         with self._lookup_lock:
             res = ent.parts.pop(mk, None)
             if res is not None:
                 ent.parts[mk] = res
         if res is None:
+            mask = (ent.start <= end_ms) & (ent.end >= start_ms)
             res = self._lookup_result(ent.ids[mask][:limit])
             if limit is None:
                 res.within = ent.whole
@@ -1223,6 +1229,7 @@ class TimeSeriesShard:
         ent.ids = self.index.part_ids_from_filters(filters, -_NEVER_MS,
                                                    _NEVER_MS)
         ent.start, ent.end = self.index.lives_of(ent.ids)
+        ent.starts, ent.ends = np.sort(ent.start), np.sort(ent.end)
         ent.max_start = int(ent.start.max()) if ent.ids.size else -_NEVER_MS
         ent.min_end = int(ent.end.min()) if ent.ids.size else _NEVER_MS
         ent.whole = self._lookup_result(ent.ids[:limit])

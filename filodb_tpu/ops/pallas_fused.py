@@ -34,6 +34,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
+import threading
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -563,6 +564,9 @@ def _committed_device(arr):
     return None
 
 
+_RESIDENT_LOCK = threading.Lock()    # a plan operand's first put (below)
+
+
 def gathers(kind: str, ragged: bool, phased: bool) -> int:
     """The `_gather_cols` calls one working set's kernel makes: the rate
     family's two boundaries (values and, on ragged rows, timestamps),
@@ -580,14 +584,16 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
                      offsets=None, sets: int = 1,
                      phased: bool = False,
                      cols: Optional[int] = None,
-                     band_tiles: int = 0) -> tuple:
+                     band_tiles: int = 0, set_rows=(),
+                     whole_rows=None) -> tuple:
     """-> (rows, tsrow, offsets) on `device`: everything one `_run` call
     takes from the host, as device arrays (so the call itself transfers
     nothing).  The plan's own operands are put on a device once and stay
     with the plan (`plan.resident`): the panels of an open and the leaves
     of a request share the plan object, so only the first enqueue of a
-    (plan, device, operand) uploads.  Enqueues that miss together each
-    put, and all go on with the array stored first.  `offsets` belongs to
+    (plan, device, operand) uploads.  Enqueues that miss together wait
+    for the one that puts (`_RESIDENT_LOCK`, taken on a miss alone: a
+    put is two small arrays a new grid) and take its array.  `offsets` belongs to
     the call and is put every time.  Counted on /metrics: enqueues,
     working sets, and the puts really made (uploads over enqueues is the
     plan's miss share).  `tsrow` rides only where the kernel reads it
@@ -609,14 +615,30 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
     computed over, for an hour of `[5m]` at 60 s over a 768-slot row; 768
     before a block followed the windows' reach), and the tiles of band its
     program builds (`band_tiles`, from `launch_band_tiles`) on
-    `fused_band_tiles_total`: 0 where every set holds its band resident."""
+    `fused_band_tiles_total`: 0 where every set holds its band resident.
+    `set_rows` / `whole_rows`: the padded rows of each of the call's sets
+    and (`_run`'s `splits`; None: none is split) the rows of each that the
+    dense body runs over, two stored ints a set: their sums go on
+    `fused_set_rows_total` and `fused_whole_rows_total` (the second over
+    the first: the share of a deployment's rows that fill every slot of
+    their grid, 0 where no mirror is placed).  A split set's gathers visit
+    tiles as its rows do: the dense body's gathers over the whole part's
+    share of the rows, the ragged body's over the rest."""
     from filodb_tpu.utils.metrics import registry
     registry.counter("fused_enqueues").increment()
     registry.counter("fused_enqueue_sets").increment(sets)
     registry.counter("fused_windows").increment(plan.W)
     registry.counter("fused_columns_read").increment(
         _load_cols(cols or plan.Tq, plan.Tp))
-    visits = sets * gathers(kind, ragged, phased) * plan.tile_visits[phased]
+    whole = sum(whole_rows or ())
+    registry.counter("fused_set_rows").increment(sum(set_rows))
+    registry.counter("fused_whole_rows").increment(whole)
+    own, dense = (gathers(kind, r, phased) for r in (ragged, False))
+    visits = sets * own
+    if whole:
+        visits = sum(own + (dense - own) * w / n
+                     for n, w in zip(set_rows, whole_rows))
+    visits = round(visits * plan.tile_visits[phased])
     if visits:
         registry.counter("fused_gather_tile_visits").increment(visits)
     steps = scan_steps(plan, kind, ragged, phased)
@@ -630,10 +652,17 @@ def enqueue_operands(plan: FusedPlan, device, kind: str, ragged: bool,
         nonlocal uploads
         arr = held.get((device, which))
         if arr is None:
-            uploads += 1
-            arr = held.setdefault(
-                (device, which),
-                jax.device_put(getattr(plan, which), device))
+            # one put an operand: the panels of an open reach a new plan's
+            # first enqueue together once nothing makes them wait in turn
+            # (0.35 puts a request became 0.51 when the churned cell
+            # stopped waiting for the device: PERF.md section 6, PR 50),
+            # and the others wait for the first's array and take it
+            with _RESIDENT_LOCK:
+                arr = held.get((device, which))
+                if arr is None:
+                    uploads += 1
+                    arr = held[(device, which)] = jax.device_put(
+                        getattr(plan, which), device)
         return arr
 
     rows = resident("prows" if phased else "rows")
@@ -1158,40 +1187,79 @@ def _epilogue(gids_ref, out, pres, out_refs, num_groups: int,
         out_refs[1][:] += _dot_1p(onehot, pres)
 
 
-def _set_forms(sets, num_groups, load: int, Wp: int, kind: str,
-               ragged: bool, phased: bool, with_drops: bool) -> list:
-    """Whether each working set of a `_run` call takes the tiled band
-    (`band_form`, by the columns loaded): what `_run` traces its sets by
-    and an enqueue books (`launch_band_tiles`)."""
-    return [band_form(load, Wp, Gp, kind, ragged, len(st[2]), phased,
-                      with_drops)[1] for st, Gp in zip(sets, num_groups)]
+def _takes_row(kind: str, with_drops: bool, ragged: bool,
+               phased: bool) -> bool:
+    """Whether a flavor computes over the row's Tp whatever the plan's
+    windows reach (`_flavor` says why): a kernel that corrects resets
+    itself, and the dense gather kinds on one shared row."""
+    return with_drops or (_selects_by_gather(kind) and not ragged
+                          and not phased)
+
+
+def _set_parts(sets, splits, ragged: bool) -> list:
+    """The Pallas calls one `_run` makes: (set index, first row, rows,
+    ragged) each.  A set is one call over its rows, of the launch's
+    flavor; a set stored whole rows first (`PaddedValues.split`: the row
+    its holed part starts at, a static int of the launch) is two, the
+    dense body over [0, split) and the ragged one over the rest."""
+    parts = []
+    for k, st in enumerate(sets):
+        Sp, Sw = st[0].shape[0], splits[k] if splits else 0
+        if Sw:
+            parts += [(k, 0, Sw, False), (k, Sw, Sp - Sw, True)]
+        else:
+            parts.append((k, 0, Sp, ragged))
+    return parts
+
+
+def _part_forms(sets, num_groups, splits, Tq: Optional[int], Tp: int,
+                Wp: int, kind: str, ragged: bool, phased: bool,
+                with_drops: bool) -> list:
+    """(part, the columns it computes over, whether it takes the tiled
+    band) for every part of a `_run` call (`_set_parts`; `band_form`, by
+    the columns loaded): what `_run` traces by and an enqueue books
+    (`launch_band_tiles`).  The columns are the launch's `Tq`, but for
+    the dense part of a split set whose dense flavor keeps the row
+    (`_takes_row`)."""
+    out = []
+    for part in _set_parts(sets, splits, ragged):
+        k, _, _, rag = part
+        cols = Tp if rag != ragged and _takes_row(
+            kind, with_drops, rag, phased) else (Tq or Tp)
+        out.append((part, cols, band_form(
+            _load_cols(cols, Tp), Wp, num_groups[k], kind, rag,
+            len(sets[k][2]), phased, with_drops)[1]))
+    return out
 
 
 def _run_shape_sig(sets, plan, num_groups, kind: str, ragged: bool,
                    phased: bool = False, steps: int = 0,
-                   Tq: Optional[int] = None) -> str:
+                   Tq: Optional[int] = None, splits=None) -> str:
     """The compile-cache shape signature recorded with jit compile
     events (utils/devicetelem): the padded dims + static flags that key
     the trace cache, so a recompile storm names the shape that drove it.
     A call of several sets names their summed rows and groups and how
     many they were.  `T` is the row block's width, the launch's `Tq`
-    (the row's Tp where it is left out)."""
+    (the row's Tp where it is left out); `whole`, the rows that sets
+    stored whole rows first run the dense body over."""
     Sp = sum(st[0].shape[0] for st in sets)
     return (f"S{Sp}xT{Tq or plan.Tp}xW{plan.t1.shape[1]}"
             f"xG{sum(num_groups)}:{kind}"
             + (":ragged" if ragged else "") + (":phased" if phased else "")
             + (f":{steps}steps" if steps else "")
-            + (f":{len(sets)}sets" if len(sets) > 1 else ""))
+            + (f":{len(sets)}sets" if len(sets) > 1 else "")
+            + (f":{sum(splits)}whole" if splits and any(splits) else ""))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
-    "kind", "ragged", "per_series", "phased", "steps", "Tq"))
+    "kind", "ragged", "per_series", "phased", "steps", "Tq", "splits"))
 def _run(sets, offsets, rows, tsrow, *,
          num_groups: Tuple[int, ...], is_counter: bool, is_rate: bool,
          with_drops: bool, interpret: bool, kind: str = "rate_family",
          ragged: bool = False, per_series: bool = False,
-         phased: bool = False, steps: int, Tq: Optional[int] = None):
+         phased: bool = False, steps: int, Tq: Optional[int] = None,
+         splits: Optional[Tuple[int, ...]] = None):
     """One fused dispatch, whole: the plan's kernel operands
     (kernel_operands), built once, then for every working set of `sets`
     its group merge (merge_gid_cols) and its own Pallas call, in one
@@ -1211,30 +1279,59 @@ def _run(sets, offsets, rows, tsrow, *,
     `Tq` (the flavor's, `_flavor`; None: the row's Tp): the columns of a
     row each kernel instance computes over, from the column the rows say
     (`_col_offset`: data, so a dashboard that moves along the row with
-    the newest sample runs one program)."""
+    the newest sample runs one program).
+    `splits` (a ragged launch's; None: no set is split): for every set
+    the row its holed part starts at, 0 for a set that has none
+    (`PaddedValues.split`).  A split set is TWO Pallas calls over the one
+    array (`_set_parts`: no row is copied, a part is a range of blocks),
+    the dense body of the launch's kind and phase over the rows that fill
+    every slot and the ragged one over the rest, their sums and present
+    counts added: a row's cells are what the ragged body gives it, bit
+    for bit (a row without a hole fills nothing and counts every slot),
+    and only the order in which rows enter a group's f32 sum differs.
+    Static, from the set's stored shape: a launch classifies nothing."""
     Tp = sets[0][0].shape[1]
-    # the band's form is a set's own (`band_form`: its group count has a
-    # say), the operands are a form's: built once for each form some set
-    # takes, and nearly always that is one
-    forms = _set_forms(sets, num_groups, _load_cols(Tq or Tp, Tp),
-                       rows.shape[1], kind, ragged, phased, with_drops)
-    operands = {tiled: kernel_operands(rows, tsrow, Tp, kind, phased, ragged,
-                                       Tq, tiled) for tiled in set(forms)}
+    Wp = rows.shape[1]
+    if per_series and splits and any(splits):
+        raise ValueError("a per-series run takes a set's rows in the "
+                         "set's order: not one stored whole rows first")
+    # the band's form is a part's own (`band_form`: its group count has a
+    # say), the operands are a form's: built once for each (body, columns,
+    # form) some part takes, and nearly always that is one
+    forms = _part_forms(sets, num_groups, splits, Tq, Tp, Wp, kind, ragged,
+                        phased, with_drops)
+    operands = {
+        (rag, cols, tiled): kernel_operands(
+            rows, tsrow if rag == ragged else None, Tp, kind, phased, rag,
+            cols, tiled)
+        for rag, cols, tiled in {(part[3], cols, tiled)
+                                 for part, cols, tiled in forms}}
+    paired = ragged or phased
     outs, p0 = [], 0
-    for st, Gp, tiled in zip(sets, num_groups, forms):
-        vals_p, vbase_p, gids = st[:3]
-        offs = None
-        if offsets is not None and len(gids) > 1:
-            offs = offsets[p0:p0 + len(gids)]
-        p0 += len(gids)
-        outs.append(_run_set(
-            vals_p, vbase_p, merge_gid_cols(gids, offs), operands[tiled],
-            rows.shape[1], Gp, st[3] if phased else None,
+    for (k, row0, nrows, rag), cols, tiled in forms:
+        vals_p, vbase_p, gids = sets[k][:3]
+        Gp = num_groups[k]
+        if row0 == 0:
+            offs = None
+            if offsets is not None and len(gids) > 1:
+                offs = offsets[p0:p0 + len(gids)]
+            p0 += len(gids)
+            merged = merge_gid_cols(gids, offs)
+        out = _run_set(
+            vals_p, vbase_p, merged, operands[(rag, cols, tiled)], Wp, Gp,
+            sets[k][3] if phased else None,
             is_counter=is_counter, is_rate=is_rate,
             with_drops=with_drops, interpret=interpret, kind=kind,
-            ragged=ragged, per_series=per_series, phased=phased,
-            steps=steps))
-    paired = ragged or phased
+            ragged=rag, per_series=per_series, phased=phased,
+            steps=steps if rag else 0, row0=row0, nrows=nrows)
+        if paired and not (rag or phased):
+            # the dense body on one shared row counts nothing: a group's
+            # present rows are its rows of this part, a window at a time
+            out = (out, _dense_counts(merged[:nrows], rows, Gp, kind))
+        if row0 == 0:
+            outs.append(out)
+        else:
+            outs[-1] = tuple(a + b for a, b in zip(outs[-1], out))
     if len(outs) == 1:
         return tuple(outs[0]) if paired else outs[0]
     if paired:
@@ -1243,14 +1340,26 @@ def _run(sets, offsets, rows, tsrow, *,
     return jnp.concatenate(outs, axis=0)
 
 
+def _dense_counts(gids, rows, Gp: int, kind: str):
+    """[Gp, Wp] present counts of dense rows on one shared row, inside
+    `_run`'s trace: the rows of each group (every panel's column; a pad
+    row's -1 counts nowhere) times the shared window validity, `n1 >= 1`
+    for the over_time kinds and `n >= 2` for the rate family, as the host
+    makes them for a dense launch (`FusedDispatch.enqueue`)."""
+    size = jnp.zeros((Gp,), jnp.float32).at[
+        jnp.where(gids >= 0, gids, Gp).reshape(-1)].add(1.0, mode="drop")
+    valid = rows[_N1:_N1 + 1] >= (1.0 if kind in OVER_TIME_FNS else 2.0)
+    return size[:, None] * valid.astype(jnp.float32)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "Wp", "Gp", "is_counter", "is_rate", "with_drops", "interpret", "kind",
-    "ragged", "per_series", "phased", "steps"))
+    "ragged", "per_series", "phased", "steps", "row0", "nrows"))
 def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
              phase_p=None, *,
              is_counter: bool, is_rate: bool, with_drops: bool,
              interpret: bool, kind: str, ragged: bool, per_series: bool,
-             phased: bool = False, steps: int):
+             phased: bool = False, steps: int, row0: int, nrows: int):
     """One working set's Pallas call inside `_run`'s trace: its own
     series block, grid and group count over the shared plan operands.
     Called from `_run` and nowhere else.  It is a jit only so that sets
@@ -1258,10 +1367,14 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     sit on five rungs of the row ladder, and tracing the kernel body 30
     times is 1.5 s under the interpreter lock at every process start
     (0.2 s so; no persistent cache keeps a trace).  The device program
-    is still `_run`'s alone."""
+    is still `_run`'s alone.  `row0` / `nrows`: the range of the set's
+    rows the call covers (`_set_parts`: the set, or a part of one stored
+    whole rows first): the grid's blocks start `row0` rows in, which
+    every block size divides (a part begins on a rung of the row ladder),
+    and the arrays are the set's own, uncopied."""
     from jax.experimental.pallas import tpu as pltpu
 
-    Sp = vals_p.shape[0]
+    Sp = nrows
     o1, o2, l1, l2, t1, t2, n, ws, we, ts, idx1, idx2, tiles = operands[:13]
     # the columns computed over: the plan's Tq (kernel_operands made `ts`
     # that wide), the row's own Tp where the windows reach the row; the
@@ -1293,6 +1406,9 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
     grid = Sp // bs
     space = {} if interpret else {"memory_space": pltpu.VMEM}
     # (an index map also gets the prefetched scalar where there is one)
+    blk0 = row0 // bs
+    at_block = (lambda i, *_: (i + blk0, 0)) if blk0 \
+        else (lambda i, *_: (i, 0))
     if trimmed:
         # `load` columns of the row from a tile only the launch knows: the
         # last operand (kernel_operands: that tile, and c0's place past
@@ -1302,13 +1418,15 @@ def _run_set(vals_p, vbase_p, gids_p, operands, Wp: int, Gp: int,
         # construction
         row_spec = pl.BlockSpec(
             (pl.Element(bs), pl.Element(load)),
-            lambda i, at: (i * bs, at[0] * _LANE), **space)
+            # (a product with the block's rows: Mosaic must see that the
+            # sublane tiling divides the row offset)
+            (lambda i, at: ((i + blk0) * bs, at[0] * _LANE)) if blk0
+            else (lambda i, at: (i * bs, at[0] * _LANE)), **space)
     else:
-        row_spec = pl.BlockSpec((bs, load), lambda i, *_: (i, 0), **space)
-    col_spec = pl.BlockSpec((bs, 1), lambda i, *_: (i, 0), **space)
+        row_spec = pl.BlockSpec((bs, load), at_block, **space)
+    col_spec = pl.BlockSpec((bs, 1), at_block, **space)
     # gids may carry P grouping columns (multi-panel batch)
-    gid_spec = pl.BlockSpec((bs, gids_p.shape[1]), lambda i, *_: (i, 0),
-                            **space)
+    gid_spec = pl.BlockSpec((bs, gids_p.shape[1]), at_block, **space)
     fix = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0, 0), **space)  # noqa: E731
     # the gathers' tile ranges are scalars the kernel branches on
     tile_spec = fix(tiles.shape) if interpret else pl.BlockSpec(
@@ -1582,6 +1700,42 @@ class PaddedValues(NamedTuple):
     vals_p: jax.Array    # [Sp, Tp] f32
     vbase_p: jax.Array   # [Sp, 1] f32
     phase_p: Optional[jax.Array] = None   # [Sp, 1] f32 ms (0 pad rows)
+    # a ragged set stored WHOLE ROWS FIRST (`whole_first`): the rows that
+    # fill every slot of their grid stand in [0, split), padded to a rung
+    # of the row ladder of their own, the rows with a hole behind them,
+    # padded to theirs, and a launch runs the dense body over the first
+    # part and the ragged one over the second (`_run`'s `splits`).  0: the
+    # set's rows stand in the set's order and run one body
+    split: int = 0
+    # where each of the set's rows stands in the arrays (host, int32 [S]);
+    # None: row i stands at i.  What a group column is laid out by
+    at: Optional[np.ndarray] = None
+
+
+def whole_first(whole: np.ndarray):
+    """The layout of a working set stored whole rows first, from the fact
+    of its rows (`whole[i]`: row i fills every slot of the grid, a fact of
+    the mirror's build): -> (index [Sw + Sh] int32: the set's row at each
+    place, -1 at the places that pad a part to its rung; at [S] int32:
+    where each row stands; Sw, the holed part's first row), the rows of
+    either part in the set's order; None where the set has no holed row
+    or no whole one and is stored as it was.  Each part is padded to a
+    rung of the row ladder of its own (`pad_series_count`), so the split
+    row is a static shape that moves a rung at a time and never with one
+    series.  One pass over the set's rows, made where a working set is
+    padded and never on a request."""
+    pad = pad_series_count
+    whole = np.asarray(whole, bool)
+    n_whole = int(np.count_nonzero(whole))
+    if not 0 < n_whole < whole.size:
+        return None
+    Sw = pad(n_whole)
+    at = np.empty(whole.size, np.int32)
+    at[whole] = np.arange(n_whole, dtype=np.int32)
+    at[~whole] = Sw + np.arange(whole.size - n_whole, dtype=np.int32)
+    index = np.full(Sw + pad(whole.size - n_whole), -1, np.int32)
+    index[at] = np.arange(whole.size, dtype=np.int32)
+    return index, at, Sw
 
 
 class PaddedGroups(NamedTuple):
@@ -1591,12 +1745,14 @@ class PaddedGroups(NamedTuple):
 
 
 def pad_values(vals, vbase, plan: FusedPlan, device=None,
-               phase=None) -> PaddedValues:
+               phase=None, split=None) -> PaddedValues:
     """`phase`: the rows' phases on the plan's base row, a host [S] array
     of whole milliseconds (all zero is None: the data decide the variant)
-    or the [Sp, 1] f32 column already on the device."""
+    or the [Sp, 1] f32 column already on the device.  `split`: the rows
+    come whole rows first, laid out by `whole_first` (its (at, Sw)), each
+    part on its rung already: they are padded along the row alone."""
     S = vals.shape[0]
-    Sp = pad_series_count(S)
+    Sp = S if split else pad_series_count(S)
     phase_p = None
     if getattr(phase, "ndim", 1) == 2:
         phase_p = phase
@@ -1620,17 +1776,26 @@ def pad_values(vals, vbase, plan: FusedPlan, device=None,
     vals_p = vals_p.at[:S, :vals.shape[1]].set(v)
     vbase_p = jnp.zeros((Sp, 1), jnp.float32)
     vbase_p = vbase_p.at[:S, 0].set(vb)
+    if split:
+        return PaddedValues(vals_p, vbase_p, phase_p, int(split[1]),
+                            split[0])
     return PaddedValues(vals_p, vbase_p, phase_p)
 
 
 def pad_groups(gids, S: int, num_groups: int,
-               device=None) -> PaddedGroups:
-    Sp = pad_series_count(S)
+               device=None, at=None, rows: Optional[int] = None
+               ) -> PaddedGroups:
+    """`at` / `rows`: the column has `rows` rows and series i stands at
+    `at[i]` (a part of a working set, or a set stored whole rows first:
+    `PaddedValues.at`); the rows nobody stands at belong to no group, as
+    the rows that pad a set to its rung do.  None: series i stands at i
+    of `pad_series_count(S)` rows."""
+    Sp = pad_series_count(S) if rows is None else rows
     gids_np = np.asarray(gids, np.int32)
     # padded on the host: one upload of a [Sp, 1] column, and no program
     # that would compile once a row count
     col = np.full((Sp, 1), -1, np.int32)
-    col[:S, 0] = gids_np
+    col[slice(S) if at is None else at, 0] = gids_np
     gids_p = (jnp.asarray(col) if device is None
               else jax.device_put(col, device))
     gsize = np.bincount(gids_np, minlength=num_groups)[:num_groups]
@@ -1900,46 +2065,51 @@ def _per_series_aggs(res, rows, gids, *, ops, num_groups, S: int, W: int,
 
 def launch_band_tiles(plan: FusedPlan, sets, num_groups, kind: str,
                       ragged: bool, phased: bool, with_drops: bool,
-                      Tq: int) -> int:
-    """The tiles of band one `_run` call over `sets` builds: for every set
-    whose block `band_form` sizes by the tiled band (as `_run` asks it, by
-    the columns loaded), ceil(Tq / _BAND_COLS); 0 for a set that holds its
-    band resident, and for the kinds that have none."""
+                      Tq: int, splits=None) -> int:
+    """The tiles of band one `_run` call over `sets` builds: for every
+    part (`_set_parts`) whose block `band_form` sizes by the tiled band
+    (as `_run` asks it, by the columns loaded), ceil(its columns /
+    _BAND_COLS); 0 for a part that holds its band resident, and for the
+    kinds that have none."""
     if _selects_by_gather(kind):
         return 0
-    return -(-Tq // _BAND_COLS) * sum(_set_forms(
-        sets, num_groups, _load_cols(Tq, plan.Tp), plan.t1.shape[1], kind,
-        ragged, phased, with_drops))
+    return sum(-(-cols // _BAND_COLS) for _, cols, tiled in _part_forms(
+        sets, num_groups, splits, Tq, plan.Tp, plan.t1.shape[1], kind,
+        ragged, phased, with_drops) if tiled)
 
 
 def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
-                 **flags):
+                 splits=None, **flags):
     """One `_run` dispatch: the small host operands put explicitly
     (enqueue_operands), then the one jit call over `sets`, compile-
     watched.  -> (the call's lazy result, the uploaded rows).  A call
     whose program builds its band in tiles says so on the host's line of
     a trace (`filodb-part:leaf.band_tiled` around the jit call) and in the
-    shape a compile event names."""
+    shape a compile event names.  `splits`: `_run`'s (None: no set of the
+    call is stored whole rows first)."""
     from filodb_tpu.utils.devicetelem import watched_call
     from filodb_tpu.utils.metrics import span_part
     kind, ragged, phased = flags["kind"], flags["ragged"], flags["phased"]
     tiles = launch_band_tiles(plan, sets, num_groups, kind, ragged, phased,
-                              flags["with_drops"], flags["Tq"])
+                              flags["with_drops"], flags["Tq"], splits)
+    set_rows = [st[0].shape[0] for st in sets]
     with span_part("leaf.enqueue_pack"):
         rows, tsrow, offs = enqueue_operands(plan, device, kind, ragged,
                                              offsets, sets=len(sets),
                                              phased=phased, cols=flags["Tq"],
-                                             band_tiles=tiles)
+                                             band_tiles=tiles,
+                                             set_rows=set_rows,
+                                             whole_rows=splits)
     with span_part("leaf.enqueue_jit"), (
             span_part("leaf.band_tiled") if tiles
             else contextlib.nullcontext()):
         res = watched_call(
             "fused_run", _run,
             _run_shape_sig(sets, plan, num_groups, kind, ragged, phased,
-                           flags["steps"], flags["Tq"])
+                           flags["steps"], flags["Tq"], splits)
             + (f":{tiles}bandtiles" if tiles else ""),
             lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
-                         **flags),
+                         splits=splits, **flags),
             device=device)
     return res, rows
 
@@ -1977,13 +2147,12 @@ def _flavor(plan: FusedPlan, fn_name: str, precorrected: bool,
     is_counter = fn_name in ("rate", "increase")
     kind = fn_name if fn_name in OVER_TIME_FNS else "rate_family"
     with_drops = is_counter and not precorrected
-    whole_row = with_drops or (_selects_by_gather(kind) and not ragged
-                               and not phased)
     return _FlavorFlags(
         is_counter, fn_name == "rate", with_drops,
         interpret, kind, ragged, phased,
         scan_steps(plan, kind, ragged, phased),
-        plan.Tp if whole_row else plan.Tq)
+        plan.Tp if _takes_row(kind, with_drops, ragged, phased)
+        else plan.Tq)
 
 
 def _kernel_set(values, gid_cols: tuple) -> tuple:
@@ -1991,6 +2160,14 @@ def _kernel_set(values, gid_cols: tuple) -> tuple:
     and the phase column behind them where the set has one."""
     st = (values.vals_p, values.vbase_p, gid_cols)
     return st if values.phase_p is None else st + (values.phase_p,)
+
+
+def _splits_of(values) -> Optional[Tuple[int, ...]]:
+    """`_run`'s `splits` for a call over these working sets: each one's
+    stored split row; None where none is stored whole rows first, which
+    is the call as it was."""
+    splits = tuple(getattr(v, "split", 0) for v in values)
+    return splits if any(splits) else None
 
 
 class FusedDispatch:
@@ -2049,7 +2226,7 @@ class FusedDispatch:
         gps = [pad_group_count(total) for *_, total in self._sets]
         order = sorted(range(len(self._sets)), key=lambda k: (
             self._sets[k][0].vals_p.shape[0], gps[k],
-            len(self._sets[k][1])))
+            len(self._sets[k][1]), self._sets[k][0].split))
         dense = not (self.flags.ragged or self.flags.phased)
         # the groups' sizes down the output's rows (a set's panels, then
         # its pad rows at 0): the counts of dense rows are made from it
@@ -2070,7 +2247,9 @@ class FusedDispatch:
         multi = len(offsets) > len(sets)
         self._res, _ = _enqueue_run(
             self.plan, self.device, tuple(sets), offsets if multi else None,
-            tuple(gps[k] for k in order), **self.flags._asdict())
+            tuple(gps[k] for k in order),
+            _splits_of(self._sets[k][0] for k in order),
+            **self.flags._asdict())
         if dense:
             # dense rows on one timestamp row: the counts are |group| x
             # the shared window validity, nothing of the result: made
@@ -2192,10 +2371,13 @@ def fused_leaf_agg_batch(plan: FusedPlan, values: PaddedValues, panels,
             gp0 = panels[ps_idx[0]][0].gids_p[:, 0]
             S = int(np.asarray(gp0 >= 0).sum())
         # one shared per-series run: the [S, W] output is group-agnostic
+        # (and its rows are the set's in the set's order: `_run` refuses a
+        # set stored whole rows first, which the leaf keeps from here)
         res, rows = _enqueue_run(
             plan, dispatch.device,
             (_kernel_set(values, (panels[ps_idx[0]][0].gids_p,)),), None,
-            (8,), per_series=True, **dispatch.flags._asdict())
+            (8,), _splits_of((values,)), per_series=True,
+            **dispatch.flags._asdict())
         with span_part("leaf.enqueue_jit"):
             ps_comps = _per_series_aggs(
                 res, rows, tuple(panels[i][0].gids_p for i in ps_idx),

@@ -145,14 +145,31 @@ class RawBlock:
         stands for all of them."""
         return self.shared_ts_row is not None and not self.phased
 
-    def rows_padded(self, field: str, rows_to: int):
+    def rows_padded(self, field: str, rows_to: int,
+                    whole_first: bool = False):
         """`field` (`values`, `vbase` or `phase`) for the fused leaf's
         padded working set: rows still in the mirror are taken with zero rows up to
         `rows_to` behind them (DeferredRows.resolve: one program a padded
-        row count); an array already here comes as it is."""
+        row count), or `whole_first`, in that layout where they have one;
+        an array already here comes as it is."""
         held = self.__dict__["_" + field]
-        return held.resolve(rows_to) if isinstance(held, DeferredRows) \
-            else held
+        return held.resolve(rows_to, whole_first) \
+            if isinstance(held, DeferredRows) else held
+
+    @property
+    def placed(self) -> bool:
+        """Whether the rows are still in a PLACED mirror snapshot, whose
+        fused working sets may be stored whole rows first (known without
+        reading anything of the rows)."""
+        held = self.__dict__["_values"]
+        return isinstance(held, DeferredRows) and held.placed
+
+    def whole_first(self):
+        """The whole-rows-first layout of the rows still in the mirror
+        (MirrorGather.whole_first: (at, Sw), or None)."""
+        held = self.__dict__["_values"]
+        return held.whole_first() if isinstance(held, DeferredRows) \
+            else None
 
 
 def _resolved_on_read(field: str) -> property:

@@ -279,6 +279,16 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             rows = whole.rows
             vkey = key[:3] + (whole.rows_key,)
             key = vkey + (key[3],)
+        # a working set out of a PLACED mirror is stored whole rows first
+        # (the rows that fill every slot of the grid, then the rows with a
+        # hole: pf.whole_first) and a launch runs the dense body over the
+        # first part.  The group-mode run alone takes such a set; min and
+        # max ride the per-series run, whose output is a row a series in
+        # the set's order: theirs is a set of its own, stored as it was
+        src = data if whole is None else whole
+        split_ok = not is_hist and t1.op in ("sum", "avg", "count")
+        if key is not None and src.placed and not split_ok:
+            key = vkey = key + ("rows in order",)
         plan = padded_vals = groups = gkeys = None
         # a plan reads differences of timestamps only: built from the row
         # moved to start at 0 (the window ends with it), two shards whose
@@ -351,20 +361,24 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                         return pf.pad_values(flat, vb_flat, plan)
                 # rows out of the mirror come padded to the ladder's rung
                 # already: the take, the pad and the kernel then compile
-                # once a rung, not once a row count
-                src = data if whole is None else whole
+                # once a rung, not once a row count.  Here, once a working
+                # set, its rows are ordered whole rows first where the
+                # mirror is placed and the set holds both kinds (each part
+                # on a rung of its own); nothing of it runs on a hit
                 Sp = pf.pad_series_count(rows)
-                vals = src.rows_padded("values", Sp)
-                vbase = src.rows_padded("vbase", Sp)
+                vals = src.rows_padded("values", Sp, split_ok)
+                vbase = src.rows_padded("vbase", Sp, split_ok)
                 if vbase is None:
                     vbase = np.zeros(vals.shape[0], np.float32)
                 # the kernel variant follows from the data: the phased one
                 # where some row of THIS working set has a phase
                 phase = None
                 if phased and src.phase.any():
-                    phase = src.rows_padded("phase", Sp)
+                    phase = src.rows_padded("phase", Sp, split_ok)
                 with span("leaf.pad_values"):
-                    return pf.pad_values(vals, vbase, plan, phase=phase)
+                    return pf.pad_values(
+                        vals, vbase, plan, phase=phase,
+                        split=src.whole_first() if split_ok else None)
             # one builder a working set: the other leaves that missed with
             # this one wait for its padded values and share them
             padded_vals = pad() if vkey is None else fused_values(vkey, pad)
@@ -375,19 +389,20 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                                  + np.arange(B)[None, :]).reshape(-1)
                     groups = pf.pad_groups(gids_flat, shape[0] * B,
                                            num_slots)
-                elif whole is None:
-                    groups = pf.pad_groups(gids, shape[0], len(gkeys))
                 else:
-                    # pad_groups' column with the part's rows where they
-                    # stand in the set: the rows this range leaves out
-                    # belong to no group, as the rows that pad the set to
-                    # its rung do
-                    col = np.full((pf.pad_series_count(rows), 1), -1,
-                                  np.int32)
-                    col[whole.member, 0] = gids
-                    groups = pf.PaddedGroups(
-                        jnp.asarray(col), np.bincount(
-                            gids, minlength=len(gkeys))[:len(gkeys)])
+                    # pad_groups' column with the leaf's rows where they
+                    # stand in the set's arrays: a part's rows where they
+                    # stand among the set's (the rows this range leaves
+                    # out belong to no group, as the rows that pad the set
+                    # to its rung do), a set stored whole rows first by
+                    # the order it was padded in
+                    at = None if whole is None else whole.member
+                    if padded_vals.at is not None:
+                        at = padded_vals.at if at is None \
+                            else padded_vals.at[at]
+                    groups = pf.pad_groups(
+                        gids, shape[0], len(gkeys), at=at,
+                        rows=padded_vals.vals_p.shape[0])
             _group_cache_insert(key, t1.by, t1.without, groups, gkeys)
         registry.counter("leaf_fused_kernel").increment()
         # the groups the leaf's epilogue sums into (a histogram's slots):
@@ -699,7 +714,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
             return estimate[0]
 
         def _check_scan_cap(when: str):
-            if not enforced:
+            # (a row's estimate is at most its count: rows that hold no
+            # more samples than the cap in all cannot pass it, whatever
+            # the range, and nothing is estimated)
+            if not enforced or facts.samples <= limit:
                 return
             to_scan = _scan_estimate()
             if to_scan > limit:
@@ -808,9 +826,10 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
                 # compared with what the leaf would gather and correct
                 per_sample = (store.num_buckets if col_def is not None
                               and col_def.col_type == "hist" else 1)
-                route_host = leaf_route(
-                    _scan_estimate(), per_sample, _route_cap,
-                    mirrored=mirrorable and not forced) == "host"
+                # (a leaf that may read the mirror goes to the device
+                # whatever its size: nothing is estimated for it)
+                route_host = not (mirrorable and not forced) and leaf_route(
+                    _scan_estimate(), per_sample, _route_cap) == "host"
         if not route_host and mirrorable:
             mirror = getattr(store, "device_mirror", None)
             if mirror is None:
@@ -1039,13 +1058,21 @@ class _WholeSet:
         return self._held["values"].shape[0]
 
     @property
+    def placed(self) -> bool:
+        return self._held["values"].placed
+
+    @property
     def phase(self) -> Optional[np.ndarray]:
         held = self._held["phase"]
         return None if held is None else held.host()
 
-    def rows_padded(self, field: str, rows_to: int):
+    def rows_padded(self, field: str, rows_to: int,
+                    whole_first: bool = False):
         held = self._held[field]
-        return None if held is None else held.resolve(rows_to)
+        return None if held is None else held.resolve(rows_to, whole_first)
+
+    def whole_first(self):
+        return self._held["values"].whole_first()
 
 
 # Range functions that count a NaN as an absent sample on every path they

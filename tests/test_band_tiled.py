@@ -108,13 +108,27 @@ def _lowered(fn, ragged=False, phased=False):
     ("avg_over_time", False, True,
      "041764e5aa7aaa9fcb2307e22f2288d66019c695"),
     ("rate", False, False, "aeb2d68283867a046ac6fdf077e0eb2a1b3cab4c"),
-], ids=["avg", "sum", "sum-ragged", "avg-phased", "rate"])
+    # ISSUE 50 (a ragged set stored whole rows first is two calls of
+    # `_run_set`): the dense and phased rate cells' flavors, and the ragged
+    # launch of a set that is not split, against 50f37bf's
+    ("increase", False, False, "ebd8ac90ea09cc13d19783708ce8043d6f45c6e3"),
+    ("rate", False, True, "b3ee258a40305844b7e6def20e0edd85c8a23b73"),
+    ("increase", False, True, "993bc83c458e9c05e77a5ae8e208ebe01fddafc1"),
+    ("rate", True, True, "8d57e7786ee250529babe1929fa9cb27b36aaf1a"),
+    ("rate", True, False, "fe0010c99b4bb8bfb263c42dbfc7ea1131076be6"),
+    ("sum_over_time", True, True,
+     "88b0c7e32daea850fc4704c6073b9a7fcb8015d1"),
+], ids=["avg", "sum", "sum-ragged", "avg-phased", "rate", "increase",
+        "rate-phased", "increase-phased", "rate-ragged-phased",
+        "rate-ragged", "sum-ragged-phased"])
 def test_the_hour_long_plan_runs_the_parents_program(fn, ragged, phased,
                                                      parent):
     """The program of a plan the resident form fits is the parent
     commit's, to the character: the lowered text of `_run` at the gauges
     cell's shape hashes to what 80f42bb's did (recorded from a checkout of
-    it), so its answers are the parent's bit for bit on any machine."""
+    it; the rate cells' from 50f37bf's, which are 80f42bb's too where both
+    are listed), so its answers are the parent's bit for bit on any
+    machine."""
     text = _lowered(fn, ragged, phased)
     assert hashlib.sha1(text.encode()).hexdigest() == parent
 

@@ -340,6 +340,64 @@ def test_a_row_block_at_the_windows_reach_compiles_for_v5e(
         == (len(S) if isinstance(S, tuple) else 1)
 
 
+# promchurn-counters-262k.open's four working sets as they are stored since
+# ISSUE 50: (rows that fill every slot, rows with a hole) a shard, 221,000 of
+# 290,975 whole
+CHURN_PARTS = ((55_600, 17_320), (55_400, 17_480), (55_250, 17_700),
+               (54_750, 17_475))
+
+
+@pytest.mark.parametrize("fn,phased", [
+    ("rate", True), ("increase", True), ("rate", False),
+    ("sum_over_time", True), ("avg_over_time", False)],
+    ids=["churn-rate", "churn-increase", "rate-one-row", "sum_ot-phased",
+         "avg_ot-one-row"])
+def test_a_launch_of_sets_stored_whole_rows_first_compiles_for_v5e(
+        one_chip, chip_runtime, fn, phased):
+    """One program a request, two Mosaic kernels a working set (ISSUE 50):
+    the dense body over the blocks of the rows that fill every slot, the
+    ragged body over the rest, each part a range of blocks of the one array
+    (a block index offset; under a trimmed plan an element offset beside the
+    prefetched tile), each with its own flavor's block; on one shared row
+    the dense part's counts are a scatter beside the kernels."""
+    plan = _plan(RANGE_MS, W_OPEN, T, 60_000)
+    assert plan.Tq == 512
+    flags = pf._flavor(plan, fn, True, False, True, phased)
+    splits = tuple(pf.pad_series_count(w) for w, _ in CHURN_PARTS)
+    rows = tuple(sw + pf.pad_series_count(h)
+                 for sw, (_, h) in zip(splits, CHURN_PARTS))
+    assert rows[0] == 57_344 + 18_432
+    sets = tuple(
+        (_sds((r, plan.Tp), jnp.float32, one_chip),
+         _sds((r, 1), jnp.float32, one_chip),
+         (_sds((r, 1), jnp.int32, one_chip),))
+        + ((_sds((r, 1), jnp.float32, one_chip),) if phased else ())
+        for r in rows)
+    compiled = pf._run.lower(
+        sets, None,
+        _sds((plan.prows if phased else plan.rows).shape, jnp.float32,
+             one_chip),
+        _sds(plan.tsrow.shape, jnp.float32, one_chip)
+        if flags.kind == "rate_family" else None,
+        num_groups=tuple(pf.pad_group_count(g) for g in (10, 10, 1, 20)),
+        splits=splits, **flags._asdict()).compile()
+    _check(compiled, pallas=True)
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 2 * len(rows)
+    # each part its own flavor's block: the dense phased body takes the
+    # block the dense twin's program takes
+    kind = flags.kind
+    (_, dense, _), (_, own, _) = pf._part_forms(
+        sets[:1], (24,), splits[:1], flags.Tq, plan.Tp, 128, kind, True,
+        phased, False)
+    assert own == 512
+    assert dense == (512 if phased or kind != "rate_family" else plan.Tp)
+    assert pf.pick_block(pf._load_cols(dense, plan.Tp), 128, 24, kind,
+                         False, phased=phased) == 256
+    assert pf.pick_block(640, 128, 24, kind, True, phased=phased) \
+        == (128 if kind == "rate_family" else 256)
+
+
 @pytest.mark.parametrize("fn,ragged,phased,windows", [
     ("rate", True, True, W_OPEN), ("rate", True, False, W_OPEN),
     ("increase", True, True, W_OPEN), ("rate", True, True, 31)],

@@ -27,6 +27,7 @@ from filodb_tpu.parallel.shardmapper import (ShardEvent, ShardMapper,
                                              SpreadProvider)
 from filodb_tpu.query.engine import QueryEngine
 from filodb_tpu.query.leafexec import _estimate_scan, leaf_route
+from filodb_tpu.query.rangevector import PlannerParams
 from filodb_tpu.standalone import DatasetConfig, FiloServer
 from filodb_tpu.utils.metrics import registry
 
@@ -109,6 +110,51 @@ def test_ranges_that_hold_every_life_share_one_object(lives):
     assert a.selection("gauge") is b.selection("gauge")
     cut = lives.lookup_partitions(filt, START, LATE - 1)
     assert cut is not a and not cut.shared
+
+
+def test_ranges_that_leave_the_same_series_out_share_one_object(lives):
+    """A range that cuts lives is answered by the series it leaves out, and
+    those are named by two ranks in the entry's sorted lives (two binary
+    searches; ISSUE 50): ranges one step apart that pass no birth and no
+    death get ONE object, and a hit lays no mask over the lives (they are
+    swapped for an object that raises on any read)."""
+    filt = ()
+    lives.lookup_partitions(filt, 0, 1 << 62)
+    ent = lives._lookup_cache[((), None)]
+    assert ent.whole.part_ids.size == 40
+    ent.parts.clear()               # the tests before this one laid masks
+    a = lives.lookup_partitions(filt, ENDED + 3 * STEP, LATE - 5 * STEP)
+    assert ent.starts.tolist() == sorted(ent.start.tolist())
+    assert ent.ends.tolist() == sorted(ent.end.tolist())
+    before = fills()
+
+    class Tripwire:
+        def _read(self, *a, **k):
+            raise AssertionError("a hit read the lives")
+        __le__ = __ge__ = __getitem__ = __array__ = __len__ = _read
+
+    start, end = ent.start, ent.end
+    ent.start = ent.end = Tripwire()
+    try:
+        for k in range(1, 4):
+            b = lives.lookup_partitions(filt, ENDED + (3 + k) * STEP,
+                                        LATE - (5 - k) * STEP)
+            assert b is a and b.within is ent.whole and not b.shared
+        # ... and a range that passes a death or a birth is a miss
+        with pytest.raises(AssertionError, match="read the lives"):
+            lives.lookup_partitions(filt, ENDED + STEP, LATE - 5 * STEP)
+        with pytest.raises(AssertionError, match="read the lives"):
+            lives.lookup_partitions(filt, ENDED + 3 * STEP, LATE)
+    finally:
+        ent.start, ent.end = start, end
+    assert not fills_since(before)
+    # random ranges: element for element the index's own answer
+    rng = np.random.default_rng(50)
+    for _ in range(200):
+        s, e = np.sort(rng.integers(START - 5 * STEP, LATE + 60 * STEP, 2))
+        got = lives.lookup_partitions(filt, int(s), int(e))
+        want = lives.index.part_ids_from_filters(filt, int(s), int(e))
+        assert got.part_ids.tolist() == want.tolist(), (s, e)
 
 
 # -------------------------------------------------------------- (b) estimate
@@ -223,8 +269,10 @@ def engine_over(ms, shards=1):
                        SpreadProvider(default_spread=0))
 
 
-def count_at(eng, t_ms, promql="count(heap_usage)"):
-    res = eng.query_range(promql, t_ms // 1000, 60, t_ms // 1000)
+def count_at(eng, t_ms, promql="count(heap_usage)", scan_limit=None):
+    params = None if scan_limit is None \
+        else PlannerParams(scan_limit=scan_limit)
+    res = eng.query_range(promql, t_ms // 1000, 60, t_ms // 1000, params)
     assert res.error is None, res.error
     if not res.blocks or not res.num_series:
         return None, res
@@ -365,16 +413,30 @@ def test_paging_that_pages_is_followed_by_a_fresh_estimate(tmp_path,
         lambda self, s, e: estimates.append(real(self, s, e)) or
         estimates[-1])
     at = START + 59 * STEP
-    v, res = count_at(eng, at)
-    assert v == 4 and res.stats.samples_paged > 0
-    # nothing resident, then what was paged: two estimates, the second
-    # from facts read after the paging moved the store's generation
-    assert len(estimates) == 2 and estimates[0] == 0 < estimates[1]
+    # a leaf whose rows hold no more samples in all than the scan cap
+    # estimates nothing (ISSUE 50): nothing resident, then the 84 samples
+    # an earlier instant's lookback pages in
+    v, res = count_at(eng, START + 20 * STEP, scan_limit=150)
+    assert v == 4 and res.stats.samples_paged == 84 and not estimates
+    # the newest instant pages the other 156: 240 resident pass the cap of
+    # 150, so the leaf estimates, from facts read AFTER the paging moved
+    # the store's generation (the range's share of the rows, 122; the
+    # stale facts' rows end before the range and would say 4)
+    v, res = count_at(eng, at, scan_limit=150)
+    assert v == 4 and res.stats.samples_paged == 156
+    assert estimates == [122]
     # the same leaf again: resident now, one estimate, nothing paged
     del estimates[:]
-    v, res = count_at(eng, at)
+    v, res = count_at(eng, at, scan_limit=150)
     assert v == 4 and res.stats.samples_paged == 0
-    assert len(estimates) == 1
+    assert estimates == [122]
+    # ... under the default cap no leaf estimates at all, and a cap the
+    # estimate passes refuses the query as before
+    del estimates[:]
+    v, res = count_at(eng, at)
+    assert v == 4 and not estimates
+    with pytest.raises(AssertionError, match="over the scan limit 100"):
+        count_at(eng, at, scan_limit=100)
 
 
 def test_eviction_forces_a_fill_and_a_repage(tmp_path):
@@ -650,13 +712,15 @@ def test_six_panels_of_one_open_race_one_key_and_agree(rig):
         assert len(sh._lookup_cache) == 1
 
 
-def test_one_lookup_and_one_estimate_a_leaf_and_the_counters_say_hit(rig):
+def test_one_lookup_and_no_estimate_a_leaf_and_the_counters_say_hit(rig):
     rig.query()
     before = rig.metrics()
     body = rig.query()
     names = [e["name"] for e in rig.spans(body["traceID"])]
+    # (no estimate: the rows hold fewer samples in all than the scan cap,
+    # and a leaf that reads the mirror is routed by no size; ISSUE 50)
     for span, n in (("leaf.index_lookup", SHARDS),
-                    ("leaf.scan_estimate", SHARDS),
+                    ("leaf.scan_estimate", 0),
                     ("leaf.page_check", SHARDS),
                     ("leaf.counts_copy", SHARDS)):
         assert names.count(span) == n, (span, names.count(span))

@@ -8,6 +8,7 @@ the CPU; `fused_enqueue_uploads_total` counts the puts really made."""
 import gc
 import sys
 import threading
+import time
 import weakref
 
 import numpy as np
@@ -131,25 +132,26 @@ def test_two_devices_hold_a_copy_each():
     assert on0[0].tobytes() == on1[0].tobytes()
 
 
-def test_six_first_enqueues_together_run_with_the_one_kept_array(
+def test_six_first_enqueues_together_make_one_put_and_share_its_array(
         monkeypatch):
-    """Six requests of an open may miss together: each puts (the counter
-    says so), the first array stored is the one all six go on with."""
+    """Six requests of an open may miss together: ONE puts (the others wait
+    at `pf._RESIDENT_LOCK`, ISSUE 50; the counter says so) and all six go
+    on with its array."""
     plan = _plan()
-    barrier = threading.Barrier(6)
-    real_put, gated = jax.device_put, []
+    real_put, puts, missed = jax.device_put, [], threading.Barrier(6)
 
-    def put_together(x, device=None, **kw):
-        if x is plan.rows:                  # every thread has missed
-            gated.append(threading.get_ident())
-            barrier.wait(timeout=60)
+    def slow_put(x, device=None, **kw):
+        if x is plan.rows:
+            puts.append(threading.get_ident())
+            time.sleep(0.2)                 # the others arrive meanwhile
         return real_put(x, device, **kw)
 
-    monkeypatch.setattr(jax, "device_put", put_together)
+    monkeypatch.setattr(jax, "device_put", slow_put)
     got, errors = [None] * 6, []
 
     def one(i):
         try:
+            missed.wait(timeout=60)         # all six before any enqueue
             rows, _, _ = pf.enqueue_operands(plan, None, "rate_family",
                                              False)
             got[i] = (rows, _call(plan, "dense-rate"))
@@ -168,9 +170,9 @@ def test_six_first_enqueues_together_run_with_the_one_kept_array(
     finally:
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
-    assert not errors and len(set(gated)) == 6
-    assert _uploads() - u0 == 6             # real puts, five of them dropped
-    assert len(plan.resident) == 1
+    assert not errors and len(puts) == 1
+    assert _uploads() - u0 == 1             # one real put, five waits
+    assert set(plan.resident) == {(None, "rows")}
     kept = plan.resident[(None, "rows")]
     assert all(rows is kept for rows, _ in got)
     assert len({res[0].tobytes() for _, res in got}) == 1

@@ -151,7 +151,7 @@ def test_one_request_is_one_tree_under_http_request(rig):
             "frontend.serve", "frontend.cache_lookup", "frontend.queue_wait",
             "query_parse", "query_plan", "execplan", "engine.present",
             "exec.ReduceAggregateExec", "exec.MultiSchemaPartitionsExec",
-            "leaf.index_lookup", "leaf.scan_estimate", "leaf.page_check",
+            "leaf.index_lookup", "leaf.page_check",
             "leaf.mirror_fresh", "leaf.counts_copy",
             "leaf.fused_prepare", "leaf.kernel_enqueue", "leaf.result_fetch",
             "leaf.present"} <= names

@@ -238,6 +238,10 @@ def _same_as_a_full_build(store, mirror):
         assert getattr(snap, f) == getattr(want, f), f
     for f in ("ts_row0", "phase", "counts"):
         np.testing.assert_array_equal(getattr(snap, f), getattr(want, f), f)
+    # the fact of each row: a refresh never calls a row whole that a full
+    # build does not (one that was holed stays so until the next build)
+    assert snap.whole.shape == want.whole.shape == (n,)
+    assert not (snap.whole & ~want.whole).any()
     np.testing.assert_array_equal(np.asarray(snap.ts_off)[:n],
                                   np.asarray(want.ts_off)[:n])
     np.testing.assert_array_equal(np.asarray(snap.phase_dev),
