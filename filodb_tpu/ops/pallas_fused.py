@@ -1352,6 +1352,92 @@ def _dense_counts(gids, rows, Gp: int, kind: str):
     return size[:, None] * valid.astype(jnp.float32)
 
 
+def _merge_hist_sets(res, rows, inv, perm, num_groups: Tuple[int, ...],
+                     B: int, kind: str, paired: bool):
+    """The cross-shard `hist_sum` merge inside a trace: `_run`'s output
+    over histogram working sets (set p's [Gp_p, Wp] f32 sums from row
+    sum(num_groups[:p]), slot = group x B + bucket, pad slots 0; beside
+    them the kernel's present counts when `paired`) -> (merged bucket sums
+    [Gm_p, B, Wp], present [Gm_p or 1, Wp] bool).
+
+    `inv` int32 [sets, Gm_p] and `perm` int32 [sets] are made on the host
+    from execbase._merge_layout's index (FusedDispatch.hist_layout):
+    inv[p, m] is the row of set p's group that is merged group m, or
+    `_ABSENT`, a row past any set's groups (taken as zeros: a group a
+    shard lacks adds zero); perm[j] is the set that is the reduce's child
+    j (the sets of a call are sorted by shape).  A set's sums are masked to its present
+    cells as the host presenter masks them (a dense set's by the shared
+    window validity, a ragged set's by the kernel's count of its bucket-0
+    slot: a series' buckets share one validity), taken into the merged
+    groups' order, and added set by set in CHILD order, f32.  `present`:
+    some series of the group in the window (the merged count the host path
+    NaNs by; on dense rows every group holds a series, so it is the
+    windows' validity, and a pad group's total of 0 is NaN by the
+    quantile's own rule)."""
+    Wp = rows.shape[1]
+    sums, counts = res if paired else (res, None)
+    valid = rows[_N1:_N1 + 1] >= (1.0 if kind in OVER_TIME_FNS else 2.0)
+    parts, cnts, lo = [], [], 0
+    for p, Gp in enumerate(num_groups):
+        Gq = -(-Gp // B)              # whole groups of B slots, pad rows 0
+
+        def by_group(x, lo=lo, Gp=Gp, Gq=Gq):
+            x = x[lo:lo + Gp]
+            if Gq * B != Gp:
+                x = jnp.pad(x, ((0, Gq * B - Gp), (0, 0)))
+            return x.reshape(Gq, B, Wp)
+
+        part = by_group(sums)
+        if paired:
+            cnt = by_group(counts)[:, 0, :]
+            part = jnp.where(cnt[:, None, :] > 0, part, 0.0)
+            cnts.append(jnp.take(cnt, inv[p], axis=0, mode="fill",
+                                 fill_value=0.0))
+        else:
+            part = jnp.where(valid[None], part, 0.0)
+        parts.append(jnp.take(part, inv[p], axis=0, mode="fill",
+                              fill_value=0.0))
+        lo += Gp
+    ordered = jnp.take(jnp.stack(parts), perm, axis=0)
+    merged = ordered[0]
+    for j in range(1, len(parts)):
+        merged = merged + ordered[j]
+    return merged, sum(cnts) > 0 if paired else valid
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_groups", "is_counter", "is_rate", "with_drops", "interpret",
+    "kind", "ragged", "per_series", "phased", "steps", "Tq", "splits"))
+def _run_hist_quantile(sets, offsets, rows, tsrow, inv, perm, q, les,
+                       **flags):
+    """`histogram_quantile(q, sum by (..)(rate(h[..])))` whole, in ONE
+    device program: `_run` over the request's histogram working sets (its
+    arguments and its body, untouched), then on its outputs, which never
+    leave the device, the cross-shard bucket merge (`_merge_hist_sets`)
+    and the quantile (ops/hist._histogram_quantile_jax, the traceable twin
+    of the host's NumPy one, the buckets moved last for it) -> [Gm_p, Wp]
+    f32, NaN where no series of the group was present in the window.  The
+    host reads that block and none of the sets'.  `flags`: `_run`'s
+    keyword arguments, every one static, handed on as they came.
+
+    `q` is a traced f32 scalar in [0, 1] and `les` [B] f32 an operand, so
+    the p50 / p90 / p99 of one grouping are one program; the merged group
+    count Gm_p (`pad_group_count` of the merged groups) is the one static
+    dimension beside `_run`'s, carried by `inv`'s shape.  Named so that
+    the device program is `jit__run_hist_quantile`: a trace's `^jit__run`
+    events are the fused launches, this one among them."""
+    from filodb_tpu.ops.hist import _histogram_quantile_jax
+    if flags.get("per_series"):
+        raise ValueError("the histogram epilogue merges group-mode sums")
+    res = _run(sets, offsets, rows, tsrow, **flags)
+    merged, present = _merge_hist_sets(
+        res, rows, inv, perm, flags["num_groups"], les.shape[0],
+        flags["kind"], flags["ragged"] or flags["phased"])
+    return jnp.where(
+        present, _histogram_quantile_jax(q, jnp.moveaxis(merged, 1, -1), les),
+        jnp.nan)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "Wp", "Gp", "is_counter", "is_rate", "with_drops", "interpret", "kind",
     "ragged", "per_series", "phased", "steps", "row0", "nrows"))
@@ -2079,14 +2165,16 @@ def launch_band_tiles(plan: FusedPlan, sets, num_groups, kind: str,
 
 
 def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
-                 splits=None, **flags):
+                 splits=None, hist=None, **flags):
     """One `_run` dispatch: the small host operands put explicitly
     (enqueue_operands), then the one jit call over `sets`, compile-
     watched.  -> (the call's lazy result, the uploaded rows).  A call
     whose program builds its band in tiles says so on the host's line of
     a trace (`filodb-part:leaf.band_tiled` around the jit call) and in the
     shape a compile event names.  `splits`: `_run`'s (None: no set of the
-    call is stored whole rows first)."""
+    call is stored whole rows first).  `hist`: the histogram epilogue's
+    device operands (inv, perm, q, les), for which the call is
+    `_run_hist_quantile` and its result the [Gm_p, Wp] quantiles."""
     from filodb_tpu.utils.devicetelem import watched_call
     from filodb_tpu.utils.metrics import span_part
     kind, ragged, phased = flags["kind"], flags["ragged"], flags["phased"]
@@ -2103,13 +2191,19 @@ def _enqueue_run(plan: FusedPlan, device, sets, offsets, num_groups,
     with span_part("leaf.enqueue_jit"), (
             span_part("leaf.band_tiled") if tiles
             else contextlib.nullcontext()):
+        sig = _run_shape_sig(sets, plan, num_groups, kind, ragged, phased,
+                             flags["steps"], flags["Tq"], splits) \
+            + (f":{tiles}bandtiles" if tiles else "")
+        # a call that ends in the histogram epilogue: the same arguments
+        # with the epilogue's operands behind them, and its merged groups
+        # in the shape a compile event names
+        run = _run if hist is None else _run_hist_quantile
+        if hist is not None:
+            sig += f":quantile{hist[0].shape[1]}g"
         res = watched_call(
-            "fused_run", _run,
-            _run_shape_sig(sets, plan, num_groups, kind, ragged, phased,
-                           flags["steps"], flags["Tq"], splits)
-            + (f":{tiles}bandtiles" if tiles else ""),
-            lambda: _run(sets, offs, rows, tsrow, num_groups=num_groups,
-                         splits=splits, **flags),
+            "fused_run", run, sig,
+            lambda: run(sets, offs, rows, tsrow, *(hist or ()),
+                        num_groups=num_groups, splits=splits, **flags),
             device=device)
     return res, rows
 
@@ -2170,6 +2264,11 @@ def _splits_of(values) -> Optional[Tuple[int, ...]]:
     return splits if any(splits) else None
 
 
+# an `inv` entry of the histogram epilogue for a group its set lacks: a row
+# past any set's groups, which `jnp.take(mode="fill")` reads as zeros
+_ABSENT = np.iinfo(np.int32).max
+
+
 class FusedDispatch:
     """The group-mode panels (`sum`, `avg`, ragged `count`) of the working
     sets that share a plan, a function flavor and a device, as ONE `_run`
@@ -2185,7 +2284,14 @@ class FusedDispatch:
     and not by the order the leaves were prepared in.  Every set is its
     own Mosaic kernel in the one XLA program; 30 sets compile cold in
     8.7 to 9.3 s on the chip's host against the server's 120 s query
-    budget (PERF.md section 6, PR 36), so a call is never split."""
+    budget (PERF.md section 6, PR 36), so a call is never split.
+
+    `hist_quantile` (before `enqueue`): the sets are the histogram leaves
+    of one `sum` reduce under a `histogram_quantile`; the call then ends
+    in the merge and the quantile (`_run_hist_quantile`) over the device
+    operands handed in (`hist_layout` makes the two that follow the sets'
+    order), `fetch` reads the [G, W] answer (`answer`) and nothing of the
+    sets', and `comps` is not to be asked."""
 
     def __init__(self, plan: FusedPlan, fn_name: str,
                  precorrected: bool = False, interpret: bool = False,
@@ -2200,6 +2306,8 @@ class FusedDispatch:
         self._counts = None         # dense rows: [rows, W] f64
         self._lo: list = []         # per set: its panels' first rows
         self._comps = None
+        self._hist = None           # ((inv, perm, q, les) on the device, G)
+        self.answer = None          # the epilogue's [G, W] f64, once fetched
 
     def __len__(self):
         return len(self._sets)
@@ -2217,17 +2325,62 @@ class FusedDispatch:
         self._sets.append((values, panels, offs, total))
         return len(self._sets) - 1
 
+    def _order(self):
+        """(the sets' order in the jit call: by shape, so that a program
+        is keyed by the shapes and not by the order the leaves came in;
+        every set's padded groups)."""
+        gps = [pad_group_count(total) for *_, total in self._sets]
+        return sorted(range(len(self._sets)), key=lambda k: (
+            self._sets[k][0].vals_p.shape[0], gps[k],
+            len(self._sets[k][1]), self._sets[k][0].split)), gps
+
+    def hist_layout(self, children, index, groups: int, B: int):
+        """The histogram epilogue's `inv` int32 [sets, Gm_p] and `perm`
+        int32 [sets] (`_merge_hist_sets` says what they hold), host arrays
+        in the order this call hands its sets to the jit: `children` the
+        call's sets (`add`'s indices) in the reduce's child order, every
+        one of them, one `sum` panel of (group, bucket) slots each;
+        `index` every child's every group's merged row, child order
+        (execbase._merge_layout's); `groups` the merged groups; `B` the
+        buckets.  What they hold follows from `index` and the sets' order
+        alone: neither the flavor nor the rows' data."""
+        if sorted(children) != list(range(len(self._sets))) \
+                or any(len(panels) != 1 or panels[0][2] != "sum"
+                       for _, panels, _, _ in self._sets):
+            raise ValueError("the histogram epilogue takes a call whose "
+                             "sets are the reduce's children, a panel each")
+        # (plain lists and one array each at the end: every NumPy call
+        # that lets the interpreter lock go costs a request a hand-off,
+        # and a fancy assignment a set read 6 ms a request on the chip)
+        at = {k: p for p, k in enumerate(self._order()[0])}
+        inv = [[_ABSENT] * pad_group_count(groups) for _ in at]
+        merged, lo = index.tolist(), 0
+        for k in children:
+            G = self._sets[k][3] // B           # the set's groups
+            row = inv[at[k]]
+            for g in range(G):
+                row[merged[lo + g]] = g
+            lo += G
+        return (np.array(inv, np.int32),
+                np.array([at[k] for k in children], np.int32))
+
+    def hist_quantile(self, operands, groups: int) -> None:
+        """End the call in the histogram epilogue: `operands` = (inv,
+        perm, q f32 scalar, les f32 [B]) ON THE CALL'S DEVICE (the jit
+        call transfers nothing), `groups` the merged groups `fetch` cuts
+        the answer to."""
+        self._hist = (tuple(operands), groups)
+
     def enqueue(self) -> None:
         """Issue the one jit call over everything added; reads nothing
         back.  Nothing added (every panel min/max or a dense count): no
         call."""
         if not self._sets or self._res is not None:
             return
-        gps = [pad_group_count(total) for *_, total in self._sets]
-        order = sorted(range(len(self._sets)), key=lambda k: (
-            self._sets[k][0].vals_p.shape[0], gps[k],
-            len(self._sets[k][1]), self._sets[k][0].split))
-        dense = not (self.flags.ragged or self.flags.phased)
+        order, gps = self._order()
+        # (an epilogue's present cells are made on the device)
+        dense = not (self.flags.ragged or self.flags.phased
+                     or self._hist is not None)
         # the groups' sizes down the output's rows (a set's panels, then
         # its pad rows at 0): the counts of dense rows are made from it
         gsize = np.zeros(sum(gps)) if dense else None
@@ -2249,6 +2402,7 @@ class FusedDispatch:
             self.plan, self.device, tuple(sets), offsets if multi else None,
             tuple(gps[k] for k in order),
             _splits_of(self._sets[k][0] for k in order),
+            None if self._hist is None else self._hist[0],
             **self.flags._asdict())
         if dense:
             # dense rows on one timestamp row: the counts are |group| x
@@ -2265,9 +2419,15 @@ class FusedDispatch:
         over the whole array: sums masked to the present cells beside
         their counts (the kernel's presence output on ragged or phased rows,
         else |group| x the shared window validity), f64 on the host."""
-        if self._comps is not None or self._res is None:
+        if self._comps is not None or self._res is None \
+                or self.answer is not None:
             return
         W = self.plan.W
+        if self._hist is not None:
+            # f32 widens exactly; NaN where no series was present
+            self.answer = np.asarray(self._res)[:self._hist[1], :W] \
+                .astype(np.float64)
+            return
         if self.flags.ragged or self.flags.phased:
             sums, counts = (r[:, :W] for r in jax.device_get(self._res))
         else:

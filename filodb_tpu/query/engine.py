@@ -146,6 +146,7 @@ class QueryEngine:
         """
         from filodb_tpu.ops import hostleaf
         from filodb_tpu.query import exprfuse
+        from filodb_tpu.query.fusedbatch import decline_hist_quantile
         from filodb_tpu.query.activequeries import (set_admission,
                                                     take_admission)
         # the coalesce LEADER's admission entry must bind to ITS query,
@@ -198,6 +199,10 @@ class QueryEngine:
                     if comp is not None:
                         comps[i] = comp
                         calls.extend(comp.calls)
+                        # a batch's panels share working sets and calls:
+                        # its histogram quantiles keep the host path
+                        for _ in comp.quantiles:
+                            decline_hist_quantile("batch")
             exprfuse.finish_prepared(calls)
         for i, ep, ctx, plan, parse_t, plan_t in entries:
             res = ep.execute(self.source)
@@ -310,7 +315,7 @@ class QueryEngine:
             took = preparing.dur_s
             if comp is not None:
                 with span("engine.dispatch_leaves") as dispatching:
-                    exprfuse.finish_prepared(comp.calls)
+                    exprfuse.finish_prepared(comp.calls, comp.quantiles)
                 took += dispatching.dur_s
                 hoisted = QueryStats()
                 fold_exec_tally(hoisted, took)

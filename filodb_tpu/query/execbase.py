@@ -525,7 +525,20 @@ def agg_token(op: str, by, without,
     return ("agg", op, tuple(by), tuple(without), data_token)
 
 
-Data = Union[RawBlock, ResultBlock, ScalarResult, AggPartial, None]
+@dataclasses.dataclass
+class HistQuantileAnswer:
+    """`histogram_quantile(q, sum by (..)(rate(h[..])))` answered whole by
+    the epilogue of the request's one device call (query/fusedbatch.py
+    HistQuantileCall): what a `ReduceAggregateExec` whose children's
+    bucket sums never left the device hands its presenter, which passes it
+    on, and the `InstantVectorFunctionMapper` it was made for, which takes
+    `block` as its own result.  None of the three does array work."""
+    q: float
+    block: ResultBlock
+
+
+Data = Union[RawBlock, ResultBlock, ScalarResult, AggPartial,
+             HistQuantileAnswer, None]
 
 
 def _block_empty(wends: np.ndarray) -> ResultBlock:

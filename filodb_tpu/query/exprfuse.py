@@ -22,6 +22,14 @@ physical plan tree (or a dashboard batch of trees), it
     fused kernel dispatch — its leaf keeps the parked FusedCall and
     ``_finish_or_degrade`` surfaces ``query_canceled``).
 
+  * recognises ``histogram_quantile(q, sum [by (..)](rate(h[..])))`` over
+    fused histogram leaves (``_hist_quantiles``) and asks the leaves' one
+    device call to end in the cross-shard bucket merge and the quantile
+    (``fusedbatch.HistQuantileCall``): the finished ``[G, W]`` block is
+    parked on the reduce node and neither it, its presenter nor the
+    function mapper does array work.  A tree the epilogue cannot take
+    (``hist_device_quantile_declined{reason}``) keeps the host path.
+
 Any leaf whose shape the fused path can't take degrades node-by-node
 to the general engine with bit-identical results — counted under
 ``query_exprfuse{verdict="degraded"}`` and surfaced per query in
@@ -44,6 +52,9 @@ class TreeCompilation:
     calls: List[Tuple[object, object]] = field(default_factory=list)
     fused: int = 0          # leaves whose preflight produced fused work
     degraded: int = 0       # eligible leaves that fell to the general path
+    # the tree's histogram quantiles the device call may finish
+    # (fusedbatch.HistQuantileCall: the reduce node and the constant q)
+    quantiles: list = field(default_factory=list)
 
 
 def _eligible_leaves(ep):
@@ -53,6 +64,67 @@ def _eligible_leaves(ep):
     return [leaf for leaf in _walk_plan(ep)
             if isinstance(leaf, MultiSchemaPartitionsExec)
             and isinstance(leaf.dispatcher, InProcessPlanDispatcher)]
+
+
+def _parked_hist(leaf) -> bool:
+    """Whether a prepared leaf gathered a native histogram column (its
+    parked block's values are [S, T, B]), whatever its preflight made of
+    it."""
+    from filodb_tpu.query.execbase import RawBlock
+    parked = getattr(leaf, "_prefused", None)
+    return parked is not None and isinstance(parked[0], RawBlock) \
+        and len(parked[0].values_shape) == 3
+
+
+def _hist_quantiles(ep, calls) -> list:
+    """The tree's `histogram_quantile(q, <presenter>(ReduceAggregateExec
+    sum(histogram leaves)))` nodes whose merge and quantile can run as the
+    epilogue of the leaves' device call, from what the tree and the
+    prepared leaves show: the function is `histogram_quantile` with a
+    constant q in [0, 1]; the reduce runs here and every child of it is a
+    prepared leaf holding a fused histogram call; the children carry ONE
+    bucket scheme.  A histogram quantile that fails one of these books
+    `hist_device_quantile_declined{reason}` and keeps the host path
+    (whether the children then ride one device call is the dispatch's to
+    see: fusedbatch.finish_fused_calls)."""
+    import numpy as np
+
+    from filodb_tpu.query.engine import _walk_plan
+    from filodb_tpu.query.execbase import InProcessPlanDispatcher
+    from filodb_tpu.query.fusedbatch import (HistQuantileCall,
+                                             decline_hist_quantile)
+    from filodb_tpu.query.nonleaf import ReduceAggregateExec
+    from filodb_tpu.query.transformers import (AggregatePresenter,
+                                               InstantVectorFunctionMapper)
+    fused = {id(leaf): fc for leaf, fc in calls}
+    out = []
+    for node in _walk_plan(ep):
+        ts = node.transformers
+        if type(node) is not ReduceAggregateExec or node.op != "sum" \
+                or len(ts) < 2 \
+                or not isinstance(ts[0], AggregatePresenter) \
+                or not isinstance(ts[1], InstantVectorFunctionMapper) \
+                or ts[1].function not in ("histogram_quantile",
+                                          "histogram_max_quantile") \
+                or not any(_parked_hist(c) for c in node.children):
+            continue
+        q = ts[1].args[0] if ts[1].args else None
+        fcs = [fused.get(id(c)) for c in node.children]
+        if ts[1].function != "histogram_quantile":
+            decline_hist_quantile("function")
+        elif isinstance(q, bool) or not isinstance(q, (int, float)) \
+                or not 0.0 <= q <= 1.0:
+            decline_hist_quantile("quantile")
+        elif not isinstance(node.dispatcher, InProcessPlanDispatcher) \
+                or node._dedup_groups() \
+                or any(fc is None or fc.bucket_les is None for fc in fcs):
+            decline_hist_quantile("child")
+        elif any(not np.array_equal(fc.bucket_les, fcs[0].bucket_les)
+                 for fc in fcs[1:]):
+            decline_hist_quantile("scheme")
+        else:
+            out.append(HistQuantileCall(float(q), node=node))
+    return out
 
 
 def compile_tree(ep, source, *, min_leaves: int = 1
@@ -88,10 +160,11 @@ def compile_tree(ep, source, *, min_leaves: int = 1
             comp.degraded += 1
             registry.counter("query_exprfuse",
                              verdict="degraded").increment()
+    comp.quantiles = _hist_quantiles(ep, comp.calls)
     return comp
 
 
-def finish_prepared(calls) -> None:
+def finish_prepared(calls, quantiles=()) -> None:
     """Phase-2: merge the prepared FusedCalls into batched dispatches.
 
     Killed queries are filtered out BEFORE any device dispatch (PR 13
@@ -99,7 +172,15 @@ def finish_prepared(calls) -> None:
     ``_finish_or_degrade`` cancel check surfaces ``query_canceled``
     without the kernel ever running.  A batch-level dispatch failure
     likewise leaves every FusedCall parked for standalone finishing.
+
+    ``quantiles`` (a tree's ``TreeCompilation.quantiles``): the histogram
+    quantiles to finish as the epilogue of their leaves' device call.
+    One that was answered is parked on its reduce node
+    (``hist_answer``, an ``execbase.HistQuantileAnswer``), its leaves get
+    partials without sums; one the dispatch declined, or whose query was
+    killed, leaves nothing parked and the tree runs as it always did.
     """
+    from filodb_tpu.query.execbase import HistQuantileAnswer
     from filodb_tpu.query.fusedbatch import finish_fused_calls
     if not calls:
         return
@@ -111,13 +192,21 @@ def finish_prepared(calls) -> None:
         live.append((leaf, fc))
     if not live:
         return
+    at = {id(leaf): i for i, (leaf, _) in enumerate(live)}
+    asked = [hq for hq in quantiles
+             if all(id(c) in at for c in hq.node.children)]
+    for hq in asked:
+        hq.calls = [at[id(c)] for c in hq.node.children]
     try:
-        partials = finish_fused_calls([fc for _, fc in live])
+        partials = finish_fused_calls([fc for _, fc in live], asked)
     except Exception:  # noqa: BLE001 — leaves finish standalone
         return
     for (leaf, fc), partial in zip(live, partials):
         if partial is not None:
             leaf.inject_fused(partial)
+    for hq in asked:
+        if hq.block is not None:
+            hq.node.hist_answer = HistQuantileAnswer(hq.q, hq.block)
 
 
 # --------------------------------------------------- join index-map cache

@@ -7,11 +7,14 @@ doc/kernels.md "Dashboard batching" for the design and on-chip numbers.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+import threading
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from filodb_tpu.query.execbase import AggPartial
+from filodb_tpu.query.execbase import (AggPartial, _lru_touch,
+                                       _merge_layout, _reduced_token)
+from filodb_tpu.query.rangevector import ResultBlock
 from filodb_tpu.utils.metrics import span
 
 @dataclasses.dataclass
@@ -61,7 +64,35 @@ class FusedCall:
         return ("id",) + base + (id(self.plan), id(self.values.vals_p))
 
 
-def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
+@dataclasses.dataclass
+class HistQuantileCall:
+    """`histogram_quantile(q, sum by (..)(rate(h[..])))` over calls that
+    are the `sum` reduce's children, asked of finish_fused_calls as the
+    epilogue of their one device call.  query/exprfuse.py recognises the
+    tree (`node`: its reduce, where the answer is parked) and, once the
+    request's live calls are listed, says where the children stand in that
+    list (`calls`, in child order).  Answered: `block` is the finished
+    [G, W] ResultBlock (merged keys in execbase._merge_layout's first-seen
+    order, the reduce's cache_token) and each child's partial is a
+    hist_sum AggPartial WITHOUT `comp` (its sums never left the device).
+    Declined (`block` stays None, `hist_device_quantile_declined{reason}`
+    booked): the children are finished as any other call."""
+    q: float
+    calls: List[int] = dataclasses.field(default_factory=list)
+    node: object = None
+    block: Optional[ResultBlock] = None
+
+
+def decline_hist_quantile(reason: str) -> None:
+    """One histogram quantile that takes the host path, and why."""
+    from filodb_tpu.utils.metrics import registry
+    registry.counter("hist_device_quantile_declined",
+                     reason=reason).increment()
+
+
+def finish_fused_calls(calls: List[FusedCall],
+                       quantiles: Sequence[HistQuantileCall] = ()
+                       ) -> List[AggPartial]:
     """Phase-2 of engine.query_range_batch and of a single request's
     hoisted leaves: dispatch every FusedCall.  Compatible calls (one
     working set) merge into one kernel run; a merged set whose combined
@@ -70,7 +101,10 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
     _try_fused already passed).  The sets that share a plan object, a
     flavor and a device then ride ONE device program and ONE readback
     (pf.FusedDispatch): a request's shard leaves cost the host one
-    dispatch, not one a shard."""
+    dispatch, not one a shard.  `quantiles`: the histogram quantiles to
+    finish as the epilogue of their children's device call
+    (HistQuantileCall), where those children are that call's sets and
+    nothing else is."""
     from filodb_tpu.ops import pallas_fused as pf
     from filodb_tpu.utils.metrics import registry
     out: List[Optional[AggPartial]] = [None] * len(calls)
@@ -159,9 +193,24 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
     # on different chips — dispatching everything first lets those chips
     # compute concurrently instead of serializing on each call's host
     # readback (the per-device dispatch contract, doc/multichip.md).
+    # a histogram quantile rides its children's call where the call's sets
+    # are those children, one unshared set each, and nothing else is
+    home = {take[0]: takes for takes in by_call.values() for take in takes
+            if len(take) == 1 and take[0] not in alias.values()}
+    epilogue_of: Dict[int, HistQuantileCall] = {}
+    for hq in quantiles:
+        takes = home.get(hq.calls[0])
+        if takes is None or len(takes) != len(hq.calls) \
+                or any(home.get(i) is not takes for i in hq.calls):
+            decline_hist_quantile("dispatch")
+        elif not _epilogue_fits(calls, hq):
+            decline_hist_quantile("size")
+        else:
+            epilogue_of[id(takes)] = hq
     pending = []
     for (*_, device), takes in by_call.items():
         fc0 = calls[takes[0][0]]
+        hq = epilogue_of.get(id(takes))
         with span("leaf.kernel_enqueue") as enqueue:
             disp = pf.FusedDispatch(fc0.plan, fc0.fn, fc0.precorrected,
                                     fc0.interpret, fc0.ragged, device,
@@ -176,19 +225,31 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
                     precorrected=fc.precorrected, interpret=fc.interpret,
                     ragged=fc.ragged, num_series=fc.num_series, lazy=True,
                     dispatch=disp))
+            merge = None if hq is None else _ask_epilogue(
+                calls, hq, takes, disp)
             disp.enqueue()
-        pending.append((takes, finishers, disp, enqueue.dur_s))
+        pending.append((takes, finishers, disp, enqueue.dur_s, hq, merge))
     from filodb_tpu.utils.devicetelem import telem
-    for takes, finishers, disp, disp_s in pending:
+    for takes, finishers, disp, disp_s, hq, merge in pending:
         # the np.asarray that blocks on the device and copies out, once
         # for the call, and the panels' presentation over the whole array
+        # (a call that ends in a histogram quantile: its [G, W] answer is
+        # the request's, and no set's block is read or presented)
         with span("leaf.result_fetch") as fetch:
             disp.fetch()
-            comps = [finisher() for finisher in finishers]
-        with span("leaf.present"):
-            for take, set_comps in zip(takes, comps):
-                for i, comp in zip(take, set_comps):
-                    out[i] = _present(calls[i], comp)
+            comps = [] if hq else [finisher() for finisher in finishers]
+        if hq is not None:
+            parts, gkeys, token = merge
+            for i, part in zip(hq.calls, parts):
+                out[i] = part
+            hq.block = ResultBlock(gkeys, parts[0].wends, disp.answer,
+                                   cache_token=token)
+            comps = [[disp.answer]]
+        else:
+            with span("leaf.present"):
+                for take, set_comps in zip(takes, comps):
+                    for i, comp in zip(take, set_comps):
+                        out[i] = _present(calls[i], comp)
         # kernel enqueue + result readback, attributed to the node that
         # triggered it AND recorded in the per-chip kernel ledger
         # (utils/devicetelem) — record_dispatch feeds the exec tally, so
@@ -209,6 +270,81 @@ def finish_fused_calls(calls: List[FusedCall]) -> List[AggPartial]:
         src = out[j]
         out[i] = dataclasses.replace(src) if src is not None else None
     return out
+
+
+def _hollow_hist_partial(fc: FusedCall) -> AggPartial:
+    """A histogram leaf's partial whose sums stayed on the device (the
+    call's epilogue merged them): its keys, windows, scheme and token."""
+    return AggPartial("hist_sum", fc.gkeys, fc.wends,
+                      bucket_les=fc.bucket_les, cache_token=fc.cache_token)
+
+
+def _epilogue_fits(calls: List[FusedCall], hq: HistQuantileCall) -> bool:
+    """Whether the epilogue's temporaries (a merged [Gm_p, B, Wp] f32
+    block for every set) stay under what the call reads anyway, the sets'
+    padded values: it is then never what decides whether the program fits
+    the device.  The merged groups are at most the children's in all."""
+    from filodb_tpu.ops import pallas_fused as pf
+    fcs = [calls[i] for i in hq.calls]
+    fc0 = fcs[0]
+    Wp = pf._pad_to(max(fc0.plan.W, 1), pf._LANE)
+    merged = pf.pad_group_count(sum(len(fc.gkeys) for fc in fcs))
+    return len(fcs) * merged * fc0.num_buckets * Wp * 4 <= sum(
+        int(getattr(fc.values.vals_p, "nbytes", 0)) for fc in fcs)
+
+
+# the epilogue's small operands on the device, least recently used first
+_EPILOGUE_OPERANDS: Dict[tuple, object] = {}
+_EPILOGUE_OPERANDS_MAX = 512
+_EPILOGUE_OPERANDS_LOCK = threading.Lock()
+
+
+def _resident(array: np.ndarray, device):
+    """`array` on `device`, put once and found again by its CONTENT (its
+    bytes, dtype and shape are the key: a merge layout's `inv` and `perm`,
+    a `q`, a scheme's `les`, some hundred bytes each), so that a histogram
+    request that ends on the device transfers nothing once its grouping
+    was seen: an explicit put a call costs the request milliseconds of
+    waiting on this backend (PERF.md section 6, PR 41 and PR 51).  Keyed
+    by what the array IS, an entry cannot be stale, whatever a token
+    leaves unnamed.  A put books `fused_enqueue_uploads_total`; of two
+    threads that miss together the first insert stays."""
+    import jax
+
+    from filodb_tpu.utils.metrics import registry
+    key = (array.tobytes(), array.dtype.str, array.shape, device)
+    with _EPILOGUE_OPERANDS_LOCK:
+        held = _lru_touch(_EPILOGUE_OPERANDS, key)
+    if held is None:
+        registry.counter("fused_enqueue_uploads").increment()
+        made = jax.device_put(array, device)
+        with _EPILOGUE_OPERANDS_LOCK:
+            held = _EPILOGUE_OPERANDS.setdefault(key, made)
+            while len(_EPILOGUE_OPERANDS) > _EPILOGUE_OPERANDS_MAX:
+                del _EPILOGUE_OPERANDS[next(iter(_EPILOGUE_OPERANDS))]
+    return held
+
+
+def _ask_epilogue(calls: List[FusedCall], hq: HistQuantileCall, takes,
+                  disp) -> tuple:
+    """Hand `disp` the histogram epilogue of `hq` -> (the children's
+    partials, which hold no sums; the merged keys; the reduce's token).
+    The layout is execbase._merge_layout's, remembered by the children's
+    tokens: the merged keys in first-seen order and every child group's
+    merged row; a hit hashes no key."""
+    with span("leaf.hist_epilogue"):
+        parts = [_hollow_hist_partial(calls[i]) for i in hq.calls]
+        token = _reduced_token(parts)
+        gkeys, index = _merge_layout(parts, token)
+        at = {take[0]: k for k, take in enumerate(takes)}
+        les = np.asarray(calls[hq.calls[0]].bucket_les, np.float32)
+        layout = disp.hist_layout([at[i] for i in hq.calls], index,
+                                  len(gkeys), len(les))
+        disp.hist_quantile(
+            [_resident(a, disp.device)
+             for a in (*layout, np.asarray(hq.q, np.float32), les)],
+            len(gkeys))
+    return parts, gkeys, token
 
 
 def _present(fc: FusedCall, comp) -> AggPartial:

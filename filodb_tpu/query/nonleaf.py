@@ -175,6 +175,11 @@ class ReduceAggregateExec(NonLeafExecPlan):
     # partials may prune to the node-local top-k before crossing the wire
     node_level = False
 
+    # the histogram quantile above this reduce, where the children's one
+    # device call finished it (an execbase.HistQuantileAnswer, parked by
+    # exprfuse.finish_prepared for the one execution that follows)
+    hist_answer = None
+
     def __init__(self, ctx, children, op: str, params: Tuple = (),
                  by: Tuple[str, ...] = (), without: Tuple[str, ...] = ()):
         super().__init__(ctx, children)
@@ -217,6 +222,12 @@ class ReduceAggregateExec(NonLeafExecPlan):
         npn = getattr(self, "pushdown_not_pushable", 0)
         if npn:
             stats.pushdown_not_pushable += npn
+        # the histogram quantile above this reduce came back from the
+        # children's device call already (the children's partials hold no
+        # sums): nothing to merge
+        answer, self.hist_answer = self.hist_answer, None
+        if answer is not None:
+            return answer, stats
         return self.compose(results, stats), stats
 
 

@@ -31,8 +31,8 @@ from filodb_tpu.query.rangevector import (QueryContext, QueryResult, QueryStats,
                                           concat_blocks, remove_nan_series)
 
 from filodb_tpu.query.execbase import (
-    AggPartial, Data, GroupCardinalityError, RawBlock, ScalarResult,
-    _block_empty, _lru_touch, agg_token, present_partial)
+    AggPartial, Data, GroupCardinalityError, HistQuantileAnswer, RawBlock,
+    ScalarResult, _block_empty, _lru_touch, agg_token, present_partial)
 
 
 # ------------------------------------------------------------- transformers
@@ -149,6 +149,15 @@ class RepeatToGridMapper(RangeVectorTransformer):
                            cache_token=data.cache_token)
 
 
+def _count_hist_quantile(on_device: bool) -> None:
+    """A quantile over a native-histogram block, and whether the device
+    call's epilogue answered it: hist_device_quantiles_total over
+    hist_quantile_requests_total is the share that did."""
+    from filodb_tpu.utils.metrics import registry
+    registry.counter("hist_quantile_requests").increment()
+    registry.counter("hist_device_quantiles").increment(int(on_device))
+
+
 @dataclasses.dataclass
 class InstantVectorFunctionMapper(RangeVectorTransformer):
     """ref: exec/RangeVectorTransformer.scala:61."""
@@ -160,6 +169,13 @@ class InstantVectorFunctionMapper(RangeVectorTransformer):
 
     def apply(self, data: Data, ctx: QueryContext, stats: QueryStats,
               source=None) -> Data:
+        if isinstance(data, HistQuantileAnswer):
+            # this mapper's quantile, finished on the device with the
+            # merge under it (exprfuse recognised the tree by this mapper)
+            assert self.function == "histogram_quantile" \
+                and float(self.args[0]) == data.q
+            _count_hist_quantile(on_device=True)
+            return data.block
         if not isinstance(data, ResultBlock) or data.num_series == 0:
             return data
         vals = data.values
@@ -171,6 +187,7 @@ class InstantVectorFunctionMapper(RangeVectorTransformer):
                 # promql/quantile.go bucketQuantile; the reference accepts
                 # both forms, prometheus/.../PrometheusModel.scala)
                 return self._classic_bucket_quantile(q, data)
+            _count_hist_quantile(on_device=False)
             # no jnp pre-conversion: host [G, W, B] comps take the
             # numpy twin inside histogram_quantile (a device round trip
             # here cost a ~70 ms dispatch per quantile panel)
@@ -483,8 +500,8 @@ class AggregatePresenter(RangeVectorTransformer):
 
     def apply(self, data: Data, ctx: QueryContext, stats: QueryStats,
               source=None) -> Data:
-        if data is None:
-            return None
+        if data is None or isinstance(data, HistQuantileAnswer):
+            return data
         assert isinstance(data, AggPartial)
         return present_partial(data)
 

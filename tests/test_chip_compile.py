@@ -635,6 +635,40 @@ def test_histogram_gather_and_flatten_compile_for_v5e(one_chip,
     _check(flat, pallas=False)
 
 
+@pytest.mark.parametrize("groups,merged", [
+    ((1, 1, 1, 1), 1), ((2, 2, 2, 2), 2), ((5, 5, 5, 5), 10)],
+    ids=["ungrouped", "by-dc", "by-ns"])
+def test_the_histogram_quantile_epilogue_compiles_for_v5e(
+        one_chip, chip_runtime, groups, merged):
+    """`_run_hist_quantile` as a FusedDispatch calls it for
+    histdev-64b-4k.quantiles (ISSUE 51): the four shards' working sets of
+    series x 64 bucket rows, (group, bucket) slots, an hour at a window a
+    minute, and behind the four kernels the bucket merge and the quantile
+    in the same program, at the cell's three merged group counts: the
+    kernels under Mosaic's scoped limit as in `_run`, the epilogue's XLA
+    ops accepted, one [Gm_p, Wp] f32 block out."""
+    B, rows = 64, (1_235, 1_223, 817, 821)
+    plan = _plan(RANGE_MS, W_OPEN, T, 60_000)
+    flags = pf._flavor(plan, "rate", True, False, False, False)
+    Sp = [pf.pad_series_count(s * B) for s in rows]
+    gps = tuple(pf.pad_group_count(g * B) for g in groups)
+    Gm_p, Wp = pf.pad_group_count(merged), plan.t1.shape[1]
+    sets = tuple((_sds((sp, plan.Tp), jnp.float32, one_chip),
+                  _sds((sp, 1), jnp.float32, one_chip),
+                  (_sds((sp, 1), jnp.int32, one_chip),)) for sp in Sp)
+    compiled = pf._run_hist_quantile.lower(
+        sets, None, _sds(plan.rows.shape, jnp.float32, one_chip), None,
+        _sds((4, Gm_p), jnp.int32, one_chip),
+        _sds((4,), jnp.int32, one_chip), _sds((), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip), num_groups=gps,
+        **flags._asdict()).compile()
+    _check(compiled, pallas=True)
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 4
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (Gm_p, Wp) and out.dtype == jnp.float32
+
+
 @pytest.mark.parametrize("fn", ["rate", "sum_over_time"])
 def test_general_xla_leaf_compiles_for_v5e(one_chip, chip_runtime,
                                            fn):

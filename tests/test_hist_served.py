@@ -87,8 +87,13 @@ def test_inflated_fused_bucket_sums_fail_the_comparison(rig, monkeypatch):
     """Every second bucket's fused sums one part in a thousand too large.
     (All of them alike would cancel: a quantile's rank and every bucket
     scale together.  The comparison is of quantiles, so it is blind to a
-    common factor on a leaf's sums, and says so here.)"""
+    common factor on a leaf's sums, and says so here.)  Bent where the
+    host reads a leaf's sums back: the host path, which since ISSUE 51 a
+    request takes only where the device call's epilogue declines it (the
+    recognition is switched off here; the next test bends the epilogue)."""
     from filodb_tpu.ops import pallas_fused as pf
+    from filodb_tpu.query import exprfuse
+    monkeypatch.setattr(exprfuse, "_hist_quantiles", lambda ep, calls: [])
     real = pf.fused_leaf_agg_batch
 
     def bent(factor_of):
@@ -112,6 +117,76 @@ def test_inflated_fused_bucket_sums_fail_the_comparison(rig, monkeypatch):
     assert max(worst(rig, 3)) <= TOL
 
 
+def test_inflated_merged_bucket_sums_fail_the_comparison(rig, monkeypatch):
+    """The same two bends where the sums are since ISSUE 51: on the
+    device, between the epilogue's merge and its quantile."""
+    import jax.numpy as jnp
+
+    from filodb_tpu.ops import pallas_fused as pf
+    real = pf._merge_hist_sets
+
+    def bent(factor_of):
+        def merge(*a, **kw):
+            merged, present = real(*a, **kw)
+            return merged * factor_of(merged.shape[1])[None, :, None], \
+                present
+        return merge
+    try:
+        monkeypatch.setattr(pf, "_merge_hist_sets", bent(
+            lambda B: jnp.where(jnp.arange(B) % 2, 1.001, 1.0)
+            .astype(jnp.float32)))
+        pf._run_hist_quantile.clear_cache()
+        assert max(worst(rig, 4)) > 10 * TOL
+        monkeypatch.setattr(pf, "_merge_hist_sets", bent(
+            lambda B: jnp.full(B, 1.001, jnp.float32)))
+        pf._run_hist_quantile.clear_cache()
+        assert max(worst(rig, 5)) <= TOL
+    finally:
+        monkeypatch.undo()
+        pf._run_hist_quantile.clear_cache()
+
+
+# ------------------------------------------------- the epilogue (ISSUE 51)
+
+
+def test_a_served_quantile_is_one_call_and_no_host_histogram_work(rig):
+    """Through the HTTP door: every request of an open is answered by the
+    epilogue of its one device call; the reduce merges nothing, none of
+    the three host spans appears, and the open's six panels (p50 / p90 /
+    p99 over three groupings) run THREE compiled programs, one a
+    grouping, whatever the q."""
+    from filodb_tpu.ops import pallas_fused as pf
+    for req in rig.open(6):                 # every grouping compiled
+        (err, why), _ = rig.ask(req)
+        assert why is None and err <= TOL
+    time.sleep(0.3)                         # spans book after the body
+    programs = pf._run_hist_quantile._cache_size()
+    plain = pf._run._cache_size()
+    before = rig.counters()
+    for req in rig.open(7):
+        (err, why), _ = rig.ask(req)
+        assert why is None and err <= TOL
+    time.sleep(0.3)
+    after = rig.counters()
+
+    def delta(fam):
+        return after.get(fam, 0.0) - before.get(fam, 0.0)
+    assert delta("hist_device_quantiles_total") == 6
+    assert delta("hist_quantile_requests_total") == 6
+    assert delta("hist_device_quantile_declined_total") == 0
+    assert delta("fused_enqueues_total") == 6
+    assert delta("leaf_hist_fused_total") == 6 * histrig.SHARDS
+    assert delta("reduce_merge_calls_total") == 0
+    assert delta("span_leaf_hist_epilogue_calls_total") == 6
+    for name in ("exec_hist_reduce", "exec_hist_quantile",
+                 "leaf_hist_finish", "leaf_present"):
+        assert delta(f"span_{name}_calls_total") == 0, name
+    assert pf._run_hist_quantile._cache_size() == programs
+    assert pf._run._cache_size() == plain
+    # three groupings a scheme, not six panels
+    assert programs <= 3 * len(SEEDS)
+
+
 # ------------------------------------------------------------------- spans
 
 
@@ -128,7 +203,8 @@ def tree(rig, trace_id):
 
 def test_a_histogram_query_adds_four_spans_nested_where_the_work_was():
     """One shard, its first histogram query (nothing cached): each of the
-    four spans once, each a child of the span that held its work before."""
+    four spans once, each a child of the span that held its work before.
+    (One leaf: the engine hoists nothing and the host path runs.)"""
     r = histrig.HistRig(SEEDS[0], shards=1)
     try:
         (err, why), body = r.ask(r.open(0)[0])

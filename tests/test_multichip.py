@@ -157,8 +157,28 @@ def test_engine_sharded_mirrors_bit_parity(monkeypatch, parity_engines,
     partial merges, only the executing device differs."""
     eng_flat, eng_shard, fused_kernel = parity_engines
     query, route = QUERIES[case]
+    on_device = registry.counter("hist_device_quantiles").value
     flat = _ask(monkeypatch, eng_flat, query, False, fused_kernel)
     assert not _mirror_devices(eng_flat)
+    if registry.counter("hist_device_quantiles").value > on_device:
+        # ISSUE 51: on ONE device a histogram quantile's merge and quantile
+        # are the epilogue of the leaves' one call, in f32; over sharded
+        # mirrors the leaves are a call a device and the fold stays on the
+        # host, in f64 (declined `dispatch`).  Bit parity is between the
+        # two engines' HOST paths; the epilogue's answer is held to it
+        from filodb_tpu.query import exprfuse
+        epilogue = flat
+        with monkeypatch.context() as m:
+            m.setattr(exprfuse, "_hist_quantiles", lambda ep, calls: [])
+            flat = _ask(m, eng_flat, query, False, fused_kernel)
+        assert flat.keys() == epilogue.keys()
+        for k, want in flat.items():
+            np.testing.assert_allclose(epilogue[k], want, rtol=2e-5,
+                                       err_msg=str(k))
+    declined = registry.counter("hist_device_quantile_declined",
+                                reason="dispatch")
+    on_device, on_host = registry.counter("hist_device_quantiles").value, \
+        declined.value
     watched = ("leaf_host_gather", "leaf_fused_errors") + route
     before = {c: registry.counter(c).value for c in watched}
     sharded = _ask(monkeypatch, eng_shard, query, True, fused_kernel)
@@ -170,6 +190,10 @@ def test_engine_sharded_mirrors_bit_parity(monkeypatch, parity_engines,
         np.testing.assert_array_equal(sharded[k], want, err_msg=str(k))
     # every leaf read its shard's mirror
     assert moved["leaf_host_gather"] == 0 and moved["leaf_fused_errors"] == 0
+    # and no epilogue ran across devices: a histogram quantile said why
+    assert registry.counter("hist_device_quantiles").value == on_device
+    if fused_kernel and case == "hist-quantile":
+        assert declined.value == on_host + 1
     if fused_kernel:
         assert all(moved[c] > 0 for c in route), moved
 
